@@ -132,16 +132,13 @@ class Accelerator:
         if mixed_precision_policy is not None:
             # GradScalerKwargs/AutocastKwargs parity: explicit policy override
             self.state.mixed_precision_policy = mixed_precision_policy
-        if self.compile_plugin.cache_dir and not getattr(
-            self.state, "compile_cache_dir", None
-        ):
-            # the singleton state predates this Accelerator (built by an
-            # earlier plugin-less one): activate directly — idempotent
-            from .compilation import activate_persistent_cache
+        # the singleton state may predate this Accelerator and never have
+        # seen its plugin: activate again — idempotent
+        from .compilation import activate_persistent_cache
 
-            self.state.compile_cache_dir = activate_persistent_cache(
-                self.compile_plugin
-            )
+        self.state.compile_cache_dir = activate_persistent_cache(
+            self.compile_plugin
+        )
         if self.compile_plugin.overlap_collectives is not False:
             # collective/compute overlap (compilation/overlap.py): emit
             # the async-collective + latency-hiding-scheduler XLA options
@@ -722,6 +719,9 @@ class Accelerator:
             _fused_step if fused else _step,
             donate_argnums=donate_args,
             static_argnames=static_names or None,
+            # on the jit, not on warm()'s .compile(): the warmed and the
+            # unwarmed step are then ONE program with one cache key
+            compiler_options=self.compile_plugin.compiler_options,
         )
         # each built step fn gets its own retrace detector: two step fns
         # legitimately see different signatures without cross-talk warnings
@@ -887,7 +887,10 @@ class Accelerator:
             return new_carry, metrics
 
         donate_args = (0,) if (donate and self.compile_plugin.donate_state) else ()
-        jitted = jax.jit(_step, donate_argnums=donate_args)
+        jitted = jax.jit(
+            _step, donate_argnums=donate_args,
+            compiler_options=self.compile_plugin.compiler_options,
+        )
         tel_label = f"unified_pipeline_step#{self._built_steps}"
         self._built_steps += 1
         # every pipeline step is an optimizer step -> sync_every=1; the 1F1B
@@ -912,11 +915,13 @@ class Accelerator:
         compile-cost attribution, and the AOT warmup fast path.
 
         ``step_fn.warm(*specs, **kw)`` lowers and compiles ahead of time
-        (``CompilePlugin.compiler_options`` threaded into
-        ``.lower().compile(...)``), pre-seeds the retrace detector, and
-        registers the compiled executable; a later call whose abstract
-        signature matches dispatches straight to it — the first real step
-        neither traces nor compiles.
+        (the jit already carries ``CompilePlugin.compiler_options``),
+        pre-seeds the retrace detector, and registers the compiled
+        executable; a later call whose abstract signature matches
+        dispatches straight to it — the first real step neither traces
+        nor compiles. ``step_fn.aot_fallbacks`` counts the calls a
+        warmed executable rejected (each one a hidden retrace+compile on
+        the jit path); it also rides every step record.
         """
         from .compilation import get_compile_monitor
         from .compilation.warmup import batch_spec_of, spec_like, warm_step
@@ -968,12 +973,16 @@ class Accelerator:
                                 if k not in static_names
                             }
                             out = compiled(*args, **dyn_kw)
-                        except Exception:
-                            # donated args are consumed only on successful
-                            # dispatch, so the jitted retry sees live buffers
+                        except (TypeError, ValueError) as exc:
+                            # the executable checks avals/shardings BEFORE
+                            # dispatch, so nothing was donated and the
+                            # jitted retry sees live buffers. Never silent:
+                            # the retry is a second trace + compile
+                            step_fn.aot_fallbacks += 1
                             logger.warning(
-                                "AOT executable for %s rejected the call; "
-                                "falling back to jit dispatch", tel_label,
+                                "AOT executable for %s rejected the call "
+                                "(%s); falling back to jit dispatch",
+                                tel_label, exc,
                             )
                             aot.clear()
                             out = jitted(*args, **kw)
@@ -1012,6 +1021,7 @@ class Accelerator:
                         "microbatches": microbatches,
                         "dispatches_per_opt_step": dispatches,
                         "fused_kernels": fused_kernels,
+                        "aot_fallbacks": step_fn.aot_fallbacks,
                     },
                 )
             return out
@@ -1036,12 +1046,12 @@ class Accelerator:
                     *specs,
                     static_kwargs=static_kw,
                     traced_kwargs=traced_kw,
-                    compiler_options=self.compile_plugin.compiler_options,
                 )
             delta = mon.delta(before)
             warm_kw = dict(static_kw)
             warm_kw.update(spec_like(traced_kw))
             aot[_aot_key(specs, warm_kw)] = compiled
+            step_fn.compiled = compiled
             # the warmup path holds the Compiled in hand, so program
             # registration (memory_analysis / cost_analysis ledger +
             # roofline) is free here — no extra lowering or compile
@@ -1083,6 +1093,8 @@ class Accelerator:
             return record
 
         step_fn.jitted = jitted  # escape hatch: no host-mirror bookkeeping
+        step_fn.compiled = None  # the last warmed executable (HLO, analyses)
+        step_fn.aot_fallbacks = 0
         step_fn.warm = warm
         step_fn.label = tel_label
         return step_fn
@@ -1122,9 +1134,8 @@ class Accelerator:
     def warmup(self, step_fn: Callable, *args, **kw) -> dict:
         """Ahead-of-time compile a built step fn: derive abstract specs
         from ``args`` (carry / batch pytrees, or a prepared dataloader for
-        the batch seat), lower + compile with the plugin's
-        ``compiler_options``, and register the executable so the first
-        real step dispatches without tracing or compiling::
+        the batch seat), lower + compile, and register the executable so
+        the first real step dispatches without tracing or compiling::
 
             step = accelerator.unified_step(loss_fn)
             carry = accelerator.init_carry(params)

@@ -3,10 +3,12 @@ accelerate_tpu.benchmarks``).
 
 Three modes:
 
-* **parent** (default): detect the backend in a subprocess (never
-  initialize an exclusively-locked TPU in the parent), build the
-  registry, plan against the deadline, launch one child per process
-  group through :class:`~.runner.BenchRunner`.
+* **parent** (default): detect the backend in a subprocess (the parent
+  never imports jax — a process that touched JAX holds the chip and its
+  children would fail or hang), build the registry, plan against the
+  deadline, launch one child per process group through
+  :class:`~.runner.BenchRunner`. Without a chip only ``--fast`` (the
+  CPU harness smoke) runs; anything else exits non-zero.
 * **child** (``--child A B ... --budget S --partial-dir D``): run the
   listed members in-process under a self-enforced budget, stream
   fsync'd partial snapshots, print one JSON line per member. Explicit
@@ -40,22 +42,23 @@ from .scheduler import (
 
 
 def _detect_backend() -> str:
-    """Backend without initializing it in THIS process: on hosts where
-    the TPU is an exclusively-locked local device, a parent that touches
-    it would starve the per-variant child processes."""
+    """The JAX backend, probed in a throwaway subprocess so THIS process
+    never imports jax. A probe that cannot answer is fatal: guessing
+    would either initialize JAX here (starving every child of the chip)
+    or quietly select the wrong registry."""
     import subprocess
 
-    try:
-        probe = subprocess.run(
-            [sys.executable, "-c",
-             "import jax; print(jax.default_backend())"],
-            capture_output=True, text=True, timeout=300,
+    probe = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, timeout=300,
+    )
+    lines = probe.stdout.strip().splitlines()
+    if probe.returncode != 0 or not lines:
+        raise SystemExit(
+            f"bench: backend probe failed (rc={probe.returncode}):\n"
+            + probe.stderr[-2000:]
         )
-        return probe.stdout.strip().splitlines()[-1]
-    except Exception:  # noqa: BLE001 — fall back to in-process detection
-        import jax
-
-        return jax.default_backend()
+    return lines[-1]
 
 
 def _parser() -> argparse.ArgumentParser:
@@ -73,13 +76,26 @@ def _parser() -> argparse.ArgumentParser:
                    help="print the registry (names, priorities, groups)")
     p.add_argument("--baseline", default=None,
                    help="previous BENCH_*.json (or raw JSON-lines output) "
-                        "to stamp prev_*/regression trend fields against; "
-                        "default: the newest BENCH_*.json in the cwd")
+                        "to stamp prev_*/regression trend fields against "
+                        "(no implicit lookup)")
     p.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
     p.add_argument("--budget", type=float, default=None,
                    help=argparse.SUPPRESS)
     p.add_argument("--partial-dir", default=None, help=argparse.SUPPRESS)
     return p
+
+
+def _activate_cache() -> None:
+    """Every process that measures joins the one persistent compile cache
+    (compilation/cache.py's rule — children of one sweep and of the next
+    all resolve the same directory), persisting EVERY compile: the sweep's
+    small programs fall under JAX's default 1 s floor."""
+    from accelerate_tpu.compilation import activate_persistent_cache
+    from accelerate_tpu.utils.dataclasses import CompilePlugin
+
+    activate_persistent_cache(CompilePlugin(
+        cache_min_compile_time_secs=0.0, cache_min_entry_size_bytes=-1,
+    ))
 
 
 def _run_child(names: list[str], budget_s: Optional[float],
@@ -94,15 +110,9 @@ def _run_child(names: list[str], budget_s: Optional[float],
 
     import jax
 
-    from accelerate_tpu.compilation import activate_persistent_cache
-    from accelerate_tpu.utils.dataclasses import CompilePlugin
-
     from .measure import result_line
 
-    # join the cache dir the parent exported (covers the decode/
-    # generation variants too, which never build an Accelerator — the
-    # training path would also pick the env var up through CompilePlugin)
-    activate_persistent_cache(CompilePlugin())  # no-op when env unset
+    _activate_cache()
     on_tpu = jax.default_backend() == "tpu"
     registry = build_registry(on_tpu)
     estimates = Estimates().load()
@@ -154,14 +164,11 @@ def _run_child(names: list[str], budget_s: Optional[float],
 def _run_direct(names: list[str]) -> int:
     """Historical interface: run the named variants in-process and print
     their lines (``python bench.py accum``)."""
-    from accelerate_tpu.compilation import activate_persistent_cache
-    from accelerate_tpu.utils.dataclasses import CompilePlugin
+    import jax
 
     from .measure import result_line
 
-    import jax
-
-    activate_persistent_cache(CompilePlugin())
+    _activate_cache()
     registry = build_registry(jax.default_backend() == "tpu")
     partial_dir = os.environ.get(ENV_PARTIAL_DIR)
     for name in names:
@@ -180,7 +187,17 @@ def main(argv: Optional[list[str]] = None) -> int:
     if args.child:
         return _run_child(args.variants, args.budget, args.partial_dir)
 
-    on_tpu = _detect_backend() == "tpu"
+    backend = _detect_backend()
+    on_tpu = backend == "tpu"
+    if not on_tpu and not args.fast:
+        # the CPU-tiny registry exists for the harness smoke only; a
+        # measurement run that finds no chip fails, it does not fall back
+        print(
+            f"bench: JAX backend is {backend!r}, not 'tpu' — refusing to "
+            "run the benchmark off-chip (use --fast for the CPU harness "
+            "smoke)", file=sys.stderr,
+        )
+        return 2
     registry = build_registry(on_tpu)
     try:
         registry = registry.select(
@@ -204,20 +221,6 @@ def main(argv: Optional[list[str]] = None) -> int:
         # bare names, no scheduling flags: the historical in-process path
         return _run_direct(args.variants)
 
-    # One persistent XLA cache dir shared by every variant child (they
-    # inherit the env; CompilePlugin reads it). The variants share model
-    # shapes across retries and the longseq/longseq4k pairs, so repeated
-    # programs deserialize instead of recompiling — the rc=124 driver
-    # timeouts that erased BENCH_r05 were mostly serial compile time.
-    # Children run SERIALLY, so sharing is safe (concurrent writers to
-    # one cache dir deadlocked in a past parallel-pytest measurement —
-    # do not copy this pattern into parallel workers).
-    os.environ.setdefault(
-        "ACCELERATE_TPU_COMPILE_CACHE",
-        os.path.join(tempfile.gettempdir(),
-                     "accelerate_tpu_bench_xla_cache"),
-    )
-
     deadline = Deadline.from_env(args.deadline)
     estimates = Estimates().load()
     scheduler = DeadlineScheduler(
@@ -231,8 +234,6 @@ def main(argv: Optional[list[str]] = None) -> int:
         registry, scheduler, estimates,
         SubprocessLauncher(partial_dir),
         partial_dir=partial_dir,
-        settle_s=60.0 if on_tpu else 5.0,
-        on_tpu=on_tpu,
         baseline=load_baseline(args.baseline),
     )
     return runner.run()

@@ -23,7 +23,6 @@ from __future__ import annotations
 import json
 import math
 import os
-import tempfile
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Iterable, Optional, Sequence
@@ -78,14 +77,9 @@ class Estimates:
 
     @staticmethod
     def default_path() -> str:
-        cache = os.environ.get("ACCELERATE_TPU_COMPILE_CACHE")
-        if not cache:
-            from ..compilation import persistent_cache_dir
+        from ..compilation.cache import resolve_cache_dir
 
-            cache = persistent_cache_dir() or os.path.join(
-                tempfile.gettempdir(), "accelerate_tpu_bench_xla_cache"
-            )
-        return os.path.abspath(cache) + ".estimates.json"
+        return os.path.abspath(resolve_cache_dir()) + ".estimates.json"
 
     def load(self) -> "Estimates":
         try:

@@ -14,9 +14,10 @@ import argparse
 import json
 from typing import Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
+
+# jax is imported inside the functions: the CLI module that also hosts
+# `launch` (a parent of chip children) imports this parser
 
 
 def _human(n: float) -> str:
@@ -41,6 +42,8 @@ def estimate_activation_bytes(
     counted separately: at large vocab they dominate and remat cannot
     remove them.
     """
+    import jax.numpy as jnp
+
     h, f, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
     qkv = (cfg.num_heads + 2 * cfg.num_kv_heads) * cfg.head_dim
     itemsize = jnp.dtype(dtype).itemsize
@@ -64,6 +67,9 @@ def estimate_from_config(preset_or_json: str, dtype: str = "bfloat16",
                          grad_accum: bool = False, batch_size: int = 8,
                          seq_len: int = 2048,
                          remat: Optional[str] = "dots") -> dict:
+    import jax
+    import jax.numpy as jnp
+
     from ..models import TransformerConfig, causal_model_for
 
     presets = {

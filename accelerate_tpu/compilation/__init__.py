@@ -8,8 +8,9 @@ JAX's persistent compilation cache) treat compile as a cached, warmed,
 measured resource. This package gives the Accelerator the same three
 levers:
 
-* :mod:`cache` — activate JAX's persistent compilation cache from
-  ``CompilePlugin.cache_dir`` (env: ``ACCELERATE_TPU_COMPILE_CACHE``),
+* :mod:`cache` — the one rule for where JAX's persistent compilation
+  cache lives (``JAX_COMPILATION_CACHE_DIR``, else
+  ``CompilePlugin.cache_dir``, else ``<checkout>/.jax_compile_cache``),
   so identical programs compile once per *cache*, not once per process;
 * :mod:`monitor` — attribute compile cost: per-step-fn compile seconds
   and persistent-cache hit/miss counts, collected from
@@ -25,27 +26,35 @@ levers:
   ``overlap_pct`` telemetry field.
 """
 
-from .cache import (
-    activate_persistent_cache,
-    persistent_cache_dir,
-    persistent_cache_entries,
-)
-from .monitor import CompileMonitor, get_compile_monitor
-from .overlap import (
-    DEFAULT_OVERLAP_OPTIONS,
-    assert_overlap,
-    collective_compute_overlap,
-    merge_compiler_options,
-    overlap_from_spans,
-    overlap_options,
-    top_self_time_ops,
-)
-from .warmup import batch_spec_of, spec_like, warm_step
+from .._lazy import lazy_exports
+
+# name -> submodule, imported on first access: importing this package
+# must not import jax (a parent that spawns chip children stays off it)
+_EXPORTS = {
+    "activate_persistent_cache": ".cache",
+    "persistent_cache_dir": ".cache",
+    "persistent_cache_entries": ".cache",
+    "resolve_cache_dir": ".cache",
+    "CompileMonitor": ".monitor",
+    "get_compile_monitor": ".monitor",
+    "DEFAULT_OVERLAP_OPTIONS": ".overlap",
+    "assert_overlap": ".overlap",
+    "collective_compute_overlap": ".overlap",
+    "merge_compiler_options": ".overlap",
+    "overlap_from_spans": ".overlap",
+    "overlap_options": ".overlap",
+    "top_self_time_ops": ".overlap",
+    "batch_spec_of": ".warmup",
+    "spec_like": ".warmup",
+    "warm_step": ".warmup",
+}
+__getattr__, __dir__ = lazy_exports(__name__, _EXPORTS)
 
 __all__ = [
     "activate_persistent_cache",
     "persistent_cache_dir",
     "persistent_cache_entries",
+    "resolve_cache_dir",
     "CompileMonitor",
     "get_compile_monitor",
     "DEFAULT_OVERLAP_OPTIONS",

@@ -1,17 +1,22 @@
-"""Persistent XLA compilation cache activation.
+"""Persistent XLA compilation cache: one rule for where it lives.
 
 JAX ships a content-addressed on-disk compilation cache (keyed on the
 optimized HLO + compile options + backend version); pointing every
-process of a run — and every *variant* of a bench sweep — at one
-directory turns the second-and-later compiles of an identical program
-into a fast deserialize. This module is the single place that translates
-:class:`~accelerate_tpu.utils.dataclasses.CompilePlugin` knobs into the
-``jax.config`` flags that implement it.
+process of a run at one directory turns the second-and-later compiles of
+an identical program into a fast deserialize. The directory is part of
+nothing's key but must not move, or nothing ever hits — so it is resolved
+in exactly one place, :func:`resolve_cache_dir`:
 
-Activation is idempotent and happens at ``AcceleratorState`` init (the
-same once-per-process seat that builds the mesh); scripts that never
-construct an Accelerator can call :func:`activate_persistent_cache`
-directly.
+1. ``JAX_COMPILATION_CACHE_DIR`` set: JAX itself already uses that
+   directory and this package sets no other (an explicit
+   ``CompilePlugin.cache_dir`` is ignored with one log line);
+2. else an explicit ``CompilePlugin.cache_dir``;
+3. else ``<checkout>/.jax_compile_cache`` — a fixed path beside the
+   package, never one built from ``tempfile``, a pid or the time.
+
+:func:`activate_persistent_cache` applies the rule and is what
+``AcceleratorState``, ``ServingEngine``, the bench children, the graft
+entry and ``tests/conftest.py`` all call. It is idempotent.
 """
 
 from __future__ import annotations
@@ -20,89 +25,88 @@ import os
 import threading
 from typing import Any, Optional
 
-import jax
-
 from ..logging import get_logger
 
 logger = get_logger(__name__)
 
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+#: the fixed default: beside the package, i.e. the root of the checkout
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_compile_cache",
+)
+
 _lock = threading.Lock()
 _active_dir: Optional[str] = None
+_warned_ignored = False
 
 
-def _set_flag(name: str, value: Any) -> bool:
-    """jax.config.update that tolerates flags missing on older/newer jax
-    (the knob is then advisory): returns True when the flag stuck."""
-    try:
-        jax.config.update(name, value)
-        return True
-    except (AttributeError, KeyError, ValueError) as exc:
-        logger.warning("compile-cache knob %s=%r not applied: %s", name, value, exc)
-        return False
+def resolve_cache_dir(plugin: Any = None) -> str:
+    """Where the persistent cache lives for this process (see module
+    docstring for the rule). Pure: touches neither JAX nor the disk (the
+    bench parent, which must not import jax, calls it too)."""
+    global _warned_ignored
+    explicit = getattr(plugin, "cache_dir", None)
+    env = os.environ.get(ENV_JAX_CACHE_DIR)
+    if env:
+        if explicit and not _warned_ignored:
+            _warned_ignored = True
+            logger.warning(
+                "%s=%s is set: CompilePlugin.cache_dir=%s is ignored",
+                ENV_JAX_CACHE_DIR, env, explicit,
+            )
+        return env
+    if explicit:
+        return os.path.abspath(os.path.expanduser(str(explicit)))
+    return DEFAULT_CACHE_DIR
 
 
-def activate_persistent_cache(plugin: Any = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``plugin.cache_dir``.
+def activate_persistent_cache(plugin: Any = None) -> str:
+    """Point JAX's persistent compilation cache at the resolved directory
+    and apply the plugin's persistence knobs. Re-activation with the same
+    directory is free; switching directories mid-process resets JAX's
+    in-memory handle so the new location takes effect.
 
-    No-op (returns None) when the plugin carries no cache dir — the env
-    fallback ``ACCELERATE_TPU_COMPILE_CACHE`` is applied by
-    ``CompilePlugin.__post_init__``, so exporting that variable is enough
-    to turn the cache on for an unmodified script. Re-activation with the
-    same directory is free; switching directories mid-process resets
-    JAX's in-memory handle so the new location takes effect.
-
-    Returns the resolved absolute cache directory (created if missing).
+    Returns the cache directory.
     """
     global _active_dir
-    if plugin is None or not getattr(plugin, "cache_dir", None):
-        return None
-    path = os.path.abspath(os.path.expanduser(str(plugin.cache_dir)))
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    path = resolve_cache_dir(plugin)
     with _lock:
         os.makedirs(path, exist_ok=True)
         # The previously active dir may have been configured OUTSIDE this
-        # module (e.g. a conftest calling jax.config directly) — JAX's
-        # lazily-initialized in-memory cache handle stays bound to it, so
-        # detect the switch from the config value, not just our own state.
-        prev = _active_dir
-        if prev is None:
-            try:
-                prev = jax.config.jax_compilation_cache_dir
-            except AttributeError:
-                prev = None
-        switched = bool(prev) and prev != path
-        _set_flag("jax_enable_compilation_cache", True)
-        _set_flag("jax_compilation_cache_dir", path)
-        # Persistence floors: JAX's defaults (1s compile floor) are tuned
-        # for giant TPU programs; a bench sweep of small programs wants
-        # every compile persisted. None leaves JAX's default untouched.
+        # module (JAX reading the env var, a direct jax.config call) —
+        # JAX's lazily-initialized cache handle stays bound to it, so
+        # detect the switch from the config value, not our own state.
+        prev = jax.config.jax_compilation_cache_dir
+        if prev != path:
+            jax.config.update("jax_compilation_cache_dir", path)
+            if prev:
+                compilation_cache.reset_cache()
+        # Persistence floors: JAX's defaults (1 s compile, 4 KiB entry)
+        # suit an always-on cache; None leaves them (or whatever the
+        # caller configured) untouched.
         if getattr(plugin, "cache_min_compile_time_secs", None) is not None:
-            _set_flag(
+            jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs",
                 float(plugin.cache_min_compile_time_secs),
             )
         if getattr(plugin, "cache_min_entry_size_bytes", None) is not None:
-            _set_flag(
+            jax.config.update(
                 "jax_persistent_cache_min_entry_size_bytes",
                 int(plugin.cache_min_entry_size_bytes),
             )
         # Cache-key knobs: fold the per-backend XLA autotune/kernel caches
         # into the same dir, and (diagnostics) log why a lookup missed.
         if getattr(plugin, "cache_enable_xla_caches", None) is not None:
-            _set_flag(
+            jax.config.update(
                 "jax_persistent_cache_enable_xla_caches",
                 str(plugin.cache_enable_xla_caches),
             )
         if getattr(plugin, "explain_cache_misses", None):
-            _set_flag("jax_explain_cache_misses", True)
-        if switched:
-            try:
-                from jax.experimental.compilation_cache import (
-                    compilation_cache as cc,
-                )
-
-                cc.reset_cache()
-            except Exception as exc:  # pragma: no cover - version drift
-                logger.warning("compilation cache reset failed: %s", exc)
+            jax.config.update("jax_explain_cache_misses", True)
         if _active_dir != path:
             logger.info("persistent XLA compilation cache: %s", path)
         _active_dir = path
@@ -110,7 +114,7 @@ def activate_persistent_cache(plugin: Any = None) -> Optional[str]:
 
 
 def persistent_cache_dir() -> Optional[str]:
-    """The directory activated this process (None when inactive)."""
+    """The directory activated this process (None before activation)."""
     return _active_dir
 
 

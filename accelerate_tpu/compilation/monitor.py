@@ -23,10 +23,6 @@ import threading
 from contextlib import contextmanager
 from typing import Any, Optional
 
-from ..logging import get_logger
-
-logger = get_logger(__name__)
-
 # jax.monitoring event name -> our counter key (counts)
 _COUNT_EVENTS = {
     "/jax/compilation_cache/cache_hits": "persistent_cache_hits",
@@ -67,13 +63,8 @@ class CompileMonitor:
         with self._lock:
             if self._installed:
                 return self
-            try:
-                from jax import monitoring
-            except ImportError:  # pragma: no cover - ancient jax
-                logger.warning("jax.monitoring unavailable; compile "
-                               "attribution disabled")
-                self._installed = True
-                return self
+            from jax import monitoring
+
             monitoring.register_event_listener(self._on_event)
             monitoring.register_event_duration_secs_listener(self._on_duration)
             self._installed = True

@@ -6,7 +6,7 @@ the prepared dataloader pads every batch to one fixed shape, and jit
 only needs *abstract* values to lower — so the compile can start from
 ``ShapeDtypeStruct`` specs while the host is still reading data.
 
-:func:`warm_step` drives ``jitted.lower(*specs).compile(options)`` and
+:func:`warm_step` drives ``jitted.lower(*specs).compile()`` and
 returns the compiled executable plus timing; the Accelerator wires it as
 ``step_fn.warm(...)`` / ``accelerator.warmup(...)`` and routes matching
 real calls straight to the compiled executable (true AOT dispatch: the
@@ -77,15 +77,15 @@ def warm_step(
     *arg_specs: Any,
     static_kwargs: Optional[dict] = None,
     traced_kwargs: Optional[dict] = None,
-    compiler_options: Optional[dict] = None,
 ) -> tuple[Any, float]:
     """Lower and compile ``jitted`` from abstract specs.
 
     ``static_kwargs`` are keyword arguments declared static on the jit
     (passed concrete — they select the program); ``traced_kwargs`` are
     ordinary traced keywords (abstracted via :func:`spec_like`).
-    ``compiler_options`` goes verbatim into ``.lower().compile(...)`` —
-    the ``CompilePlugin.compiler_options`` seat.
+    Compiler options belong on the ``jax.jit`` itself (where
+    ``unified_step`` puts ``CompilePlugin.compiler_options``), so this
+    compile and a plain call of ``jitted`` share one cache key.
 
     Returns ``(compiled, seconds)`` where ``seconds`` is the wall time
     of lower+compile (with the persistent cache warm this is mostly
@@ -96,6 +96,6 @@ def warm_step(
     specs = tuple(spec_like(a) for a in arg_specs)
     t0 = time.perf_counter()
     lowered = jitted.lower(*specs, **kwargs)
-    compiled = lowered.compile(compiler_options=compiler_options)
+    compiled = lowered.compile()
     seconds = time.perf_counter() - t0
     return compiled, seconds

@@ -509,12 +509,9 @@ class DataLoaderShard(DataLoaderStateMixin):
                         x.shape[0],
                         x.shape[1] * num_processes,
                     ) + x.shape[2:]
-                    try:
-                        return jax.make_array_from_process_local_data(
-                            sharding, x, global_shape
-                        )
-                    except TypeError:  # older jax: no global_shape arg
-                        return jax.make_array_from_process_local_data(sharding, x)
+                    return jax.make_array_from_process_local_data(
+                        sharding, x, global_shape
+                    )
                 return jax.device_put(x, sharding)
             if num_processes > 1:
                 return jax.make_array_from_process_local_data(sharding, x)

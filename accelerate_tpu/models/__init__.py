@@ -7,10 +7,19 @@ names onto the device mesh, so the same model definition runs pure-DP, FSDP,
 TP, SP or EP without edits — the whole point of the GSPMD redesign.
 """
 
-from .config import TransformerConfig
-from .gpt2 import GPT2LM
-from .seq2seq import Seq2SeqLM
-from .transformer import CausalLM, SequenceClassifier, count_params
+from .._lazy import lazy_exports
+
+# name -> submodule, imported on first access: importing this package
+# must not import jax (a parent that spawns chip children stays off it)
+_EXPORTS = {
+    "TransformerConfig": ".config",
+    "GPT2LM": ".gpt2",
+    "Seq2SeqLM": ".seq2seq",
+    "CausalLM": ".transformer",
+    "SequenceClassifier": ".transformer",
+    "count_params": ".transformer",
+}
+__getattr__, __dir__ = lazy_exports(__name__, _EXPORTS)
 
 __all__ = [
     "TransformerConfig",
@@ -23,8 +32,14 @@ __all__ = [
 ]
 
 
-def causal_model_for(config: TransformerConfig):
+def causal_model_for(config: "TransformerConfig"):
     """The decoder-LM module class instance matching ``config.arch`` —
     lets arch-agnostic call sites (examples, estimate-memory, interop
     tests) mirror the reference's AutoModel dispatch."""
-    return GPT2LM(config) if config.arch == "gpt2" else CausalLM(config)
+    if config.arch == "gpt2":
+        from .gpt2 import GPT2LM
+
+        return GPT2LM(config)
+    from .transformer import CausalLM
+
+    return CausalLM(config)

@@ -124,8 +124,7 @@ class TransformerConfig:
     # MoE (Mixtral family); 0 experts = dense MLP
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    # "auto" (default): "ragged" at every ep (falls back to "capacity"
-    # only on jax versions without partial-manual shard_map). "ragged":
+    # "auto" (default): "ragged" at every ep. "ragged":
     # grouped-matmul dispatch (jax.lax.ragged_dot) — exact math at ep==1
     # (no padding, no drops), measured FASTER than capacity at bench
     # shapes (ops/moe.py docstring numbers); under ep>1 it runs the

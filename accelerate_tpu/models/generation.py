@@ -49,15 +49,18 @@ def _sample_logits(logits, key, temperature, top_k, top_p):
     return jax.random.categorical(key, logits, axis=-1)
 
 
-def init_cache(model_init, *init_args, **init_kwargs):
+def init_cache(model_init, *init_args, device=None, **init_kwargs):
     """Zeroed decode-cache template via eval_shape: a full ``model.init``
     here would materialize (and randomly initialize) an entire spare
     parameter tree just to learn the cache shapes — pure HBM/time waste at
-    8B+ scale. Shared by CausalLM and Seq2SeqLM generation."""
+    8B+ scale. Shared by CausalLM and Seq2SeqLM generation. ``device``
+    allocates the zeros there (None: the default device, uncommitted)."""
     cache_shapes = jax.eval_shape(
         lambda: model_init(*init_args, **init_kwargs)["cache"]
     )
-    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), cache_shapes)
+    return jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype, device=device), cache_shapes
+    )
 
 
 def generate(

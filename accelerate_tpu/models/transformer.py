@@ -594,14 +594,8 @@ class MoE(nn.Module):
             # axes — at equal capacity_factor it drops 3-10x fewer tokens
             # under skewed routing and its compiled step moves ~2x fewer
             # collective bytes (dp=2 x ep=4 mesh; numbers in
-            # moe_ragged_ep's docstring). capacity remains only for jax
-            # versions without partial-manual shard_map.
-            from ..ops.moe import ragged_ep_supported
-
-            dispatch = (
-                "capacity" if ep_live and not ragged_ep_supported()
-                else "ragged"
-            )
+            # moe_ragged_ep's docstring).
+            dispatch = "ragged"
         if dispatch == "ragged":
             from ..ops.moe import moe_ragged, moe_ragged_ep
 

@@ -27,34 +27,44 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+
+# jit-cache-sensitive: a function traced inside the context is never
+# served to a call outside it (or the reverse)
+_INTERPRET = jax.make_user_context(False)
 
 
 @contextlib.contextmanager
 def kernel_interpret_mode():
-    """Run the Pallas TPU kernels under the interpreter — same kernel
-    code, exact semantics — so CPU CI covers them without a chip. No-op
-    on a real TPU backend. Newer pallas exposes a process-wide switch
-    (``force_tpu_interpret_mode``); older pallas only has the per-call
-    ``interpret`` flag, flipped here for the duration of the context."""
+    """Run the Pallas kernels of ``ops/`` under the Pallas interpreter —
+    same kernel code, exact semantics — so CPU tests cover them without a
+    chip. This context is the ONLY thing that turns interpretation on:
+    outside it every ``pallas_call`` is lowered for Mosaic, and a machine
+    without a TPU fails instead of quietly interpreting. No-op on a real
+    TPU backend (the kernels compile)."""
     if jax.default_backend() == "tpu":
         yield
         return
-    if hasattr(pltpu, "force_tpu_interpret_mode"):
-        with pltpu.force_tpu_interpret_mode():
-            yield
-        return
-    real = pl.pallas_call
-    pl.pallas_call = functools.partial(real, interpret=True)
-    try:
+    with _INTERPRET(True):
         yield
-    finally:
-        pl.pallas_call = real
+
+
+def kernels_interpreted() -> bool:
+    """Whether the caller is inside :func:`kernel_interpret_mode`: the
+    ``interpret=`` of every ``pallas_call`` in ``ops/``, and the reason
+    shape gates relax the Mosaic tiling rules there. ``chip_smoke.py``
+    asserts it is False."""
+    return bool(_INTERPRET.value)
+
 
 # Measured on v5e at (B8, S1024, H32/8, D128) fwd+bwd: 1024/1024 runs ~15%
 # faster than 512/512 (fewer grid steps, better MXU occupancy); the
@@ -259,6 +269,7 @@ def _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window):
             scratch_shapes=scratch_shapes,
         ),
         out_shape=out_shape,
+        interpret=kernels_interpreted(),
     )(*(((lengths,) if padded else ()) + (q, k, v)))
     return out, lse
 
@@ -532,6 +543,7 @@ def _bwd_fused(scale, causal, bq, bk, window, prefix, q, k, v, dout, lse,
             scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
         ),
         out_shape=out_shape,
+        interpret=kernels_interpreted(),
     )(*(prefix + (q, k, v, dout, lse, delta)))
     if g > 1:  # sum the GQA group partials back onto the kv heads
         dk = dkh.reshape(B, Hkv, g, Skv, D).sum(2).astype(k.dtype)
@@ -591,6 +603,7 @@ def _bwd(scale, causal, block_q, block_k, window, res, dout):
             scratch_shapes=dq_scratch,
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=kernels_interpreted(),
     )(*(prefix + (q, k, v, dout, lse, delta)))
 
     dkv_in_specs = [
@@ -627,6 +640,7 @@ def _bwd(scale, causal, block_q, block_k, window, res, dout):
             scratch_shapes=dkv_scratch,
         ),
         out_shape=dkv_out_shape,
+        interpret=kernels_interpreted(),
     )(*(prefix + (q, k, v, dout, lse, delta)))
     return dq, dk, dv, None
 
@@ -688,14 +702,12 @@ def flash_attention(
         window = int(window)
         if window <= 0:
             raise ValueError(f"window must be positive, got {window}")
-    # (B,S,H,D) -> (B,H,S,D)
-    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    bq = fit_block(qt.shape[2], block_q)
-    bk = fit_block(kt.shape[2], block_k)
+    bq = fit_block(q.shape[1], block_q)
+    bk = fit_block(k.shape[1], block_k)
     if bq is None or bk is None:
         raise ValueError(
             f"flash_attention needs seq divisible by a block size >= "
-            f"{MIN_BLOCK}: q seq {qt.shape[2]}, kv seq {kt.shape[2]}"
+            f"{MIN_BLOCK}: q seq {q.shape[1]}, kv seq {k.shape[1]}"
         )
     if kv_lengths is not None:
         if kv_lengths.shape != (q.shape[0],):
@@ -704,5 +716,61 @@ def flash_attention(
                 f"{kv_lengths.shape}"
             )
         kv_lengths = kv_lengths.astype(jnp.int32)
-    out = _flash(qt, kt, vt, kv_lengths, scale, causal, bq, bk, window)
-    return jnp.swapaxes(out, 1, 2)
+
+    def local(q, k, v, lengths):
+        # (B,S,H,D) -> (B,H,S,D)
+        qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+        out = _flash(qt, kt, vt, lengths, scale, causal, bq, bk, window)
+        return jnp.swapaxes(out, 1, 2)
+
+    return _over_mesh(local, q, k, v, kv_lengths)
+
+
+def _over_mesh(local, q, k, v, kv_lengths):
+    """Run the per-device kernel body over the live mesh. A Mosaic kernel
+    cannot be partitioned by GSPMD ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"): on any mesh of more
+    than one device the kernel runs inside a ``shard_map`` — batch over the
+    data axes, heads over ``tp``, each device on its own rows and heads
+    (attention never mixes either). Whatever cannot tile an axis (the
+    batch-1 init probe) is replicated instead; the sequence is never
+    split here — that is ring attention's job."""
+    from ..parallel.mesh import data_axes
+    from ..parallel.sharding import live_mesh
+    from ..utils.constants import MESH_AXIS_TENSOR
+
+    mesh = live_mesh()
+    if mesh is None:
+        return local(q, k, v, kv_lengths)
+    from ..utils.operations import nested_manual_mesh
+
+    # inside a pipeline stage (pp already Manual) the nested shard_map is
+    # built on the context mesh and manualizes the remaining axes; see
+    # ops/ring_attention.py for why check_vma is on exactly there
+    ctx = nested_manual_mesh()
+    sm_mesh = ctx if ctx is not None else mesh
+    manual = set() if ctx is None else {
+        name for name, kind in zip(ctx.axis_names, ctx.axis_types)
+        if kind == jax.sharding.AxisType.Manual
+    }
+    batch_axes = tuple(a for a in data_axes(mesh) if a not in manual)
+    if q.shape[0] % math.prod(mesh.shape[a] for a in batch_axes):
+        batch_axes = ()
+    tp = mesh.shape[MESH_AXIS_TENSOR]
+    heads = (
+        MESH_AXIS_TENSOR
+        if MESH_AXIS_TENSOR not in manual and tp > 1
+        and q.shape[2] % tp == 0 and k.shape[2] % tp == 0
+        else None
+    )
+    spec = P(batch_axes or None, None, heads, None)
+    args, in_specs = (q, k, v), (spec, spec, spec)
+    body = lambda q, k, v: local(q, k, v, None)
+    if kv_lengths is not None:
+        args, in_specs = args + (kv_lengths,), in_specs + (P(batch_axes or None),)
+        body = local
+    return shard_map(
+        body, mesh=sm_mesh, in_specs=in_specs, out_specs=spec,
+        axis_names=set(mesh.axis_names) - manual,
+        check_vma=ctx is not None,
+    )(*args)

@@ -27,9 +27,9 @@ stays outside (global_norm's reduction order must not change), as does
 the ZeRO shard pin (``_pin_to_shardings`` — a sharding constraint, not
 arithmetic).
 
-CPU fallback semantics: every ``pallas_call`` here takes
-``interpret=jax.default_backend() != "tpu"`` by default, so the same
-kernels run (slowly, exactly) on CPU CI — no separate code path.
+No CPU fallback: every ``pallas_call`` here is lowered for Mosaic. CPU
+tests run the same kernels under
+``ops.flash_attention.kernel_interpret_mode()``.
 """
 
 from __future__ import annotations
@@ -45,7 +45,7 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import MIN_BLOCK, fit_block
+from .flash_attention import MIN_BLOCK, fit_block, kernels_interpreted
 
 __all__ = [
     "fused_qkv_prologue",
@@ -60,12 +60,9 @@ __all__ = [
 ]
 
 LANES = 128  # TPU vector lane width — minor-dim tile granularity
-
-
-def _default_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is not None:
-        return bool(interpret)
-    return jax.default_backend() != "tpu"
+# Mosaic's default scoped-VMEM limit is 16 MiB (v5e); tiles are sized to
+# leave headroom for the compiler's own temporaries
+VMEM_TILE_BUDGET = 14 * 2**20
 
 
 # ---------------------------------------------------------------------- #
@@ -158,16 +155,53 @@ def prologue_reference(
     )
 
 
-def _col_block(num_heads: int, num_kv_heads: int, head_dim: int) -> int:
-    """Widest weight-column tile <= 512 that is a whole number of heads
-    AND divides both the q and k/v column spans — so no tile straddles
-    the q/k/v boundaries and the rope predicate is uniform per tile."""
+def _col_blocks(num_heads: int, num_kv_heads: int, head_dim: int) -> list[int]:
+    """Candidate weight-column tiles, widest first: each is <= 512, a
+    whole number of heads, and divides both the q and k/v column spans —
+    so no tile straddles the q/k/v boundaries and the rope predicate is
+    uniform per tile."""
     g = math.gcd(num_heads, num_kv_heads)
-    best = head_dim
-    for m in range(1, g + 1):
-        if g % m == 0 and m * head_dim <= 512:
-            best = m * head_dim
-    return best
+    return sorted(
+        (m * head_dim for m in range(1, g + 1)
+         if g % m == 0 and m * head_dim <= 512),
+        reverse=True,
+    ) or [head_dim]
+
+
+def _prologue_vmem_bytes(br, c, hidden, head_dim, x_bytes, w_bytes, out_bytes):
+    """Scoped VMEM one grid step needs: Pallas double-buffers every
+    in/out tile, and the body holds an f32 copy of the x tile plus a
+    handful of (br, c) f32 temporaries (acc, rotated, roped, select)."""
+    pipelined = 2 * (
+        br * hidden * x_bytes + hidden * c * w_bytes + br * c * out_bytes
+        + 2 * br * head_dim * 4 + hidden * 4
+    )
+    body = br * hidden * 4 + 4 * br * c * 4
+    return pipelined + body
+
+
+def _prologue_tiles(
+    rows, hidden, num_heads, num_kv_heads, head_dim,
+    x_bytes, w_bytes, out_bytes,
+) -> tuple[int, int]:
+    """(row block, column block) chosen from the shape so the step fits
+    scoped VMEM: the widest column tile that fits at the preferred 256-row
+    block, halving the row block only when no column tile does. With fp32
+    master weights at hidden 4096 this lands on (256, 128); the historical
+    fixed (256, 512) tile needed 20.5 MiB of Mosaic's 16."""
+    br = fit_block(rows, 256)
+    while br is not None:
+        for c in _col_blocks(num_heads, num_kv_heads, head_dim):
+            need = _prologue_vmem_bytes(
+                br, c, hidden, head_dim, x_bytes, w_bytes, out_bytes
+            )
+            if need <= VMEM_TILE_BUDGET:
+                return br, c
+        br = fit_block(rows, br // 2) if br > MIN_BLOCK else None
+    raise ValueError(
+        f"fused prologue: no (row, column) tile of a ({rows}, {hidden}) "
+        f"activation fits {VMEM_TILE_BUDGET} bytes of VMEM"
+    )
 
 
 def prologue_supported(
@@ -177,36 +211,33 @@ def prologue_supported(
     batch: int,
     seq: int,
     hidden: int,
-    interpret: Optional[bool] = None,
 ) -> bool:
-    """Shape gate for the fused prologue. Callers fall back to the
-    unfused module chain when False — correctness never depends on the
-    kernel being available."""
+    """Alignment gate for the fused prologue: shapes Mosaic cannot tile at
+    all take the unfused module chain."""
     if head_dim % 2:
         return False  # rope pairs i with i + D/2
     rows = batch * seq
     if fit_block(rows, 256) is None:
         return False
-    if _default_interpret(interpret):
-        return True  # interpreter has no tiling constraints
-    # Real TPU Mosaic: respect (8, 128) f32 tile granularity on every
-    # block minor dim — hidden (x / weight rows), head_dim (cos/sin and
-    # the in-tile head reshape), and the column tile.
-    c = _col_block(num_heads, num_kv_heads, head_dim)
-    return hidden % LANES == 0 and head_dim % LANES == 0 and c % LANES == 0
+    if kernels_interpreted():
+        return True  # the interpreter has no tiling constraints
+    # Mosaic: (8, 128) tile granularity on every block minor dim — hidden
+    # (x / weight rows) and head_dim (cos/sin, the in-tile head reshape,
+    # and every column tile, a whole number of heads)
+    return hidden % LANES == 0 and head_dim % LANES == 0
 
 
 def _prologue_call(
     x2d, scale, wqkv, bqkv, cosd, sind,
-    *, eps: float, norm_offset: bool, head_dim: int, col_block: int,
-    rope_cols: int, dtype, interpret: bool,
+    *, eps: float, norm_offset: bool, head_dim: int, row_block: int,
+    col_block: int, rope_cols: int, dtype,
 ):
     """One pallas_call over the flattened (rows, E) activations and the
     concatenated (E, W) qkv weight. Grid (rows/br, W/c), col-minor — the
     x tile stays resident across the j sweep."""
     rows, hidden = x2d.shape
     width = wqkv.shape[1]
-    br = fit_block(rows, 256)
+    br = row_block
     c = col_block
     d = head_dim
     has_bias = bqkv is not None
@@ -268,7 +299,7 @@ def _prologue_call(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, c), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, width), dtype),
-        interpret=interpret,
+        interpret=kernels_interpreted(),
     )(*operands)
 
 
@@ -291,7 +322,7 @@ def fused_qkv_prologue(
     *, eps: float, norm_offset: bool,
     num_heads: int, num_kv_heads: int, head_dim: int,
     theta: float, scaling: Optional[dict] = None,
-    dtype=jnp.float32, interpret: Optional[bool] = None,
+    dtype=jnp.float32,
 ):
     """Fused RMSNorm -> QKV -> rope -> head split.
 
@@ -302,14 +333,16 @@ def fused_qkv_prologue(
     in fp32. Backward is ``jax.vjp`` of ``prologue_reference`` (the
     flash_attention FUSED_BWD precedent: XLA's backward beats a hand
     kernel here, and the reference IS the parity definition)."""
-    interp = _default_interpret(interpret)
     b, s, hidden = x.shape
     d = head_dim
     rows = b * s
     q_cols = num_heads * d
     kv_cols = num_kv_heads * d
     rope_cols = q_cols + kv_cols  # q and k rotate; v passes through
-    col_block = _col_block(num_heads, num_kv_heads, d)
+    row_block, col_block = _prologue_tiles(
+        rows, hidden, num_heads, num_kv_heads, d,
+        x.dtype.itemsize, wq.dtype.itemsize, jnp.dtype(dtype).itemsize,
+    )
     statics = dict(
         eps=eps, norm_offset=norm_offset, num_heads=num_heads,
         num_kv_heads=num_kv_heads, head_dim=d, dtype=dtype,
@@ -331,8 +364,8 @@ def fused_qkv_prologue(
         out = _prologue_call(
             x2d, scale, wqkv, bqkv, cosd, sind,
             eps=eps, norm_offset=norm_offset, head_dim=d,
-            col_block=col_block, rope_cols=rope_cols, dtype=dtype,
-            interpret=interp,
+            row_block=row_block, col_block=col_block, rope_cols=rope_cols,
+            dtype=dtype,
         )
         q = out[:, :q_cols].reshape(b, s, num_heads, d)
         k = out[:, q_cols:rope_cols].reshape(b, s, num_kv_heads, d)
@@ -403,7 +436,7 @@ def fused_adamw(
 
 def _adamw_leaf_kernel(
     g, p, mu, nu, scalars,
-    *, b1, b2, eps, eps_root, weight_decay, interpret,
+    *, b1, b2, eps, eps_root, weight_decay,
 ):
     """One elementwise kernel for a single leaf: adam moment update ->
     bias correction -> weight decay -> lr scale -> apply -> finite hold.
@@ -445,12 +478,9 @@ def _adamw_leaf_kernel(
         muo_ref[...] = jnp.where(fin, mu2, mu)
         nuo_ref[...] = jnp.where(fin, nu2, nu)
 
-    if interpret:
-        scal_spec = pl.BlockSpec((1, 8), lambda i: (0, 0))
-    else:
-        scal_spec = pl.BlockSpec(
-            (1, 8), lambda i: (0, 0), memory_space=pltpu.SMEM
-        )
+    scal_spec = pl.BlockSpec(
+        (1, 8), lambda i: (0, 0), memory_space=pltpu.SMEM
+    )
     leaf_spec = pl.BlockSpec((br, LANES), lambda i: (i, 0))
     outs = pl.pallas_call(
         kernel,
@@ -458,7 +488,7 @@ def _adamw_leaf_kernel(
         in_specs=[scal_spec] + [leaf_spec] * 4,
         out_specs=[leaf_spec] * 3,
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.float32)] * 3,
-        interpret=interpret,
+        interpret=kernels_interpreted(),
     )(scalars, flat(g), flat(p), flat(mu), flat(nu))
     return tuple(o.reshape(-1)[:n].reshape(shape) for o in outs)
 
@@ -500,7 +530,7 @@ def adamw_epilogue_reference(
 
 def maybe_fused_epilogue(
     opt_transform, grads, opt_state, params,
-    *, clip_scale, finite, interpret: Optional[bool] = None,
+    *, clip_scale, finite,
 ):
     """Run the fused adamw epilogue if ``opt_transform`` opted in and the
     state matches the layout this kernel understands; else None and the
@@ -524,7 +554,6 @@ def maybe_fused_epilogue(
     if not all(l.dtype == jnp.float32 for l in leaves):
         return None  # the bitwise contract is scoped to fp32 trees
 
-    interp = _default_interpret(interpret)
     if clip_scale is not None:
         # the clip multiply stays OUTSIDE the kernel, exactly where the
         # unfused chain applies it (see _adamw_leaf_kernel docstring)
@@ -554,7 +583,6 @@ def maybe_fused_epilogue(
         scalars=scalars,
         b1=hp["b1"], b2=hp["b2"], eps=hp["eps"],
         eps_root=hp["eps_root"], weight_decay=hp["weight_decay"],
-        interpret=interp,
     )
     flat_p, treedef = jax.tree.flatten(params)
     flat_g = jax.tree.leaves(grads)

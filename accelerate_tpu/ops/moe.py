@@ -112,19 +112,6 @@ def moe_ragged(
     return jnp.zeros((T, h), out.dtype).at[tok].add(out * w_flat[:, None])
 
 
-def ragged_ep_supported() -> bool:
-    """Whether this jax has the partial-manual shard_map mode
-    (``axis_names``) that :func:`moe_ragged_ep` requires. The auto
-    dispatch resolves to capacity when it is absent."""
-    import inspect
-
-    try:
-        from jax import shard_map
-    except ImportError:  # pre-top-level-shard_map jax: experimental only,
-        return False     # which also predates partial-manual mode
-    return "axis_names" in inspect.signature(shard_map).parameters
-
-
 def moe_ragged_ep(
     x: jax.Array,
     sel: jax.Array,
@@ -239,18 +226,8 @@ def moe_ragged_ep(
     ctx = nested_manual_mesh()
     sm_mesh = ctx if ctx is not None else mesh
 
-    if not ragged_ep_supported():
-        # full-manual would manualize dp/fsdp too: in_specs P() for the
-        # activations would all-gather the global batch onto every device
-        # (dp-times redundant FLOPs + memory) — refuse, like
-        # parallel/pipeline.py does for the same capability gap
-        raise NotImplementedError(
-            "moe_ragged_ep needs jax shard_map partial-manual mode "
-            "(axis_names), unavailable in this jax version — use "
-            "moe_dispatch='capacity' for expert parallelism"
-        )
-    # the capability check above guarantees the top-level import exists
     from jax import shard_map
+
     return shard_map(
         body,
         mesh=sm_mesh,

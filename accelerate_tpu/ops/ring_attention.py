@@ -94,8 +94,7 @@ def _ring_attention_local(
 ):
     """Per-device body (inside shard_map): local q stays put, k/v rotate."""
     # the ring length must be a static python int (it unrolls the scan
-    # permutation below); the caller reads it off the mesh rather than
-    # jax.lax.axis_size, which older jax doesn't have
+    # permutation below); the caller reads it off the mesh
     n = axis_size
     my = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]  # chunks move to the right,
@@ -190,9 +189,7 @@ def ring_attention(
 
     spec = P(batch_axes, axis_name, heads, None)
 
-    # version-compat wrapper: top-level jax.shard_map on new jax,
-    # jax.experimental on old, check_rep/check_vma normalized either way
-    from ..parallel.pipeline import shard_map
+    from jax import shard_map
 
     # sp under pp: when this runs INSIDE the pipeline's partial-manual
     # stage body (parallel/pipeline.py — pp is already Manual there), the

@@ -21,6 +21,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import optax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from .state import AcceleratorState, GradientState
 from .parallel.sharding import shardings_of
@@ -109,13 +110,17 @@ class AcceleratedOptimizer:
     def init(self, params: Any) -> Any:
         """Create opt state sharded congruently with the parallelism plan.
 
-        * FULL_SHARD/HYBRID (ZeRO-3): jit without out_shardings — each
-          moment buffer inherits its (already fsdp-sharded) param leaf's
-          sharding via GSPMD propagation.
+        * FULL_SHARD/HYBRID (ZeRO-3) and unsharded layouts: every copy of
+          the parameter tree inside the state (adam's mu and nu) takes its
+          parameter's sharding; counts replicate. Explicit, because
+          nothing propagates: optax builds moments with ``zeros_like``,
+          which has no data dependence on the sharded params, so a bare
+          jit leaves every moment WHOLE on the default device — all of a
+          ZeRO-3 job's optimizer bytes on chip 0 until the first step
+          reshards them.
         * SHARD_OPT/SHARD_GRAD_OP (ZeRO-1/2, reference DeepSpeed stages
-          utils/dataclasses.py:739): params are replicated, so propagation
-          would replicate the moments too; instead explicit out_shardings
-          shard every moment buffer over the fsdp axis.
+          utils/dataclasses.py:739): params are replicated, so the moments
+          are sharded over the fsdp axis by their own rule.
         """
         from .utils.dataclasses import ShardingStrategy
 
@@ -137,8 +142,34 @@ class AcceleratedOptimizer:
                 self.optimizer.init, out_shardings=out_shardings
             )(params)
         else:
-            self.opt_state = jax.jit(self.optimizer.init)(params)
+            self.opt_state = jax.jit(
+                self.optimizer.init,
+                out_shardings=self._shardings_like(params),
+            )(params)
         return self.opt_state
+
+    def _shardings_like(self, params: Any) -> Any:
+        """out_shardings for ``optimizer.init``: param-shaped state leaves
+        follow their param, the rest replicate beside them. None (let jit
+        place everything) when the params were never placed — plain
+        uncommitted arrays outside ``prepare``."""
+        leaves = jax.tree.leaves(params)
+        if not leaves or not all(
+            isinstance(p, jax.Array) and p.committed for p in leaves
+        ):
+            return None
+        first = leaves[0].sharding
+        beside = (
+            NamedSharding(first.mesh, PartitionSpec())
+            if isinstance(first, NamedSharding) else first
+        )
+        return optax.tree_utils.tree_map_params(
+            self.optimizer,
+            lambda _moment, sharding: sharding,
+            jax.eval_shape(self.optimizer.init, params),
+            jax.tree.map(lambda p: p.sharding, params),
+            transform_non_params=lambda _leaf: beside,
+        )
 
     def apply_gradients(self, grads: Any, params: Any, opt_state: Any):
         """Pure optax update (traced inside the train step)."""

@@ -47,99 +47,38 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.6
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-checking kwarg was renamed check_rep -> check_vma in
-# jax 0.7; detect from the actual signature rather than guessing by import
-import inspect as _inspect
-
-_REP_KWARG = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f=None, **kwargs):
-    for alias in ("check_rep", "check_vma"):
-        if alias in kwargs and alias != _REP_KWARG:
-            kwargs[_REP_KWARG] = kwargs.pop(alias)
-    return _shard_map(f, **kwargs) if f is not None else _shard_map(**kwargs)
-
-
-_PARTIAL_MANUAL = "axis_names" in _inspect.signature(_shard_map).parameters
-
-
-def partial_manual_supported() -> bool:
-    """True when this jax's ``shard_map`` has partial-manual mode
-    (``axis_names``) — required by :func:`pipeline_train_step` (1F1B) and
-    by any pp mesh composed with tp/sp/ep. On older jax those paths raise
-    ``NotImplementedError``; GPipe (:func:`pipeline_apply`) still works."""
-    return _PARTIAL_MANUAL
 
 from ..utils.constants import MESH_AXIS_PIPELINE
 from ..utils.dataclasses import ParallelismPlugin
-from .mesh import data_axes
 
 
 def _stage_shard_map(mesh, in_specs, out_specs):
     """shard_map over ONLY the pp axis (partial-manual): tp/dp/fsdp stay
     automatic so GSPMD partitions the stage body and inserts their
-    collectives inside each stage — this is what makes pp x tp compose.
-    Falls back to full-manual on older jax (pp-only meshes keep working;
-    validate_pipeline_plugin rejects tp there)."""
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    if _PARTIAL_MANUAL:
-        kwargs["axis_names"] = {MESH_AXIS_PIPELINE}
-    return functools.partial(shard_map, **kwargs)
+    collectives inside each stage — this is what makes pp x tp compose."""
+    return functools.partial(
+        shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False, axis_names={MESH_AXIS_PIPELINE},
+    )
 
 
 def validate_pipeline_plugin(
     plugin: ParallelismPlugin, resolved_shape: Optional[dict] = None
 ) -> None:
-    """pp>1 with tp/sp/ep>1 needs partial-manual shard_map (the nested
-    collectives live inside the stage body) — reject on older jax instead
-    of silently mis-sharding.
+    """A pipeline needs at least one microbatch per stage. (tp, sp and ep
+    all compose with pp: they stay auto axes inside the partial-manual
+    stage body; ring attention and moe_ragged_ep nest their own sp/ep
+    shard_maps on the context mesh — ops/ring_attention.py, ops/moe.py.)
 
     ``resolved_shape`` (from ``resolve_mesh_shape``) covers the ``-1`` auto
     axes — validation must run on the *resolved* degrees, else ``pp_size=-1``
     slips past every check.
     """
-    sizes = (
-        {"pp": resolved_shape["pp"],
-         "sp_size": resolved_shape["sp"], "ep_size": resolved_shape["ep"]}
-        if resolved_shape is not None
-        else {"pp": plugin.pp_size,
-              "sp_size": plugin.sp_size, "ep_size": plugin.ep_size}
-    )
-    pp = sizes.pop("pp")
+    pp = resolved_shape["pp"] if resolved_shape is not None else plugin.pp_size
     if pp in (1, -1):
         return
-    # tp, sp AND ep compose since partial-manual shard_map (all stay auto
-    # axes inside the stage body; ring attention and moe_ragged_ep nest
-    # their own sp/ep shard_maps on the context mesh —
-    # ops/ring_attention.py, ops/moe.py). On older jax full-manual would
-    # silently replicate tp (duplicate compute + per-step weight
-    # all-gather) and cannot nest the sp ring or the ep dispatch, so all
-    # three are rejected there.
-    tp = (
-        resolved_shape["tp"] if resolved_shape is not None else plugin.tp_size
-    )
-    sp = sizes.pop("sp_size")
-    ep = sizes.pop("ep_size")
-    if not _PARTIAL_MANUAL:
-        for name, v in (("tp_size", tp), ("sp_size", sp), ("ep_size", ep)):
-            if v not in (1, -1):
-                raise NotImplementedError(
-                    f"pp_size={pp} with {name}={v} needs jax shard_map "
-                    "partial-manual mode (axis_names), unavailable in this "
-                    "jax version"
-                )
     if plugin.num_micro_batches < pp:
         raise ValueError(
             f"num_micro_batches ({plugin.num_micro_batches}) must be >= "
@@ -195,13 +134,9 @@ def pipeline_apply(
     B = x.shape[batch_dim]
     xm = _microbatch(x, M, batch_dim)  # (B, ...) -> (M, B/M, ...)
 
-    if _PARTIAL_MANUAL:
-        # partial-manual: specs constrain only the pp axis; dp/fsdp/tp
-        # sharding of x and params is propagated by GSPMD (auto axes)
-        x_spec = P()
-    else:
-        batch_axes = data_axes(mesh)
-        x_spec = P(None, batch_axes if mesh.shape[batch_axes[0]] > 1 else None)
+    # partial-manual: specs constrain only the pp axis; dp/fsdp/tp
+    # sharding of x and params is propagated by GSPMD (auto axes)
+    x_spec = P()
     param_specs = jax.tree.map(
         lambda l: P(MESH_AXIS_PIPELINE), stacked_params
     )
@@ -313,15 +248,6 @@ def pipeline_train_step(
 
         return jax.value_and_grad(total)(stacked_params)
 
-    if not _PARTIAL_MANUAL:
-        # full-manual would batch-shard the data over dp but never reduce
-        # loss/dparams across the data axes — silently wrong grads. The
-        # 1F1B step is partial-manual-only by design.
-        raise NotImplementedError(
-            "pipeline_train_step needs jax shard_map partial-manual mode "
-            "(axis_names), unavailable in this jax version — use "
-            "pipeline_apply (GPipe) + jax.grad instead"
-        )
     xm = _microbatch(x, M, batch_dim)
     tm = _microbatch(targets, M, batch_dim)
     param_specs = jax.tree.map(lambda l: P(MESH_AXIS_PIPELINE), stacked_params)

@@ -76,6 +76,11 @@ _SHAPE_RE = re.compile(
 )
 
 _INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%(?P<name>[^\s=]+)\s*=\s*")
+#: computation header: ``%name (params) -> type {`` / ``ENTRY %main (...) {``
+_COMPUTATION_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?(?P<name>[^\s(]+)\s*\(.*\{\s*$")
+#: XLA:TPU has no reduce-scatter instruction in its optimized HLO: it emits
+#: a fusion computation of this name holding ``all-reduce`` + ``dynamic-slice``
+_TPU_REDUCE_SCATTER_FUSION = "all-reduce-scatter"
 
 #: explicit replica-group list: ``replica_groups={{0,1},{2,3}}``
 _GROUPS_LIST_RE = re.compile(r"replica_groups=\{(\{[0-9,{}\s]*\})\}")
@@ -280,6 +285,18 @@ def _operand_region(line: str, start: int) -> str:
 _OP_TOKEN_RE = re.compile(
     r"\b(" + "|".join(COLLECTIVE_KINDS) + r")(-start|-done)?\("
 )
+_OPERAND_NAME_RE = re.compile(r"%([^\s,()]+)")
+
+
+def _result_type_bytes(rest: str) -> int:
+    """Bytes of an instruction's result type — ``rest`` is the line after
+    ``%name = ``: either one shape token (``f32[4,3]{1,0} parameter(0)``)
+    or a parenthesized tuple of them."""
+    if rest.startswith("("):
+        region = _operand_region(rest, 0)
+    else:
+        region = rest.split(" ", 1)[0]
+    return sum(_shape_bytes(d, s) for d, s in _SHAPE_RE.findall(region))
 
 
 def parse_hlo_collectives(
@@ -300,18 +317,31 @@ def parse_hlo_collectives(
         m = _NUM_PARTITIONS_RE.search(hlo_text)
         num_devices = int(m.group(1)) if m else 1
     ops: list[CollectiveOp] = []
+    # result bytes of every instruction seen so far, by name: newer XLA
+    # prints operands as bare names (``reduce-scatter(%param.1)``, no
+    # inline shape), so an operand's size is its definition's result
+    defined: dict[str, int] = {}
+    computation = ""
     for raw in hlo_text.splitlines():
         im = _INSTR_RE.match(raw)
         if im is None:
+            cm = _COMPUTATION_RE.match(raw)
+            if cm is not None:
+                computation = cm.group("name")
             continue
         # metadata can quote arbitrary op_name strings — cut it off so
         # neither the shape scan nor the op-token scan reads it
         line = raw.split(", metadata=")[0]
+        defined[im.group("name")] = _result_type_bytes(line[im.end():])
         om = _OP_TOKEN_RE.search(line)
         if om is not None:
             if om.group(2) == "-done":
                 continue  # counted at the matching -start
             kind = om.group(1)
+            if kind == "all-reduce" and computation.startswith(
+                _TPU_REDUCE_SCATTER_FUSION
+            ):
+                kind = "reduce-scatter"
             result_part = line[:om.start()]
             operand_part = _operand_region(line, line.index("(", om.start()))
             attr_part = line[om.start():]
@@ -320,6 +350,9 @@ def parse_hlo_collectives(
             )
             operand_bytes = sum(
                 _shape_bytes(d, s) for d, s in _SHAPE_RE.findall(operand_part)
+            ) or sum(
+                defined.get(name, 0)
+                for name in _OPERAND_NAME_RE.findall(operand_part)
             )
             groups = parse_replica_groups(attr_part)
             if groups:

@@ -48,7 +48,6 @@ PEAK_HBM_BYTES_PER_S = {
     "TPU v5p": 2.77e12,
     "TPU v6 lite": 1.64e12,
     "TPU v6e": 1.64e12,
-    "cpu": 0.1e12,
 }
 
 
@@ -389,32 +388,32 @@ class ProgramRegistry:
         ``attribution_gap`` (peak_bound − achieved) is the share of MFU
         lost to *this* program's schedule rather than to physics.
 
-        On CPU ``cost_analysis`` is partial, so flops/bytes may be 0 and
-        the roofline degrades to None — callers must treat the numbers
-        as TPU-grade evidence only (see README "roofline caveats").
+        Without explicit peaks they come from the published per-chip
+        tables keyed by ``device_kind``; a kind that is in neither table
+        (CPU included) has no roofline and this returns None.
         """
         rec = self.get(label)
         if rec is None:
             return None
         if peak_flops is None or peak_bytes_per_s is None:
+            import jax
+
+            from ..benchmarks.measure import _peak_flops
+
+            # record-only: a device kind with no published peak (CPU
+            # included) has no roofline — None, never a default
+            device = jax.devices()[0]
+            kind = str(device.device_kind).lower()
             try:
-                import jax
-
-                from ..benchmarks.measure import _peak_flops
-
-                device = jax.devices()[0]
                 peak_flops = peak_flops or _peak_flops(device)
-                if peak_bytes_per_s is None:
-                    kind = str(
-                        getattr(device, "device_kind", "cpu"),
-                    ).lower()
-                    peak_bytes_per_s = next(
-                        (bw for name, bw in PEAK_HBM_BYTES_PER_S.items()
-                         if name.lower() in kind),
-                        PEAK_HBM_BYTES_PER_S["cpu"],
-                    )
-            except Exception:  # noqa: BLE001
+            except ValueError:
                 return None
+            if peak_bytes_per_s is None:
+                peak_bytes_per_s = next(
+                    (bw for name, bw in PEAK_HBM_BYTES_PER_S.items()
+                     if name.lower() in kind),
+                    None,
+                )
         intensity = rec.arithmetic_intensity
         if intensity is None or not peak_flops or not peak_bytes_per_s:
             return None

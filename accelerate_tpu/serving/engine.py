@@ -30,6 +30,7 @@ Zero-retrace is an explicit contract: trace-time counters
 from __future__ import annotations
 
 import collections
+import contextlib
 import hashlib
 import time
 from dataclasses import dataclass
@@ -60,6 +61,18 @@ class TokenEvent:
     request_id: str
     token: int
     done: bool
+
+
+def _single_device_of(params: Any) -> Optional[jax.Device]:
+    """The one device every array leaf of ``params`` lives on, else None
+    (weights sharded over a mesh, or no device arrays at all)."""
+    devices = set()
+    for leaf in jax.tree.leaves(params):
+        if isinstance(leaf, jax.Array):
+            devices |= leaf.devices()
+            if len(devices) > 1:
+                return None
+    return devices.pop() if devices else None
 
 
 def _next_pow2(n: int) -> int:
@@ -156,8 +169,17 @@ class ServingEngine:
         role: str = "colocated",
         transfer_plane: Any = None,
     ):
+        from ..compilation import activate_persistent_cache
+
+        activate_persistent_cache()
         self.model = model
         self.params = params
+        # the engine has no device of its own: it lives where its weights
+        # do. With weights on ONE device (a replica of a fleet, each on
+        # its own chip) the KV pool and every per-step host put are
+        # placed there too — never first on chip 0 and then moved.
+        # Weights sharded over a mesh leave placement to jit.
+        self._device = _single_device_of(params)
         self.max_slots = max_slots
         self.block_size = block_size
         # --- PR 17 capacity levers (all default OFF) ---------------- #
@@ -299,7 +321,7 @@ class ServingEngine:
         )
         self.cache = init_cache(
             model.init, jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-            decode=True, paged=init_state,
+            decode=True, paged=init_state, device=self._device,
         )
         # paged cache leaves by position: (flat leaf index, block axis)
         # for every K/V pool ((..., num_blocks, block_size, Hkv, D)) and
@@ -609,13 +631,21 @@ class ServingEngine:
         ONE decode step over the whole slot batch. Returns the tokens
         produced this iteration."""
         try:
-            return self._step_inner()
+            with self._placed():
+                return self._step_inner()
         except Exception as exc:
             # device OOM: the autopsy is written from state already in
             # memory (ledger + last census + pool stats), then the
             # original error propagates untouched
             self._handle_oom(exc, context="serving_step")
             raise
+
+    def _placed(self):
+        """Context in which uncommitted arrays (``jnp.asarray`` of host
+        step inputs, fresh zeros) land on the engine's device."""
+        if self._device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self._device)
 
     def _step_inner(self) -> list[TokenEvent]:
         had_work = self.has_work
@@ -2102,14 +2132,15 @@ class ServingEngine:
         proposer = self._proposers.get(id(spec))
         if proposer is None:
             if spec.method == "draft_model":
-                proposer = DraftModelProposer(
-                    spec,
-                    target_config=self.model.config,
-                    num_blocks=self.num_blocks,
-                    block_size=self.block_size,
-                    max_table=self._max_table,
-                    max_slots=self.max_slots,
-                )
+                with self._placed():  # the draft's own KV pool
+                    proposer = DraftModelProposer(
+                        spec,
+                        target_config=self.model.config,
+                        num_blocks=self.num_blocks,
+                        block_size=self.block_size,
+                        max_table=self._max_table,
+                        max_slots=self.max_slots,
+                    )
             else:
                 proposer = NGramProposer(spec)
             self._proposers[id(spec)] = proposer

@@ -274,7 +274,7 @@ class ParallelismPlugin(KwargsHandler):
 
     Degrees multiply up the mesh: ``dp * fsdp * ep * sp * tp`` must divide
     the device count. ``-1`` for exactly one axis means "absorb all remaining
-    devices". GSPMD turns the per-axis shardings into reduce-scatter /
+    devices" (``dp_size`` does by default, and yields when another axis asks). GSPMD turns the per-axis shardings into reduce-scatter /
     all-gather / all-to-all over ICI; nothing here spawns wrappers or
     engines.
     """
@@ -316,8 +316,14 @@ class ParallelismPlugin(KwargsHandler):
         env = os.environ.get(ENV_PREFIX + "SHARDING_STRATEGY")
         if env is not None and self.sharding_strategy == defaults["sharding_strategy"]:
             self.sharding_strategy = ShardingStrategy(env)
-        sizes = [self.dp_size, self.pp_size, self.fsdp_size, self.tp_size,
-                 self.sp_size, self.ep_size]
+        others = [self.pp_size, self.fsdp_size, self.tp_size, self.sp_size,
+                  self.ep_size]
+        if self.dp_size == -1 and -1 in others:
+            # dp's -1 is only the default "absorb what is left": an axis the
+            # caller marks auto takes its place, so the documented
+            # ParallelismPlugin(fsdp_size=-1) means ZeRO-3 over every chip
+            self.dp_size = 1
+        sizes = [self.dp_size, *others]
         if sizes.count(-1) > 1:
             raise ValueError("at most one mesh axis may be -1 (auto)")
         for s in sizes:
@@ -371,12 +377,15 @@ class CompilePlugin(KwargsHandler):
     # sharding. Always a no-op on non-TPU backends. Explicit keys in
     # ``compiler_options`` win over the emitted defaults.
     overlap_collectives: Optional[bool] = None
-    cache_dir: Optional[str] = None  # persistent compilation cache
-    # Persistence floors: JAX defaults persist only compiles >1s / >4KiB —
-    # tuned for giant programs. 0.0 / -1 persist everything (what a bench
-    # sweep of small programs wants). None leaves JAX's default untouched.
-    cache_min_compile_time_secs: Optional[float] = 0.0
-    cache_min_entry_size_bytes: Optional[int] = -1
+    # persistent compilation cache directory. None = the one rule in
+    # compilation/cache.py (JAX_COMPILATION_CACHE_DIR, else
+    # <checkout>/.jax_compile_cache); ignored when that variable is set
+    cache_dir: Optional[str] = None
+    # Persistence floors: JAX persists only compiles >1s / >4KiB, which
+    # suits a cache that is always on. 0.0 / -1 persist everything (what a
+    # bench sweep of small programs wants). None leaves them untouched.
+    cache_min_compile_time_secs: Optional[float] = None
+    cache_min_entry_size_bytes: Optional[int] = None
     # cache-key scope: "all" folds the per-backend XLA autotune/kernel
     # caches into the same dir; "none" keeps only the executable cache
     cache_enable_xla_caches: Optional[str] = None
@@ -384,8 +393,6 @@ class CompilePlugin(KwargsHandler):
     explain_cache_misses: bool = False
 
     def __post_init__(self):
-        if self.cache_dir is None:
-            self.cache_dir = os.environ.get(ENV_PREFIX + "COMPILE_CACHE")
         if isinstance(self.static_argnames, str):
             self.static_argnames = (self.static_argnames,)
         else:

@@ -40,20 +40,17 @@ def nested_manual_mesh() -> Optional[Any]:
     """The tracing context's abstract mesh when any of its axes is already
     Manual — i.e. we are INSIDE a shard_map body (a pipeline stage) and a
     nested shard_map must be built on this mesh, not the concrete one.
-    Returns None at top level (or on older jax without abstract meshes).
+    Returns None at top level.
 
     Compares against ``jax.sharding.AxisType.Manual`` — not the enum's
     repr, which a jax upgrade could change silently, disabling the
     context-mesh path and surfacing only as an obscure mesh-mismatch
-    error under pp x sp / pp x ep (ADVICE r4).
+    error under pp x sp / pp x ep.
     """
-    try:
-        ctx = jax.sharding.get_abstract_mesh()
-        manual = jax.sharding.AxisType.Manual
-        if any(t == manual for t in getattr(ctx, "axis_types", ())):
-            return ctx
-    except Exception:  # noqa: BLE001 — older jax without abstract meshes
-        pass
+    ctx = jax.sharding.get_abstract_mesh()
+    manual = jax.sharding.AxisType.Manual
+    if any(t == manual for t in ctx.axis_types):
+        return ctx
     return None
 
 

@@ -291,24 +291,14 @@ class ProfileKwargs:
 
 
 def _start_trace_kwargs(kw: ProfileKwargs) -> dict:
-    """Only pass options the running jax version supports (the kwarg set
-    changed across versions; detect from the signature, never by try/except
-    around user code)."""
-    import inspect
-
-    params = inspect.signature(jax.profiler.start_trace).parameters
-    out: dict[str, Any] = {}
-    if "create_perfetto_link" in params:
-        out["create_perfetto_link"] = kw.create_perfetto_link
-    if "profiler_options" in params and hasattr(jax.profiler, "ProfileOptions"):
-        try:
-            opts = jax.profiler.ProfileOptions()
-            opts.host_tracer_level = kw.host_tracer_level
-            opts.python_tracer_level = kw.python_tracer_level
-            out["profiler_options"] = opts
-        except Exception:
-            pass
-    return out
+    """``jax.profiler.start_trace`` keyword arguments for ``kw``."""
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = kw.host_tracer_level
+    opts.python_tracer_level = kw.python_tracer_level
+    return {
+        "create_perfetto_link": kw.create_perfetto_link,
+        "profiler_options": opts,
+    }
 
 
 class ProfileHandle:

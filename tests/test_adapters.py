@@ -282,9 +282,14 @@ def test_unified_step_adapter_only_carry(optimizer):
     )
     batch = {"input_ids": _ids(seed=2)}
     losses = []
-    for _ in range(5):
-        carry, metrics = step(carry, batch)
-        losses.append(float(metrics["loss"]))
+    # the fused epilogue is a Pallas kernel: off-chip it runs only under
+    # the tests' interpreter context
+    from accelerate_tpu.ops.flash_attention import kernel_interpret_mode
+
+    with kernel_interpret_mode():
+        for _ in range(5):
+            carry, metrics = step(carry, batch)
+            losses.append(float(metrics["loss"]))
     assert losses[-1] < losses[0], losses
     assert_adapter_only(carry["params"], _LCFG)
     with pytest.raises(AssertionError):

@@ -6,7 +6,7 @@ Three layers:
   exceeds the global window, skip-with-record, runtime re-clamp),
   estimates persistence, partial-snapshot round-trips and the registry;
 * fake-launch runner tests (no subprocess, no wall time): streaming
-  order, budget-kill partial harvest, the implausible-retry paths —
+  order, budget-kill partial harvest, crashes —
   including the fixed first_rec fallback;
 * slow-marked end-to-end subprocess tests: a SIGKILLed child leaves a
   recoverable partial, and ``bench.py --fast --deadline 120`` produces a
@@ -320,7 +320,7 @@ class FakeLaunch:
 
 
 def _runner(variants, responses, *, deadline=None, clock=None,
-            tmp_path=None, on_tpu=True, **kw):
+            tmp_path=None, **kw):
     clock = clock or FakeClock()
     reg = VariantRegistry(variants)
     sched = DeadlineScheduler(Deadline(deadline, clock=clock),
@@ -332,9 +332,7 @@ def _runner(variants, responses, *, deadline=None, clock=None,
     runner = BenchRunner(
         reg, sched, est, launch,
         partial_dir=str(tmp_path) if tmp_path else None,
-        emit=emitted.append, log=logged.append,
-        sleep=lambda s: clock.advance(s), settle_s=kw.pop("settle_s", 1.0),
-        on_tpu=on_tpu, **kw,
+        emit=emitted.append, log=logged.append, **kw,
     )
     return runner, launch, emitted, logged
 
@@ -437,87 +435,31 @@ def test_runner_grant_collapse_skips_at_runtime(tmp_path):
         reg, sched, Estimates(str(tmp_path / "e.json")),
         OverrunLaunch([([_rec("dense")], {})]),
         partial_dir=str(tmp_path), emit=emitted.append,
-        log=lambda s: None, sleep=clock.advance, on_tpu=True,
+        log=lambda s: None,
     )
     assert runner.run() == 0
     assert runner.skipped and runner.skipped[0]["variant"] == "ckpt"
 
 
-def test_runner_implausible_retry_recovers(tmp_path):
-    # transient chip degradation: first attempt measures 20x slow, the
-    # retry after the settle measures the real number — keep the better
-    variants = [_v("dense", 0, "dense", headline=True)]
+def test_runner_slow_number_is_a_number_and_crash_is_an_error(tmp_path):
+    # a local chip has no transients to wait out: one launch per group,
+    # a 3%-MFU record is published as measured, a crash is an error
+    variants = [
+        _v("dense", 0, "dense", headline=True),
+        _v("accum", 1, "accum"),
+    ]
     responses = [
         ([_rec("dense", value=5.0, mfu=0.03)], {}),
-        ([_rec("dense", value=100.0, mfu=0.55)], {}),
+        ([], {"returncode": 1, "stderr": "boom"}),
     ]
     runner, launch, _, logged = _runner(variants, responses,
                                         tmp_path=tmp_path)
     assert runner.run() == 0
-    rec = runner.results["dense"]
-    assert rec["value"] == pytest.approx(100.0)
-    assert rec["extra"]["retried"] is True
-    assert not rec.get("partial")
-    assert len(launch.calls) == 2
-    assert any("implausibly slow" in l for l in logged)
-
-
-def test_runner_retry_timeout_publishes_first_rec(tmp_path):
-    # SATELLITE: the old bench.py timeout branch set rec=None and
-    # discarded an implausible-but-MEASURED first attempt. It must be
-    # published, marked retried+partial.
-    variants = [_v("dense", 0, "dense", headline=True, iters=20)]
-    responses = [
-        ([_rec("dense", value=5.0, mfu=0.03)], {}),
-        ([], {"timed_out": True, "returncode": -9}),
-    ]
-    runner, _, emitted, _ = _runner(variants, responses, tmp_path=tmp_path)
-    assert runner.run() == 0
+    assert len(launch.calls) == 2  # one per group, nothing re-run
     rec = runner.results["dense"]
     assert rec["value"] == pytest.approx(5.0)
-    assert rec["partial"] is True
-    assert rec["extra"]["retried"] is True
-    assert rec["iters_measured"] == 20
-    assert "dense" not in runner.errors
-    assert json.loads(emitted[-1])["partial"] is True
-
-
-def test_runner_unfunded_retry_publishes_first_rec(tmp_path):
-    # the window can't fund a second attempt: same fallback, no launch
-    clock = FakeClock()
-    variants = [_v("dense", 0, "dense", headline=True, est=30.0, iters=20)]
-
-    class SlowLaunch(FakeLaunch):
-        def __call__(self, members, budget_s):
-            clock.advance(80.0)
-            return super().__call__(members, budget_s)
-
-    responses = [([_rec("dense", value=5.0, mfu=0.03)], {})]
-    reg = VariantRegistry(variants)
-    sched = DeadlineScheduler(Deadline(100.0, clock=clock), min_budget_s=10.0)
-    runner = BenchRunner(
-        reg, sched, Estimates(str(tmp_path / "e.json")),
-        SlowLaunch(responses), partial_dir=str(tmp_path),
-        emit=lambda s: None, log=lambda s: None, sleep=clock.advance,
-        settle_s=30.0, on_tpu=True,
-    )
-    assert runner.run() == 0
-    rec = runner.results["dense"]
-    assert rec["partial"] is True and rec["extra"]["retried"] is True
-
-
-def test_runner_crash_retries_once_then_errors(tmp_path):
-    variants = [_v("dense", 0, "dense", headline=True)]
-    responses = [
-        ([], {"returncode": 1, "stderr": "boom"}),
-        ([], {"returncode": 1, "stderr": "boom again"}),
-    ]
-    runner, launch, _, logged = _runner(variants, responses,
-                                        tmp_path=tmp_path)
-    assert runner.run() == 1
-    assert len(launch.calls) == 2
-    assert "boom again" in runner.errors["dense"]
-    assert any("crashed" in l for l in logged)
+    assert not rec.get("partial") and "retried" not in rec["extra"]
+    assert "boom" in runner.errors["accum"]
 
 
 def test_runner_oom_is_not_retried(tmp_path):
@@ -575,15 +517,76 @@ def test_runner_folds_longseq_helpers(tmp_path):
     assert json.loads(emitted[-1])["variant"] == "dense"
 
 
-def test_runner_cpu_mode_never_flags_implausible(tmp_path):
-    # on CPU an mfu < 0.10 is the expected reality, not a transient
-    variants = [_v("dense", 0, "dense", headline=True)]
-    responses = [([_rec("dense", value=5.0, mfu=0.01)], {})]
-    runner, launch, _, _ = _runner(variants, responses, tmp_path=tmp_path,
-                                   on_tpu=False)
-    assert runner.run() == 0
-    assert len(launch.calls) == 1
-    assert not runner.results["dense"].get("partial")
+# --------------------------------------------------------------------- #
+# No silent off-chip fallback (peaks table, backend probe, parent imports)
+# --------------------------------------------------------------------- #
+def test_unknown_device_kind_has_no_peak():
+    from accelerate_tpu.benchmarks.measure import PEAK_FLOPS, _peak_flops
+    from accelerate_tpu.profiling import ProgramRegistry
+
+    class Device:
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    assert _peak_flops(Device("TPU v5 lite")) == 197e12
+    with pytest.raises(ValueError, match="no published peak"):
+        _peak_flops(Device("TPU v99"))
+    with pytest.raises(ValueError, match="no published peak"):
+        _peak_flops(Device("cpu"))
+    assert not any("cpu" in kind.lower() for kind in PEAK_FLOPS)
+    # the record-only roofline answers None on a kind with no peak (this
+    # CPU backend) instead of inventing one
+    reg = ProgramRegistry()
+    reg.register_analysis("p", kind="train", flops=1e9, bytes_accessed=1e6)
+    assert reg.roofline("p") is None
+
+
+def test_bench_refuses_to_measure_off_chip(monkeypatch, capsys):
+    from accelerate_tpu.benchmarks import cli
+
+    monkeypatch.setattr(cli, "_detect_backend", lambda: "cpu")
+    assert cli.main([]) == 2
+    assert cli.main(["dense"]) == 2
+    assert "refusing" in capsys.readouterr().err
+    # --list is not a measurement; --fast is the CPU harness smoke
+    assert cli.main(["--list", "--fast"]) == 0
+
+
+def test_backend_probe_failure_is_fatal(monkeypatch):
+    import subprocess
+
+    from accelerate_tpu.benchmarks import cli
+
+    class Probe:
+        returncode, stdout, stderr = 1, "", "libtpu: no chip"
+
+    monkeypatch.setattr(subprocess, "run", lambda *a, **k: Probe())
+    with pytest.raises(SystemExit, match="backend probe failed"):
+        cli._detect_backend()
+    Probe.returncode = 0  # a probe that prints nothing is no answer either
+    with pytest.raises(SystemExit, match="backend probe failed"):
+        cli._detect_backend()
+
+
+def test_parents_of_chip_children_never_import_jax():
+    # a process that touched JAX holds the chip: the bench parent and
+    # `accelerate-tpu launch` must be able to do their whole job without it
+    code = (
+        "import sys\n"
+        "import accelerate_tpu.commands.accelerate_cli\n"
+        "import accelerate_tpu.commands.launch\n"
+        "from accelerate_tpu.benchmarks import cli\n"
+        "from accelerate_tpu.benchmarks.scheduler import Estimates\n"
+        "cli.build_registry(True); cli.build_registry(False)\n"
+        "Estimates.default_path()\n"
+        "bad = [m for m in sys.modules if m.split('.')[0] in ('jax', 'jaxlib', 'flax')]\n"
+        "assert not bad, bad[:5]\n"
+    )
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO_ROOT,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr[-2000:]
 
 
 # --------------------------------------------------------------------- #
@@ -634,9 +637,6 @@ def _child_env(extra=None):
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONUNBUFFERED"] = "1"
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    # keep bench children off the repo's pytest compile cache (the
-    # multiprocess tier deadlocked on shared-cache contention once)
-    env.pop("ACCELERATE_TPU_COMPILE_CACHE", None)
     env.update(extra or {})
     return env
 
@@ -654,6 +654,9 @@ def test_sigkilled_child_leaves_recoverable_partial(tmp_path):
         env=_child_env({
             ENV_ITERS: "100000",  # stretch the measured loop
             "ACCELERATE_TPU_BENCH_PARTIAL_EVERY": "5",
+            # a private compile cache: the child's pipes are not drained
+            # here, and a warm XLA:CPU cache floods stderr on load
+            "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "xla_cache"),
         }),
         stdout=subprocess.PIPE, stderr=subprocess.PIPE,
     )
@@ -699,7 +702,7 @@ def test_bench_fast_deadline_end_to_end(tmp_path):
         env=_child_env({
             # a private estimates/cache location: the test must not
             # inherit (or pollute) the operator's persisted estimates
-            "ACCELERATE_TPU_COMPILE_CACHE": str(tmp_path / "xla_cache"),
+            "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "xla_cache"),
         }),
         capture_output=True, text=True, timeout=150,
     )

@@ -129,27 +129,10 @@ def test_in_package_test_script_single_process():
     from accelerate_tpu.test_utils import path_in_accelerate_package
 
     script = path_in_accelerate_package("test_utils", "scripts", "test_script.py")
+    # JAX_PLATFORMS="" lets the child auto-detect its backend; its
+    # Accelerator joins the suite's persistent compile cache by the one
+    # rule in compilation/cache.py (same in-checkout directory)
     env = {**os.environ, "JAX_PLATFORMS": ""}
-    # JAX_PLATFORMS="" lets the child auto-detect its backend. On a box
-    # with libtpu but no TPU (nor GCP metadata service), that detection
-    # stalls ~7.5 MINUTES: libtpu retries the metadata server 30x for
-    # each of ~8 variables before giving up and falling back to CPU —
-    # this one test was over half of tier-1 wall clock. Skip the
-    # metadata queries (the libtpu switch for running outside GCP);
-    # single-host init needs none of them. setdefault so a real GCP
-    # TPU environment can pre-set it to 0.
-    env.setdefault("TPU_SKIP_MDS_QUERY", "1")
-    # Share the suite's persistent compile cache with the child (the
-    # script's Accelerator picks the env var up via CompilePlugin).
-    # Safe here — ONE child, run serially — unlike the multiprocess
-    # launcher tier, where cache contention during the collective
-    # rendezvous deadlocked (see tests/conftest.py).
-    if os.environ.get("ACCELERATE_TPU_TEST_NO_CACHE", "0") != "1":
-        env.setdefault(
-            "ACCELERATE_TPU_COMPILE_CACHE",
-            os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), ".jax_compile_cache"),
-        )
     out = subprocess.run(
         [sys.executable, script], capture_output=True, text=True,
         env=env,

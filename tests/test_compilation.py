@@ -25,6 +25,7 @@ from accelerate_tpu.compilation import (
     get_compile_monitor,
     persistent_cache_dir,
     persistent_cache_entries,
+    resolve_cache_dir,
     spec_like,
 )
 from accelerate_tpu.compilation import cache as cache_mod
@@ -103,16 +104,96 @@ def test_cache_dir_activates_and_writes_entries(tmp_path, restore_cache_config):
     assert persistent_cache_entries(resolved) > 0
 
 
-def test_no_cache_dir_is_a_noop(restore_cache_config, monkeypatch):
-    monkeypatch.delenv("ACCELERATE_TPU_COMPILE_CACHE", raising=False)
-    assert activate_persistent_cache(CompilePlugin()) is None
+# ---------------------------------------------------------------------- #
+# the one rule for where the cache lives (compilation/cache.py)
+# ---------------------------------------------------------------------- #
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_env_var_seeds_plugin_cache_dir(tmp_path, monkeypatch):
-    monkeypatch.setenv("ACCELERATE_TPU_COMPILE_CACHE", str(tmp_path / "env"))
-    assert CompilePlugin().cache_dir == str(tmp_path / "env")
-    # an explicit cache_dir wins over the env
-    assert CompilePlugin(cache_dir="/explicit").cache_dir == "/explicit"
+def test_resolver_default_is_the_fixed_in_checkout_path(monkeypatch):
+    monkeypatch.delenv(cache_mod.ENV_JAX_CACHE_DIR, raising=False)
+    want = os.path.join(REPO_ROOT, ".jax_compile_cache")
+    assert resolve_cache_dir() == resolve_cache_dir(CompilePlugin()) == want
+    # ... and is what a plain Accelerator() leaves active (conftest went
+    # through the same resolver, so nothing switches or resets)
+    acc = _fresh_accelerator()
+    assert acc.state.compile_cache_dir == want
+    assert jax.config.jax_compilation_cache_dir == want
+    # an explicit plugin dir still wins while the variable is unset
+    assert resolve_cache_dir(CompilePlugin(cache_dir="/explicit")) == "/explicit"
+
+
+def test_resolver_env_wins_over_plugin_with_one_log_line(monkeypatch, caplog):
+    monkeypatch.setenv(cache_mod.ENV_JAX_CACHE_DIR, "/from/env")
+    monkeypatch.setattr(cache_mod, "_warned_ignored", False)
+    plugin = CompilePlugin(cache_dir="/explicit")
+    with caplog.at_level("WARNING"):
+        assert resolve_cache_dir(plugin) == "/from/env"
+        assert resolve_cache_dir(plugin) == "/from/env"
+    assert sum("is ignored" in r.getMessage() for r in caplog.records) == 1
+    assert resolve_cache_dir() == "/from/env"
+
+
+def test_env_cache_dir_is_the_only_one_any_entry_point_sets(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, Accelerator(), ServingEngine()
+    and the bench child's activation all leave jax pointing at it."""
+    import subprocess
+    import sys
+
+    code = (
+        "import jax\n"
+        "from accelerate_tpu import Accelerator, ServingEngine\n"
+        "from accelerate_tpu.benchmarks.cli import _activate_cache\n"
+        "from accelerate_tpu.models import CausalLM, TransformerConfig\n"
+        "from accelerate_tpu.utils.dataclasses import CompilePlugin\n"
+        "seen = []\n"
+        "Accelerator(compile_plugin=CompilePlugin(cache_dir='/explicit'))\n"
+        "seen.append(jax.config.jax_compilation_cache_dir)\n"
+        "model = CausalLM(TransformerConfig.tiny(num_layers=1))\n"
+        "params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))\n"
+        "ServingEngine(model, params, max_slots=1, block_size=8)\n"
+        "seen.append(jax.config.jax_compilation_cache_dir)\n"
+        "_activate_cache()\n"
+        "seen.append(jax.config.jax_compilation_cache_dir)\n"
+        "print('SEEN', *seen)\n"
+    )
+    target = str(tmp_path / "x")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, cwd=REPO_ROOT,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             cache_mod.ENV_JAX_CACHE_DIR: target},
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = [l for l in proc.stdout.splitlines() if l.startswith("SEEN")][-1]
+    assert line.split()[1:] == [target] * 3
+
+
+def test_no_cache_path_is_built_from_tempfile():
+    """The cache must not move between runs: no path made from tempfile
+    anywhere under the package names the compile cache, and the deleted
+    package-specific variable is gone from the tree."""
+    import re
+
+    gone = "ACCELERATE_TPU_" + "COMPILE_CACHE"
+    tmp = re.compile(r"gettempdir|mkdtemp|mkstemp|NamedTemporary")
+    offenders = []
+    for root in ("accelerate_tpu", "tests", "examples"):
+        for dirpath, _dirs, files in os.walk(os.path.join(REPO_ROOT, root)):
+            for name in files:
+                if not name.endswith(".py"):
+                    continue
+                path = os.path.join(dirpath, name)
+                with open(path) as f:
+                    lines = f.read().splitlines()
+                for i, line in enumerate(lines):
+                    window = " ".join(lines[max(0, i - 1):i + 2])
+                    if gone in line or (
+                        root == "accelerate_tpu" and tmp.search(line)
+                        and "cache" in window.lower()
+                    ):
+                        offenders.append(f"{path}:{i + 1}: {line.strip()}")
+    assert not offenders, offenders
 
 
 def test_state_activates_cache_from_plugin(tmp_path, restore_cache_config):
@@ -215,34 +296,59 @@ def test_plugin_normalizes_string_static_argnames():
 
 
 # ---------------------------------------------------------------------- #
-# CompilePlugin.compiler_options -> .lower().compile() (wired consumer)
+# CompilePlugin.compiler_options -> the jit itself (wired consumer)
 # ---------------------------------------------------------------------- #
-def test_compiler_options_reach_lowered_compile(monkeypatch):
-    import jax.stages
+def test_compiler_options_reach_the_warmed_and_the_unwarmed_step():
+    """The options sit on the jax.jit, so warmup's AOT compile and a plain
+    first call build ONE program (one cache key): an option XLA does not
+    know must stop both paths, not only warm()."""
 
-    seen = {}
-    orig = jax.stages.Lowered.compile
+    def build(opts):
+        acc = _fresh_accelerator(
+            compile_plugin=CompilePlugin(compiler_options=opts)
+        )
+        params = {"w": jnp.asarray(1.0), "b": jnp.asarray(0.5)}
+        params, opt = acc.prepare(params, optax.sgd(0.1))
+        step = acc.unified_step(loss_fn, opt)
+        return acc, step, acc.init_carry(params, opt)
 
-    def spy(self, compiler_options=None, **kw):
-        seen["compiler_options"] = compiler_options
-        return orig(self, compiler_options=compiler_options, **kw)
+    batch = {"x": jnp.asarray(np.ones((8,), np.float32))}
+    bogus = {"xla_no_such_option_for_this_test": True}
+    acc, step, carry = build(bogus)
+    with pytest.raises(Exception, match="No such compile option"):
+        acc.warmup(step, carry, batch)
+    acc, step, carry = build(bogus)
+    with pytest.raises(Exception, match="No such compile option"):
+        step(carry, batch)
 
-    monkeypatch.setattr(jax.stages.Lowered, "compile", spy)
+    acc, step, carry = build({"xla_embed_ir_in_executable": True})
+    acc.warmup(step, carry, batch)
+    carry, metrics = step(carry, batch)
+    assert np.isfinite(float(metrics["loss"]))
+    assert step.aot_fallbacks == 0 and step.compiled is not None
 
-    opts = {"xla_embed_ir_in_executable": True}
-    acc = _fresh_accelerator(
-        compile_plugin=CompilePlugin(compiler_options=opts)
-    )
+
+def test_aot_fallback_is_counted_and_lands_on_the_step_record():
+    """A warmed executable that rejects a call (same avals, another
+    sharding) still falls back to jit — but never silently: the step fn
+    counts it and every later step record carries the count."""
+    acc = _fresh_accelerator(telemetry=True)
     params = {"w": jnp.asarray(1.0), "b": jnp.asarray(0.5)}
     params, opt = acc.prepare(params, optax.sgd(0.1))
     step = acc.unified_step(loss_fn, opt)
     carry = acc.init_carry(params, opt)
     batch = {"x": jnp.asarray(np.ones((8,), np.float32))}
     acc.warmup(step, carry, batch)
-    assert seen["compiler_options"] == opts
-    # the AOT executable compiled with those options serves the real call
-    carry, metrics = step(carry, batch)
+    carry, _ = step(carry, batch)
+    assert step.aot_fallbacks == 0
+    assert acc.telemetry.records[-1]["aot_fallbacks"] == 0
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    split = jax.device_put(batch, NamedSharding(acc.mesh, P("dp")))
+    carry, metrics = step(carry, split)
     assert np.isfinite(float(metrics["loss"]))
+    assert step.aot_fallbacks == 1
+    assert acc.telemetry.records[-1]["aot_fallbacks"] == 1
 
 
 # ---------------------------------------------------------------------- #

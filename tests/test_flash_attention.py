@@ -337,3 +337,52 @@ def test_fit_block_and_nonpow2_seq():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
     )
+
+
+def test_flash_runs_per_device_over_a_live_mesh():
+    """A Mosaic kernel cannot be partitioned by GSPMD, so under a live
+    multi-device mesh the kernel runs inside a shard_map: batch over the
+    data axes, heads over tp. Values and grads must match dense attention
+    on the global arrays (kv_lengths rides along, split with the batch)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from accelerate_tpu import Accelerator, ParallelismPlugin
+
+    acc = Accelerator(parallelism_plugin=ParallelismPlugin(
+        dp_size=2, fsdp_size=2, tp_size=2,
+    ))
+    q, k, v = _qkv(B=4, S=128, H=4, Hkv=2, D=32)
+    lens = jnp.asarray([128, 77, 128, 31], jnp.int32)
+    place = lambda x, *spec: jax.device_put(x, NamedSharding(acc.mesh, P(*spec)))
+    qs, ks, vs = (place(x, ("dp", "fsdp"), None, "tp") for x in (q, k, v))
+    valid = (jnp.arange(128)[None, :] < lens[:, None])[:, :, None, None]
+
+    def loss(attn, q, k, v):
+        return jnp.sum(jnp.where(valid, attn(q, k, v) ** 2, 0.0))
+
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=True, kv_lengths=lens)
+    dense = lambda q, k, v: xla_attention(q, k, v, causal=True, kv_lengths=lens)
+    with _kernel_mode():
+        out = jax.jit(flash)(qs, ks, vs)
+        grads = jax.jit(jax.grad(
+            lambda q, k, v: loss(flash, q, k, v), argnums=(0, 1, 2)
+        ))(qs, ks, vs)
+        # the batch-1 init probe cannot tile dp x fsdp: replicated, not split
+        probe = jax.jit(lambda q, k, v: flash_attention(q, k, v))(
+            q[:1], k[:1], v[:1]
+        )
+    ref = dense(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(jnp.where(valid, out, 0)), np.asarray(jnp.where(valid, ref, 0)),
+        atol=5e-3, rtol=1e-2,
+    )
+    _assert_grads_close(
+        grads, jax.grad(lambda q, k, v: loss(dense, q, k, v),
+                        argnums=(0, 1, 2))(q, k, v),
+    )
+    np.testing.assert_allclose(
+        np.asarray(probe), np.asarray(xla_attention(q[:1], k[:1], v[:1], causal=True)),
+        atol=5e-3, rtol=1e-2,
+    )
+    # each device really got its own rows and heads
+    assert out.sharding.is_equivalent_to(qs.sharding, out.ndim)

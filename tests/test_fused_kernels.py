@@ -25,7 +25,16 @@ from accelerate_tpu.ops.fused import (
     prologue_supported,
     rope_inv_freqs,
 )
+from accelerate_tpu.ops.flash_attention import kernel_interpret_mode
 from accelerate_tpu.state import AcceleratorState, GradientState
+
+
+@pytest.fixture(autouse=True)
+def _interpret_kernels():
+    """Nothing in ops/ picks interpret mode by itself: without this
+    context the kernels lower for Mosaic and fail on the CPU backend."""
+    with kernel_interpret_mode():
+        yield
 
 
 def _reset():
@@ -114,7 +123,7 @@ def test_prologue_supported_gates_shapes():
     # rope pairs i with i + D/2: odd head_dim can never fuse
     assert not prologue_supported(4, 2, 15, 2, 32, 64)
     # interpret mode (CPU) has no tiling constraints beyond row blocking
-    assert prologue_supported(4, 2, 16, 2, 32, 64, interpret=True)
+    assert prologue_supported(4, 2, 16, 2, 32, 64)
 
 
 # --------------------------------------------------------------------- #

@@ -69,6 +69,48 @@ def test_parse_hlo_collectives_counts_and_skips_done_halves():
     assert len(ops) == 2
 
 
+def test_parse_hlo_collectives_sizes_name_only_operands():
+    # jax 0.9's HLO text prints operands as bare names, no inline shape
+    # (verbatim from hierarchical_psum on a 2x4 mesh): the operand's
+    # size is its definition's result. Parsed as operand_bytes=0 before.
+    text = """
+  %param.1 = f32[4,3]{1,0} parameter(0), metadata={op_name="x"}
+  %reduce_scatter.7 = f32[1,3]{1,0} reduce-scatter(%param.1), channel_id=1, replica_groups={{0,1,2,3},{4,5,6,7}}, use_global_device_ids=true, dimensions={0}, to_apply=%region_0.0, metadata={op_name="jit(hierarchical_psum)/shard_map/reduce_scatter" stack_frame_id=2}
+  %psum.7 = f32[1,3]{1,0} all-reduce(%reduce_scatter.7), channel_id=1, replica_groups={{0,4},{1,5},{2,6},{3,7}}, use_global_device_ids=true, to_apply=%region_1.0
+  %pair = (f32[2]{0}, s32[]) tuple(%a, %b)
+  ROOT %ar2 = (f32[2]{0}, s32[]) all-reduce(%pair), replica_groups={{0,1}}, to_apply=%add
+"""
+    rs, ar, ar2 = parse_hlo_collectives(text, num_devices=8, num_slices=2)
+    assert (rs.kind, rs.operand_bytes, rs.result_bytes) == (
+        "reduce-scatter", 48, 12,
+    )
+    assert rs.bytes_moved == 36  # 48 * 3/4 around the 4-ring
+    assert (ar.operand_bytes, ar.bytes_moved, ar.fabric) == (12, 12, "dcn")
+    assert ar2.operand_bytes == 12  # tuple-typed definition: 2*4 + 4
+
+
+def test_parse_hlo_collectives_reads_tpu_reduce_scatter_fusion():
+    # XLA:TPU's optimized HLO has no reduce-scatter instruction: a fusion
+    # computation named all-reduce-scatter* holds all-reduce + dynamic-slice
+    # (verbatim from a ZeRO-3 train step compiled for a v5e 2x2)
+    text = """
+%all-reduce-scatter.1.clone.clone (input.21: bf16[4096,4096]) -> bf16[4096,1024] {
+  %input.21 = bf16[4096,4096]{1,0:T(8,128)(2,1)} parameter(0)
+  %all-reduce.86 = bf16[4096,4096]{1,0:T(8,128)(2,1)} all-reduce(%input.21), channel_id=109, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%add.3.clone
+  ROOT %dynamic-slice.329 = bf16[4096,1024]{1,0:T(8,128)(2,1)} dynamic-slice(%all-reduce.86, %constant.1476, %multiply.176), dynamic_slice_sizes={4096,1024}
+}
+
+ENTRY %main.1 (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  ROOT %all-reduce.1 = f32[4]{0} all-reduce(%p), replica_groups={{0,1,2,3}}, to_apply=%add
+}
+"""
+    rs, ar = parse_hlo_collectives(text, num_devices=4)
+    assert (rs.kind, rs.operand_bytes) == ("reduce-scatter", 4096 * 4096 * 2)
+    assert rs.bytes_moved == 4096 * 4096 * 2 * 3 // 4
+    assert ar.kind == "all-reduce"  # a plain one outside the fusion stays
+
+
 def test_ring_bytes_estimates_are_analytic():
     # ring schedules: all-gather moves result*(g-1)/g, reduce-scatter
     # operand*(g-1)/g, all-reduce 2*operand*(g-1)/g

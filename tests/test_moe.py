@@ -13,15 +13,6 @@ from accelerate_tpu.ops.moe import (
     load_balancing_loss,
     moe_dispatch_combine,
     no_drop_capacity_factor,
-    ragged_ep_supported,
-)
-
-# the ragged EP schedule needs jax's partial-manual shard_map mode
-# (axis_names); on older jax the library refuses with NotImplementedError
-# and auto dispatch resolves to capacity instead
-requires_ragged_ep = pytest.mark.skipif(
-    not ragged_ep_supported(),
-    reason="jax shard_map partial-manual mode unavailable",
 )
 
 
@@ -193,7 +184,6 @@ def test_ragged_matches_dense_oracle():
         )
 
 
-@requires_ragged_ep
 def test_ragged_ep_matches_dense_oracle():
     """moe_ragged_ep (shard-capacity ragged schedule over an ep=2 mesh)
     matches the dense oracle exactly when the window covers everything
@@ -256,7 +246,6 @@ def test_ragged_ep_matches_dense_oracle():
     PartialState._reset_state()
 
 
-@requires_ragged_ep
 def test_auto_dispatch_resolves_to_ragged_under_ep():
     """moe_dispatch="auto" routes through the shard-capacity ragged EP
     schedule when the mesh has ep>1 — the r5 default flip, backed by the
@@ -305,7 +294,6 @@ def test_auto_dispatch_resolves_to_ragged_under_ep():
     PartialState._reset_state()
 
 
-@requires_ragged_ep
 def test_ragged_ep_shard_capacity_drops_overflow():
     """With a tight window (capacity_factor < needed) overflow rows drop
     to zero contribution — graceful degradation, not corruption."""

@@ -15,7 +15,6 @@ import pytest
 from accelerate_tpu import Accelerator
 from accelerate_tpu.parallel.mesh import build_mesh
 from accelerate_tpu.parallel.pipeline import (
-    partial_manual_supported,
     pipeline_apply,
     stacked_layer_shardings,
     validate_pipeline_plugin,
@@ -23,13 +22,6 @@ from accelerate_tpu.parallel.pipeline import (
 from accelerate_tpu.utils.dataclasses import ParallelismPlugin, ShardingStrategy
 
 L, H, F = 4, 16, 32  # layers, width, hidden
-
-# 1F1B (pipeline_train_step / unified_pipeline_step) and pp x tp/sp/ep are
-# partial-manual-only by design — older jax raises NotImplementedError
-requires_partial_manual = pytest.mark.skipif(
-    not partial_manual_supported(),
-    reason="jax shard_map partial-manual mode (axis_names) unavailable",
-)
 
 
 def _stacked_params(key=0):
@@ -167,11 +159,7 @@ def test_pipeline_plugin_validation():
         ParallelismPlugin(pp_size=2, ep_size=2, num_micro_batches=4),
     ]
     for plugin in compositions:
-        if partial_manual_supported():
-            validate_pipeline_plugin(plugin)
-        else:
-            with pytest.raises(NotImplementedError, match="partial-manual"):
-                validate_pipeline_plugin(plugin)
+        validate_pipeline_plugin(plugin)
     with pytest.raises(ValueError, match="num_micro_batches"):
         validate_pipeline_plugin(
             ParallelismPlugin(pp_size=4, num_micro_batches=2)
@@ -195,7 +183,6 @@ def _mse(y, tgt):
     return jnp.mean((y - tgt) ** 2)
 
 
-@requires_partial_manual
 @pytest.mark.parametrize("pp,tp", [(2, 1), (4, 1), (2, 2)])
 def test_1f1b_matches_sequential(pp, tp):
     """pipeline_train_step (1F1B, loss folded in) reproduces sequential
@@ -232,7 +219,6 @@ def test_1f1b_matches_sequential(pp, tp):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
-@requires_partial_manual
 def test_1f1b_composes_with_sp_ring_attention():
     """pp=2 x sp=2 (VERDICT r3 weak #6): a stage body containing RING
     attention runs under the 1F1B schedule — sp stays an auto axis of the
@@ -305,7 +291,6 @@ def test_1f1b_composes_with_sp_ring_attention():
         )
 
 
-@requires_partial_manual
 def test_1f1b_composes_with_ep_ragged_moe():
     """pp=2 x ep=2 (VERDICT r4 missing #2, the last composition
     rejection): a stage body containing the shard-capacity ragged MoE
@@ -417,7 +402,6 @@ def test_1f1b_single_stage_fallback():
     assert jax.tree.structure(grads) == jax.tree.structure(params)
 
 
-@requires_partial_manual
 def test_1f1b_peak_memory_beats_gpipe_autodiff():
     """The point of 1F1B: per-stage in-flight state is bounded by the ring
     (depth 2S-1), not by M. At M=32, S=2 the compiled temp allocation must
@@ -465,7 +449,6 @@ def test_1f1b_peak_memory_beats_gpipe_autodiff():
     assert temp_1f1b * 4 < temp_gpipe, (temp_1f1b, temp_gpipe)
 
 
-@requires_partial_manual
 def test_1f1b_feed_sharding_cuts_input_memory():
     """The (M, ...) input/target buffers shard over pp (feed discipline,
     VERDICT r3 weak #5): at large M the per-device argument bytes for
@@ -517,7 +500,6 @@ def test_1f1b_feed_sharding_cuts_input_memory():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
-@requires_partial_manual
 def test_unified_pipeline_step_fp16_gradscaler():
     """fp16 loss scaling under 1F1B (VERDICT r4 missing #3, the last AMP
     rejection): scaling each microbatch loss scales the cotangents the
@@ -610,7 +592,6 @@ def test_unified_pipeline_step_fp16_gradscaler():
     PartialState._reset_state()
 
 
-@requires_partial_manual
 def test_unified_pipeline_step_trains():
     """accelerator.unified_pipeline_step: the 1F1B schedule + clip +
     update as ONE program, first-class through the Accelerator. Trains the

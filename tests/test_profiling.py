@@ -763,7 +763,7 @@ def test_stamp_trend_flags_regressions_in_both_directions():
     assert "prev_value" not in rec
 
 
-def test_parse_baseline_records_wrapper_and_final_wins(tmp_path):
+def test_parse_baseline_records_wrapper_and_final_wins(tmp_path, monkeypatch):
     from accelerate_tpu.benchmarks.runner import (
         load_baseline,
         parse_baseline_records,
@@ -789,9 +789,10 @@ def test_parse_baseline_records_wrapper_and_final_wins(tmp_path):
     path = tmp_path / "BENCH_r06.json"
     path.write_text(wrapper)
     assert load_baseline(str(path))["dense"]["value"] == 55.0
-    assert load_baseline(None, search_dir=str(tmp_path))["dense"][
-        "value"] == 55.0
-    assert load_baseline(None, search_dir=str(tmp_path / "empty")) == {}
+    # no implicit lookup: a record that merely sits in the working
+    # directory is never a baseline
+    monkeypatch.chdir(tmp_path)
+    assert load_baseline(None) == {}
 
 
 # --------------------------------------------------------------------- #

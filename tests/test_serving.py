@@ -298,6 +298,28 @@ def test_eos_slot_refill_completes_all_requests(tiny_model):
     assert not engine.scheduler.has_work
 
 
+def test_engine_lives_where_its_weights_do(tiny_model):
+    """A fleet puts one engine per chip: the KV pool and every per-step
+    host put must land on the device that holds the engine's weights —
+    never first on device 0. Same tokens as an engine on device 0."""
+    cfg, model, params = tiny_model
+    home = jax.devices()[3]
+    there = ServingEngine(model, jax.device_put(params, home),
+                          max_slots=2, block_size=8)
+    here = ServingEngine(model, params, max_slots=2, block_size=8)
+    on = lambda tree: {d for leaf in jax.tree.leaves(tree) for d in leaf.devices()}
+    assert on(there.cache) == {home}  # allocated there, not moved there
+    outs = []
+    for eng in (there, here):
+        rid = eng.add_request([5, 9, 2, 7, 1], max_new_tokens=3)
+        for _ in eng.stream():
+            pass
+        outs.append(eng.result(rid))
+    assert outs[0] == outs[1] and len(outs[0]) == 3
+    assert on(there.cache) == {home} and on(there.params) == {home}
+    assert on(here.cache) == {jax.devices()[0]}
+
+
 def test_zero_decode_retrace_after_warmup(tiny_model):
     """The decode step must compile exactly ONCE: admissions, evictions,
     mixed depths and temperatures are all traced data. Prefill stays

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from accelerate_tpu import Accelerator, ParallelismPlugin
@@ -116,14 +116,14 @@ def _psum_fns(mesh):
         in_specs=spec,
         out_specs=P(),
     )
-    # check_rep=False: shard_map's static replication checker cannot
+    # check_vma=False: shard_map's static replication checker cannot
     # infer that the closing all_gather replicates over fsdp
     hier = shard_map(
         hierarchical_psum,
         mesh=mesh,
         in_specs=spec,
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return flat, hier
 

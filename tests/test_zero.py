@@ -166,3 +166,29 @@ def test_zero1_keeps_embedding_replicated():
     )
     embed_spec = specs["embed"]["embedding"].spec
     assert "fsdp" not in str(embed_spec), embed_spec
+
+
+def test_zero3_moments_are_born_on_their_params_shards():
+    """FULL_SHARD through the README line ParallelismPlugin(fsdp_size=-1):
+    adam's mu/nu take their parameter's sharding AT INIT. optax builds
+    them with zeros_like (no data dependence on the sharded params), so a
+    bare jit left every moment whole on device 0 until the first step."""
+    from accelerate_tpu.state import AcceleratorState, GradientState, PartialState
+
+    AcceleratorState._reset_state()
+    GradientState._reset_state()
+    PartialState._reset_state()
+    # dp's default -1 yields to the axis the caller marks auto
+    plugin = ParallelismPlugin(fsdp_size=-1, min_weight_size=8)
+    assert (plugin.dp_size, plugin.fsdp_size) == (1, -1)
+    acc = Accelerator(parallelism_plugin=plugin)
+    assert dict(acc.mesh.shape)["fsdp"] == 8
+    params, opt = acc.prepare(_params(), optax.adamw(1e-2))
+    adam = opt.opt_state[0]
+    for moments in (adam.mu, adam.nu):
+        for name, leaf in moments.items():
+            assert leaf.committed, name
+            assert leaf.sharding == params[name].sharding, name
+            assert len(leaf.addressable_shards) == 8
+            assert leaf.addressable_shards[0].data.size == leaf.size // 8
+    assert adam.count.committed and adam.count.sharding.is_fully_replicated
