@@ -504,24 +504,27 @@ class Accelerator:
             check (fp16), clip, update, sharding pins, GradScaler skip.
             Shared verbatim by the unfused cond branch and the fused scan
             path so the two modes are arithmetically identical."""
-            mean_grads = jax.tree.map(lambda a: a / num_accum, accum)
-            mean_grads, finite, new_ls = unscale_and_check(
-                mean_grads, ls, policy
-            )
-            gnorm = optax.global_norm(mean_grads)
-            scale_c = (
-                jnp.minimum(1.0, max_grad_norm / (gnorm + 1e-6))
-                if max_grad_norm is not None
-                else None
-            )
+            with jax.named_scope("accumulate"):
+                mean_grads = jax.tree.map(lambda a: a / num_accum, accum)
+                mean_grads, finite, new_ls = unscale_and_check(
+                    mean_grads, ls, policy
+                )
+            with jax.named_scope("clip"):
+                gnorm = optax.global_norm(mean_grads)
+                scale_c = (
+                    jnp.minimum(1.0, max_grad_norm / (gnorm + 1e-6))
+                    if max_grad_norm is not None
+                    else None
+                )
             # fused epilogue (ops/fused.py): when the optimizer is a
             # fused_adamw, the clip-mult -> moment update -> apply ->
             # overflow-hold tail runs as one Pallas kernel per leaf —
             # bitwise fp32 parity with the optax chain below
-            fused_out = maybe_fused_epilogue(
-                opt_transform, mean_grads, opt_state, params,
-                clip_scale=scale_c, finite=finite,
-            )
+            with jax.named_scope("optimizer"):
+                fused_out = maybe_fused_epilogue(
+                    opt_transform, mean_grads, opt_state, params,
+                    clip_scale=scale_c, finite=finite,
+                )
             if fused_out is not None:
                 new_params, new_opt_state = fused_out
                 new_params = _pin_to_shardings(
@@ -532,22 +535,24 @@ class Accelerator:
                 )
                 return new_params, new_opt_state, new_ls, gnorm, finite
             if scale_c is not None:
-                mean_grads = jax.tree.map(lambda g: g * scale_c, mean_grads)
-            updates, new_opt_state = opt_transform.update(
-                mean_grads, opt_state, params
-            )
-            new_params = optax.apply_updates(params, updates)
-            # self._param_shardings read at trace time for the same
-            # build-order reason as _opt_shardings
-            new_params = _pin_to_shardings(new_params, self._param_shardings)
-            new_opt_state = _pin_to_shardings(new_opt_state, _opt_shardings())
-            # fp16 overflow: keep old params/state (GradScaler skip)
-            new_params = jax.tree.map(
-                lambda n, o: jnp.where(finite, n, o), new_params, params
-            )
-            new_opt_state = jax.tree.map(
-                lambda n, o: jnp.where(finite, n, o), new_opt_state, opt_state
-            )
+                with jax.named_scope("clip"):
+                    mean_grads = jax.tree.map(lambda g: g * scale_c, mean_grads)
+            with jax.named_scope("optimizer"):
+                updates, new_opt_state = opt_transform.update(
+                    mean_grads, opt_state, params
+                )
+                new_params = optax.apply_updates(params, updates)
+                # self._param_shardings read at trace time for the same
+                # build-order reason as _opt_shardings
+                new_params = _pin_to_shardings(new_params, self._param_shardings)
+                new_opt_state = _pin_to_shardings(new_opt_state, _opt_shardings())
+                # fp16 overflow: keep old params/state (GradScaler skip)
+                new_params = jax.tree.map(
+                    lambda n, o: jnp.where(finite, n, o), new_params, params
+                )
+                new_opt_state = jax.tree.map(
+                    lambda n, o: jnp.where(finite, n, o), new_opt_state, opt_state
+                )
             return new_params, new_opt_state, new_ls, gnorm, finite
 
         # accumulate in grad_dtype (default fp32; bf16 halves the accum
@@ -565,13 +570,15 @@ class Accelerator:
             params = carry["params"]
             opt_state = carry["opt_state"]
             ls = carry.get("loss_scale")
-            compute_params = _cast_floating(params, policy.compute_dtype)
+            with jax.named_scope("cast"):
+                compute_params = _cast_floating(params, policy.compute_dtype)
 
             def _micro_loss(p, b):
-                out = loss_fn(p, b, **kw)
-                loss = out[0] if has_aux else out
-                aux = out[1] if has_aux else None
-                return scale_loss(loss.astype(jnp.float32), ls), (loss, aux)
+                with jax.named_scope("loss"):
+                    out = loss_fn(p, b, **kw)
+                    loss = out[0] if has_aux else out
+                    aux = out[1] if has_aux else None
+                    return scale_loss(loss.astype(jnp.float32), ls), (loss, aux)
 
             if remat_policy is not None:
                 # activation memory stays at one-microbatch scale: backward
@@ -582,23 +589,29 @@ class Accelerator:
             zero2 = self._zero2_grad_shardings(params)
 
             def _body(acc, micro_batch):
-                compute_batch = _cast_floating(micro_batch, policy.compute_dtype)
+                with jax.named_scope("cast"):
+                    compute_batch = _cast_floating(
+                        micro_batch, policy.compute_dtype
+                    )
                 grads, (loss, aux) = jax.grad(
                     lambda p: _micro_loss(p, compute_batch), has_aux=True
                 )(compute_params)
-                grads = _cast_floating(grads, accum_dtype)
-                acc = jax.tree.map(lambda a, g: a + g, acc, grads)
-                if zero2 is not None:
-                    # ZeRO-2: pin the scan carry to its fsdp shards so the
-                    # grad sum lowers to reduce-scatter, not all-reduce
-                    acc = jax.tree.map(
-                        jax.lax.with_sharding_constraint, acc, zero2
-                    )
+                with jax.named_scope("accumulate"):
+                    grads = _cast_floating(grads, accum_dtype)
+                    acc = jax.tree.map(lambda a, g: a + g, acc, grads)
+                    if zero2 is not None:
+                        # ZeRO-2: pin the scan carry to its fsdp shards so
+                        # the grad sum lowers to reduce-scatter, not
+                        # all-reduce
+                        acc = jax.tree.map(
+                            jax.lax.with_sharding_constraint, acc, zero2
+                        )
                 return acc, (loss.astype(jnp.float32), aux)
 
-            zeros = jax.tree.map(
-                lambda p: jnp.zeros(jnp.shape(p), accum_dtype), params
-            )
+            with jax.named_scope("accumulate"):
+                zeros = jax.tree.map(
+                    lambda p: jnp.zeros(jnp.shape(p), accum_dtype), params
+                )
             accum, (losses, auxes) = jax.lax.scan(_body, zeros, batch)
             params, opt_state, ls, gnorm, finite = _sync_apply(
                 accum, opt_state, params, ls
@@ -630,14 +643,16 @@ class Accelerator:
             micro = carry["micro_step"]
             ls = carry.get("loss_scale")
 
-            compute_params = _cast_floating(params, policy.compute_dtype)
-            compute_batch = _cast_floating(batch, policy.compute_dtype)
+            with jax.named_scope("cast"):
+                compute_params = _cast_floating(params, policy.compute_dtype)
+                compute_batch = _cast_floating(batch, policy.compute_dtype)
 
             def _scaled_loss(p, b):
-                out = loss_fn(p, b, **kw)
-                loss = out[0] if has_aux else out
-                aux = out[1] if has_aux else None
-                return scale_loss(loss.astype(jnp.float32), ls), (loss, aux)
+                with jax.named_scope("loss"):
+                    out = loss_fn(p, b, **kw)
+                    loss = out[0] if has_aux else out
+                    aux = out[1] if has_aux else None
+                    return scale_loss(loss.astype(jnp.float32), ls), (loss, aux)
 
             if remat_policy is not None:
                 ckpt_kw = {} if remat_policy is True else {"policy": remat_policy}
@@ -646,18 +661,22 @@ class Accelerator:
             grads, (loss, aux) = jax.grad(
                 lambda p: _scaled_loss(p, compute_batch), has_aux=True
             )(compute_params)
-            grads = _cast_floating(grads, accum_dtype)
-            if num_accum > 1:
-                accum = jax.tree.map(lambda a, g: a + g, carry["accum_grads"], grads)
-                zero2 = self._zero2_grad_shardings(accum)
-                if zero2 is not None:
-                    # ZeRO-2: pin the carried buffer to its fsdp shards so
-                    # the grad sum lowers to reduce-scatter, not all-reduce
+            with jax.named_scope("accumulate"):
+                grads = _cast_floating(grads, accum_dtype)
+                if num_accum > 1:
                     accum = jax.tree.map(
-                        jax.lax.with_sharding_constraint, accum, zero2
+                        lambda a, g: a + g, carry["accum_grads"], grads
                     )
-            else:
-                accum = grads  # no buffer carried: saves 4 bytes/param HBM
+                    zero2 = self._zero2_grad_shardings(accum)
+                    if zero2 is not None:
+                        # ZeRO-2: pin the carried buffer to its fsdp shards
+                        # so the grad sum lowers to reduce-scatter, not
+                        # all-reduce
+                        accum = jax.tree.map(
+                            jax.lax.with_sharding_constraint, accum, zero2
+                        )
+                else:
+                    accum = grads  # no buffer carried: saves 4 bytes/param HBM
             micro = micro + 1
             is_sync = micro >= num_accum
 
@@ -666,7 +685,8 @@ class Accelerator:
                 new_params, new_opt_state, new_ls, gnorm, finite = _sync_apply(
                     accum, opt_state, params, ls
                 )
-                zeroed = jax.tree.map(jnp.zeros_like, accum)
+                with jax.named_scope("accumulate"):
+                    zeroed = jax.tree.map(jnp.zeros_like, accum)
                 return (zeroed, new_opt_state, new_params, new_ls, gnorm, finite)
 
             def _hold(operand):

@@ -14,6 +14,10 @@ in exactly one place, :func:`resolve_cache_dir`:
 3. else ``<checkout>/.jax_compile_cache`` — a fixed path beside the
    package, never one built from ``tempfile``, a pid or the time.
 
+The names a program gives its operations (``jax.named_scope``, module
+names) ARE part of the key here, source lines are not: a trace of a
+cached program shows the scopes of the tree that runs it.
+
 :func:`activate_persistent_cache` applies the rule and is what
 ``AcceleratorState``, ``ServingEngine``, the bench children, the graft
 entry and ``tests/conftest.py`` all call. It is idempotent.
@@ -107,6 +111,14 @@ def activate_persistent_cache(plugin: Any = None) -> str:
             )
         if getattr(plugin, "explain_cache_misses", None):
             jax.config.update("jax_explain_cache_misses", True)
+        # Names are part of the program: JAX's default key leaves an
+        # operation's metadata out, so an executable compiled from ANOTHER
+        # tree answers the lookup and a profile then shows that tree's
+        # scope paths (the per-layer metrics read them). With metadata in
+        # the key and no traceback frames in it, the key holds the scope
+        # paths and neither source lines, checkout path nor call site.
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+        jax.config.update("jax_traceback_in_locations_limit", 0)
         if _active_dir != path:
             logger.info("persistent XLA compilation cache: %s", path)
         _active_dir = path

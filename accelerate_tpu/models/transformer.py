@@ -829,17 +829,20 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
         if per_layer is not None:
             in_axes = in_axes + (0,)
             args = extra + (per_layer,)
-        x, _ = nn.scan(
-            cls,
-            variable_axes={"params": 0, "intermediates": 0, "cache": 0},
-            # "dropout": LoRA delta dropout inside the scanned block — the
-            # entry is inert unless a dropout rng is actually passed to
-            # apply (adapter training with LoraConfig.dropout > 0)
-            split_rngs={"params": True, "dropout": True},
-            in_axes=in_axes,
-            length=n,
-            metadata_params={nn.PARTITION_NAME: "layers"},
-        )(cfg, **block_kwargs, name="layers")(x, *args)
+        # a name for the loop's own copies and slices in a device trace,
+        # not a Flax scope: the parameter tree is unchanged
+        with jax.named_scope("layers"):
+            x, _ = nn.scan(
+                cls,
+                variable_axes={"params": 0, "intermediates": 0, "cache": 0},
+                # "dropout": LoRA delta dropout inside the scanned block —
+                # the entry is inert unless a dropout rng is actually passed
+                # to apply (adapter training with LoraConfig.dropout > 0)
+                split_rngs={"params": True, "dropout": True},
+                in_axes=in_axes,
+                length=n,
+                metadata_params={nn.PARTITION_NAME: "layers"},
+            )(cfg, **block_kwargs, name="layers")(x, *args)
     else:
         for i in range(n):
             if per_layer is None:

@@ -92,6 +92,7 @@ def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return jnp.clip(q, -127.0, 127.0).astype(jnp.int8), scale
 
 
+@jax.named_scope("kv_write")
 def paged_update(
     key_pool: jax.Array,
     value_pool: jax.Array,
@@ -152,6 +153,7 @@ def paged_update(
     return key_pool.at[bf, of].set(kf), value_pool.at[bf, of].set(vf)
 
 
+@jax.named_scope("paged_attention")
 def paged_attention(
     q: jax.Array,
     key_pool: jax.Array,

@@ -270,6 +270,7 @@ def _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window):
         ),
         out_shape=out_shape,
         interpret=kernels_interpreted(),
+        name="flash_fwd",
     )(*(((lengths,) if padded else ()) + (q, k, v)))
     return out, lse
 
@@ -544,6 +545,7 @@ def _bwd_fused(scale, causal, bq, bk, window, prefix, q, k, v, dout, lse,
         ),
         out_shape=out_shape,
         interpret=kernels_interpreted(),
+        name="flash_bwd",
     )(*(prefix + (q, k, v, dout, lse, delta)))
     if g > 1:  # sum the GQA group partials back onto the kv heads
         dk = dkh.reshape(B, Hkv, g, Skv, D).sum(2).astype(k.dtype)
@@ -604,6 +606,7 @@ def _bwd(scale, causal, block_q, block_k, window, res, dout):
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=kernels_interpreted(),
+        name="flash_bwd_dq",
     )(*(prefix + (q, k, v, dout, lse, delta)))
 
     dkv_in_specs = [
@@ -641,6 +644,7 @@ def _bwd(scale, causal, block_q, block_k, window, res, dout):
         ),
         out_shape=dkv_out_shape,
         interpret=kernels_interpreted(),
+        name="flash_bwd_dkv",
     )(*(prefix + (q, k, v, dout, lse, delta)))
     return dq, dk, dv, None
 

@@ -42,6 +42,7 @@ import numpy as np
 
 from ..logging import get_logger
 from ..ops.attention import PagedKVState
+from ..utils.profiling import annotate
 from .block_pool import BlockPool, PrefixCache, prefix_keys
 from .sampling import SlotSampling, sample_tokens
 from .scheduler import ContinuousScheduler, Request, Slot
@@ -393,7 +394,8 @@ class ServingEngine:
             last = jnp.take_along_axis(
                 logits, (length - 1)[:, None, None], axis=1
             )[:, 0]
-            token = sample_tokens(last, key, temp, top_k=top_k, top_p=top_p)
+            with jax.named_scope("sample"):
+                token = sample_tokens(last, key, temp, top_k=top_k, top_p=top_p)
             return mutated["cache"], token
 
         def _decode(params, cache, tokens, tables, cache_lens, lengths,
@@ -411,9 +413,10 @@ class ServingEngine:
                 {"params": params, "cache": cache}, tokens, decode=True,
                 paged=state, mutable=["cache"], **_lora_kwargs(lora_args),
             )
-            token = sample_tokens(
-                logits[:, -1], key, temps, top_k=top_k, top_p=top_p
-            )
+            with jax.named_scope("sample"):
+                token = sample_tokens(
+                    logits[:, -1], key, temps, top_k=top_k, top_p=top_p
+                )
             return mutated["cache"], token
 
         def _key_chain(key):
@@ -479,13 +482,16 @@ class ServingEngine:
                     paged=state, mutable=["cache"],
                     **_lora_kwargs(lora_args),
                 )
-                outs = [
-                    sample_tokens(
-                        logits[:, j], keys[j], temps, top_k=top_k, top_p=top_p
-                    )
-                    for j in range(width)
-                ]
-                return mutated["cache"], jnp.stack(outs, axis=1)
+                with jax.named_scope("sample"):
+                    outs = [
+                        sample_tokens(
+                            logits[:, j], keys[j], temps, top_k=top_k,
+                            top_p=top_p,
+                        )
+                        for j in range(width)
+                    ]
+                    out = jnp.stack(outs, axis=1)
+                return mutated["cache"], out
 
             return jax.jit(_verify)
 
@@ -631,7 +637,7 @@ class ServingEngine:
         ONE decode step over the whole slot batch. Returns the tokens
         produced this iteration."""
         try:
-            with self._placed():
+            with annotate("atpu:serve.step", step=self._steps), self._placed():
                 return self._step_inner()
         except Exception as exc:
             # device OOM: the autopsy is written from state already in
@@ -648,35 +654,41 @@ class ServingEngine:
         return jax.default_device(self._device)
 
     def _step_inner(self) -> list[TokenEvent]:
-        had_work = self.has_work
-        events: list[TokenEvent] = []
-        for req in self.scheduler.shed_expired():
-            self._shed(req)
-        for slot in self.scheduler.slots:
-            if slot.busy and slot.done:
-                self._finish(slot)
-        if self.preemption:
-            self._try_resume()
-        if self._inbox:
-            self._seat_manifests()
-        blocked_before = dict(self.scheduler.blocked_reasons)
-        admitted = self.scheduler.admit()
-        if self.preemption and self._maybe_preempt(
-            blocked_before, exclude={s.index for s in admitted}
-        ):
-            # the freed seat/blocks fund the queue head THIS step
-            admitted += self.scheduler.admit()
-        for slot in admitted:
-            if self.adapters is not None:
-                # pin the adapter for the request's whole flight — evict
-                # refuses while any seated request still decodes under it
-                self.adapters.acquire(slot.request.adapter)
-            self.span_log.on_admit(slot.request.request_id, slot.admit_time)
-            if self.prefill_chunk_tokens is None:
+        # the phases below are host spans in the profiler's trace
+        # (schedule, prefill per request, decode.inputs, decode.fetch,
+        # emit): they tile the step, so a gap of the chip falls in one
+        with annotate("atpu:serve.schedule") as phase:
+            had_work = self.has_work
+            events: list[TokenEvent] = []
+            for req in self.scheduler.shed_expired():
+                self._shed(req)
+            for slot in self.scheduler.slots:
+                if slot.busy and slot.done:
+                    self._finish(slot)
+            if self.preemption:
+                self._try_resume()
+            if self._inbox:
+                self._seat_manifests()
+            blocked_before = dict(self.scheduler.blocked_reasons)
+            admitted = self.scheduler.admit()
+            if self.preemption and self._maybe_preempt(
+                blocked_before, exclude={s.index for s in admitted}
+            ):
+                # the freed seat/blocks fund the queue head THIS step
+                admitted += self.scheduler.admit()
+            for slot in admitted:
+                if self.adapters is not None:
+                    # pin the adapter for the request's whole flight — evict
+                    # refuses while any seated request still decodes under it
+                    self.adapters.acquire(slot.request.adapter)
+                self.span_log.on_admit(slot.request.request_id, slot.admit_time)
+                if self.prefill_chunk_tokens is not None:
+                    self._begin_chunked(slot)
+            phase.set_metadata(admitted=len(admitted))
+        if self.prefill_chunk_tokens is None:
+            for slot in admitted:
                 self._prefill_slot(slot, events)
-            else:
-                self._begin_chunked(slot)
-        if self.prefill_chunk_tokens is not None:
+        else:
             self._chunked_prefill_step(events)
         if self._role == "prefill":
             # prompt ingestion only: every seat whose prefill just
@@ -695,6 +707,7 @@ class ServingEngine:
         ]
         if active and self.scheduler.chunked_reserve:
             active = self._grow_active(active)
+        emit = None  # the host half of the decode: fetched tokens -> events
         if active:
             # speculate only when some slot holds a +k block reservation
             # (granted at admission) — slots seated before speculation
@@ -702,23 +715,27 @@ class ServingEngine:
             if self._proposer is not None and any(
                 s.lookahead > 0 for s in active
             ):
-                self._spec_step(active, events)
+                emit = self._spec_step(active)
             else:
-                self._decode_step(active, events)
-        self._steps += 1
-        if self.gauge_interval and self._steps % self.gauge_interval == 0:
-            self._sample_gauges()
-        if self.slo_tracker is not None and (
-            (
-                self.slo_tracker.config.interval_steps
-                and self._steps % self.slo_tracker.config.interval_steps == 0
-            )
-            # drain edge: the last SLO record in the stream (and the
-            # flight ring) must reflect final end-of-run attainment,
-            # not the cadence snapshot from mid-flight
-            or (had_work and not self.scheduler.has_work)
-        ):
-            self._emit_slo()
+                emit = self._decode_step(active)
+        with annotate("atpu:serve.emit") as phase:
+            if emit is not None:
+                emit(events)
+            self._steps += 1
+            if self.gauge_interval and self._steps % self.gauge_interval == 0:
+                self._sample_gauges()
+            if self.slo_tracker is not None and (
+                (
+                    self.slo_tracker.config.interval_steps
+                    and self._steps % self.slo_tracker.config.interval_steps == 0
+                )
+                # drain edge: the last SLO record in the stream (and the
+                # flight ring) must reflect final end-of-run attainment,
+                # not the cadence snapshot from mid-flight
+                or (had_work and not self.scheduler.has_work)
+            ):
+                self._emit_slo()
+            phase.set_metadata(tokens=len(events))
         return events
 
     def stream(self) -> Iterator[TokenEvent]:
@@ -851,60 +868,62 @@ class ServingEngine:
         # at — prefill covers only the tail (always >= 1 token: the last
         # prompt position's logits seed sampling).
         cached = slot.cached_tokens
-        self.span_log.on_prefill(
-            req.request_id, self._now(), cached_prefix_tokens=cached
-        )
-        if cached and self.prefix_cache is not None:
-            self.prefix_cache.tokens_saved_total += cached
-        # COW any SHARED block the tail prefill will write into. With
-        # block-aligned hits the tail starts on a private block, so this
-        # loop only fires on a full-prompt hit (cached == prompt_len-1):
-        # the 1-token tail re-writes the last shared block's final slot.
-        for t in range(cached // self.block_size,
-                       (prompt_len - 1) // self.block_size + 1):
-            if t in slot.shared:
-                self._cow_block(slot, t)
-        tail = req.prompt[cached:]
         tail_len = prompt_len - cached
         bucket = _next_pow2(tail_len)
-        self._prefill_buckets.add(bucket)
-        self.prefill_bucket_tokens_total += bucket
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :tail_len] = tail
-        table = np.zeros((1, self._max_table), np.int32)
-        table[0, :len(slot.blocks)] = slot.blocks
-        if self.adapters is not None:
-            self._slot_adapter[slot.index] = self.adapters.slot_of(req.adapter)
-        self.cache, token = self._prefill_fn(
-            self.params, self.cache, jnp.asarray(ids), jnp.asarray(table),
-            jnp.asarray([tail_len], jnp.int32),
-            jnp.asarray([cached], jnp.int32), self._split_key(),
-            jnp.asarray([req.temperature], jnp.float32),
-            *self._lora_call_args([self._slot_adapter[slot.index]]),
-        )
-        token = int(np.asarray(token)[0])
-        slot.cache_len = prompt_len
-        slot.pending = token
-        slot.generated = [token]
-        # index every FULL prompt block we freshly prefilled so the next
-        # identical prefix skips it. Shared positions are already
-        # canonical; COW copies stay out (partially recomputed content).
-        if self.prefix_cache is not None:
-            self.prefix_cache.publish(
-                req.prompt, req.adapter, slot.blocks,
-                skip_indices=slot.shared | slot.cow_indices,
-                keys=req.prefix_keys,
+        with annotate("atpu:serve.prefill", request_id=req.request_id,
+                      bucket=bucket, cached=cached):
+            self.span_log.on_prefill(
+                req.request_id, self._now(), cached_prefix_tokens=cached
             )
-        slot.first_token_time = self._now()
-        self.span_log.on_first_token(req.request_id, slot.first_token_time)
-        self._tables[slot.index] = table[0]
-        self._tables_dev = None
-        if self._proposer is not None and slot.lookahead > 0:
-            # seed the proposer (the draft model prefills the FULL
-            # prompt through its own paged cache; n-gram is a no-op)
-            self._proposer.prefill_slot(slot)
-        self.sampling.set_slot(slot.index, req.temperature)
-        self._note_token(slot, token, events)
+            if cached and self.prefix_cache is not None:
+                self.prefix_cache.tokens_saved_total += cached
+            # COW any SHARED block the tail prefill will write into. With
+            # block-aligned hits the tail starts on a private block, so this
+            # loop only fires on a full-prompt hit (cached == prompt_len-1):
+            # the 1-token tail re-writes the last shared block's final slot.
+            for t in range(cached // self.block_size,
+                           (prompt_len - 1) // self.block_size + 1):
+                if t in slot.shared:
+                    self._cow_block(slot, t)
+            tail = req.prompt[cached:]
+            self._prefill_buckets.add(bucket)
+            self.prefill_bucket_tokens_total += bucket
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :tail_len] = tail
+            table = np.zeros((1, self._max_table), np.int32)
+            table[0, :len(slot.blocks)] = slot.blocks
+            if self.adapters is not None:
+                self._slot_adapter[slot.index] = self.adapters.slot_of(req.adapter)
+            self.cache, token = self._prefill_fn(
+                self.params, self.cache, jnp.asarray(ids), jnp.asarray(table),
+                jnp.asarray([tail_len], jnp.int32),
+                jnp.asarray([cached], jnp.int32), self._split_key(),
+                jnp.asarray([req.temperature], jnp.float32),
+                *self._lora_call_args([self._slot_adapter[slot.index]]),
+            )
+            token = int(np.asarray(token)[0])
+            slot.cache_len = prompt_len
+            slot.pending = token
+            slot.generated = [token]
+            # index every FULL prompt block we freshly prefilled so the next
+            # identical prefix skips it. Shared positions are already
+            # canonical; COW copies stay out (partially recomputed content).
+            if self.prefix_cache is not None:
+                self.prefix_cache.publish(
+                    req.prompt, req.adapter, slot.blocks,
+                    skip_indices=slot.shared | slot.cow_indices,
+                    keys=req.prefix_keys,
+                )
+            slot.first_token_time = self._now()
+            self.span_log.on_first_token(req.request_id, slot.first_token_time)
+            self._tables[slot.index] = table[0]
+            self._tables_dev = None
+            if self._proposer is not None and slot.lookahead > 0:
+                # seed the proposer (the draft model prefills the FULL
+                # prompt through its own paged cache; n-gram is a no-op)
+                self._proposer.prefill_slot(slot)
+            self.sampling.set_slot(slot.index, req.temperature)
+            self._note_token(slot, token, events)
 
     # ------------------------------------------------------------------ #
     # chunked prefill (PR 17): prompt ingestion under a per-step budget
@@ -988,58 +1007,60 @@ class ServingEngine:
                                              else 0)
         if not self._ensure_blocks(slot, tokens_needed):
             return False
-        for t in range(start // self.block_size,
-                       (start + chunk_len - 1) // self.block_size + 1):
-            if t in slot.shared:
-                self._cow_block(slot, t)
         bucket = _next_pow2(chunk_len)
-        self._prefill_buckets.add(bucket)
-        self.prefill_bucket_tokens_total += bucket
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :chunk_len] = req.prompt[start:start + chunk_len]
-        table = np.zeros((1, self._max_table), np.int32)
-        table[0, :len(slot.blocks)] = slot.blocks
-        # intermediate chunks DISCARD their sampled token, so they must
-        # not consume a chain key either — only the final chunk (whose
-        # sample is the request's first token) draws one. A solo
-        # request's outputs are bit-identical chunked or not at any
-        # temperature; batched timelines interleave the shared per-step
-        # decode keys differently, so cross-run parity is greedy-exact.
-        key = self._split_key() if final else self._key
-        self.cache, token = self._prefill_fn(
-            self.params, self.cache, jnp.asarray(ids), jnp.asarray(table),
-            jnp.asarray([chunk_len], jnp.int32),
-            jnp.asarray([start], jnp.int32), key,
-            jnp.asarray([req.temperature], jnp.float32),
-            *self._lora_call_args([self._slot_adapter[slot.index]]),
-        )
-        slot.cache_len = start + chunk_len
-        slot.chunks += 1
-        self._prefill_chunks_total += 1
-        self._tables[slot.index] = table[0]
-        self._tables_dev = None
-        if final:
-            token = int(np.asarray(token)[0])
-            slot.pending = token
-            slot.generated.append(token)
-            if self.prefix_cache is not None and slot.chunks == 1:
-                # single-chunk == the unchunked bucket width, so the
-                # content is canonical; multi-chunk prefills stay out of
-                # the index (their blocks were written at per-chunk
-                # bucket widths)
-                self.prefix_cache.publish(
-                    req.prompt, req.adapter, slot.blocks,
-                    skip_indices=slot.shared | slot.cow_indices,
-                    keys=req.prefix_keys,
-                )
-            slot.first_token_time = self._now()
-            self.span_log.on_first_token(
-                req.request_id, slot.first_token_time, chunks=slot.chunks
+        with annotate("atpu:serve.prefill", request_id=req.request_id,
+                      bucket=bucket, cached=start):
+            for t in range(start // self.block_size,
+                           (start + chunk_len - 1) // self.block_size + 1):
+                if t in slot.shared:
+                    self._cow_block(slot, t)
+            self._prefill_buckets.add(bucket)
+            self.prefill_bucket_tokens_total += bucket
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :chunk_len] = req.prompt[start:start + chunk_len]
+            table = np.zeros((1, self._max_table), np.int32)
+            table[0, :len(slot.blocks)] = slot.blocks
+            # intermediate chunks DISCARD their sampled token, so they must
+            # not consume a chain key either — only the final chunk (whose
+            # sample is the request's first token) draws one. A solo
+            # request's outputs are bit-identical chunked or not at any
+            # temperature; batched timelines interleave the shared per-step
+            # decode keys differently, so cross-run parity is greedy-exact.
+            key = self._split_key() if final else self._key
+            self.cache, token = self._prefill_fn(
+                self.params, self.cache, jnp.asarray(ids), jnp.asarray(table),
+                jnp.asarray([chunk_len], jnp.int32),
+                jnp.asarray([start], jnp.int32), key,
+                jnp.asarray([req.temperature], jnp.float32),
+                *self._lora_call_args([self._slot_adapter[slot.index]]),
             )
-            if self._proposer is not None and slot.lookahead > 0:
-                self._proposer.prefill_slot(slot)
-            self.sampling.set_slot(slot.index, req.temperature)
-            self._note_token(slot, token, events)
+            slot.cache_len = start + chunk_len
+            slot.chunks += 1
+            self._prefill_chunks_total += 1
+            self._tables[slot.index] = table[0]
+            self._tables_dev = None
+            if final:
+                token = int(np.asarray(token)[0])
+                slot.pending = token
+                slot.generated.append(token)
+                if self.prefix_cache is not None and slot.chunks == 1:
+                    # single-chunk == the unchunked bucket width, so the
+                    # content is canonical; multi-chunk prefills stay out of
+                    # the index (their blocks were written at per-chunk
+                    # bucket widths)
+                    self.prefix_cache.publish(
+                        req.prompt, req.adapter, slot.blocks,
+                        skip_indices=slot.shared | slot.cow_indices,
+                        keys=req.prefix_keys,
+                    )
+                slot.first_token_time = self._now()
+                self.span_log.on_first_token(
+                    req.request_id, slot.first_token_time, chunks=slot.chunks
+                )
+                if self._proposer is not None and slot.lookahead > 0:
+                    self._proposer.prefill_slot(slot)
+                self.sampling.set_slot(slot.index, req.temperature)
+                self._note_token(slot, token, events)
         return True
 
     def _ensure_blocks(self, slot: Slot, tokens: int) -> bool:
@@ -1520,37 +1541,47 @@ class ServingEngine:
             transfer_outbox_depth=len(self._outbox),
         )
 
-    def _decode_step(self, active: list[Slot], events: list[TokenEvent]) -> None:
-        tokens = np.zeros((self.max_slots, 1), np.int32)
-        cache_lens = np.zeros(self.max_slots, np.int32)
-        lengths = np.zeros(self.max_slots, np.int32)
-        for slot in active:
-            # shared blocks are immutable: a decode step about to write
-            # into one (the pending token lands at cache_len) copies it
-            # private first. Block-aligned hits mean this only fires when
-            # generation flows into a still-shared block boundary case.
-            t = slot.cache_len // self.block_size
-            if t in slot.shared:
-                self._cow_block(slot, t)
-            tokens[slot.index, 0] = slot.pending
-            cache_lens[slot.index] = slot.cache_len
-            lengths[slot.index] = 1
-        self.cache, out = self._decode_fn(
-            self.params, self.cache, jnp.asarray(tokens),
-            self._tables_device(), jnp.asarray(cache_lens),
-            jnp.asarray(lengths), self.sampling.temperatures(),
-            self._split_key(),
-            *self._lora_call_args(self._slot_adapter),
-        )
-        out = np.asarray(out)
-        for slot in active:
-            token = int(out[slot.index])
-            slot.cache_len += 1  # the fed token was written this step
-            slot.pending = token
-            slot.generated.append(token)
-            self._note_token(slot, token, events)
+    def _decode_step(self, active: list[Slot]) -> Callable:
+        """The device half of one decode step over the seated batch: the
+        inputs, the dispatch and the fetch of the sampled tokens. Returns
+        the host half, ``emit(events)``, which :meth:`_step_inner` runs
+        in its emit phase."""
+        with annotate("atpu:serve.decode.inputs", seated=len(active)):
+            tokens = np.zeros((self.max_slots, 1), np.int32)
+            cache_lens = np.zeros(self.max_slots, np.int32)
+            lengths = np.zeros(self.max_slots, np.int32)
+            for slot in active:
+                # shared blocks are immutable: a decode step about to write
+                # into one (the pending token lands at cache_len) copies it
+                # private first. Block-aligned hits mean this only fires when
+                # generation flows into a still-shared block boundary case.
+                t = slot.cache_len // self.block_size
+                if t in slot.shared:
+                    self._cow_block(slot, t)
+                tokens[slot.index, 0] = slot.pending
+                cache_lens[slot.index] = slot.cache_len
+                lengths[slot.index] = 1
+            self.cache, out = self._decode_fn(
+                self.params, self.cache, jnp.asarray(tokens),
+                self._tables_device(), jnp.asarray(cache_lens),
+                jnp.asarray(lengths), self.sampling.temperatures(),
+                self._split_key(),
+                *self._lora_call_args(self._slot_adapter),
+            )
+        with annotate("atpu:serve.decode.fetch"):
+            out = np.asarray(out)
 
-    def _spec_step(self, active: list[Slot], events: list[TokenEvent]) -> None:
+        def emit(events: list[TokenEvent]) -> None:
+            for slot in active:
+                token = int(out[slot.index])
+                slot.cache_len += 1  # the fed token was written this step
+                slot.pending = token
+                slot.generated.append(token)
+                self._note_token(slot, token, events)
+
+        return emit
+
+    def _spec_step(self, active: list[Slot]) -> Callable:
         """One speculative iteration: propose up to k tokens per slot,
         verify pending + drafts in ONE compiled pass at ``(max_slots,
         k + 1)``, commit the longest target-agreeing prefix host-side.
@@ -1563,82 +1594,91 @@ class ServingEngine:
         copied-on-write up front, before any speculative write."""
         k = self._spec.k
         width = k + 1
-        for slot in active:
-            # COW the whole speculative write span [cache_len, cache_len
-            # + lookahead]. Under block-aligned admission shared blocks
-            # sit strictly below the cursor's block, so this loop firing
-            # means a boundary case (full-prompt hit) — same defensive
-            # posture as _decode_step, widened by the lookahead.
-            span = slot.lookahead
-            hi = min(
-                (slot.cache_len + span) // self.block_size,
-                len(slot.blocks) - 1,
-            )
-            for t in range(slot.cache_len // self.block_size, hi + 1):
-                if t in slot.shared:
-                    self._cow_block(slot, t)
-        spec_slots = [s for s in active if s.lookahead > 0]
-        drafts = self._proposer.propose(spec_slots, self._tables_device())
-        if not any(drafts.values()):
+        with annotate("atpu:serve.decode.inputs", seated=len(active)):
+            for slot in active:
+                # COW the whole speculative write span [cache_len,
+                # cache_len + lookahead]. Under block-aligned admission
+                # shared blocks sit strictly below the cursor's block, so
+                # this loop firing means a boundary case (full-prompt hit)
+                # — same defensive posture as _decode_step, widened by the
+                # lookahead.
+                span = slot.lookahead
+                hi = min(
+                    (slot.cache_len + span) // self.block_size,
+                    len(slot.blocks) - 1,
+                )
+                for t in range(slot.cache_len // self.block_size, hi + 1):
+                    if t in slot.shared:
+                        self._cow_block(slot, t)
+            spec_slots = [s for s in active if s.lookahead > 0]
+            drafts = self._proposer.propose(spec_slots, self._tables_device())
+            drafted_any = any(drafts.values())
+            if drafted_any:
+                tokens = np.zeros((self.max_slots, width), np.int32)
+                cache_lens = np.zeros(self.max_slots, np.int32)
+                lengths = np.zeros(self.max_slots, np.int32)
+                n_drafted = {}
+                for slot in active:
+                    d = drafts.get(slot.index, [])[: min(k, slot.lookahead)]
+                    n_drafted[slot.index] = len(d)
+                    tokens[slot.index, 0] = slot.pending
+                    if d:
+                        tokens[slot.index, 1:1 + len(d)] = d
+                    cache_lens[slot.index] = slot.cache_len
+                    lengths[slot.index] = 1 + len(d)
+                vfn = self._verify_fns.get(width)
+                if vfn is None:
+                    vfn = self._verify_fns[width] = self._make_verify(width)
+                # one host-side stack -> one device put (a per-key jnp.stack
+                # would cost width+1 dispatches on the hottest loop in
+                # serving)
+                keys = np.stack(self._peek_keys(width))
+                self.cache, out = vfn(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    self._tables_device(), jnp.asarray(cache_lens),
+                    jnp.asarray(lengths), self.sampling.temperatures(),
+                    jnp.asarray(keys),
+                    *self._lora_call_args(self._slot_adapter),
+                )
+        if not drafted_any:
             # nothing proposed this round (n-gram miss everywhere): the
             # plain decode program is the cheaper identical-output path,
             # and it consumes one chain key exactly like a 0-draft verify
-            self._decode_step(active, events)
             self._spec_rounds_total += 1
-            return
-        tokens = np.zeros((self.max_slots, width), np.int32)
-        cache_lens = np.zeros(self.max_slots, np.int32)
-        lengths = np.zeros(self.max_slots, np.int32)
-        n_drafted = {}
-        for slot in active:
-            d = drafts.get(slot.index, [])[: min(k, slot.lookahead)]
-            n_drafted[slot.index] = len(d)
-            tokens[slot.index, 0] = slot.pending
-            if d:
-                tokens[slot.index, 1:1 + len(d)] = d
-            cache_lens[slot.index] = slot.cache_len
-            lengths[slot.index] = 1 + len(d)
-        vfn = self._verify_fns.get(width)
-        if vfn is None:
-            vfn = self._verify_fns[width] = self._make_verify(width)
-        # one host-side stack -> one device put (a per-key jnp.stack
-        # would cost width+1 dispatches on the hottest loop in serving)
-        keys = np.stack(self._peek_keys(width))
-        self.cache, out = vfn(
-            self.params, self.cache, jnp.asarray(tokens),
-            self._tables_device(), jnp.asarray(cache_lens),
-            jnp.asarray(lengths), self.sampling.temperatures(),
-            jnp.asarray(keys),
-            *self._lora_call_args(self._slot_adapter),
-        )
-        out = np.asarray(out)
-        max_emitted = 1
-        for slot in active:
-            n = n_drafted[slot.index]
-            drafted = tokens[slot.index, 1:1 + n]
-            slot.cache_len += 1  # the pending token's write is always valid
-            emitted = 0
-            for j in range(n + 1):
-                token = int(out[slot.index, j])
-                accepted = j < n and token == int(drafted[j])
-                slot.pending = token
-                slot.generated.append(token)
-                emitted += 1
-                if accepted:
-                    slot.spec_accepted += 1
-                    self._spec_accepted_total += 1
-                self._note_token(slot, token, events)
-                if slot.done or not accepted:
-                    break
-                # the matched draft was written at this position by the
-                # verify pass — committing it is pure cursor advancement
-                slot.cache_len += 1
-            slot.spec_proposed += n
-            self._spec_proposed_total += n
-            max_emitted = max(max_emitted, emitted)
-            self._proposer.commit(slot)
-        self._spec_rounds_total += 1
-        self._consume_keys(max_emitted)
+            return self._decode_step(active)
+        with annotate("atpu:serve.decode.fetch"):
+            out = np.asarray(out)
+
+        def emit(events: list[TokenEvent]) -> None:
+            max_emitted = 1
+            for slot in active:
+                n = n_drafted[slot.index]
+                drafted = tokens[slot.index, 1:1 + n]
+                slot.cache_len += 1  # the pending token's write is always valid
+                emitted = 0
+                for j in range(n + 1):
+                    token = int(out[slot.index, j])
+                    accepted = j < n and token == int(drafted[j])
+                    slot.pending = token
+                    slot.generated.append(token)
+                    emitted += 1
+                    if accepted:
+                        slot.spec_accepted += 1
+                        self._spec_accepted_total += 1
+                    self._note_token(slot, token, events)
+                    if slot.done or not accepted:
+                        break
+                    # the matched draft was written at this position by the
+                    # verify pass — committing it is pure cursor advancement
+                    slot.cache_len += 1
+                slot.spec_proposed += n
+                self._spec_proposed_total += n
+                max_emitted = max(max_emitted, emitted)
+                self._proposer.commit(slot)
+            self._spec_rounds_total += 1
+            self._consume_keys(max_emitted)
+
+        return emit
 
     def _note_token(self, slot: Slot, token: int,
                     events: list[TokenEvent]) -> None:

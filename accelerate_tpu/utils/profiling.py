@@ -359,7 +359,13 @@ def profile(
         handle._stop()
 
 
-def annotate(name: str):
-    """Named region in the trace timeline (``jax.profiler.TraceAnnotation``)
-    — the torch.profiler ``record_function`` analogue."""
-    return jax.profiler.TraceAnnotation(name)
+def annotate(name: str, **stats):
+    """A host span in the profiler's own trace
+    (``jax.profiler.TraceAnnotation``): it lands in the session's
+    ``.xplane.pb`` on the clock of the device planes, so an idle gap of the
+    chip can be laid to what the host was doing in it. ``stats`` become the
+    event's stats; one known only at the end is added with
+    ``span.set_metadata(...)`` before the exit. A span exists when a profiler
+    session does; with none it costs the object and an inactive-check.
+    ``ServingEngine.step`` draws its phases with it (``atpu:serve.*``)."""
+    return jax.profiler.TraceAnnotation(name, **stats)
