@@ -57,6 +57,13 @@ class PagedKVState:
     HBM-bandwidth-bound, so the 2x (vs bf16) byte shrink is a direct
     capacity/throughput lever (:func:`paged_update` quantizes on
     write, :func:`paged_attention` dequantizes on gather).
+
+    ``single_device`` (static) is what the pools' owner knows about
+    where they live: True when every pool sits whole on ONE device (the
+    engine sets it from where its weights are), False — the default —
+    when they may be sharded over a mesh. A Mosaic kernel cannot be
+    auto-partitioned, so only True lets :func:`paged_attention` take
+    the decode kernel.
     """
 
     block_table: jax.Array
@@ -65,6 +72,7 @@ class PagedKVState:
     num_blocks: int = flax.struct.field(pytree_node=False)
     block_size: int = flax.struct.field(pytree_node=False)
     kv_dtype: str = flax.struct.field(pytree_node=False, default="native")
+    single_device: bool = flax.struct.field(pytree_node=False, default=False)
 
 
 # floor on the per-token amax scale: keeps all-zero rows (garbage block,
@@ -153,6 +161,29 @@ def paged_update(
     return key_pool.at[bf, of].set(kf), value_pool.at[bf, of].set(vf)
 
 
+def decode_kernel_eligible(state: PagedKVState, q_len: int, pool) -> bool:
+    """Whether :func:`paged_attention` takes the Pallas decode kernel for
+    a call of ``q_len`` query tokens against ``pool`` ((..., block_size,
+    Hkv, D); only its shape and dtype are read) — decided at trace time
+    from what the call can observe, never from a model's name or a user
+    option. ONE predicate: the serving engine asks it too, for its
+    ``decode_attn_kernel`` trace count. The last clause is the kernel's
+    tiling rule: a block's ``block_size * Hkv`` rows fill whole sublane
+    tiles of the pool's dtype."""
+    from .flash_attention import kernels_interpreted
+
+    kv_heads, head_dim = pool.shape[-2:]
+    sublanes = 8 * max(1, 4 // jnp.dtype(pool.dtype).itemsize)
+    return (
+        q_len == 1
+        and state.kv_dtype == "native"
+        and state.single_device
+        and head_dim % 128 == 0
+        and (state.block_size * kv_heads) % sublanes == 0
+        and (jax.default_backend() == "tpu" or kernels_interpreted())
+    )
+
+
 @jax.named_scope("paged_attention")
 def paged_attention(
     q: jax.Array,
@@ -165,20 +196,39 @@ def paged_attention(
     key_scale: Optional[jax.Array] = None,
     value_scale: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Attention read through the block table: gather each slot's blocks
-    into a (B, max_blocks*block_size, Hkv, D) view and run the xla path
-    over it. Because the table is indexed by ``pos // block_size``,
-    gathered column j IS global position j, so the decode mask is the
-    same globally-anchored band as the dense cache path: query at global
-    row r sees column c iff ``c <= r`` (and ``c > r - window`` under a
-    sliding band). Table tail entries point at the garbage block, whose
-    columns sit beyond every row and mask out. One compiled program for
-    prefill (B=1, S=bucket) and decode (B=slots, S=1) alike.
+    """Attention read through the block table, in one of two forms of
+    one algorithm (grouped softmax over a slot's blocks, under one mask
+    rule): query at global row r sees column c iff ``c <= r`` (and ``c >
+    r - window`` under a sliding band).
+
+    * The decode kernel (:mod:`.paged_attention`, where
+      :func:`decode_kernel_eligible` holds: S == 1, native pools whole
+      on one device, head_dim a multiple of 128, a TPU or
+      ``kernel_interpret_mode()``): walks each slot's LIVE blocks only,
+      straight out of the pools. Every live K/V byte moves once.
+    * The gather form (everything else: prefill, chunked prefill,
+      speculative verify, int8 pools, pools sharded over a mesh, CPU):
+      gather each slot's blocks into a (B, max_blocks*block_size, Hkv,
+      D) view and run the xla path over it. Because the table is indexed
+      by ``pos // block_size``, gathered column j IS global position j,
+      so the mask is the same globally-anchored band as the dense cache
+      path. Table tail entries point at the garbage block, whose columns
+      sit beyond every row and mask out. Its cost follows ``max_blocks``,
+      not ``cache_len``: on the v5e at 16 slots x 1024 positions the
+      gather and the two contractions over it were 28 % of the decode
+      program even without the GQA repeat (PERF.md, PR 25).
 
     Under ``kv_dtype="int8"`` the gathered int8 rows are dequantized
     (row * its per-token scale) at the query's dtype before the math —
     the pools stay int8 in HBM, only the gathered working set widens.
     """
+    if decode_kernel_eligible(state, q.shape[1], key_pool):
+        from .paged_attention import paged_decode_attention
+
+        return paged_decode_attention(
+            q, key_pool, value_pool, state.block_table, state.cache_len,
+            scale=scale, softcap=softcap, window=window,
+        )
     b, s = q.shape[:2]
     bs = state.block_size
     max_blocks = state.block_table.shape[1]
@@ -225,16 +275,6 @@ def make_causal_mask(
     return keep.astype(dtype)
 
 
-def _repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
-    """(B, S, n_kv, D) -> (B, S, n_kv*n_rep, D) for grouped-query attention."""
-    if n_rep == 1:
-        return x
-    b, s, h, d = x.shape
-    return jnp.broadcast_to(x[:, :, :, None, :], (b, s, h, n_rep, d)).reshape(
-        b, s, h * n_rep, d
-    )
-
-
 def lengths_to_mask(kv_lengths: jax.Array, kv_len: int) -> jax.Array:
     """(B,) valid-prefix lengths -> (B, 1, 1, kv_len) bool key mask."""
     cols = jnp.arange(kv_len)[None, :]
@@ -255,8 +295,24 @@ def xla_attention(
 ) -> jax.Array:
     """Reference-path attention, shapes (B, S, H, D) / kv (B, Skv, Hkv, D).
 
-    fp32 softmax regardless of input dtype (bf16-safe), GQA via kv head
-    repetition (broadcast, not materialized by XLA after fusion).
+    fp32 softmax regardless of input dtype (bf16-safe). GQA (``G = H //
+    Hkv > 1``) takes one of two forms, chosen by shape, as the v5e
+    measured them (PERF.md, PR 25):
+
+    * ``S < Skv`` — a query block against a longer cache: paged prefill,
+      speculative verify, every decode that is not the Pallas kernel —
+      contracts per KV-head GROUP: ``q`` is viewed as (B, S, Hkv, G, D)
+      and both einsums run against the un-repeated ``k``/``v``, so each
+      K/V byte is read once. Until PR 25 K and V were repeated ``G``
+      times first, on the belief that XLA fuses the broadcast away; for
+      the decode shape it did not — the repeat was written to HBM and
+      read back, 28 % of the decode program (16 x 1 x 1024: 1398 us a
+      call repeated, 54 us grouped).
+    * ``S == Skv`` — self-attention in training and evaluation — keeps
+      the repeat: there it is a few MB, and forward + backward measured
+      5 % faster with it (8 x 192 tokens, 32/8 heads: 218 us against 229).
+
+    ``G == 1`` is the plain multi-head program either way.
     ``window`` (requires ``causal``): the Mistral/Qwen2 sliding-window
     band — each query sees at most the last ``window`` keys; a TRACED
     window (the per-layer Gemma-2 pattern riding the layer scan) is fine
@@ -266,11 +322,29 @@ def xla_attention(
     if window is not None and not causal:
         raise ValueError("sliding window requires causal attention")
     orig_dtype = q.dtype
-    n_rep = q.shape[2] // k.shape[2]
-    k = _repeat_kv(k, n_rep)
-    v = _repeat_kv(v, n_rep)
-    scale = scale if scale is not None else q.shape[-1] ** -0.5
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
+    b, s_q, h, d = q.shape
+    s_kv, h_kv = k.shape[1], k.shape[2]
+    g = h // h_kv
+    if g > 1 and s_q == s_kv:
+        k, v = (
+            jnp.broadcast_to(
+                x[:, :, :, None, :], (b, s_kv, h_kv, g, d)
+            ).reshape(b, s_kv, h, d)
+            for x in (k, v)
+        )
+        g = 1
+    scale = scale if scale is not None else d ** -0.5
+    if g == 1:
+        logits = jnp.einsum(
+            "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
+        )
+    else:
+        # H = Hkv * G is contiguous: the grouped scores fold back to
+        # (B, H, S, Skv) for free, where masks and bias live
+        logits = jnp.einsum(
+            "bqhgd,bkhd->bhgqk", q.reshape(b, s_q, h_kv, g, d), k,
+            preferred_element_type=jnp.float32,
+        ).reshape(b, h, s_q, s_kv)
     logits = logits * scale
     if softcap is not None:
         # Gemma-2 tanh soft-capping, applied to raw scores BEFORE any
@@ -279,19 +353,24 @@ def xla_attention(
     if bias is not None:
         logits = logits + bias.astype(jnp.float32)
     if causal:
-        cmask = make_causal_mask(q.shape[1], k.shape[1], window=window)
+        cmask = make_causal_mask(s_q, s_kv, window=window)
         logits = jnp.where(cmask[None, None, :, :], logits, jnp.finfo(jnp.float32).min)
     if kv_lengths is not None:
         mask = (
-            lengths_to_mask(kv_lengths, k.shape[1])
+            lengths_to_mask(kv_lengths, s_kv)
             if mask is None
-            else jnp.logical_and(mask, lengths_to_mask(kv_lengths, k.shape[1]))
+            else jnp.logical_and(mask, lengths_to_mask(kv_lengths, s_kv))
         )
     if mask is not None:
         # mask: broadcastable to (B, H, Q, K); True = attend
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(orig_dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    if g == 1:
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    out = jnp.einsum(
+        "bhgqk,bkhd->bqhgd", probs.reshape(b, h_kv, g, s_q, s_kv), v
+    )
+    return out.reshape(b, s_q, h, d)
 
 
 def flash_self_attention_eligible(seq_len: int) -> bool:

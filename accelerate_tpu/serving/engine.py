@@ -41,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..logging import get_logger
-from ..ops.attention import PagedKVState
+from ..ops.attention import PagedKVState, decode_kernel_eligible
 from ..utils.profiling import annotate
 from .block_pool import BlockPool, PrefixCache, prefix_keys
 from .sampling import SlotSampling, sample_tokens
@@ -298,9 +298,11 @@ class ServingEngine:
         self._shed_order: collections.deque = collections.deque()
         self._steps = 0
         self._http: Any = None
+        # decode_attn_kernel: of the traced decode programs, how many
+        # took the Pallas paged-attention kernel (the rest gather)
         self._traces = {
-            "prefill": 0, "decode": 0, "cow": 0, "verify": 0,
-            "swap_out": 0, "swap_in": 0,
+            "prefill": 0, "decode": 0, "decode_attn_kernel": 0, "cow": 0,
+            "verify": 0, "swap_out": 0, "swap_in": 0,
         }
         # every bucket width a prefill ever ran at — the set
         # capture_programs() reconstructs abstract specs from
@@ -351,6 +353,10 @@ class ServingEngine:
         self.kv_bytes_per_token = kv_bytes / (num_blocks * block_size)
 
         traces = self._traces
+        # what the engine knows about its pools and attention cannot see
+        # from inside a trace: they sit whole on the weights' one device
+        single_device = self._device is not None
+        pool_leaf = self._kv_leaf_info[0][0]
 
         def _lora_kwargs(lora_args):
             """(stacks, scales, slot_ids) trailing args -> the model's
@@ -385,6 +391,7 @@ class ServingEngine:
                 num_blocks=num_blocks,
                 block_size=block_size,
                 kv_dtype=kv_state_dtype,
+                single_device=single_device,
             )
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, ids, decode=True,
@@ -408,6 +415,10 @@ class ServingEngine:
                 num_blocks=num_blocks,
                 block_size=block_size,
                 kv_dtype=kv_state_dtype,
+                single_device=single_device,
+            )
+            traces["decode_attn_kernel"] += decode_kernel_eligible(
+                state, tokens.shape[1], jax.tree.leaves(cache)[pool_leaf]
             )
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, tokens, decode=True,
@@ -476,6 +487,7 @@ class ServingEngine:
                     num_blocks=num_blocks,
                     block_size=block_size,
                     kv_dtype=kv_state_dtype,
+                    single_device=single_device,
                 )
                 logits, mutated = model.apply(
                     {"params": params, "cache": cache}, tokens, decode=True,
@@ -1826,6 +1838,13 @@ class ServingEngine:
                 if self.prefix_cache is not None else 0
             ),
             "tokens_in_flight": sum(s.cache_len for s in active),
+            # table entries that hold a position the next decode step
+            # reads (cache_len included: its token is written first),
+            # over the max_slots x max_blocks a gather reads: the share
+            # of that read the decode kernel's live-block walk still makes
+            "live_block_share": sum(
+                s.cache_len // self.block_size + 1 for s in active
+            ) / (self.max_slots * self._max_table),
             "admission_blocked_no_free_slot_total":
                 sched.blocked_reasons["no_free_slot"],
             "admission_blocked_pool_exhausted_total":

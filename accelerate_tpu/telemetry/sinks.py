@@ -142,6 +142,10 @@ shed; emitted by the ServingEngine's span log)::
     cow_copies_total                     int    copy-on-write block copies
     prefill_tokens_saved_total           int    prompt tokens never prefilled
     tokens_in_flight                     int    KV tokens held by active slots
+    live_block_share                     float  table entries holding live
+                                                positions / (max_slots x
+                                                max_blocks): what the decode
+                                                kernel reads of a gather
     admission_blocked_no_free_slot_total  int   admit() stalls: batch full
     admission_blocked_pool_exhausted_total int  admit() stalls: pool empty
     shed_queue_full_total                int    cumulative sheds per reason

@@ -392,6 +392,57 @@ def adamw_case(say, sz: Sizes, dry: bool) -> None:
         "(rtol 1e-5, atol 1e-6)")
 
 
+def paged_decode_case(say, sz: Sizes, dry: bool) -> None:
+    """The decode kernel (live blocks only, straight out of the pools)
+    against the gather form of the same read, at the serve shape: ragged
+    contexts, one idle slot, one at the end of its table, scattered
+    blocks, the sliding band on."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu.ops.attention import PagedKVState, paged_attention
+    from accelerate_tpu.ops.paged_attention import paged_decode_attention
+
+    rng = np.random.default_rng(SEED + 5)
+    slots, block = sz.serve_slots, sz.serve_block
+    max_table = -(-sz.serve_max_seq // block)
+    num_blocks = slots * max_table + 1
+    pool = lambda: jnp.asarray(
+        rng.standard_normal((num_blocks, block, sz.kv_heads, sz.head_dim)),
+        jnp.bfloat16)
+    key_pool, value_pool = pool(), pool()
+    q = jnp.asarray(rng.standard_normal((slots, 1, sz.heads, sz.head_dim)),
+                    jnp.bfloat16)
+    cache_len = rng.integers(1, sz.serve_max_seq - 1, slots)
+    cache_len[0], cache_len[-1] = 0, sz.serve_max_seq - 1
+    table = np.zeros((slots, max_table), np.int32)
+    ids = 1 + rng.permutation(num_blocks - 1)
+    for b, n in enumerate(cache_len):
+        used = int(n) // block + 1
+        table[b, :used] = ids[b * max_table:b * max_table + used]
+    table, cache_len = jnp.asarray(table), jnp.asarray(cache_len, jnp.int32)
+    window = sz.serve_max_seq // 3
+
+    def kernel(q, key_pool, value_pool, table, cache_len):
+        return paged_decode_attention(q, key_pool, value_pool, table,
+                                      cache_len, window=window)
+
+    name = (f"paged decode bf16 {slots} slots x {sz.heads}/{sz.kv_heads} "
+            f"heads, table {max_table} x {block}, window {window}")
+    got = run_compiled(say, name, kernel,
+                       (q, key_pool, value_pool, table, cache_len),
+                       expect_mosaic=1, dry=dry)
+    state = PagedKVState(  # single_device left False: the gather form
+        block_table=table, cache_len=cache_len,
+        lengths=jnp.ones((slots,), jnp.int32), num_blocks=num_blocks,
+        block_size=block)
+    want = jax.jit(
+        lambda q, k, v: paged_attention(q, k, v, state, window=window)
+    )(q, key_pool, value_pool)
+    check_errors(say, name, {"out": nerr(got, want)}, TOL_KERNEL_BF16)
+
+
 def kernel_phase(say, sz: Sizes, dry: bool) -> None:
     import contextlib
 
@@ -407,6 +458,7 @@ def kernel_phase(say, sz: Sizes, dry: bool) -> None:
         flash_cases(say, sz, dry)
         prologue_case(say, sz, dry)
         adamw_case(say, sz, dry)
+        paged_decode_case(say, sz, dry)
     say("kernel phase PASSED")
 
 
@@ -643,6 +695,9 @@ def paged_logits_check(say, model, params, sz: Sizes, device) -> None:
             block_table=jnp.asarray(table), num_blocks=num_blocks,
             cache_len=jnp.asarray([cache_len], jnp.int32),
             lengths=jnp.asarray([length], jnp.int32), block_size=block,
+            # the pools below sit whole on ``device``: on the chip the
+            # decode steps run the paged_decode kernel, as the engine's do
+            single_device=True,
         )
 
     with jax.default_device(device):
@@ -686,6 +741,32 @@ def paged_logits_check(say, model, params, sz: Sizes, device) -> None:
     assert err <= TOL_LOGITS_BF16, (
         f"paged cache disagrees with the full forward pass by {err:.3e}"
     )
+
+
+def assert_decode_kernel(say, engine, cfg) -> None:
+    """The compiled decode program reads the KV pools through the Pallas
+    kernel: exactly one Mosaic custom call per compiled layer body (one
+    under ``nn.scan``), every one under scope ``paged_attention``, none
+    interpreted — as the train phase counts its flash kernels."""
+    from accelerate_tpu.ops.flash_attention import kernels_interpreted
+    from accelerate_tpu.profiling.registry import ProgramRegistry
+
+    assert not kernels_interpreted(), "a kernel would run interpreted"
+    before = engine.trace_counts()
+    engine.capture_programs(ProgramRegistry())
+    assert engine.trace_counts() == before
+    text = engine._captured_programs["serve_decode"].as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    bodies = 1 if cfg.scan_layers else cfg.num_layers
+    assert len(calls) == bodies, (
+        f"decode program holds {len(calls)} Mosaic custom call(s), expected "
+        f"{bodies}: the paged_decode kernel was dropped or duplicated"
+    )
+    assert all("paged_attention/paged_decode" in line for line in calls), calls
+    say(f"serve: compiled decode program holds {len(calls)} Mosaic custom "
+        f"call(s) under scope paged_attention ({bodies} compiled layer "
+        "body), none interpreted")
 
 
 def serve_phase(say, sz: Sizes, dry: bool) -> None:
@@ -779,6 +860,12 @@ def serve_phase(say, sz: Sizes, dry: bool) -> None:
         assert counts["decode"] == 1, f"decode traced {counts['decode']}x"
         assert counts["prefill"] >= 1
         assert stats["allocated"] == 0, f"leaked blocks: {stats}"
+        if not dry:
+            assert counts["decode_attn_kernel"] == 1, (
+                "the decode program took the gather form of paged_attention"
+            )
+    if not dry:
+        assert_decode_kernel(say, engines[0], cfg)
     assert_placement("after traffic")
     delta = mon.delta(cache_before)
     say(f"serve: persistent cache over the phase hits="
