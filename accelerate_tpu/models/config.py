@@ -13,6 +13,8 @@ from typing import Optional
 
 
 SUPPORTED_ROPE_TYPES = ("default", "llama3", "linear")
+# per-layer operators a ``layer_types`` entry may name (config.json names)
+LAYER_TYPES = ("full_attention", "conv")
 # required rope_scaling keys per type (beyond rope_type itself)
 _ROPE_REQUIRED_KEYS = {
     "default": (),
@@ -137,6 +139,40 @@ class TransformerConfig:
     # expert sees every token (the exact-math test oracle, O(E) FLOPs)
     moe_dispatch: str = "auto"
     moe_capacity_factor: float = 2.0
+    # expert width; None -> intermediate_size (Mixtral: experts as wide as
+    # the dense MLP would be)
+    moe_intermediate_size: Optional[int] = None
+    # leading layers that keep the dense MLP in a model whose other layers
+    # are expert layers
+    num_dense_layers: int = 0
+    # how the router scores: "softmax" (Mixtral: softmax over all experts,
+    # top-k, weights renormalised) or "sigmoid" (independent scores; the
+    # three switches below apply)
+    moe_router: str = "softmax"
+    # a per-expert bias added to the scores for the CHOICE only: the
+    # combine weight of a chosen expert is its unbiased score
+    moe_expert_bias: bool = False
+    # divide the k chosen scores by (their sum + 1e-6)
+    moe_norm_topk_prob: bool = True
+    moe_routed_scaling_factor: float = 1.0
+    # the chip's share of an expert-parallel deployment: ``num_experts`` is
+    # how many experts this layer HOLDS, the router keeps its published
+    # width ``moe_router_width`` (None -> num_experts) and chooses among all
+    # of them, and the layer computes the part of the result that experts
+    # [moe_expert_offset, moe_expert_offset + num_experts) give — exact, no
+    # token dropped; a token with no choice here gets 0 from the layer
+    moe_router_width: Optional[int] = None
+    moe_expert_offset: int = 0
+    # per-layer operator, len num_layers: "full_attention" or "conv" (the
+    # gated short convolution: in_proj to 3 x hidden, B * x, depthwise
+    # causal conv of length conv_L_cache, C * ., out_proj). None -> every
+    # layer attends. Layers of different kinds have different parameter
+    # shapes: models/transformer._plan_layers scans each maximal run of a
+    # repeating period and unrolls what does not repeat.
+    layer_types: Optional[tuple] = None
+    conv_L_cache: int = 3
+    # RMSNorm over head_dim on q and k (one weight vector each), before rope
+    qk_norm: bool = False
     # fp8 projections: e4m3 fwd / e5m2 bwd matmuls (ops/fp8.py) — the
     # TransformerEngine capability; pair with mixed_precision="fp8"
     fp8: bool = False
@@ -203,6 +239,60 @@ class TransformerConfig:
                     "per-layer windows ride the scan as traced values, "
                     "which only the xla attention path supports — use "
                     "attention_impl 'xla' or None"
+                )
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types has {len(self.layer_types)} entries for "
+                    f"{self.num_layers} layers"
+                )
+            unknown = set(self.layer_types) - set(LAYER_TYPES)
+            if unknown:
+                raise ValueError(
+                    f"unknown layer_types {sorted(unknown)}; supported: "
+                    f"{', '.join(LAYER_TYPES)}"
+                )
+            if self.layer_windows is not None:
+                raise ValueError(
+                    "layer_windows and layer_types cannot be combined: the "
+                    "per-layer window rides ONE homogeneous scan"
+                )
+            if self.conv_L_cache < 1:
+                raise ValueError(
+                    f"conv_L_cache must be >= 1, got {self.conv_L_cache}"
+                )
+        if not 0 <= self.num_dense_layers <= self.num_layers:
+            raise ValueError(
+                f"num_dense_layers {self.num_dense_layers} outside "
+                f"[0, {self.num_layers}]"
+            )
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown moe_router {self.moe_router!r}; supported: "
+                "softmax, sigmoid"
+            )
+        if self.num_experts > 0:
+            width = self.moe_router_width or self.num_experts
+            if not (0 <= self.moe_expert_offset
+                    and self.moe_expert_offset + self.num_experts <= width):
+                raise ValueError(
+                    f"experts [{self.moe_expert_offset}, "
+                    f"{self.moe_expert_offset + self.num_experts}) do not lie "
+                    f"inside the router's {width} outputs"
+                )
+            if self.num_experts_per_tok > width:
+                raise ValueError(
+                    f"num_experts_per_tok {self.num_experts_per_tok} exceeds "
+                    f"the router's {width} outputs"
+                )
+            if width != self.num_experts and self.moe_dispatch not in (
+                "auto", "ragged"
+            ):
+                raise ValueError(
+                    "a layer that holds a share of the experts computes it "
+                    "through the ragged dispatch only (moe_dispatch 'auto' "
+                    "or 'ragged')"
                 )
         if self.num_kv_heads is None:
             self.num_kv_heads = self.num_heads

@@ -286,6 +286,7 @@ class Attention(nn.Module):
             if (
                 not self.decode
                 and not cfg.fp8
+                and not cfg.qk_norm  # the kernel ropes what it projects
                 and not lora_on_qkv
                 and fused_ops.prologue_supported(
                     cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
@@ -342,6 +343,12 @@ class Attention(nn.Module):
             q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
             k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
             v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+            if cfg.qk_norm:
+                # per-head RMSNorm over head_dim, one weight vector each,
+                # BEFORE rope (every cache regime below ropes after this)
+                with jax.named_scope("qk_norm"):
+                    q = RMSNorm(cfg, name="q_norm")(q)
+                    k = RMSNorm(cfg, name="k_norm")(k)
 
         use_paged = False
         if decode and paged is not None:
@@ -488,6 +495,61 @@ class Attention(nn.Module):
         return y
 
 
+class _CausalDepthwiseConv(nn.Module):
+    """c_t = sum_j kernel[j] * z_{t-(L-1)+j}, z_{<0} = 0: one filter of
+    ``length`` taps per channel, no bias, no activation. Three shifted
+    multiply-adds — at L = 3 XLA fuses them into one pass over z."""
+
+    length: int
+
+    @nn.compact
+    def __call__(self, z):
+        kernel = self.param(
+            "kernel",
+            nn.with_partitioning(
+                nn.initializers.variance_scaling(1.0, "fan_in", "normal",
+                                                 in_axis=0, out_axis=1),
+                (None, "embed"),
+            ),
+            (self.length, z.shape[-1]),
+            jnp.float32,
+        )
+        if hasattr(kernel, "unbox"):
+            kernel = kernel.unbox()
+        kernel = kernel.astype(z.dtype)
+        s = z.shape[1]
+        padded = jnp.pad(z, ((0, 0), (self.length - 1, 0), (0, 0)))
+        return sum(
+            kernel[j] * jax.lax.slice_in_dim(padded, j, j + s, axis=1)
+            for j in range(self.length)
+        )
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution that stands where attention would:
+    ``in_proj`` to 3 x hidden, split in this order into B, C, X;
+    ``out_proj(C * conv(B * X))`` with a depthwise causal convolution of
+    ``conv_L_cache`` taps over the sequence. No state is carried between
+    calls: the training and evaluation path only (its decode state beside
+    a KV cache is ROADMAP Reach A4)."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        proj = _make_proj(cfg, _dtype(cfg))
+        h = cfg.hidden_size
+        u = proj("in_proj", 3 * h, ("embed", "mlp"))(x)
+        b_gate, c_gate, xs = jnp.split(u, 3, axis=-1)
+        with jax.named_scope("gate"):
+            z = b_gate * xs
+        c = _CausalDepthwiseConv(cfg.conv_L_cache, name="conv1d")(z)
+        with jax.named_scope("gate"):
+            y = c_gate * c
+        return proj("out_proj", h, ("mlp", "embed"))(y)
+
+
 class MLP(nn.Module):
     """SwiGLU feed-forward (Llama family)."""
 
@@ -529,7 +591,24 @@ class MLP(nn.Module):
 
 
 class MoE(nn.Module):
-    """Mixtral-style sparse MoE.
+    """Sparse mixture of experts.
+
+    Routing (``config.moe_router``): "softmax" — Mixtral's: softmax over
+    all experts, top-k, weights renormalised; "sigmoid" — independent
+    sigmoid scores, the choice made on score + ``expert_bias`` (when
+    ``moe_expert_bias``) while the combine weight stays the unbiased
+    score, divided by (sum + 1e-6) when ``moe_norm_topk_prob``, times
+    ``moe_routed_scaling_factor``. The router works in float32.
+
+    The share: ``num_experts`` is how many experts this layer HOLDS, of a
+    router ``moe_router_width`` wide (None: all of them), starting at
+    ``moe_expert_offset``. The router chooses among all its outputs, the
+    weights are normalised over all k choices, and the layer returns the
+    part of the result that the experts held here give: a token with no
+    choice here gets 0 and keeps its residual. No token is dropped at any
+    imbalance (``ops.moe.moe_ragged``: the choices of absent experts go
+    through one more group, of zero weights). What the absent experts would
+    add lies on other chips; nothing here stands in for them.
 
     Expert weights are stacked on a leading ``expert`` logical axis; with
     ``ep_size > 1`` GSPMD shards experts across the ``ep`` mesh axis and the
@@ -538,36 +617,72 @@ class MoE(nn.Module):
 
     Three dispatch modes (``config.moe_dispatch``): "ragged" — grouped
     matmuls via jax.lax.ragged_dot, exact at ep==1, shard-capacity
-    schedule (moe_ragged_ep) under ep>1 — the default at every ep;
-    "capacity" — the GShard-style static-shape schedule (ops/moe.py,
-    FLOPs independent of E, the GSPMD-auto alternative and old-jax
-    fallback); "dense" — every expert computes every token (O(E) FLOPs,
-    exact math, the test oracle).
+    schedule (moe_ragged_ep) under ep>1 — the default at every ep, and the
+    one path of a layer that holds a share; "capacity" — the GShard-style
+    static-shape schedule (ops/moe.py, FLOPs independent of E, the
+    GSPMD-auto alternative and old-jax fallback); "dense" — every expert
+    computes every token (O(E) FLOPs, exact math, the test oracle).
+
+    Sown under ``intermediates`` (``CausalLM.loss_fn(model, with_aux=True)``
+    returns their means over the expert layers): ``moe_aux_loss``, and from
+    the ragged path ``moe_local_choice_share``,
+    ``moe_expert_load_max_over_mean`` and ``moe_rows_computed_over_needed``.
     """
 
     config: TransformerConfig
 
     @nn.compact
     def __call__(self, x):
-        from ..ops.moe import load_balancing_loss, moe_dispatch_combine
+        from ..ops.moe import (
+            load_balancing_loss, moe_dispatch_combine, ragged_load_stats,
+        )
 
         cfg = self.config
         dtype = _dtype(cfg)
         E, K = cfg.num_experts, cfg.num_experts_per_tok
+        R = cfg.moe_router_width or E  # the router's published width
+        share = R != E
         b, s, h = x.shape
-        f = cfg.intermediate_size
+        f = cfg.moe_intermediate_size or cfg.intermediate_size
 
-        router = nn.Dense(
-            E,
-            use_bias=False,
-            dtype=jnp.float32,
-            param_dtype=jnp.float32,
-            kernel_init=nn.with_partitioning(nn.initializers.lecun_normal(), ("embed", None)),
-            name="router",
-        )
-        logits = router(x.astype(jnp.float32))  # (B,S,E)
-        weights, sel = jax.lax.top_k(jax.nn.softmax(logits, -1), K)  # (B,S,K)
-        weights = weights / jnp.sum(weights, -1, keepdims=True)
+        with jax.named_scope("route"):
+            logits = nn.Dense(
+                R,
+                use_bias=False,
+                dtype=jnp.float32,
+                param_dtype=jnp.float32,
+                kernel_init=nn.with_partitioning(
+                    nn.initializers.lecun_normal(), ("embed", None)
+                ),
+                name="router",
+            )(x.astype(jnp.float32))  # (B,S,R)
+            if cfg.moe_router == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+                choice = scores
+                if cfg.moe_expert_bias:
+                    bias = self.param(
+                        "expert_bias",
+                        nn.with_partitioning(
+                            nn.initializers.zeros_init(), (None,)
+                        ),
+                        (R,),
+                        jnp.float32,
+                    )
+                    if hasattr(bias, "unbox"):
+                        bias = bias.unbox()
+                    # moves the choice and not the weight: top_k's indices
+                    # carry no gradient, so the bias's is exactly zero
+                    choice = scores + bias
+                _, sel = jax.lax.top_k(choice, K)  # (B,S,K)
+                weights = jnp.take_along_axis(scores, sel, axis=-1)
+                if cfg.moe_norm_topk_prob:
+                    weights = weights / (
+                        jnp.sum(weights, -1, keepdims=True) + 1e-6
+                    )
+                weights = weights * cfg.moe_routed_scaling_factor
+            else:
+                weights, sel = jax.lax.top_k(jax.nn.softmax(logits, -1), K)
+                weights = weights / jnp.sum(weights, -1, keepdims=True)
 
         def epar(name, shape, axes):
             return self.param(
@@ -596,6 +711,12 @@ class MoE(nn.Module):
             # collective bytes (dp=2 x ep=4 mesh; numbers in
             # moe_ragged_ep's docstring).
             dispatch = "ragged"
+        if share and (dispatch != "ragged" or ep_live):
+            raise ValueError(
+                "a layer that holds a share of the experts is one chip's "
+                "part of an expert-parallel deployment: ragged dispatch, no "
+                "live ep axis"
+            )
         if dispatch == "ragged":
             from ..ops.moe import moe_ragged, moe_ragged_ep
 
@@ -621,7 +742,13 @@ class MoE(nn.Module):
                     w_gate.astype(dtype),
                     w_up.astype(dtype),
                     w_down.astype(dtype),
+                    expert_offset=cfg.moe_expert_offset,
+                    router_width=R,
                 ).reshape(b, s, h)
+                for name, value in ragged_load_stats(
+                    sel, E, cfg.moe_expert_offset
+                ).items():
+                    self.sow("intermediates", name, value)
         elif dispatch == "capacity":
             def experts_fn(buf):  # (E, C, h) -> (E, C, h)
                 hidden = jnp.einsum("ech,ehf->ecf", buf, w_gate.astype(dtype))
@@ -657,14 +784,22 @@ class MoE(nn.Module):
                 "'ragged', 'capacity' or 'dense'"
             )
         self.sow(
-            "intermediates", "moe_aux_loss", load_balancing_loss(logits, sel, E)
+            "intermediates", "moe_aux_loss", load_balancing_loss(logits, sel, R)
         )
         return out.astype(x.dtype)
 
 
 class Block(nn.Module):
+    """One decoder layer: x + operator(norm(x)), then + feed-forward(norm).
+    ``mixer`` is the layer's operator (a ``layer_types`` entry) and ``ff``
+    its feed-forward ("mlp" | "moe"; None: "moe" where the config has
+    experts) — the defaults are the one kind every layer was before
+    ``layer_types``, with the same parameter tree."""
+
     config: TransformerConfig
     decode: bool = False
+    mixer: str = "full_attention"
+    ff: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, positions, mask=None, kv_lengths=None,
@@ -672,6 +807,8 @@ class Block(nn.Module):
         from ..parallel.sharding import constrain_activations
 
         cfg = self.config
+        if self.mixer == "conv":
+            return _conv_layer(self, x), None
         # ``scanned`` is this layer's slice of the per-layer traced data:
         # either the bare layer-window array (the pre-adapter form) or a
         # dict {"window": ..., "lora": {target: {lora_a, lora_b}}} — both
@@ -713,21 +850,45 @@ class Block(nn.Module):
             # 4 per block — transformers Gemma2DecoderLayer)
             attn_out = RMSNorm(cfg, name="post_attn_norm")(attn_out)
         h = checkpoint_name(x + attn_out, "attn_res")
-        if cfg.num_experts > 0:
-            # MoE blocks don't take adapters (the expert weights are the
-            # specialization mechanism there); attention adapters still apply
-            ff_out = MoE(cfg, name="moe")(RMSNorm(cfg, name="mlp_norm")(h))
-        else:
-            ff_out = MLP(cfg, name="mlp")(
-                RMSNorm(cfg, name="mlp_norm")(h),
-                lora=lora, lora_stacks=mlp_lora,
-            )
+        ff_out = _feed_forward(self, h, lora, mlp_lora)
         if cfg.post_norms:
             ff_out = RMSNorm(cfg, name="post_mlp_norm")(ff_out)
         # pin the residual stream's layout once per layer so GSPMD cannot
         # alternate it between batch-sharded and weight-following layouts
         # (each flip is a full resharding per layer)
         return constrain_activations(h + ff_out), None
+
+def _feed_forward(block: Block, h, lora=None, mlp_lora=None):
+    """``block``'s feed-forward on the residual stream ``h``. A function,
+    not a method: a method of a module would put its own name into every
+    operation's scope path, and ``layers/mlp/...`` is what the benchmark's
+    metrics read."""
+    cfg = block.config
+    ff = block.ff or ("moe" if cfg.num_experts > 0 else "mlp")
+    if ff == "moe":
+        # MoE blocks don't take adapters (the expert weights are the
+        # specialization mechanism there); attention adapters still apply
+        return MoE(cfg, name="moe")(RMSNorm(cfg, name="mlp_norm")(h))
+    return MLP(cfg, name="mlp")(
+        RMSNorm(cfg, name="mlp_norm")(h), lora=lora, lora_stacks=mlp_lora,
+    )
+
+
+def _conv_layer(block: Block, x):
+    """The layer whose operator is the gated short convolution. It has no
+    state to decode from, takes no adapters and no fused prologue."""
+    from ..parallel.sharding import constrain_activations
+
+    if block.decode:
+        raise NotImplementedError(
+            "a convolution layer keeps no state between calls yet: "
+            "serving a stack with convolution state beside KV is "
+            "ROADMAP Reach A4"
+        )
+    cfg = block.config
+    h = x + ShortConv(cfg, name="conv")(RMSNorm(cfg, name="conv_norm")(x))
+    h = checkpoint_name(h, "attn_res")
+    return constrain_activations(h + _feed_forward(block, h))
 
 
 def _make_embed(cfg: TransformerConfig, dtype, name: Optional[str] = "embed") -> nn.Embed:
@@ -794,6 +955,59 @@ def _layer_windows_array(cfg: TransformerConfig):
     )
 
 
+def layer_kinds(cfg: TransformerConfig, num_layers=None) -> list:
+    """Per layer ``(mixer, ff)``: its operator from ``layer_types`` and its
+    feed-forward — the dense MLP in the ``num_dense_layers`` leading layers
+    and wherever the config has no experts, else the expert layer."""
+    n = num_layers or cfg.num_layers
+    types = cfg.layer_types or ("full_attention",) * n
+    return [
+        (types[l],
+         "moe" if cfg.num_experts > 0 and l >= cfg.num_dense_layers else "mlp")
+        for l in range(n)
+    ]
+
+
+def plan_layers(kinds: list) -> list:
+    """Cut the list of layer kinds into ``(start, period, repeats)``
+    segments, left to right: at each position the period (a tuple of kinds)
+    whose consecutive repeats cover most layers, the shorter period on a
+    tie; where nothing repeats, one layer alone (``repeats`` 1). A segment
+    that repeats is scanned as one body of its period, the others are
+    unrolled. A homogeneous list is one segment of period 1."""
+    segments, i, n = [], 0, len(kinds)
+    while i < n:
+        period, repeats = 1, 1
+        for p in range(1, (n - i) // 2 + 1):
+            r = 1
+            while kinds[i + r * p:i + (r + 1) * p] == kinds[i:i + p]:
+                r += 1
+            if r >= 2 and p * r > period * repeats:
+                period, repeats = p, r
+        segments.append((i, tuple(kinds[i:i + period]), repeats))
+        i += period * repeats
+    return segments
+
+
+class _Period(nn.Module):
+    """One period of a repeating layer pattern — the body of a scan over
+    layers of different parameter shapes: blocks ``b0`` .. ``b{p-1}``."""
+
+    config: TransformerConfig
+    block_cls: Any
+    kinds: tuple
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, *args):
+        for j, (mixer, ff) in enumerate(self.kinds):
+            x, _ = self.block_cls(
+                self.config, decode=self.decode, mixer=mixer, ff=ff,
+                name=f"b{j}",
+            )(x, *args)
+        return x, None
+
+
 def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
                        block_cls=None, num_layers=None, per_layer=None):
     """Run a block stack (scan or unrolled, optional remat) on hidden
@@ -810,40 +1024,55 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
     array). ``block_cls``: defaults to :class:`Block`; the seq2seq decoder
     passes :class:`~.seq2seq.DecoderBlock`. Blocks must return
     ``(x, None)``.
+
+    Layers of ONE kind (every configuration before ``layer_types``) are one
+    scan named ``layers``. Layers of several kinds (:func:`layer_kinds`)
+    follow :func:`plan_layers`: a segment that repeats is a scan named
+    ``layers_<first layer>`` — over the :class:`Block` itself at period 1,
+    over a :class:`_Period` of blocks ``b0..`` otherwise — and a layer that
+    stands alone is a block named ``layer_<index>``, as every layer is
+    with ``scan_layers`` off.
     """
     base_cls = block_cls or Block
-    block_kwargs = {"decode": decode}  # every block class supports decode
-    cls = base_cls
-    if cfg.remat:
-        cls = nn.remat(
-            base_cls,
-            policy=_REMAT_POLICIES[cfg.remat](),
-            prevent_cse=not cfg.scan_layers,
-            static_argnums=(),
-        )
     n = num_layers or cfg.num_layers
 
-    if cfg.scan_layers:
-        in_axes = tuple(nn.broadcast for _ in extra)
-        args = extra
-        if per_layer is not None:
-            in_axes = in_axes + (0,)
-            args = extra + (per_layer,)
-        # a name for the loop's own copies and slices in a device trace,
-        # not a Flax scope: the parameter tree is unchanged
-        with jax.named_scope("layers"):
-            x, _ = nn.scan(
-                cls,
-                variable_axes={"params": 0, "intermediates": 0, "cache": 0},
-                # "dropout": LoRA delta dropout inside the scanned block —
-                # the entry is inert unless a dropout rng is actually passed
-                # to apply (adapter training with LoraConfig.dropout > 0)
-                split_rngs={"params": True, "dropout": True},
-                in_axes=in_axes,
-                length=n,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(cfg, **block_kwargs, name="layers")(x, *args)
-    else:
+    def remat(cls, scanned: bool):
+        if not cfg.remat:
+            return cls
+        return nn.remat(
+            cls,
+            policy=_REMAT_POLICIES[cfg.remat](),
+            prevent_cse=not scanned,
+            static_argnums=(),
+        )
+
+    kinds = layer_kinds(cfg, n) if base_cls is Block else [None] * n
+    segments = plan_layers(kinds)
+    mixed = len(segments) > 1 or len(segments[0][1]) > 1
+    if mixed and per_layer is not None:
+        raise NotImplementedError(
+            "per-layer windows and adapter stacks ride ONE scan over layers "
+            "of one kind; this stack has layers of several kinds"
+        )
+
+    def kind_kwargs(kind):
+        return {} if kind is None else {"mixer": kind[0], "ff": kind[1]}
+
+    def scan(body, length, in_axes):
+        return nn.scan(
+            body,
+            variable_axes={"params": 0, "intermediates": 0, "cache": 0},
+            # "dropout": LoRA delta dropout inside the scanned block —
+            # the entry is inert unless a dropout rng is actually passed
+            # to apply (adapter training with LoraConfig.dropout > 0)
+            split_rngs={"params": True, "dropout": True},
+            in_axes=in_axes,
+            length=length,
+            metadata_params={nn.PARTITION_NAME: "layers"},
+        )
+
+    if not cfg.scan_layers:
+        cls = remat(base_cls, scanned=False)
         for i in range(n):
             if per_layer is None:
                 args = extra
@@ -851,8 +1080,51 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
                 # slice EVERY leaf's layer axis (per_layer may be a dict
                 # of adapter stacks, not just the bare window array)
                 args = extra + (jax.tree.map(lambda l: l[i], per_layer),)
-            x, _ = cls(cfg, **block_kwargs, name=f"layer_{i}")(x, *args)
+            x, _ = cls(cfg, decode=decode, **kind_kwargs(kinds[i]),
+                       name=f"layer_{i}")(x, *args)
+        return x
+
+    in_axes = tuple(nn.broadcast for _ in extra)
+    if not mixed:
+        args = extra
+        if per_layer is not None:
+            in_axes = in_axes + (0,)
+            args = extra + (per_layer,)
+        # a name for the loop's own copies and slices in a device trace,
+        # not a Flax scope: the parameter tree is unchanged
+        with jax.named_scope("layers"):
+            x, _ = scan(remat(base_cls, scanned=True), n, in_axes)(
+                cfg, decode=decode, **kind_kwargs(kinds[0]), name="layers"
+            )(x, *args)
+        return x
+
+    for start, period, repeats in segments:
+        if repeats == 1:
+            x, _ = remat(base_cls, scanned=False)(
+                cfg, decode=decode, **kind_kwargs(period[0]),
+                name=f"layer_{start}",
+            )(x, *extra)
+            continue
+        name = f"layers_{start}"
+        if len(period) == 1:
+            body = scan(remat(base_cls, scanned=True), repeats, in_axes)(
+                cfg, decode=decode, **kind_kwargs(period[0]), name=name)
+        else:
+            body = scan(_Period, repeats, in_axes)(
+                cfg, remat(base_cls, scanned=True), period, decode, name=name)
+        with jax.named_scope(name):
+            x, _ = body(x, *extra)
     return x
+
+
+def _sown_means(sown) -> dict:
+    """``{name: mean}`` over every layer's value of each scalar sowed under
+    ``intermediates`` (a scan stacks them, ``sow`` wraps them in tuples)."""
+    by_name: dict = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(sown)[0]:
+        name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+        by_name.setdefault(name, []).append(jnp.ravel(leaf))
+    return {k: jnp.mean(jnp.concatenate(v)) for k, v in by_name.items()}
 
 
 class CausalLM(nn.Module):
@@ -902,7 +1174,9 @@ class CausalLM(nn.Module):
         # here costs ~4x on the biggest matmul); the loss upcasts to fp32
         # before log_softmax, which is where precision actually matters
         if cfg.tie_embeddings:
-            logits = embed.attend(x)
+            # the tied head opens no module of its own: name its matmul
+            with jax.named_scope("tied_head"):
+                logits = embed.attend(x)
         else:
             logits = nn.Dense(
                 cfg.vocab_size,
@@ -932,13 +1206,24 @@ class CausalLM(nn.Module):
         return self.init(rng, dummy)["params"]
 
     @staticmethod
-    def loss_fn(model: "CausalLM"):
+    def loss_fn(model: "CausalLM", with_aux: bool = False):
         """Next-token cross-entropy closure for Accelerator.unified_step:
-        ``loss_fn(params, batch)`` with batch {input_ids, [loss_mask]}."""
+        ``loss_fn(params, batch)`` with batch {input_ids, [loss_mask]}.
+        ``with_aux`` (for ``unified_step(..., has_aux=True)``): returns
+        ``(loss, aux)``, ``aux`` the mean over layers of every scalar the
+        model sowed under ``intermediates`` (the MoE counters)."""
 
         def fn(params, batch):
             ids = batch["input_ids"]
+            if with_aux:
+                logits, sown = model.apply(
+                    {"params": params}, ids, mutable=["intermediates"])
+                return _next_token_loss(logits, ids, batch), _sown_means(
+                    sown.get("intermediates", {}))
             logits = model.apply({"params": params}, ids)
+            return _next_token_loss(logits, ids, batch)
+
+        def _next_token_loss(logits, ids, batch):
             targets = ids[:, 1:]
             logits = logits[:, :-1]
             logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)

@@ -61,6 +61,9 @@ def moe_ragged(
     w_gate: jax.Array,
     w_up: jax.Array,
     w_down: jax.Array,
+    *,
+    expert_offset: int = 0,
+    router_width: Optional[int] = None,
 ) -> jax.Array:
     """Exact sparse MoE via grouped matmuls (``jax.lax.ragged_dot``).
 
@@ -68,6 +71,24 @@ def moe_ragged(
     multiplies against its weights with NO capacity padding and NO drops —
     exactly ``T*K`` token-expert pairs of FLOPs (the capacity schedule
     computes ``capacity_factor`` times that and drops overflow).
+
+    **A share of the experts.** ``w_*`` hold experts ``[expert_offset,
+    expert_offset + E)`` of a router ``router_width`` wide. A choice of an
+    expert that is not held sorts behind every held one and adds nothing:
+    those rows form one more group whose weights are ZERO. Shapes stay
+    static at ``T*K`` rows — the worst case, every choice held here — so
+    no choice of a held expert is dropped at any imbalance, and every row
+    a grouped matmul returns is defined. The price is stated, not hidden:
+    a layer that holds E of R experts multiplies about R / E times the
+    rows it needs, and its time does not follow the routing. (XLA:TPU's
+    kernel SKIPS rows of no group — group sizes that sum to the live rows
+    alone cost only those — but leaves stale memory in them, in the
+    backward kernel too: autodiff's ``d xs`` then has undefined rows that
+    no ``jnp.where`` on a forward output reaches, and the gather's
+    transpose adds them into ``dx``. A ``custom_vjp`` that also zeroes
+    cotangents and backward outputs is exact and half the time; it waits
+    for a routing that holds still, because a step whose time follows the
+    routing cannot be measured within a percent: PERF.md, PR 26.)
 
     Measured on v5e (bf16, B=16, S=1024, E=8, K=2, round-4 sweep): at
     Mixtral-width experts (h=4096, f=3584, L=1) ragged reaches 0.516 MFU
@@ -95,21 +116,58 @@ def moe_ragged(
     T, h = x.shape
     K = sel.shape[-1]
     E = w_gate.shape[0]
-    flat_sel = sel.reshape(T * K)
-    order = jnp.argsort(flat_sel)  # jnp.argsort is stable: ties keep token order
-    tok = jnp.repeat(jnp.arange(T), K)[order]  # source token per sorted row
-    xs = jnp.take(x, tok, axis=0)  # (TK, h) rows grouped by expert
-    group_sizes = jnp.bincount(flat_sel, length=E).astype(jnp.int32)
+    TK = T * K
+    with jax.named_scope("dispatch"):
+        local = sel.reshape(TK) - expert_offset
+        flat_sel = jnp.where((local >= 0) & (local < E), local, E)
+        order = jnp.argsort(flat_sel)  # stable: ties keep token order
+        tok = jnp.repeat(jnp.arange(T), K)[order]  # source token per sorted row
+        xs = jnp.take(x, tok, axis=0)  # (TK, h) rows grouped by expert
+        group_sizes = jnp.bincount(flat_sel, length=E + 1).astype(jnp.int32)
+        if (router_width or E) == E:
+            group_sizes = group_sizes[:E]  # every choice is of a held expert
+        else:  # one more group, of zero weights, for the choices that are not
+            w_gate, w_up, w_down = (
+                jnp.concatenate([w, jnp.zeros((1,) + w.shape[1:], w.dtype)])
+                for w in (w_gate, w_up, w_down)
+            )
 
-    hidden = jax.nn.silu(
-        jax.lax.ragged_dot(xs, w_gate, group_sizes)
-    ) * jax.lax.ragged_dot(xs, w_up, group_sizes)  # (TK, f)
-    out = jax.lax.ragged_dot(hidden, w_down, group_sizes)  # (TK, h)
+    with jax.named_scope("experts"):
+        hidden = jax.nn.silu(
+            jax.lax.ragged_dot(xs, w_gate, group_sizes)
+        ) * jax.lax.ragged_dot(xs, w_up, group_sizes)  # (TK, f)
+        out = jax.lax.ragged_dot(hidden, w_down, group_sizes)  # (TK, h)
 
-    w_flat = weights.reshape(T * K)[order].astype(out.dtype)
-    # combine: weighted scatter-add back into token order (sums the K
-    # expert contributions per token)
-    return jnp.zeros((T, h), out.dtype).at[tok].add(out * w_flat[:, None])
+    with jax.named_scope("combine"):
+        w_flat = weights.reshape(TK)[order].astype(out.dtype)
+        # weighted scatter-add back into token order (sums the K expert
+        # contributions per token)
+        return jnp.zeros((T, h), out.dtype).at[tok].add(out * w_flat[:, None])
+
+
+def ragged_load_stats(
+    sel: jax.Array, num_experts: int, expert_offset: int = 0
+) -> dict:
+    """What the choices ``sel`` (..., K) say of the load of a layer that
+    holds experts ``[expert_offset, expert_offset + num_experts)``, as
+    float32 scalars: the share of the choices that fell on experts held
+    here (held / router width when routing is even), the fullest held
+    expert's load over the mean, and the rows that go through
+    :func:`moe_ragged`'s grouped matmuls — every choice, T*K rows — over
+    the rows that are needed."""
+    local = sel.reshape(-1) - expert_offset
+    num_choices = local.shape[0]
+    sizes = jnp.bincount(
+        jnp.where((local >= 0) & (local < num_experts), local, num_experts),
+        length=num_experts + 1,
+    )[:num_experts].astype(jnp.float32)
+    needed = jnp.sum(sizes)
+    return {
+        "moe_local_choice_share": needed / num_choices,
+        "moe_expert_load_max_over_mean": jnp.max(sizes)
+        / jnp.maximum(jnp.mean(sizes), 1.0),
+        "moe_rows_computed_over_needed": num_choices / jnp.maximum(needed, 1.0),
+    }
 
 
 def moe_ragged_ep(

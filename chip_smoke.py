@@ -11,6 +11,8 @@ is, and the cut is printed), with random weights made from a seed:
   reference;
 * train   — ``Accelerator`` -> ``prepare`` -> ``unified_step`` -> ``warmup``
   -> a few steps (ZeRO-3 over every chip of the host);
+* hybrid  — the same path over layers of two kinds at LFM2-8B-A1B's widths:
+  a conv layer, an attention layer (head_dim 64) with 8 of 32 experts held;
 * serve   — ``ServingEngine`` answering more requests than it has slots (one
   engine per chip behind ``FleetRouter`` when the host has several).
 
@@ -20,6 +22,7 @@ exit — nothing is caught and summarised. Wall times are printed as set-up
 facts and asserted on nowhere.
 
     python chip_smoke.py                # needs a TPU; exits 2 without one
+    python chip_smoke.py --phases hybrid    # some phases only: no pass line
     JAX_PLATFORMS=cpu python chip_smoke.py --cpu-dry-run
                                         # rehearses the control flow at
                                         # TransformerConfig.tiny size; every
@@ -43,6 +46,7 @@ import sys
 import time
 
 SEED = 20260926
+PHASES = ("kernels", "train", "hybrid", "serve")
 
 
 # --------------------------------------------------------------------------- #
@@ -217,14 +221,14 @@ def run_compiled(say, name, fn, args, *, expect_mosaic, dry):
     return out
 
 
-def flash_cases(say, sz: Sizes, dry: bool) -> None:
+def flash_cases(say, sz: Sizes, dry: bool, shapes=None) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from accelerate_tpu.ops.flash_attention import flash_attention
 
-    for batch, seq in (sz.flash_short, sz.flash_long):
+    for batch, seq in shapes or (sz.flash_short, sz.flash_long):
         rng = np.random.default_rng(SEED + seq)
         shape_q = (batch, seq, sz.heads, sz.head_dim)
         shape_kv = (batch, seq, sz.kv_heads, sz.head_dim)
@@ -456,6 +460,11 @@ def kernel_phase(say, sz: Sizes, dry: bool) -> None:
     with ctx:
         assert kernels_interpreted() == dry, "a kernel would run interpreted"
         flash_cases(say, sz, dry)
+        # the hybrid stack's attention: heads of 64 (never compiled here
+        # before PR 26), causal, no window, at its training shape
+        flash_cases(say, dataclasses.replace(
+            sz, head_dim=sz.head_dim // 2, flash_variants=((False, False),)),
+            dry, shapes=((2, 128) if dry else (4, 4096),))
         prologue_case(say, sz, dry)
         adamw_case(say, sz, dry)
         paged_decode_case(say, sz, dry)
@@ -626,6 +635,110 @@ def train_phase(say, sz: Sizes, dry: bool) -> None:
     GradientState._reset_state()
     gc.collect()
     say(f"train phase PASSED; freed, {live_bytes() / 2**20:.0f}MiB live")
+
+
+# --------------------------------------------------------------------------- #
+# hybrid phase: layers of two kinds and a share of the experts, one chip
+# --------------------------------------------------------------------------- #
+def hybrid_phase(say, dry: bool) -> None:
+    """One conv layer with the dense MLP and one attention layer with the
+    expert layer, at LFM2-8B-A1B's published widths (hidden 2048, FFN 7168,
+    expert width 1792, 32/8 heads of 64, q/k norms, 4 of 32 sigmoid-routed
+    experts a token) with one chip's share of a 4-chip deployment: experts
+    0-7 and 16,384 rows of the vocabulary. Through ``unified_step`` with the
+    model's counters as aux, on ONE chip whatever the host has."""
+    import jax
+    import numpy as np
+    import optax
+
+    from accelerate_tpu import (
+        Accelerator, AcceleratorState, DataLoader, GradientState,
+        ParallelismPlugin,
+    )
+    from accelerate_tpu.models import CausalLM, TransformerConfig
+
+    if dry:
+        w = dict(vocab_size=512, hidden_size=64, intermediate_size=160,
+                 moe_intermediate_size=48, num_heads=4, num_kv_heads=2,
+                 head_dim=16)
+        rows, seq = 2, 64
+    else:
+        w = dict(vocab_size=16384, hidden_size=2048, intermediate_size=7168,
+                 moe_intermediate_size=1792, num_heads=32, num_kv_heads=8,
+                 head_dim=64)
+        rows, seq = 4, 4096
+    cfg = TransformerConfig(
+        **w, num_layers=2, layer_types=("conv", "full_attention"),
+        num_dense_layers=1, qk_norm=True, num_experts=8, moe_router_width=32,
+        moe_expert_offset=0, num_experts_per_tok=4, moe_router="sigmoid",
+        moe_expert_bias=True, tie_embeddings=True, rope_theta=1e6,
+        max_seq_len=seq, remat="dots_ragged", dtype="bfloat16")
+    say(f"hybrid: conv + dense MLP, attention + 8 of 32 experts (top 4), "
+        f"head_dim {cfg.head_dim}, {rows}x{seq} tokens, remat=dots_ragged")
+    acc = Accelerator(mixed_precision="bf16",
+                      parallelism_plugin=ParallelismPlugin(fsdp_size=-1),
+                      telemetry=True)
+    if jax.device_count() > 1:  # a share of the experts lives on one chip
+        acc.reform_mesh(jax.devices()[:1])
+    model = CausalLM(cfg)
+    raw = model.init(jax.random.PRNGKey(SEED), np.zeros((1, 16), np.int32))["params"]
+    ids = np.random.default_rng(SEED).integers(0, cfg.vocab_size, (rows, seq))
+    params, opt, loader = acc.prepare(
+        raw, optax.adamw(3e-4),
+        DataLoader([{"input_ids": r.astype(np.int32)} for r in ids], batch_size=rows))
+    del raw
+    step = acc.unified_step(CausalLM.loss_fn(model, with_aux=True),
+                            has_aux=True, max_grad_norm=1.0)
+    carry = acc.init_carry(params, opt)
+    del params
+    warm = acc.warmup(step, carry, loader)
+    say(f"hybrid: warmup compile {warm['compile_time_s']:.1f}s")
+    losses = []
+    for _ in range(4):
+        for batch in loader:
+            carry, metrics = step(carry, batch)
+            losses.append(float(metrics["loss"]))
+    aux = {k: float(v) for k, v in metrics["aux"].items()}
+    say("hybrid: loss " + " ".join(f"{l:.4f}" for l in losses)
+        + "; " + " ".join(f"{k}={v:.4f}" for k, v in sorted(aux.items())))
+    assert all(math.isfinite(l) for l in losses) and losses[-1] < losses[0], losses
+    retraces = acc.telemetry.detector(step.label).retraces
+    assert retraces == 0, f"{retraces} retraces after warmup"
+    assert step.aot_fallbacks == 0, step.aot_fallbacks
+    # a quarter of the router's experts are held: about a quarter of the
+    # choices fall here. Every choice, held or not, is a row of the grouped
+    # matmuls (those of absent experts in a group of zero weights, so that
+    # the step's time does not follow the routing): rows computed over rows
+    # needed is 1 / the local share, about 4 at an even routing
+    share = aux["moe_local_choice_share"]
+    assert 0.1 < share < 0.5, aux
+    assert aux["moe_expert_load_max_over_mean"] >= 1.0, aux
+    assert abs(aux["moe_rows_computed_over_needed"] * share - 1.0) < 1e-3, aux
+    if not dry:
+        calls = [line for line in step.compiled.as_text().splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        # an instruction is named after its kernel: flash_fwd (twice: the
+        # remat policy saves matmul outputs, not the kernel's), flash_bwd_dq,
+        # flash_bwd_dkv; XLA's own grouped matmuls are ragged-dot-*
+        def named(prefix):
+            return sorted(c.split("=")[0].strip().lstrip("%") for c in calls
+                          if c.strip().lstrip("%").startswith(prefix))
+        flash, grouped = named("flash_"), named("ragged-dot-none")
+        assert {f.split(".")[0] for f in flash} == {
+            "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, (
+            f"Mosaic calls {flash}: head_dim {cfg.head_dim} left the flash "
+            "kernels for xla_attention")
+        assert len(grouped) >= 9, (
+            f"{len(grouped)} grouped-matmul kernels {grouped}: expected 3 "
+            "forward and 6 backward from XLA's ragged-dot lowering")
+        say(f"hybrid: compiled step holds flash calls {flash} at head_dim "
+            f"{cfg.head_dim} and {len(grouped)} grouped-matmul calls")
+    acc.telemetry.close()
+    del carry, step, opt, loader, acc, metrics, batch
+    AcceleratorState._reset_state(reset_partial_state=True)
+    GradientState._reset_state()
+    gc.collect()
+    say(f"hybrid phase PASSED; freed, {live_bytes() / 2**20:.0f}MiB live")
 
 
 # --------------------------------------------------------------------------- #
@@ -882,8 +995,16 @@ def main(argv=None) -> int:
         help="rehearse the control flow on the CPU backend at tiny size; "
              "never prints the pass line",
     )
+    ap.add_argument(
+        "--phases", default=",".join(PHASES),
+        help="comma-separated subset of %(default)s, run in that order; a "
+             "subset never prints the pass line",
+    )
     args = ap.parse_args(argv)
     dry = args.cpu_dry_run
+    phases = [p for p in PHASES if p in args.phases.split(",")]
+    if not phases or set(args.phases.split(",")) - set(PHASES):
+        ap.error(f"--phases takes names out of {PHASES}")
 
     import jax
 
@@ -934,22 +1055,26 @@ def main(argv=None) -> int:
     say(f"persistent compile cache: {cache_dir}")
 
     sz = TINY if dry else REAL
-    t0 = time.perf_counter()
-    kernel_phase(say, sz, dry)
-    t1 = time.perf_counter()
-    train_phase(say, sz, dry)
-    t2 = time.perf_counter()
-    serve_phase(say, sz, dry)
-    t3 = time.perf_counter()
+    run = {"kernels": lambda: kernel_phase(say, sz, dry),
+           "train": lambda: train_phase(say, sz, dry),
+           "hybrid": lambda: hybrid_phase(say, dry),
+           "serve": lambda: serve_phase(say, sz, dry)}
+    wall = []
+    for phase in phases:
+        t0 = time.perf_counter()
+        run[phase]()
+        wall.append(f"{phase} {time.perf_counter() - t0:.0f}s")
 
     totals = monitor.snapshot()
-    say(f"wall: kernels {t1 - t0:.0f}s train {t2 - t1:.0f}s serve "
-        f"{t3 - t2:.0f}s; process persistent-cache hits="
+    say(f"wall: {' '.join(wall)}; process persistent-cache hits="
         f"{int(totals['persistent_cache_hits'])} misses="
         f"{int(totals['persistent_cache_misses'])}, XLA compile "
         f"{totals['compile_time_s']:.0f}s")
     if dry:
         say("rehearsal complete — this is NOT a pass: nothing ran on a chip")
+        return 0
+    if phases != list(PHASES):
+        say(f"only {phases} ran: NOT the pass line of a whole run")
         return 0
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

@@ -62,7 +62,16 @@ def test_cpu_dry_run_rehearses_every_phase_but_never_passes():
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = proc.stdout.splitlines()
     assert lines and all(l.startswith("[DRY RUN cpu x1]") for l in lines)
-    for phase in ("kernel", "train", "serve"):
+    for phase in ("kernel", "train", "hybrid", "serve"):
         assert any(f"{phase} phase PASSED" in l for l in lines), phase
     assert _result_lines(proc.stdout) == []
     assert "NOT a pass" in lines[-1]
+
+
+def test_a_subset_of_phases_runs_alone_and_never_passes():
+    proc = _run("--cpu-dry-run", "--phases", "hybrid", timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    passed = [l for l in proc.stdout.splitlines() if "phase PASSED" in l]
+    assert len(passed) == 1 and "hybrid phase PASSED" in passed[0]
+    assert _result_lines(proc.stdout) == []
+    assert _run("--cpu-dry-run", "--phases", "nothing", timeout=60).returncode == 2

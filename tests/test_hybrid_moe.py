@@ -1,0 +1,431 @@
+"""Layers of several kinds in one stack — gated short convolution and GQA
+attention with q/k norms, a leading dense layer, sigmoid-routed experts of
+which a layer holds a share — through the normal path, held against the
+benchmark's plain float32 reference (``benchmark/harness/lfm2_reference.py``)
+on seeded weights (``lfm2_weights.py``), at widths the CPU can hold."""
+
+import hashlib
+import os
+import sys
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+sys.path.insert(0, os.path.join(ROOT, "benchmark", "tests"))
+
+import tiny_hybrid  # noqa: E402
+from harness import common  # noqa: E402
+from harness import lfm2_reference as ref  # noqa: E402
+from harness import lfm2_weights as W  # noqa: E402
+
+from accelerate_tpu.models import CausalLM, TransformerConfig  # noqa: E402
+from accelerate_tpu.models.transformer import (  # noqa: E402
+    MoE, ShortConv, layer_kinds, plan_layers)
+from accelerate_tpu.ops.moe import moe_ragged, ragged_load_stats  # noqa: E402
+
+SEED = 2**31 + 5
+
+
+def _ids(cfg, rows=2, seq=48, seed=0):
+    return jnp.asarray(np.random.default_rng(seed).integers(
+        0, cfg["vocab_size"], (rows, seq)), jnp.int32)
+
+
+def _model(cfg, **kw):
+    return CausalLM(common.program_config(
+        cfg, max_seq_len=cfg["max_position_embeddings"], **kw))
+
+
+def _flat(tree):
+    return {jax.tree_util.keystr(p): x
+            for p, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+# --------------------------------------------------------------------------- #
+# the plan and the tree
+# --------------------------------------------------------------------------- #
+def test_published_list_scans_its_periods_and_unrolls_nothing():
+    cfg = tiny_hybrid.config(tiny_hybrid.PUBLISHED_LAYER_TYPES, num_dense_layers=2)
+    plan = plan_layers(layer_kinds(common.program_config(cfg)))
+    assert [(s, len(p), r) for s, p, r in plan] == [(0, 1, 2), (2, 4, 4), (18, 3, 2)]
+    assert plan[1][1] == (("full_attention", "moe"),) + (("conv", "moe"),) * 3
+    # the benchmark's own statement of the rule cuts the same way
+    assert W.segments(W.layer_kinds(cfg)) == plan
+
+
+def test_the_cut_is_two_single_layers_and_one_scan():
+    cfg = tiny_hybrid.config()
+    plan = plan_layers(layer_kinds(common.program_config(cfg)))
+    assert [(s, p, r) for s, p, r in plan] == [
+        (0, (("conv", "mlp"),), 1), (1, (("full_attention", "moe"),), 1),
+        (2, (("conv", "moe"),), 3)]
+
+
+@pytest.mark.parametrize("layer_types,dense", [
+    (("conv", "full_attention", "conv", "conv", "conv"), 1),
+    (tuple(tiny_hybrid.PUBLISHED_LAYER_TYPES), 2)])
+@pytest.mark.parametrize("scan_layers", [True, False])
+def test_seeded_tree_is_the_programs_tree(layer_types, dense, scan_layers):
+    cfg = tiny_hybrid.config(layer_types, num_dense_layers=dense)
+    model = _model(cfg, scan_layers=scan_layers)
+    own = nn.unbox(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), _ids(cfg, 1, 8)))["params"])
+    if scan_layers:
+        made = W.abstract_tree(cfg, jnp.float32)
+        assert {k: (v.shape, v.dtype) for k, v in _flat(own).items()} == {
+            k: (v.shape, v.dtype) for k, v in _flat(made).items()}
+    else:  # every layer alone: the same leaves under layer_<i>
+        per_layer = sum(len(W.layer_leaves(cfg, k)) for k in W.layer_kinds(cfg))
+        assert len(_flat(own)) == per_layer + 2
+        assert set(own) == {"embed", "final_norm"} | {
+            f"layer_{i}" for i in range(len(layer_types))}
+
+
+# hashes of (path, shape, bytes) of CausalLM.init(PRNGKey(0)) at the parent of
+# the PR that brought layer kinds (d9fc0ae): no existing configuration's tree
+# or initial values may move
+PARENT_TREES = {
+    "dense": (dict(), "0feeec8cffdfb4b3"),
+    "gqa_tied": (dict(num_kv_heads=2, tie_embeddings=True), "f1ea0548f0947577"),
+    "mixtral": (dict(num_experts=4, num_experts_per_tok=2), "ed78958e4fff27d7"),
+    "windows": (dict(layer_windows=(8, None), attention_impl="xla"),
+                "0feeec8cffdfb4b3"),
+    "unrolled": (dict(scan_layers=False), "71f9692f3279c83b"),
+    "unrolled_moe_remat": (dict(scan_layers=False, num_experts=4,
+                                remat="dots_ragged"), "3535099229d3ba16"),
+    "gemma": (dict(norm_offset=True, post_norms=True, embed_scale=True,
+                   mlp_activation="gelu_tanh"), "9f48aedb9d5d32bb"),
+    "qkv_bias_one_layer": (dict(qkv_bias=True, num_layers=1), "d65299ca8e959689"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_TREES))
+def test_existing_configurations_keep_their_tree_byte_for_byte(case):
+    kw, want = PARENT_TREES[case]
+    model = CausalLM(TransformerConfig.tiny(**kw))
+    params = nn.unbox(model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))["params"])
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(leaf.shape).encode())
+        h.update(np.asarray(leaf).tobytes())
+    assert h.hexdigest()[:16] == want
+
+
+# --------------------------------------------------------------------------- #
+# the program against the reference
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("layer_types,dense,remat", [
+    (("conv", "full_attention", "conv", "conv", "conv"), 1, "dots_ragged"),
+    (tuple(tiny_hybrid.PUBLISHED_LAYER_TYPES), 2, None)])
+def test_logits_loss_and_every_gradient_leaf_match_the_reference(
+        layer_types, dense, remat):
+    cfg = tiny_hybrid.config(layer_types, num_dense_layers=dense)
+    params = W.make_tree(cfg, SEED, jnp.float32)
+    ids = _ids(cfg)
+    model = _model(cfg, remat=remat)
+    with jax.default_matmul_precision("highest"):
+        logits = model.apply({"params": params}, ids)
+        loss, grads = jax.value_and_grad(CausalLM.loss_fn(model))(
+            params, {"input_ids": ids})
+    want_logits = ref.forward(params, cfg, ids)
+    want_loss, want_grads = jax.value_and_grad(ref.loss)(params, cfg, ids)
+    np.testing.assert_allclose(logits, want_logits, atol=2e-4, rtol=2e-4)
+    assert abs(float(loss) - float(want_loss)) < 1e-5
+    got, want = _flat(grads), _flat(want_grads)
+    assert set(got) == set(want)
+    for key in want:
+        scale = float(jnp.max(jnp.abs(want[key]))) + 1e-8
+        np.testing.assert_allclose(got[key], want[key], atol=2e-4 * scale,
+                                   rtol=2e-3, err_msg=key)
+        if key.endswith("['expert_bias']"):  # moves the choice, not the weight
+            assert not np.any(np.asarray(got[key]))
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """Four chips hold 8 experts each of a 32-wide router; what their expert
+    layers return for the same input sums to the uncut reference layer."""
+    whole = tiny_hybrid.config(num_experts=32, expert_offset=0, router_width=32)
+    base = W.base_key(SEED)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 40, whole["hidden_size"]))
+    whole_lw = W.layer_slice(base, whole, 2, jnp.float32)
+    want = ref.experts_ff(x, whole_lw, whole)
+    total, shares = 0.0, []
+    for offset in (0, 8, 16, 24):
+        cfg = tiny_hybrid.config(num_experts=8, expert_offset=offset,
+                                 router_width=32)
+        lw = W.layer_slice(base, cfg, 2, jnp.float32)
+        np.testing.assert_array_equal(  # the share's experts ARE the whole's
+            lw["moe/up_proj"], whole_lw["moe/up_proj"][offset:offset + 8])
+        moe = MoE(common.program_config(cfg))
+        out, sown = moe.apply({"params": {
+            "router": {"kernel": lw["moe/router/kernel"]},
+            "expert_bias": lw["moe/expert_bias"],
+            "gate_proj": lw["moe/gate_proj"], "up_proj": lw["moe/up_proj"],
+            "down_proj": lw["moe/down_proj"]}}, x, mutable=["intermediates"])
+        np.testing.assert_allclose(out, ref.experts_ff(x, lw, cfg), atol=1e-5)
+        total = total + out
+        shares.append(float(sown["intermediates"]["moe_local_choice_share"][0]))
+    np.testing.assert_allclose(total, want, atol=2e-5)
+    assert abs(sum(shares) - 1.0) < 1e-6  # every choice lies on one chip
+
+
+# --------------------------------------------------------------------------- #
+# the convolution
+# --------------------------------------------------------------------------- #
+def test_short_conv_is_causal_and_equals_an_explicit_loop():
+    cfg = TransformerConfig.tiny(hidden_size=32, num_heads=2)
+    conv = ShortConv(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 32))
+    params = nn.unbox(conv.init(jax.random.PRNGKey(1), x)["params"])
+    out = np.asarray(conv.apply({"params": params}, x))
+    w_in, w_out = (np.asarray(params[n]["kernel"]) for n in ("in_proj", "out_proj"))
+    taps = np.asarray(params["conv1d"]["kernel"])  # (3, h)
+    u = np.asarray(x) @ w_in
+    gate_b, gate_c, xs = np.split(u, 3, axis=-1)
+    z = gate_b * xs
+    want = np.zeros_like(out)
+    for t in range(12):
+        c = sum(taps[j] * z[:, t - 2 + j] for j in range(3) if t - 2 + j >= 0)
+        want[:, t] = (gate_c[:, t] * c) @ w_out
+    np.testing.assert_allclose(out, want, atol=1e-5)
+    # causal: a later token moves no earlier output
+    x2 = x.at[:, 7].add(1.0)
+    out2 = np.asarray(conv.apply({"params": params}, x2))
+    np.testing.assert_array_equal(out2[:, :7], out[:, :7])
+    assert np.abs(out2[:, 7:10] - out[:, 7:10]).max() > 1e-3
+    np.testing.assert_array_equal(out2[:, 10:], out[:, 10:])  # three taps reach two back
+
+
+# --------------------------------------------------------------------------- #
+# routing
+# --------------------------------------------------------------------------- #
+def _moe_parts(cfg_kw, x, bias):
+    cfg = TransformerConfig.tiny(
+        hidden_size=32, num_heads=2, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=16, moe_router="sigmoid", moe_expert_bias=True,
+        moe_dispatch="dense", **cfg_kw)
+    moe = MoE(cfg)
+    params = nn.unbox(moe.init(jax.random.PRNGKey(0), x)["params"])
+    params["expert_bias"] = bias
+    logits = x @ params["router"]["kernel"]
+    return moe, params, jax.nn.sigmoid(logits)
+
+
+def test_expert_bias_moves_the_choice_and_not_the_weight():
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 6, 32))
+    moe, params, scores = _moe_parts({"moe_norm_topk_prob": False}, x, jnp.zeros(8))
+    # a bias that lifts expert 5 over everything: it is chosen everywhere...
+    bias = jnp.zeros(8).at[5].set(10.0)
+    plain = moe.apply({"params": params}, x)
+    lifted = moe.apply({"params": {**params, "expert_bias": bias}}, x)
+    top2 = jax.lax.top_k(scores, 2)[1]
+    # ...and the result is the unbiased top-1's and expert 5's outputs, each
+    # weighted by its UNBIASED score (no 10.0 in any weight)
+    w_gate, w_up, w_down = (params[n] for n in ("gate_proj", "up_proj", "down_proj"))
+
+    def expert(e, v):
+        return (jax.nn.silu(v @ w_gate[e]) * (v @ w_up[e])) @ w_down[e]
+
+    for t in range(6):
+        first = int(top2[0, t, 0]) if int(top2[0, t, 0]) != 5 else int(top2[0, t, 1])
+        want = sum(scores[0, t, e] * expert(e, x[0, t]) for e in (first, 5))
+        np.testing.assert_allclose(lifted[0, t], want, atol=1e-5)
+    assert np.abs(np.asarray(lifted - plain)).max() > 1e-4
+    # its gradient is exactly zero
+    g = jax.grad(lambda p: jnp.sum(moe.apply({"params": p}, x)))(params)
+    assert not np.any(np.asarray(g["expert_bias"]))
+
+
+def test_normalised_weights_sum_to_one_less_the_epsilon():
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 6, 32))
+    moe, params, scores = _moe_parts({}, x, jnp.zeros(8))
+    top = jax.lax.top_k(scores, 2)[0]
+    w = top / (jnp.sum(top, -1, keepdims=True) + 1e-6)
+    assert float(jnp.max(jnp.sum(w, -1))) < 1.0
+    w_gate, w_up, w_down = (params[n] for n in ("gate_proj", "up_proj", "down_proj"))
+    sel = jax.lax.top_k(scores, 2)[1]
+    want = sum(
+        w[0, :, k, None] * jnp.stack([
+            (jax.nn.silu(x[0, t] @ w_gate[e]) * (x[0, t] @ w_up[e])) @ w_down[e]
+            for t, e in enumerate(np.asarray(sel[0, :, k]))])
+        for k in range(2))
+    np.testing.assert_allclose(moe.apply({"params": params}, x)[0], want, atol=1e-5)
+
+
+@pytest.mark.parametrize("target", ["one_held_expert", "no_held_expert"])
+def test_an_imbalanced_router_drops_nothing(target):
+    """Every token's every choice on ONE expert held here (the worst case
+    the static shapes are sized for), or on none of them."""
+    t, k, h, f, held = 24, 2, 16, 8, 4
+    key = jax.random.PRNGKey(7)
+    x = jax.random.normal(key, (t, h))
+    w_gate, w_up = jax.random.normal(key, (2, held, h, f)) * 0.3
+    w_down = jax.random.normal(key, (held, f, h)) * 0.3
+    weights = jnp.full((t, k), 0.5)
+    chosen = 6 if target == "one_held_expert" else 1  # held: experts 4..7
+    sel = jnp.full((t, k), chosen, jnp.int32)
+    out = moe_ragged(x, sel, weights, w_gate, w_up, w_down, expert_offset=4,
+                     router_width=16)
+    stats = ragged_load_stats(sel, held, expert_offset=4)
+    if target == "no_held_expert":
+        assert not np.any(np.asarray(out))
+        assert float(stats["moe_local_choice_share"]) == 0.0
+        return
+    e = chosen - 4
+    want = (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]  # 2 x 0.5
+    np.testing.assert_allclose(out, want, atol=1e-5)
+    assert float(stats["moe_local_choice_share"]) == 1.0
+    assert float(stats["moe_expert_load_max_over_mean"]) == held
+    assert float(stats["moe_rows_computed_over_needed"]) == 1.0
+    # gradients reach every token and only the chosen expert
+    gx, gw = jax.grad(lambda x, w: jnp.sum(moe_ragged(
+        x, sel, weights, w, w_up, w_down, expert_offset=4, router_width=16)),
+        (0, 1))(x, w_gate)
+    assert np.all(np.abs(np.asarray(gx)).sum(-1) > 0)
+    assert np.any(np.asarray(gw[e])) and not np.any(np.asarray(gw[:e]))
+
+
+# --------------------------------------------------------------------------- #
+# through the Accelerator, with the counters
+# --------------------------------------------------------------------------- #
+def test_unified_step_trains_the_stack_and_returns_its_counters():
+    import optax
+
+    from accelerate_tpu import Accelerator
+
+    cfg = tiny_hybrid.config()
+    model = _model(cfg, remat="dots_ragged")
+    acc = Accelerator()
+    params, optimizer = acc.prepare(
+        W.make_tree(cfg, SEED, jnp.float32), optax.adamw(1e-3))
+    step = acc.unified_step(CausalLM.loss_fn(model, with_aux=True), has_aux=True)
+    carry = acc.init_carry(params, optimizer)
+    rows = _ids(cfg, rows=8, seq=32, seed=1)
+    losses = []
+    for _ in range(4):
+        carry, metrics = step(carry, {"input_ids": rows})
+        losses.append(float(metrics["loss"]))
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    aux = {k: float(v) for k, v in metrics["aux"].items()}
+    assert 0.0 < aux["moe_local_choice_share"] < 1.0
+    assert aux["moe_expert_load_max_over_mean"] >= 1.0
+    assert aux["moe_rows_computed_over_needed"] >= 1.0
+    assert step.detector.retraces == 0 if hasattr(step, "detector") else True
+
+
+# --------------------------------------------------------------------------- #
+# what the configuration refuses
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("kw,match", [
+    (dict(layer_types=("conv",)), "layer_types has 1 entries"),
+    (dict(layer_types=("conv", "mamba")), "unknown layer_types"),
+    (dict(layer_types=("conv", "conv"), layer_windows=(4, None)), "cannot be combined"),
+    (dict(num_dense_layers=3), "num_dense_layers"),
+    (dict(num_experts=4, moe_router="tanh"), "unknown moe_router"),
+    (dict(num_experts=4, moe_router_width=8, moe_expert_offset=6), "do not lie inside"),
+    (dict(num_experts=4, moe_router_width=8, moe_dispatch="capacity"), "ragged dispatch only"),
+])
+def test_configuration_refuses_what_it_cannot_run(kw, match):
+    with pytest.raises(ValueError, match=match):
+        TransformerConfig.tiny(**kw)
+
+
+def test_a_convolution_layer_refuses_to_decode():
+    cfg = TransformerConfig.tiny(layer_types=("conv", "full_attention"))
+    model = CausalLM(cfg)
+    ids = jnp.zeros((1, 8), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    with pytest.raises(NotImplementedError, match="Reach A4"):
+        model.apply({"params": params}, ids, decode=True, mutable=["cache"])
+
+
+# --------------------------------------------------------------------------- #
+# the scope paths the benchmark's per-layer metrics read
+# --------------------------------------------------------------------------- #
+def _scope_paths(model, ids):
+    """Every operation's path in the loss's gradient, cleaned as the
+    benchmark's ``scope_share`` cleans the profiler's."""
+    import re
+
+    from harness import program_trace
+
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"]
+    text = jax.jit(jax.grad(CausalLM.loss_fn(model))).lower(
+        params, {"input_ids": ids}).compile().as_text()
+    return {program_trace.scope_of(raw, "CausalLM")
+            for raw in re.findall(r'op_name="([^"]+)"', text)}
+
+
+def _metric_scope(name):
+    import json
+
+    with open(os.path.join(ROOT, "benchmark", "metrics", f"{name}.json")) as f:
+        return json.load(f)["args"]["scope"]
+
+
+@pytest.mark.parametrize("stack", ["dense", "hybrid"])
+def test_the_per_layer_metrics_find_their_scope_paths(stack):
+    """A flax METHOD called from ``__call__`` writes ``<module>.<method>``
+    into every path below it, and ``layers/mlp`` then matches nothing: the
+    dense stack's paths are pinned as the accepted metrics read them."""
+    import re
+
+    if stack == "dense":
+        model = CausalLM(TransformerConfig.tiny(remat="dots"))
+        ids = jnp.zeros((2, 32), jnp.int32)
+        metrics = {"mlp_device_share.train": "layers/mlp/up_proj/dot_general",
+                   "attn_device_share.train": "layers/attn/q_proj/dot_general",
+                   "head_loss_device_share.train": "lm_head/dot_general"}
+    else:
+        cfg = tiny_hybrid.config()
+        model, ids = _model(cfg, remat="dots_ragged"), _ids(cfg)
+        metrics = {"mlp_device_share.moe_train": "layer_0/mlp/up_proj/dot_general",
+                   "conv_device_share.train": "layers_2/conv/conv1d/",
+                   "attn_device_share.moe_train": "layer_1/attn/qk_norm/",
+                   "moe_route_device_share.train": "layers_2/moe/route/router/dot_general",
+                   "moe_experts_device_share.train": "layer_1/moe/experts/",
+                   "head_loss_device_share.moe_train": "tied_head/"}
+    paths = _scope_paths(model, ids)
+    assert not [p for p in paths if "._" in p], "a method's name is in a path"
+    for name, sample in metrics.items():
+        rx = re.compile(_metric_scope(name))
+        hit = [p for p in paths if rx.search(p)]
+        assert any(sample in p for p in hit), (name, sample, sorted(hit)[:8])
+
+
+def test_the_grouped_matmul_that_skips_rows_equals_the_one_that_does_not():
+    """``benchmark/tests/ragged_leak_on_chip.py``'s ``masked_ragged_dot`` —
+    group sizes that sum to the held choices alone, rows of no group zeroed
+    forward and backward — against ``moe_ragged``'s zero-weight group: the
+    form a later PR may switch to (PERF.md section 7, row 9)."""
+    import ragged_leak_on_chip as leak
+
+    make, args, live = leak.build(48, 4, 16, 8, 8, 32, seed=5, dtype=jnp.float32)
+    assert 0 < live < 48 * 4
+    want = leak.results(make("zero_group"), args)
+    got = leak.results(make("vjp_masks"), args)
+    for name in leak.NAMES:
+        np.testing.assert_allclose(got[name], want[name], atol=1e-5, err_msg=name)
+    assert not np.any(want["dw_gate"][3]) and not np.any(got["dw_gate"][3])  # the empty group
+
+
+@pytest.mark.parametrize("layout", ["cut", "published"])
+def test_first_gradient_norms_leave_out_the_first_expert_layer_where_it_stands_alone(layout):
+    """``lfm2_reference.leaf_norms`` (what ``first_grad_worst_leaf_gap`` is
+    read over): without the router and the expert stacks of ``layer_1`` in
+    the cut; every leaf where the first expert layer lies inside a scan."""
+    cfg = (tiny_hybrid.config() if layout == "cut" else tiny_hybrid.config(
+        tiny_hybrid.PUBLISHED_LAYER_TYPES, num_dense_layers=2))
+    tree = W.make_tree(cfg, SEED, jnp.float32)
+    every, kept = set(_flat(tree)), set(jax.jit(ref.leaf_norms)(tree))
+    left_out = {k for k in every if k.startswith("['layer_1']['moe']")}
+    assert len(left_out) == (5 if layout == "cut" else 0)
+    assert kept == every - left_out
+    assert set(ref.param_change_leaf_norms(cfg, SEED, tree)) == every
