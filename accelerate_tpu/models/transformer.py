@@ -250,7 +250,7 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions, mask=None, kv_lengths=None,
                  paged=None, layer_window=None, pre_norm_scale=None,
-                 lora=None, lora_stacks=None):
+                 lora=None, lora_stacks=None, layer=None):
         decode = self.decode
         cfg = self.config
         delta = _lora_delta_fn(self, lora, lora_stacks)
@@ -357,7 +357,11 @@ class Attention(nn.Module):
             # and every prefill call shares ONE pool pytree, routed through
             # the per-call block tables in ``paged`` (ops/attention.py's
             # PagedKVState). The has_variable guard keeps the init pass on
-            # the plain path (creation must not write).
+            # the plain path (creation must not write). In a scanned stack
+            # the live pools are the loop's carry (_apply_layer_stack):
+            # the variables then hold every layer's pool, (L, num_blocks,
+            # ...), and ``layer`` is this one's row — the three operations
+            # below address that row inside the stack, never a copy of it.
             # int8 paged KV: pools store sym-quantized rows, one fp32
             # amax scale per token slot beside them ((num_blocks,
             # block_size) — ~4 bytes/token overhead vs the 2x row
@@ -429,20 +433,21 @@ class Attention(nn.Module):
                 new_k, new_v, new_ks, new_vs = paged_update(
                     key_pool.value, value_pool.value, k, v, paged,
                     key_scale=key_scale.value,
-                    value_scale=value_scale.value,
+                    value_scale=value_scale.value, layer=layer,
                 )
                 key_scale.value = new_ks
                 value_scale.value = new_vs
             else:
                 new_k, new_v = paged_update(
-                    key_pool.value, value_pool.value, k, v, paged
+                    key_pool.value, value_pool.value, k, v, paged,
+                    layer=layer,
                 )
             key_pool.value = new_k
             value_pool.value = new_v
             out = paged_attention(
                 q, new_k, new_v, paged, scale=scale,
                 softcap=cfg.attn_softcap, window=window,
-                key_scale=new_ks, value_scale=new_vs,
+                key_scale=new_ks, value_scale=new_vs, layer=layer,
             )
         elif decode:
             idx = cache_index.value
@@ -817,8 +822,9 @@ class Block(nn.Module):
         if isinstance(scanned, dict):
             layer_window = scanned.get("window")
             lora_scan = scanned.get("lora")
+            layer = scanned.get("layer")
         else:
-            layer_window, lora_scan = scanned, None
+            layer_window, lora_scan, layer = scanned, None, None
         attn_lora = mlp_lora = None
         if lora_scan is not None:
             attn_lora = {
@@ -838,12 +844,13 @@ class Block(nn.Module):
             attn_out = Attention(cfg, decode=self.decode, name="attn")(
                 x, positions, mask, kv_lengths, paged, layer_window,
                 pre_norm_scale=attn_scale, lora=lora, lora_stacks=attn_lora,
+                layer=layer,
             )
         else:
             attn_out = Attention(cfg, decode=self.decode, name="attn")(
                 RMSNorm(cfg, name="attn_norm")(x), positions, mask,
                 kv_lengths, paged, layer_window,
-                lora=lora, lora_stacks=attn_lora,
+                lora=lora, lora_stacks=attn_lora, layer=layer,
             )
         if cfg.post_norms:
             # Gemma-2 block: a norm AFTER each sublayer too (pre + post,
@@ -1009,7 +1016,8 @@ class _Period(nn.Module):
 
 
 def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
-                       block_cls=None, num_layers=None, per_layer=None):
+                       block_cls=None, num_layers=None, per_layer=None,
+                       carry_cache=False):
     """Run a block stack (scan or unrolled, optional remat) on hidden
     states. Must be called inside an ``nn.compact`` context — the created
     modules attach to the calling module's scope, so CausalLM,
@@ -1024,6 +1032,16 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
     array). ``block_cls``: defaults to :class:`Block`; the seq2seq decoder
     passes :class:`~.seq2seq.DecoderBlock`. Blocks must return
     ``(x, None)``.
+
+    ``carry_cache``: the ``cache`` collection of a scanned segment rides
+    the layer loop as a CARRY — each body sees the whole stacked leaves,
+    ``(repeats, ...)``, and is told its row as ``{"layer": index}`` in the
+    per-layer argument — instead of being sliced in and stacked out along
+    the scan axis. It is how live paged K/V pools go through the loop: the
+    loop then holds ONE buffer a pool, written in place, where a scanned
+    input and a scanned output are two, with a slice copied out and a
+    slice copied back each layer (three passes over the pool to store a
+    call's rows — a third of the v5e's decode step, PERF.md, PR 27).
 
     Layers of ONE kind (every configuration before ``layer_types``) are one
     scan named ``layers``. Layers of several kinds (:func:`layer_kinds`)
@@ -1059,9 +1077,13 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
         return {} if kind is None else {"mixer": kind[0], "ff": kind[1]}
 
     def scan(body, length, in_axes):
+        axes = {"params": 0, "intermediates": 0}
+        if not carry_cache:
+            axes["cache"] = 0
         return nn.scan(
             body,
-            variable_axes={"params": 0, "intermediates": 0, "cache": 0},
+            variable_axes=axes,
+            variable_carry="cache" if carry_cache else False,
             # "dropout": LoRA delta dropout inside the scanned block —
             # the entry is inert unless a dropout rng is actually passed
             # to apply (adapter training with LoraConfig.dropout > 0)
@@ -1084,12 +1106,20 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
                        name=f"layer_{i}")(x, *args)
         return x
 
-    in_axes = tuple(nn.broadcast for _ in extra)
+    def scan_args(per_layer, length):
+        """``(in_axes, args)`` of a scan over ``length`` layers: ``extra``
+        broadcast, then the per-layer pytree if there is one — with each
+        body's row of a carried cache in it."""
+        if carry_cache:
+            per_layer = dict(
+                per_layer or {}, layer=jnp.arange(length, dtype=jnp.int32))
+        in_axes = tuple(nn.broadcast for _ in extra)
+        if per_layer is None:
+            return in_axes, extra
+        return in_axes + (0,), extra + (per_layer,)
+
     if not mixed:
-        args = extra
-        if per_layer is not None:
-            in_axes = in_axes + (0,)
-            args = extra + (per_layer,)
+        in_axes, args = scan_args(per_layer, n)
         # a name for the loop's own copies and slices in a device trace,
         # not a Flax scope: the parameter tree is unchanged
         with jax.named_scope("layers"):
@@ -1106,6 +1136,7 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
             )(x, *extra)
             continue
         name = f"layers_{start}"
+        in_axes, args = scan_args(None, repeats)
         if len(period) == 1:
             body = scan(remat(base_cls, scanned=True), repeats, in_axes)(
                 cfg, decode=decode, **kind_kwargs(period[0]), name=name)
@@ -1113,7 +1144,7 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
             body = scan(_Period, repeats, in_axes)(
                 cfg, remat(base_cls, scanned=True), period, decode, name=name)
         with jax.named_scope(name):
-            x, _ = body(x, *extra)
+            x, _ = body(x, *args)
     return x
 
 
@@ -1165,9 +1196,16 @@ class CausalLM(nn.Module):
                 scanned["window"] = windows
             if lora is not None and lora.stacks is not None:
                 scanned["lora"] = lora.stacks
+        # Paged pools of a scanned stack are always carried through the
+        # layer loop, written in place. ``init``, which creates them and
+        # neither reads nor writes one, is the one pass that has them on
+        # the scan axis: flax creates no variable inside a carry.
         x = _apply_layer_stack(
             cfg, x, positions, mask, None, paged, lora_ctx, decode=decode,
             per_layer=scanned,
+            carry_cache=(
+                decode and paged is not None and not self.is_initializing()
+            ),
         )
         x = constrain_activations(RMSNorm(cfg, name="final_norm")(x))
         # logits matmul stays in the compute dtype (bf16 on the MXU — fp32

@@ -100,6 +100,22 @@ def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return jnp.clip(q, -127.0, 127.0).astype(jnp.int8), scale
 
 
+def _flat_pool(pool: jax.Array, inner: int) -> jax.Array:
+    """``pool`` with everything before its last ``inner`` axes (a block's
+    own: 3 of a K/V pool, 1 of a scale array) merged into ONE block axis:
+    a layer's own pool is returned as it is, a stack of pools (L,
+    num_blocks, ...) becomes (L * num_blocks, ...), in which layer ``l``'s
+    block ``b`` is row ``l * num_blocks + b``. The merged axes are major
+    in every layout, so the reshape moves nothing."""
+    return pool.reshape(-1, *pool.shape[pool.ndim - inner:])
+
+
+def _first_block(state: "PagedKVState", layer):
+    """Where layer ``layer``'s blocks start in a flattened stack of pools
+    (0 for a pool of its own, ``layer`` None)."""
+    return 0 if layer is None else layer * state.num_blocks
+
+
 @jax.named_scope("kv_write")
 def paged_update(
     key_pool: jax.Array,
@@ -109,8 +125,15 @@ def paged_update(
     state: PagedKVState,
     key_scale: Optional[jax.Array] = None,
     value_scale: Optional[jax.Array] = None,
+    layer=None,
 ) -> tuple[jax.Array, ...]:
     """Scatter one call's K/V into the block pools.
+
+    The pools are one layer's own, (num_blocks, block_size, Hkv, D), or
+    with ``layer`` (a traced index) the stack of every layer's, (L,
+    num_blocks, ...): the rows then land in that layer's part of the
+    stack, in place, and the stack is returned whole — never sliced out
+    and written back.
 
     ``k``/``v``: (B, S, Hkv, D); token i of slot b belongs at global
     position ``cache_len[b] + i``, which lives in table slot
@@ -138,9 +161,14 @@ def paged_update(
     valid = jnp.arange(s)[None, :] < state.lengths[:, None]
     tbl = jnp.clip(pos // bs, 0, max_blocks - 1)
     blocks = jnp.take_along_axis(state.block_table, tbl, axis=1)
-    blocks = jnp.where(valid, blocks, 0)
+    blocks = jnp.where(valid, blocks, 0) + _first_block(state, layer)
     offsets = pos % bs
     bf, of = blocks.reshape(-1), offsets.reshape(-1)
+
+    def put(pool, rows, inner):
+        return _flat_pool(pool, inner).at[bf, of].set(rows).reshape(
+            pool.shape)
+
     if state.kv_dtype == "int8":
         if key_scale is None or value_scale is None:
             raise ValueError(
@@ -151,14 +179,14 @@ def paged_update(
         kf = k.reshape(b * s, *k.shape[2:])
         vf = v.reshape(b * s, *v.shape[2:])
         return (
-            key_pool.at[bf, of].set(kf),
-            value_pool.at[bf, of].set(vf),
-            key_scale.at[bf, of].set(k_s.reshape(-1)),
-            value_scale.at[bf, of].set(v_s.reshape(-1)),
+            put(key_pool, kf, 3),
+            put(value_pool, vf, 3),
+            put(key_scale, k_s.reshape(-1), 1),
+            put(value_scale, v_s.reshape(-1), 1),
         )
     kf = k.reshape(b * s, *k.shape[2:])
     vf = v.reshape(b * s, *v.shape[2:])
-    return key_pool.at[bf, of].set(kf), value_pool.at[bf, of].set(vf)
+    return put(key_pool, kf, 3), put(value_pool, vf, 3)
 
 
 def decode_kernel_eligible(state: PagedKVState, q_len: int, pool) -> bool:
@@ -195,11 +223,15 @@ def paged_attention(
     window=None,
     key_scale: Optional[jax.Array] = None,
     value_scale: Optional[jax.Array] = None,
+    layer=None,
 ) -> jax.Array:
     """Attention read through the block table, in one of two forms of
     one algorithm (grouped softmax over a slot's blocks, under one mask
     rule): query at global row r sees column c iff ``c <= r`` (and ``c >
-    r - window`` under a sliding band).
+    r - window`` under a sliding band). With ``layer`` the pools are the
+    stack of every layer's (see :func:`paged_update`) and both forms read
+    that layer's blocks straight out of the stack: the table is offset,
+    the stack is never sliced.
 
     * The decode kernel (:mod:`.paged_attention`, where
       :func:`decode_kernel_eligible` holds: S == 1, native pools whole
@@ -227,24 +259,25 @@ def paged_attention(
 
         return paged_decode_attention(
             q, key_pool, value_pool, state.block_table, state.cache_len,
-            scale=scale, softcap=softcap, window=window,
+            scale=scale, softcap=softcap, window=window, layer=layer,
         )
     b, s = q.shape[:2]
     bs = state.block_size
     max_blocks = state.block_table.shape[1]
-    k = key_pool[state.block_table].reshape(
-        b, max_blocks * bs, *key_pool.shape[2:]
+    table = state.block_table + _first_block(state, layer)
+    k = _flat_pool(key_pool, 3)[table].reshape(
+        b, max_blocks * bs, *key_pool.shape[-2:]
     )
-    v = value_pool[state.block_table].reshape(
-        b, max_blocks * bs, *value_pool.shape[2:]
+    v = _flat_pool(value_pool, 3)[table].reshape(
+        b, max_blocks * bs, *value_pool.shape[-2:]
     )
     if state.kv_dtype == "int8":
         if key_scale is None or value_scale is None:
             raise ValueError(
                 "kv_dtype='int8' needs the key_scale/value_scale arrays"
             )
-        k_s = key_scale[state.block_table].reshape(b, max_blocks * bs)
-        v_s = value_scale[state.block_table].reshape(b, max_blocks * bs)
+        k_s = _flat_pool(key_scale, 1)[table].reshape(b, max_blocks * bs)
+        v_s = _flat_pool(value_scale, 1)[table].reshape(b, max_blocks * bs)
         k = (k.astype(jnp.float32) * k_s[:, :, None, None]).astype(q.dtype)
         v = (v.astype(jnp.float32) * v_s[:, :, None, None]).astype(q.dtype)
     rows = (state.cache_len[:, None] + jnp.arange(s)[None, :])[:, None, :, None]

@@ -188,17 +188,24 @@ def paged_decode_attention(
     scale: Optional[float] = None,
     softcap: Optional[float] = None,
     window=None,
+    layer=None,
 ) -> jax.Array:
     """``q`` (B, 1, H, D) against the blocks ``block_table`` (B,
     max_blocks) names in the pools (num_blocks, block_size, Hkv, D);
     slot ``b``'s query sits at global position ``cache_len[b]`` (its own
     K/V already written there). Returns (B, 1, H, D) at ``q``'s dtype:
     fp32 scores and softmax statistics, the pools' dtype into the MXU.
-    ``window``: None, an int or a traced scalar — the sliding band."""
+    ``window``: None, an int or a traced scalar — the sliding band.
+    ``layer``: the pools are the stack of every layer's, (L, num_blocks,
+    ...), and this traced index names the layer: the kernel is handed the
+    whole stack and a table offset into it, and copies what it always
+    copied — a slice of the stack would be a copy of a layer's pool."""
     b, s, h, d = q.shape
     if s != 1:
         raise ValueError(f"paged_decode_attention is the S == 1 shape, got {s}")
-    num_blocks, block_size, kv_heads, _ = key_pool.shape
+    block_size, kv_heads = key_pool.shape[-3:-1]
+    if layer is not None:
+        block_table = block_table + layer * key_pool.shape[-4]
     scale = scale if scale is not None else d ** -0.5
     chunk = max(1, min(CHUNK_ROWS // (block_size * kv_heads),
                        block_table.shape[1]))
@@ -213,7 +220,7 @@ def paged_decode_attention(
     )
     whole = pl.BlockSpec((b, h, d), lambda i, *refs: (0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    pool_shape = (num_blocks, block_size * kv_heads, d)
+    pool_shape = (-1, block_size * kv_heads, d)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -299,10 +299,13 @@ class ServingEngine:
         self._steps = 0
         self._http: Any = None
         # decode_attn_kernel: of the traced decode programs, how many
-        # took the Pallas paged-attention kernel (the rest gather)
+        # took the Pallas paged-attention kernel (the rest gather).
+        # kv_in_place: of the traced prefill, decode and verify programs,
+        # how many hold each pool as ONE buffer from their (donated)
+        # input through the layer loop to their output
         self._traces = {
             "prefill": 0, "decode": 0, "decode_attn_kernel": 0, "cow": 0,
-            "verify": 0, "swap_out": 0, "swap_in": 0,
+            "verify": 0, "swap_out": 0, "swap_in": 0, "kv_in_place": 0,
         }
         # every bucket width a prefill ever ran at — the set
         # capture_programs() reconstructs abstract specs from
@@ -311,6 +314,15 @@ class ServingEngine:
         # second capture (or the auditor) never pays a second compile
         self._captured_programs: dict[str, Any] = {}
         self.capture_compile_count = 0
+        # the compiler's word on the pools being written in place: of the
+        # captured prefill, decode and verify programs, the fewest bytes
+        # one of them gives back in the buffers they came in
+        # (``memory_analysis().alias_size_in_bytes``): ``kv_pool_bytes``
+        # when every one keeps the whole pool; 0 before a capture, and
+        # where the backend reports none
+        self.pool_alias_bytes = 0
+        # set when a call failed that had been given the pools to keep
+        self._pool_lost = False
 
         from ..models.generation import init_cache
 
@@ -351,12 +363,19 @@ class ServingEngine:
         # the sizing headline int8 halves: HBM bytes per cached token
         # across every layer's pools (+ scale overhead when quantized)
         self.kv_bytes_per_token = kv_bytes / (num_blocks * block_size)
+        self.kv_pool_bytes = kv_bytes
 
         traces = self._traces
         # what the engine knows about its pools and attention cannot see
         # from inside a trace: they sit whole on the weights' one device
         single_device = self._device is not None
         pool_leaf = self._kv_leaf_info[0][0]
+        # every program below that writes the pools is given them to keep
+        # (donated: each call site rebinds ``self.cache``), and the model
+        # carries the pools of a scanned stack through its layer loop
+        # (models/transformer.py): a pool is ONE buffer from input to
+        # output, and each such program counts itself under kv_in_place.
+        # ``pool_alias_bytes`` is the compiler's word on the same thing.
 
         def _lora_kwargs(lora_args):
             """(stacks, scales, slot_ids) trailing args -> the model's
@@ -377,6 +396,7 @@ class ServingEngine:
         def _prefill(params, cache, ids, table, length, cached_len, key,
                      temp, *lora_args):
             traces["prefill"] += 1  # trace-time counter (not per call)
+            traces["kv_in_place"] += 1
             # cached_len > 0 is the warm-hit path: ``ids`` holds only the
             # UNCACHED tail and the paged cache already contains KV for
             # the first cached_len positions (shared prefix blocks in
@@ -408,6 +428,7 @@ class ServingEngine:
         def _decode(params, cache, tokens, tables, cache_lens, lengths,
                     temps, key, *lora_args):
             traces["decode"] += 1  # zero-retrace contract rides on this
+            traces["kv_in_place"] += 1
             state = PagedKVState(
                 block_table=tables,
                 cache_len=cache_lens,
@@ -480,6 +501,7 @@ class ServingEngine:
             def _verify(params, cache, tokens, tables, cache_lens, lengths,
                         temps, keys, *lora_args):
                 traces["verify"] += 1
+                traces["kv_in_place"] += 1
                 state = PagedKVState(
                     block_table=tables,
                     cache_len=cache_lens,
@@ -505,11 +527,11 @@ class ServingEngine:
                     out = jnp.stack(outs, axis=1)
                 return mutated["cache"], out
 
-            return jax.jit(_verify)
+            return jax.jit(_verify, donate_argnums=1)
 
-        self._prefill_fn = jax.jit(_prefill)
-        self._decode_fn = jax.jit(_decode)
-        self._cow_fn = jax.jit(_cow)
+        self._prefill_fn = jax.jit(_prefill, donate_argnums=1)
+        self._decode_fn = jax.jit(_decode, donate_argnums=1)
+        self._cow_fn = jax.jit(_cow, donate_argnums=0)
         self._key_chain_fn = jax.jit(_key_chain)
         self._key_buf: collections.deque = collections.deque()
         # speculative decoding: verify programs cached by width (k + 1)
@@ -648,6 +670,13 @@ class ServingEngine:
         admit + prefill queued requests into the empty seats, then run
         ONE decode step over the whole slot batch. Returns the tokens
         produced this iteration."""
+        if self._pool_lost:
+            raise RuntimeError(
+                "this engine's KV pool went with a call that failed after "
+                "taking it (the pools are donated to every program that "
+                "writes them): the seated requests' state is gone; build a "
+                "new engine"
+            )
         try:
             with annotate("atpu:serve.step", step=self._steps), self._placed():
                 return self._step_inner()
@@ -656,6 +685,11 @@ class ServingEngine:
             # memory (ledger + last census + pool stats), then the
             # original error propagates untouched
             self._handle_oom(exc, context="serving_step")
+            # a call that fails on the device has already consumed the
+            # pools it was donated: never serve on from deleted buffers
+            self._pool_lost = any(
+                leaf.is_deleted() for leaf in jax.tree.leaves(self.cache)
+            )
             raise
 
     def _placed(self):
@@ -1163,7 +1197,8 @@ class ServingEngine:
                 leaves[i] = leaf.at[lead + (idx,)].set(jnp.moveaxis(d, 0, ax))
             return jax.tree.unflatten(treedef, leaves)
 
-        return jax.jit(_gather), jax.jit(_scatter)
+        # the gather only reads; the scatter keeps the pools it is given
+        return jax.jit(_gather), jax.jit(_scatter, donate_argnums=0)
 
     def _swap_fns_for(self, n: int) -> tuple:
         width = _next_pow2(n)
@@ -1876,6 +1911,7 @@ class ServingEngine:
             "resumes_total": self._resumes_total,
             "prefill_chunks_total": self._prefill_chunks_total,
             "kv_bytes_per_token": self.kv_bytes_per_token,
+            "pool_alias_bytes": self.pool_alias_bytes,
         }
         if self._role != "colocated":
             # PR 19 disaggregation plane: hand-off accounting only for
@@ -2063,6 +2099,13 @@ class ServingEngine:
             # steady-state counters the zero-retrace assertions read
             self._traces.clear()
             self._traces.update(snapshot)
+        self.pool_alias_bytes = int(min(
+            (getattr(compiled.memory_analysis(), "alias_size_in_bytes", 0)
+             for label, compiled in self._captured_programs.items()
+             if label.startswith(
+                 ("serve_prefill", "serve_decode", "serve_verify"))),
+            default=0,
+        ))
         return labels
 
     def audit_programs(

@@ -266,8 +266,10 @@ class DraftModelProposer:
             )[:, 0]
             return mutated["cache"], jnp.argmax(last, axis=-1)
 
-        self._prefill_fn = jax.jit(_prefill)
-        self._step_fn = jax.jit(_step)
+        # the draft pools are donated like the target's (engine.py):
+        # every call site rebinds ``self.cache``
+        self._prefill_fn = jax.jit(_prefill, donate_argnums=1)
+        self._step_fn = jax.jit(_step, donate_argnums=1)
 
     # ------------------------------------------------------------------ #
     # engine hooks

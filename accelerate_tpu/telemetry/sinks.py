@@ -163,6 +163,12 @@ shed; emitted by the ServingEngine's span log)::
     prefill_chunks_total                 int    chunked-prefill calls run
     kv_bytes_per_token                   float  KV+scale bytes per cached
                                                 token (int8 shrinks this)
+    pool_alias_bytes                     int    bytes the captured prefill/
+                                                decode/verify programs keep
+                                                in their input's buffers, the
+                                                least of them: the pool's
+                                                bytes when it is written in
+                                                place (0 before a capture)
 
 ``kind="memory"`` (one per live-buffer census, every
 ``census_interval`` emitted step records — or on demand via

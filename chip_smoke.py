@@ -68,8 +68,8 @@ class Sizes:
     train_batch_per_chip: int
     train_seq: int
     train_steps: int
-    # serve: bf16 weights + TWO copies of the KV pool must fit (the engine
-    # donates nothing yet)
+    # serve: bf16 weights + ONE KV pool must fit: the engine's programs keep
+    # the pool they are given (donated, carried through the layer loop)
     serve_layers: int
     serve_slots: int
     serve_block: int
@@ -856,6 +856,27 @@ def paged_logits_check(say, model, params, sz: Sizes, device) -> None:
     )
 
 
+def assert_pool_in_place(say, engine) -> None:
+    """Every prefill, decode and verify program the engine traced holds
+    each pool as ONE buffer from its input to its output: the engine's
+    count says so, and the compiler's: the bytes the captured programs
+    give back in the buffers they came in are the pool's, all of them."""
+    from accelerate_tpu.profiling.registry import ProgramRegistry
+
+    counts = engine.trace_counts()
+    programs = counts["prefill"] + counts["decode"] + counts["verify"]
+    assert counts["kv_in_place"] == programs > 0, counts
+    engine.capture_programs(ProgramRegistry())
+    assert engine.trace_counts() == counts, "capture_programs retraced"
+    aliased, pool = engine.pool_alias_bytes, engine.kv_pool_bytes
+    assert aliased == engine._gauge_fields()["pool_alias_bytes"] == pool, (
+        f"the compiled programs keep {aliased} bytes in place of a "
+        f"{pool}-byte pool: a call returns a second pool"
+    )
+    say(f"serve: {programs} programs carry their pools in place; "
+        f"pool_alias_bytes {aliased} == the pool's {pool} bytes")
+
+
 def assert_decode_kernel(say, engine, cfg) -> None:
     """The compiled decode program reads the KV pools through the Pallas
     kernel: exactly one Mosaic custom call per compiled layer body (one
@@ -896,7 +917,7 @@ def serve_phase(say, sz: Sizes, dry: bool) -> None:
                          max_seq_len=sz.serve_max_seq)
     model = CausalLM(cfg)
     say(f"serve: {sz.name} widths, depth cut 32 -> {cfg.num_layers} layers "
-        "(bf16 weights + two copies of the KV pool must fit), "
+        "(bf16 weights + one KV pool, written in place, must fit), "
         f"max_slots={sz.serve_slots} block_size={sz.serve_block} "
         f"max_seq_len={cfg.max_seq_len}, one engine per chip x "
         f"{len(devices)}")
@@ -971,12 +992,16 @@ def serve_phase(say, sz: Sizes, dry: bool) -> None:
         say(f"serve: engine on device {dev.id} trace_counts {counts} "
             f"pool allocated={stats['allocated']} free={stats['free']}")
         assert counts["decode"] == 1, f"decode traced {counts['decode']}x"
-        assert counts["prefill"] >= 1
+        assert 1 <= counts["prefill"] <= len(buckets), (
+            f"{counts['prefill']} prefill traces for buckets {buckets}: "
+            "a retrace"
+        )
         assert stats["allocated"] == 0, f"leaked blocks: {stats}"
         if not dry:
             assert counts["decode_attn_kernel"] == 1, (
                 "the decode program took the gather form of paged_attention"
             )
+    assert_pool_in_place(say, engines[0])
     if not dry:
         assert_decode_kernel(say, engines[0], cfg)
     assert_placement("after traffic")

@@ -410,3 +410,394 @@ def test_live_block_share_is_the_hand_count(head128_model):
     while engine.has_work:
         engine.step()
     assert engine._gauge_fields()["live_block_share"] == 0.0
+
+
+# ---------------------------------------------------------------------- #
+# (e) PR 27: the pools ride the layer loop as a carry, written in place
+# ---------------------------------------------------------------------- #
+def _layer_rows(stacked, layers):
+    """A scanned stack's parameters as the unrolled stack holds them."""
+    flat = {k: v for k, v in stacked.items() if k != "layers"}
+    for i in range(layers):
+        flat[f"layer_{i}"] = jax.tree.map(lambda x: x[i], stacked["layers"])
+    return flat
+
+
+_STACK_CASES = {
+    # kv dtype, tokens of the second call, window
+    "native-kernel": ("native", 1, None),
+    "native-kernel-window": ("native", 1, 5),
+    "native-gather": ("native", 3, None),
+    "native-gather-window": ("native", 3, 5),
+    "int8-decode": ("int8", 1, None),
+    "int8-decode-window": ("int8", 1, 5),
+    "int8-gather": ("int8", 3, None),
+    "int8-gather-window": ("int8", 3, 5),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STACK_CASES))
+def test_scanned_stack_carries_its_pools_and_equals_the_unrolled_stack(case):
+    """Prefill, then a second call (one token: the kernel where the pools
+    are native; three: the gather form), through ``CausalLM.apply`` over a
+    3-layer SCANNED stack — whose pools are one (L, num_blocks, ...) leaf
+    each, carried through the loop and addressed by layer — against the
+    same weights UNROLLED, every layer a 4-D pool of its own: the same
+    logits and the same final pools, to the last few bits (XLA:CPU rounds a
+    one-token matmul inside a loop body and outside one 2e-6 apart; a row
+    in the wrong layer or block is wrong by ~1). Slot 1 shares slot 0's
+    first block (a cached prefix); slot 2 is idle."""
+    kv, q_len, window = _STACK_CASES[case]
+    layers, nb, bs = 3, 9, 4
+    base = dict(hidden_size=256, num_heads=2, num_kv_heads=1, max_seq_len=16,
+                num_layers=layers, sliding_window=window)
+    scanned = CausalLM(TransformerConfig.tiny(**base))
+    unrolled = CausalLM(TransformerConfig.tiny(scan_layers=False, **base))
+    params = scanned.init(
+        jax.random.PRNGKey(3), jnp.zeros((1, 8), jnp.int32))["params"]
+
+    def state(cache_len, lengths):
+        return PagedKVState(
+            block_table=jnp.asarray(
+                [[1, 2, 3, 0], [1, 4, 5, 6], [0, 0, 0, 0]], jnp.int32),
+            cache_len=jnp.asarray(cache_len, jnp.int32),
+            lengths=jnp.asarray(lengths, jnp.int32),
+            num_blocks=nb, block_size=bs, kv_dtype=kv, single_device=True)
+
+    rng = np.random.default_rng(11)
+    vocab = scanned.config.vocab_size
+    first = rng.integers(1, vocab, (3, 8))
+    first[1, :2] = rng.integers(1, vocab, 2)  # row 1 holds positions 4..9
+    second = rng.integers(1, vocab, (3, q_len))
+    calls = [  # (ids, cache_len, lengths)
+        (first, [0, bs, 0], [8, 6, 0]),
+        (second, [8, bs + 6, 0], [q_len, q_len, 0]),
+    ]
+
+    def run(model, weights):
+        cache = model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32), decode=True,
+            paged=state([0], [1]))["cache"]
+        apply = jax.jit(lambda c, ids, st: model.apply(
+            {"params": weights, "cache": c}, ids, decode=True, paged=st,
+            mutable=["cache"]))
+        logits = []
+        with kernel_interpret_mode():
+            for ids, cache_len, lengths in calls:
+                out, mutated = apply(cache, jnp.asarray(ids, jnp.int32),
+                                     state(cache_len, lengths))
+                cache = mutated["cache"]
+                logits.append(out)
+        return logits, cache
+
+    got, stack = run(scanned, params)
+    want, rows = run(unrolled, _layer_rows(params, layers))
+    leaves = stack["layers"]["attn"]
+    assert set(leaves) == {"key_pool", "value_pool"} | (
+        {"key_scale", "value_scale"} if kv == "int8" else set())
+    for name, leaf in leaves.items():
+        assert leaf.shape[:2] == (layers, nb), (name, leaf.shape)
+        per_layer = jnp.stack(
+            [rows[f"layer_{i}"]["attn"][name] for i in range(layers)])
+        # block 0 takes every masked write, several to a row: no order
+        a = np.asarray(leaf[:, 1:], np.float32)
+        b = np.asarray(per_layer[:, 1:], np.float32)
+        # an int8 row may round to the next step where its scale's last bit differs
+        np.testing.assert_allclose(
+            a, b, rtol=2e-5, atol=1 if leaf.dtype == jnp.int8 else 2e-5)
+        assert np.mean(a == b) > 0.9 and np.any(a != 0)
+    # rows 0 and 1 alone: the idle slot's logits attend to the garbage block
+    tol = 2e-2 if kv == "int8" else 1e-4
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a[:2], b[:2], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("what", ["update", "gather", "kernel", "int8"])
+def test_the_paged_operations_take_a_stack_and_a_layer_like_a_pool(what, layer):
+    """``paged_update``, ``paged_attention`` and ``paged_decode_attention``
+    given (the stack of every layer's pools, a layer) do to that layer's
+    part of the stack what they do to the layer's own 4-D pool, and leave
+    every other layer's as it was."""
+    from accelerate_tpu.ops.attention import paged_update
+
+    rng = np.random.default_rng(7)
+    layers, nb = 3, 7
+    int8 = what == "int8"
+    dtype = jnp.int8 if int8 else jnp.float32
+    draw = (lambda s: rng.integers(-127, 128, s)) if int8 else rng.standard_normal
+    ks, vs = (jnp.asarray(draw((layers, nb, BS, HKV, D)), dtype)
+              for _ in range(2))
+    scales = [jnp.asarray(rng.uniform(0.01, 0.1, (layers, nb, BS)), jnp.float32)
+              for _ in range(2)] if int8 else [None, None]
+    cache_len = [BS + 3, 2, 0]
+    table = _scattered_table(rng, cache_len, nb)
+    s = 1 if what == "kernel" else 2
+    st = dataclasses.replace(
+        _state(table, cache_len, [s, s, 0], nb, what == "kernel"),
+        kv_dtype="int8" if int8 else "native")
+    k, v = (jnp.asarray(rng.standard_normal((3, s, HKV, D)), jnp.float32)
+            for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((3, s, 2 * HKV, D)), jnp.float32)
+    at = lambda stack: None if stack is None else stack[layer]
+    traced = jnp.asarray(layer, jnp.int32)
+
+    def on_stack(ks, vs, k_scale, v_scale):
+        new = paged_update(ks, vs, k, v, st, key_scale=k_scale,
+                           value_scale=v_scale, layer=traced)
+        return new, paged_attention(
+            q, *new[:2], st, key_scale=(new[2:] or [None])[0],
+            value_scale=(new[2:] or [None, None])[1], layer=traced)
+
+    def on_pool(kp, vp, k_scale, v_scale):
+        new = paged_update(kp, vp, k, v, st, key_scale=k_scale,
+                           value_scale=v_scale)
+        return new, paged_attention(
+            q, *new[:2], st, key_scale=(new[2:] or [None])[0],
+            value_scale=(new[2:] or [None, None])[1])
+
+    with kernel_interpret_mode():
+        assert decode_kernel_eligible(st, s, ks) == (what == "kernel")
+        got_pools, got = jax.jit(on_stack)(ks, vs, *scales)
+        want_pools, want = jax.jit(on_pool)(at(ks), at(vs), *map(at, scales))
+    np.testing.assert_array_equal(got[:2], want[:2])
+    for stack, before, pool in zip(got_pools, [ks, vs, *scales], want_pools):
+        assert stack.shape == before.shape
+        np.testing.assert_array_equal(stack[layer, 1:], pool[1:])
+        others = [i for i in range(layers) if i != layer]
+        np.testing.assert_array_equal(stack[others, 1:], before[others, 1:])
+    if what == "kernel":
+        direct = paged_attention_kernel.paged_decode_attention
+        with kernel_interpret_mode():
+            np.testing.assert_array_equal(
+                direct(q, *got_pools, st.block_table, st.cache_len,
+                       layer=traced)[:2],
+                direct(q, *want_pools, st.block_table, st.cache_len)[:2])
+
+
+def _while_operands(cfg):
+    """Operand counts of the ``while`` loops in a forward pass's lowered
+    text: everything the layer scans thread through."""
+    import re
+
+    model = CausalLM(cfg)
+    ids = jnp.zeros((2, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids))["params"]
+    text = jax.jit(lambda p, x: model.apply({"params": p}, x)).lower(
+        params, ids).as_text()
+    return [len(re.findall("%iterArg", line.split(") :")[0]))
+            for line in text.splitlines() if "stablehlo.while" in line]
+
+
+_TRAIN_LOOPS = {
+    # counted on the parent of PR 27 (commit 40f032d): the loop counter,
+    # the hidden states, the positions and one operand a parameter leaf
+    "dense": (TransformerConfig.tiny(num_layers=3), [14]),
+    "hybrid-moe": (TransformerConfig.tiny(
+        num_layers=9, layer_types=("conv",) + 2 * (
+            "full_attention", "conv", "conv", "conv"),
+        num_dense_layers=1, num_experts=2, moe_router_width=8,
+        moe_expert_offset=2, num_experts_per_tok=4, moe_router="sigmoid",
+        moe_expert_bias=True, qk_norm=True, tie_embeddings=True), [48]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_TRAIN_LOOPS))
+def test_a_stack_applied_without_decode_threads_nothing_new(name):
+    """The carry and the layer index exist for live paged pools alone: a
+    training or evaluation pass scans what it scanned before PR 27 (both
+    train cells' lowered steps were byte-identical to the parent's)."""
+    cfg, counted = _TRAIN_LOOPS[name]
+    assert _while_operands(cfg) == counted
+
+
+# the serve cells' engine: Mistral-7B widths, 24 layers, 16 slots x 1024
+_CELL = dict(vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+             num_layers=24, num_heads=32, num_kv_heads=8, head_dim=128,
+             sliding_window=4096, max_seq_len=1024, dtype="bfloat16")
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_widest"])
+def test_the_engine_program_holds_one_pool_on_the_v5e(
+        one_chip, program, monkeypatch):
+    """The engine's own decode and widest-prefill programs at the serve
+    cells' shapes (abstract weights, a 1025-block pool), compiled for the
+    described chip: every byte of the pool comes back in the buffer it came
+    in, and no operation is left whose result is one layer's pool."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cfg = TransformerConfig(**_CELL)
+    model = CausalLM(cfg)
+    # the engine lives where its weights do: one (CPU) device here, and
+    # the programs are lowered for the described chip below
+    held = {"w": jax.device_put(jnp.zeros(()), jax.devices()[0])}
+    engine = ServingEngine(model, held, max_slots=16, block_size=16)
+    assert engine.num_blocks == 1025
+
+    def sds(x, dtype=None):
+        return jax.ShapeDtypeStruct(
+            jnp.shape(x), dtype or jnp.result_type(x), sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x, jnp.bfloat16),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"])
+    cache = jax.tree.map(sds, engine.cache)
+    layer_pool = jax.tree.leaves(engine.cache)[0].shape[1:]
+    assert layer_pool == (1025, 16, 8, 128)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    n, table, key = engine.max_slots, engine._max_table, sds(engine._key)
+    # dispatch asks the default backend, the CPU here: answer for the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if program == "decode":
+        lowered = engine._decode_fn.lower(
+            params, cache, i32(n, 1), i32(n, table), i32(n), i32(n), f32(n), key)
+        assert engine.trace_counts()["decode_attn_kernel"] == 1
+    else:
+        lowered = engine._prefill_fn.lower(
+            params, cache, i32(1, 1024), i32(1, table), i32(1), i32(1), key,
+            f32(1))
+    monkeypatch.undo()
+    assert engine.trace_counts()["kv_in_place"] == 1
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == engine.kv_pool_bytes == 1612185600
+    if program == "decode":  # under one layer's K pool (it was 471 MB)
+        assert memory.temp_size_in_bytes < engine.kv_pool_bytes // 48
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        program == "decode")
+    a_layers_pool = re.compile(
+        r"= bf16\[(1,)?1025,16,8,128\]\S* "
+        r"(dynamic-slice|dynamic-update-slice|copy|fusion)\(")
+    assert not [line for line in text.splitlines()
+                if a_layers_pool.search(line)]
+    assert not re.search(r"= bf16\[24,1025,16,8,128\]\S* copy\(", text)
+
+
+# ---------------------------------------------------------------------- #
+# (f) PR 27: every program that writes the pools is given them to keep
+# ---------------------------------------------------------------------- #
+def test_every_pool_writing_program_donates_and_the_old_pool_is_gone():
+    """A decode step, a prefill, a copy-on-write, a swap-out and -in and a
+    speculative verify each leave the pool they were called with deleted
+    (the swap-out's gather reads and keeps it), the lowered text marks the
+    cache arguments as donated, and the tokens are those of an engine that
+    never preempts, shares or speculates."""
+    from accelerate_tpu.serving import SpecConfig
+
+    cfg = TransformerConfig.tiny(max_seq_len=64)
+    model = CausalLM(cfg)
+    params = jax.device_put(
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))[
+            "params"], jax.devices()[0])
+    rng = np.random.default_rng(3)
+    shared = list(map(int, rng.integers(1, cfg.vocab_size, 16)))
+    prompts = [shared, shared, shared[:8] + [5, 6, 7], [9, 8, 7, 6, 5] * 3]
+
+    def serve(eng):
+        ids = [eng.add_request(prompts[0], max_new_tokens=12)]
+        eng.step()  # published: the same prompt again is a full hit, a COW
+        ids.append(eng.add_request(prompts[1], max_new_tokens=12))
+        eng.step()
+        # both seats taken: the head of the queue evicts a lower priority
+        ids += [eng.add_request(p, max_new_tokens=12, priority=1)
+                for p in prompts[2:]]
+        while eng.has_work:
+            eng.step()
+        return [eng.result(i) for i in ids]
+
+    plain = ServingEngine(model, params, max_slots=2, block_size=8)
+    want = serve(plain)
+    eng = ServingEngine(model, params, max_slots=2, block_size=8,
+                        prefix_cache=True, preemption=True,
+                        spec_decode=SpecConfig(k=2))
+    gone: dict = {}
+
+    def spy(name, fn, argnum, keeps=False):
+        def call(*args):
+            pools = jax.tree.leaves(args[argnum])
+            out = fn(*args)
+            deleted = [leaf.is_deleted() for leaf in pools]
+            gone.setdefault(name, []).append(
+                not any(deleted) if keeps else all(deleted))
+            return out
+        call.lower = fn.lower
+        return call
+
+    eng._prefill_fn = spy("prefill", eng._prefill_fn, 1)
+    eng._decode_fn = spy("decode", eng._decode_fn, 1)
+    eng._cow_fn = spy("cow", eng._cow_fn, 0)
+    make_verify, make_swap = eng._make_verify, eng._make_swap_fns
+    eng._make_verify = lambda width: spy("verify", make_verify(width), 1)
+
+    def swap_fns(width):
+        gather, scatter = make_swap(width)
+        return (spy("swap_out", gather, 0, keeps=True),
+                spy("swap_in", scatter, 0))
+
+    eng._make_swap_fns = swap_fns
+    got = serve(eng)
+    counts = eng.trace_counts()
+    assert counts["kv_in_place"] == (
+        counts["prefill"] + counts["decode"] + counts["verify"])
+    seen = {name for name, calls in gone.items() if calls}
+    assert seen >= {"prefill", "cow", "swap_out", "swap_in", "verify"}, seen
+    assert all(all(calls) for calls in gone.values()), gone
+    assert got == want and all(len(tokens) == 12 for tokens in got)
+    # a plain decode step, and what the lowered programs say
+    before = jax.tree.leaves(plain.cache)
+    rid = plain.add_request(prompts[3], max_new_tokens=3)
+    while plain.has_work:
+        plain.step()
+    assert all(leaf.is_deleted() for leaf in before) and plain.result(rid)
+    n = plain.max_slots
+    text = plain._decode_fn.lower(
+        plain.params, plain.cache, jnp.zeros((n, 1), jnp.int32),
+        plain._tables_device(), jnp.zeros(n, jnp.int32),
+        jnp.ones(n, jnp.int32), plain.sampling.temperatures(),
+        plain._split_key()).as_text()
+    pools = len(jax.tree.leaves(plain.cache))
+    assert text.count("tf.aliasing_output") + text.count(
+        "jax.buffer_donor") == pools
+
+
+def test_a_call_that_fails_holding_the_pool_stops_the_engine(
+        tmp_path, monkeypatch):
+    """A program that fails on the device has consumed the pools it was
+    donated: the autopsy is written, the error goes up as it is, and the
+    next ``step`` refuses to serve from deleted buffers rather than
+    failing somewhere inside."""
+    from accelerate_tpu.profiling.oom import read_oom_report
+
+    monkeypatch.setenv("ACCELERATE_TPU_OOM_DIR", str(tmp_path))
+    cfg = TransformerConfig.tiny(max_seq_len=64)
+    model = CausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))[
+        "params"]
+    eng = ServingEngine(model, params, max_slots=2, block_size=8)
+    eng.add_request([1, 2, 3], max_new_tokens=8)
+    eng.step()
+
+    def fails_on_the_device(params, cache, *rest):
+        for leaf in jax.tree.leaves(cache):
+            leaf.delete()
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    eng._decode_fn = fails_on_the_device
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        eng.step()
+    assert read_oom_report(str(tmp_path))["context"] == "serving_step"
+    with pytest.raises(RuntimeError, match="KV pool went with a call"):
+        eng.step()
