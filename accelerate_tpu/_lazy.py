@@ -1,10 +1,10 @@
 """PEP 562 lazy re-exports for package ``__init__`` files.
 
 Importing ``accelerate_tpu`` (or ``.utils`` / ``.models`` / ``.profiling``)
-must not import jax: a process that only SPAWNS chip children — the bench
-parent, ``accelerate-tpu launch`` — has to stay off JAX entirely, and
-Python runs every parent package's ``__init__`` on the way to
-``accelerate_tpu.benchmarks.cli``. So those packages declare their
+must not import jax: a process that only SPAWNS chip children —
+``accelerate-tpu launch`` — has to stay off JAX entirely, and Python runs
+every parent package's ``__init__`` on the way to
+``accelerate_tpu.commands.launch``. So those packages declare their
 re-exports as a ``name -> submodule`` table and resolve them on first
 access.
 """

@@ -36,9 +36,9 @@ def estimate_activation_bytes(
     here activations are first-class because remat changes them 10x).
 
     Model: per layer, the saved residuals depend on the remat policy —
-    "full" keeps only each layer's input; "dots" (the bench default) keeps
-    matmul outputs (qkv/o projections, gate/up/down); None keeps those plus
-    the elementwise intermediates. The lm-head logits (+fp32 softmax) are
+    "full" keeps only each layer's input; "dots" (what the dense train cell
+    runs) keeps matmul outputs (qkv/o projections, gate/up/down); None
+    keeps those plus the elementwise intermediates. The lm-head logits (+fp32 softmax) are
     counted separately: at large vocab they dominate and remat cannot
     remove them.
     """

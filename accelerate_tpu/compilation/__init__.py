@@ -2,7 +2,7 @@
 observable phase.
 
 XLA always compiles; untuned, it compiles *repeatedly* — every process,
-every restart, every bench variant pays the full lowering + backend
+every restart, every benchmark run pays the full lowering + backend
 compile again. Production JAX trainers (MaxText/T5X-style AOT compile,
 JAX's persistent compilation cache) treat compile as a cached, warmed,
 measured resource. This package gives the Accelerator the same three

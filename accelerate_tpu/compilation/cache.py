@@ -19,8 +19,8 @@ names) ARE part of the key here, source lines are not: a trace of a
 cached program shows the scopes of the tree that runs it.
 
 :func:`activate_persistent_cache` applies the rule and is what
-``AcceleratorState``, ``ServingEngine``, the bench children, the graft
-entry and ``tests/conftest.py`` all call. It is idempotent.
+``AcceleratorState``, ``ServingEngine``, the graft entry and
+``tests/conftest.py`` all call. It is idempotent.
 """
 
 from __future__ import annotations
@@ -47,8 +47,7 @@ _warned_ignored = False
 
 def resolve_cache_dir(plugin: Any = None) -> str:
     """Where the persistent cache lives for this process (see module
-    docstring for the rule). Pure: touches neither JAX nor the disk (the
-    bench parent, which must not import jax, calls it too)."""
+    docstring for the rule). Pure: touches neither JAX nor the disk."""
     global _warned_ignored
     explicit = getattr(plugin, "cache_dir", None)
     env = os.environ.get(ENV_JAX_CACHE_DIR)
@@ -132,7 +131,7 @@ def persistent_cache_dir() -> Optional[str]:
 
 def persistent_cache_entries(path: Optional[str] = None) -> int:
     """Count cache entries on disk — a cheap proxy for 'did anything
-    persist' in smoke tests and bench records."""
+    persist' in smoke tests."""
     path = path or _active_dir
     if not path or not os.path.isdir(path):
         return 0

@@ -510,7 +510,7 @@ def assert_overlap(
 ) -> dict[str, Any]:
     """The multichip profile assertion: parse ``trace_dir`` and require
     ``overlap_pct >= min_pct``. Raises AssertionError with the report
-    (or the absence of one) spelled out — bench/dryrun harness hook."""
+    (or the absence of one) spelled out — dryrun harness hook."""
     report = collective_compute_overlap(trace_dir)
     assert report is not None, (
         f"no collective events found in any device plane under {trace_dir}"

@@ -67,8 +67,7 @@ class DiagnosticsManager:
         median/MAD fold, which sorts its rolling window; with
         ``DiagnosticsConfig.anomaly_sample_every > 1`` that fold runs on
         every Nth step only (NaN detection still every step), making the
-        whole path O(1) amortized — the bench's ON-vs-OFF ``overhead``
-        variant measures the result as ``harness_overhead_pct``.
+        whole path O(1) amortized.
         """
         kind = record.get("kind")
         if kind in ("anomaly", "goodput"):

@@ -5,8 +5,8 @@ warmup → ramp → soak → fault → recovery phase program, serving-scoped
 chaos via the ``ACCELERATE_TPU_FAULT_INJECT`` grammar, and an
 atomically-written ``soak-report.json`` with goodput-under-SLO and
 capacity-at-breach-point headlines. Everything is default-off and
-record-only: nothing here runs unless a bench variant, a test, or user
-code builds a :class:`SoakHarness`.
+record-only: nothing here runs unless a test or user code
+builds a :class:`SoakHarness`.
 """
 
 from .chaos import ChaosAdapter

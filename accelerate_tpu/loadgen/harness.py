@@ -65,14 +65,6 @@ class SoakConfig:
     """One soak run: workload x phase program x clocking x chaos.
 
     ``step_dt_s``: virtual seconds per engine step (None = wall clock).
-    ``step_cost``: virtual mode only — a callable taking the engine and
-    returning THIS step's virtual duration, consulted after each engine
-    step instead of the flat ``step_dt_s`` quantum. Use it to charge
-    steps by the work they actually issued (e.g. a delta of the
-    engine's ``prefill_bucket_tokens_total``), so compute serialization
-    — a giant prefill stalling the whole batch for one long step — is
-    visible on hosts whose wall clock is all dispatch overhead. Idle
-    gaps still advance at the flat quantum.
     ``fault_specs``: ``ACCELERATE_TPU_FAULT_INJECT``-grammar string with
     steps relative to the fault-window entry step; empty string reads
     the env var (and stays inert if that is unset too).
@@ -88,7 +80,6 @@ class SoakConfig:
     phases: tuple = dataclasses.field(default_factory=standard_program)
     seed: int = 0
     step_dt_s: Optional[float] = 0.01
-    step_cost: Optional[Callable] = None
     slo: object = None
     gauge_interval: int = 4
     fault_specs: str = ""
@@ -229,11 +220,7 @@ class SoakHarness:
                         continue  # the fault fired on THIS step
                     self.engine.step()
                     if cfg.step_dt_s is not None:
-                        self.clock.advance(
-                            cfg.step_cost(self.engine)
-                            if cfg.step_cost is not None
-                            else cfg.step_dt_s
-                        )
+                        self.clock.advance(cfg.step_dt_s)
                     self._poll_recovery()
                 else:
                     self._advance_idle(rel, trace, next_i, total_s)

@@ -14,8 +14,8 @@ The shape mirrors production templated traffic:
   prompts); a ``cohort_fraction`` slice of requests opens with one, so a
   prefix-cache-enabled engine sees real chain reuse under load;
 * **long tail** — prompt-body and output lengths are Pareto-tailed
-  around a median (the 3/4-short / 1/4-long production mix the serving
-  bench already uses, generalised to a continuous tail);
+  around a median (a 3/4-short / 1/4-long production mix, generalised
+  to a continuous tail);
 * **tenants** — an ``adapter_fraction`` slice carries one of
   ``adapters``' names, exercising registry residency and refcounts.
 

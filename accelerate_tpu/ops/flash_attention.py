@@ -395,166 +395,6 @@ def _bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, group, offset,
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-# Single-pass fused backward (r5 stretch, VERDICT r4 item 10): one kernel
-# computes dq, dk AND dv per (q-block, kv-block) pair. The two-pass
-# backward recomputes s = qk^T and dp = do v^T in BOTH kernels (7 MXU
-# matmuls per pair) and streams q/k/v/do through VMEM twice; the fused
-# kernel computes each intermediate once (5 matmuls per pair) and reads
-# the inputs once.
-#
-# TPU Pallas only allows output blocks to be revisited in CONSECUTIVE
-# grid steps, so per-pair accumulation must be arranged as:
-#   * grid (B, H, ik, iq) — per-HEAD dk/dv partials accumulate in their
-#     OUTPUT blocks (index (b, h, ik): constant across the inner iq
-#     sweep -> resident in VMEM); GQA groups sum outside the kernel;
-#   * dq accumulates in a FULL-SEQUENCE f32 VMEM scratch (S x D — 4 MiB
-#     at S=8192/D=128) and flushes during the LAST kv sweep, where its
-#     collapsing index map (iq on ik==nk-1, else block 0) makes every
-#     output block's visit run consecutive.
-#
-# MEASURED OUTCOME (r5, v5e, longseq bench shape B=1 S=8192 H=32/8
-# D=128, fwd+bwd train step): the fused kernel is ~26x SLOWER — 8,137 ms
-# vs the two-pass 310 ms (chip re-verified healthy on the two-pass
-# rerun). Numerics are correct (all interpret-mode oracle tests pass);
-# the cost is structural: the data-dependent collapsing index map and
-# the dynamically-indexed full-sequence scratch defeat Mosaic's
-# double-buffered pipelining, serializing the grid, and 1024-blocks
-# overflow v5e's 16 MiB scoped VMEM with the scratch in place (measured
-# 19.88M), forcing 512-blocks. The naive fused form (dq accumulated by
-# HBM read-modify-write) is rejected outright by the consecutive-visit
-# rule. CONCLUSION: the 7-matmul two-pass backward stays the production
-# path — the same structural choice jax's own pallas TPU flash kernels
-# make — and the ~29% matmul saving of a single-pass design is not
-# reachable under current Mosaic output-visit semantics. FUSED_BWD
-# stays off; the kernel is kept as the measured record of the attempt.
-FUSED_BWD = False
-_FUSED_DQ_SCRATCH_LIMIT = 8 * 2**20  # bytes of dq scratch (f32 S x D)
-
-
-def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k,
-                      offset, padded, window):
-    if padded:
-        (lens_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dk_ref, dv_ref, dq_scr) = refs
-        kvlen = lens_ref[pl.program_id(0)]
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dk_ref, dv_ref, dq_scr) = refs
-        kvlen = None
-    ik, iq = pl.program_id(2), pl.program_id(3)
-    nk, nq = pl.num_programs(2), pl.num_programs(3)
-
-    @pl.when(iq == 0)
-    def _init_kv():  # dk/dv blocks are resident across the iq sweep
-        dk_ref[0, 0] = jnp.zeros_like(dk_ref[0, 0])
-        dv_ref[0, 0] = jnp.zeros_like(dv_ref[0, 0])
-
-    @pl.when((ik == 0) & (iq == 0))
-    def _init_dq():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    run = _block_visible(iq, ik, block_q, block_k, causal, offset, kvlen,
-                         window)
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, 0:1]
-        delta = delta_ref[0, 0][:, 0:1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if causal:
-            s = _apply_causal(s, iq, ik, block_q, block_k, offset, window)
-        if padded:
-            s = _apply_kv_padding(s, ik, block_q, block_k, kvlen)
-        if padded or (causal and offset < 0):
-            # see _bwd_dq_kernel: zero fully-masked rows (lse == NEG_INF)
-            p = jnp.where(lse <= NEG_INF * 0.5, 0.0, jnp.exp(s - lse))
-        else:
-            p = jnp.exp(s - lse)  # (bq, bk) f32
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
-        dv_ref[0, 0] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dv_ref.dtype)
-        dk_ref[0, 0] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        ).astype(dk_ref.dtype)
-        rows = pl.ds(iq * block_q, block_q)
-        dq_scr[rows, :] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    @pl.when(ik == nk - 1)
-    def _flush_dq():
-        dq_ref[0, 0] = dq_scr[pl.ds(iq * block_q, block_q), :]
-
-
-def _bwd_fused(scale, causal, bq, bk, window, prefix, q, k, v, dout, lse,
-               delta, padded):
-    B, H, S, D = q.shape
-    Hkv, Skv = k.shape[1], k.shape[2]
-    g = H // Hkv
-    nq, nk = pl.cdiv(S, bq), pl.cdiv(Skv, bk)
-
-    q_idx = lambda b, h, ik, iq, *refs: (b, h, iq, 0)
-    kv_idx = lambda b, h, ik, iq, *refs, g=g: (b, h // g, ik, 0)
-    kvh_idx = lambda b, h, ik, iq, *refs: (b, h, ik, 0)
-    # collapsing map: block 0 until the last kv sweep, then iq — every
-    # output block's visits stay consecutive (Pallas TPU requirement)
-    dq_idx = lambda b, h, ik, iq, *refs, nk=nk: (
-        b, h, jnp.where(ik == nk - 1, iq, 0), 0
-    )
-    in_specs = [
-        pl.BlockSpec((1, 1, bq, D), q_idx),
-        pl.BlockSpec((1, 1, bk, D), kv_idx),
-        pl.BlockSpec((1, 1, bk, D), kv_idx),
-        pl.BlockSpec((1, 1, bq, D), q_idx),
-        pl.BlockSpec((1, 1, bq, 128), q_idx),
-        pl.BlockSpec((1, 1, bq, 128), q_idx),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, 1, bq, D), dq_idx),
-        pl.BlockSpec((1, 1, bk, D), kvh_idx),  # per-HEAD dk partial
-        pl.BlockSpec((1, 1, bk, D), kvh_idx),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        jax.ShapeDtypeStruct((B, H, Skv, D), jnp.float32),
-        jax.ShapeDtypeStruct((B, H, Skv, D), jnp.float32),
-    ]
-    kernel = functools.partial(
-        _bwd_fused_kernel, scale=scale, causal=causal, block_q=bq,
-        block_k=bk, offset=Skv - S, padded=padded, window=window,
-    )
-    dq, dkh, dvh = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1 if padded else 0,
-            grid=(B, H, nk, nq),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
-        ),
-        out_shape=out_shape,
-        interpret=kernels_interpreted(),
-        name="flash_bwd",
-    )(*(prefix + (q, k, v, dout, lse, delta)))
-    if g > 1:  # sum the GQA group partials back onto the kv heads
-        dk = dkh.reshape(B, Hkv, g, Skv, D).sum(2).astype(k.dtype)
-        dv = dvh.reshape(B, Hkv, g, Skv, D).sum(2).astype(v.dtype)
-    else:
-        dk, dv = dkh.astype(k.dtype), dvh.astype(v.dtype)
-    return dq.astype(q.dtype), dk, dv, None
-
-
 def _bwd(scale, causal, block_q, block_k, window, res, dout):
     q, k, v, lengths, out, lse = res
     B, H, S, D = q.shape
@@ -566,19 +406,6 @@ def _bwd(scale, causal, block_q, block_k, window, res, dout):
 
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, 128))
-
-    if FUSED_BWD and S * D * 4 <= _FUSED_DQ_SCRATCH_LIMIT:
-        # the full-sequence dq scratch shares the 16 MiB scoped-vmem
-        # budget with the score tiles — 1024-blocks overflow it at
-        # S=8192 (measured: 19.88M > 16M), 512-blocks fit
-        # cannot return None: the wrapper guaranteed bq | S with bq % 8
-        # == 0, so the halving chain from min(bq, 512) always lands
-        fbq = fit_block(S, min(bq, 512))
-        fbk = fit_block(Skv, min(bk, 512))
-        return _bwd_fused(
-            scale, causal, fbq, fbk, window,
-            (lengths,) if padded else (), q, k, v, dout, lse, delta, padded,
-        )
 
     dq_in_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik, *refs: (b, h, iq, 0)),

@@ -9,8 +9,8 @@ materializes the normalized activations and three pre-rope projections
 in HBM; here the norm is recomputed per weight tile in registers, the
 three projection matmuls run against one concatenated (E, (H+2*Hkv)*D)
 weight block, and the rotation is applied before the tile ever leaves
-VMEM. Backward follows the FUSED_BWD precedent in flash_attention.py:
-a hand-fused backward was measured far slower than XLA's, so the vjp is
+VMEM. Backward follows flash attention's precedent (PERF.md section 6,
+PRs 21-25): a hand-fused backward was measured far slower, so the vjp is
 ``jax.vjp`` of the plain-JAX reference chain (``prologue_reference``,
 numerically the exact module-path math).
 
@@ -330,9 +330,9 @@ def fused_qkv_prologue(
     (E,)``, the three projection kernels ``(E, H*D)/(E, Hkv*D)`` (+
     optional biases), and ``positions (B,S)``. Returns ``q (B,S,H,D)``,
     ``k/v (B,S,Hkv,D)`` — bit-compatible with the unfused module chain
-    in fp32. Backward is ``jax.vjp`` of ``prologue_reference`` (the
-    flash_attention FUSED_BWD precedent: XLA's backward beats a hand
-    kernel here, and the reference IS the parity definition)."""
+    in fp32. Backward is ``jax.vjp`` of ``prologue_reference`` (XLA's
+    backward beats a hand kernel here, and the reference IS the parity
+    definition)."""
     b, s, hidden = x.shape
     d = head_dim
     rows = b * s

@@ -10,7 +10,7 @@ Three host-side pieces answering "where do the bytes and FLOPs go":
   ``kind="memory"`` telemetry records and the leak detector's signal.
 * :mod:`oom` — RESOURCE_EXHAUSTED autopsies: an atomic
   ``oom-report.json`` written from already-resident data at the
-  step/engine/bench boundaries.
+  step/engine boundaries.
 * :mod:`hlo_audit` — the sharding X-ray: per-program collective
   inventories (kind / bytes moved / ICI-vs-DCN) parsed from compiled
   HLO, checked against each program's expected-collective contract;

@@ -577,7 +577,7 @@ def audit_compiled(
 
 def summarize_audits(audits: Iterable[ProgramAudit]) -> dict:
     """Roll a set of program audits into the ledger summary stamped
-    into soak reports / BENCH records / diagnose: totals per fabric,
+    into soak reports / diagnose: totals per fabric,
     the per-program inventory map, and the (bounded) violation list."""
     audits = list(audits)
     violations: list[dict] = []

@@ -1,7 +1,7 @@
 """OOM forensics: turn RESOURCE_EXHAUSTED into an autopsy, not a shrug.
 
 An XLA out-of-memory kills the process with a wall of allocator text and
-no record of *what was resident*. The step/engine/bench boundaries catch
+no record of *what was resident*. The step/engine boundaries catch
 the error, and :func:`write_oom_report` writes an atomic
 ``oom-report.json`` from data that is **already in memory** — the
 program ledger, the last census, pool stats, the top-3 largest programs
@@ -28,7 +28,7 @@ from ..logging import get_logger
 
 logger = get_logger(__name__)
 
-#: filename of the autopsy (searched for by diagnose / the bench runner)
+#: filename of the autopsy (searched for by diagnose)
 OOM_REPORT_NAME = "oom-report.json"
 #: env override for where autopsies land
 ENV_OOM_DIR = "ACCELERATE_TPU_OOM_DIR"

@@ -38,17 +38,33 @@ from ..logging import get_logger
 
 logger = get_logger(__name__)
 
-#: nominal HBM bandwidth (bytes/s) by device kind, public cloud specs —
-#: the roofline's memory roof. CPU gets a nominal figure so the math
-#: stays defined in tests.
-PEAK_HBM_BYTES_PER_S = {
-    "TPU v4": 1.2e12,
-    "TPU v5 lite": 0.82e12,
-    "TPU v5e": 0.82e12,
-    "TPU v5p": 2.77e12,
-    "TPU v6 lite": 1.64e12,
-    "TPU v6e": 1.64e12,
+#: published per-chip peaks by device kind (public cloud specs): bf16
+#: FLOP/s and HBM bytes/s side by side — the roofline's two roofs. Matched
+#: as a substring of ``device_kind`` in this order, so the bare "TPU v5"
+#: (how a v5p reports itself) comes after "TPU v5 lite". No CPU row: a
+#: kind that is not here has no peak, never a default.
+DEVICE_PEAKS = {
+    "TPU v4": {"flops_per_s": 275e12, "hbm_bytes_per_s": 1.2e12},
+    "TPU v5 lite": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+    "TPU v5e": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+    "TPU v5p": {"flops_per_s": 459e12, "hbm_bytes_per_s": 2.77e12},
+    "TPU v5": {"flops_per_s": 459e12, "hbm_bytes_per_s": 2.77e12},
+    "TPU v6 lite": {"flops_per_s": 918e12, "hbm_bytes_per_s": 1.64e12},
+    "TPU v6e": {"flops_per_s": 918e12, "hbm_bytes_per_s": 1.64e12},
 }
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The ``DEVICE_PEAKS`` row of ``device_kind``; ``ValueError`` for a
+    kind with no published peak (CPU included)."""
+    kind = device_kind.lower()
+    for name, row in DEVICE_PEAKS.items():
+        if name.lower() in kind:
+            return row
+    raise ValueError(
+        f"no published peak for device_kind {device_kind!r}: add it to "
+        "DEVICE_PEAKS with its source before reporting a utilization"
+    )
 
 
 @dataclass
@@ -389,7 +405,7 @@ class ProgramRegistry:
         lost to *this* program's schedule rather than to physics.
 
         Without explicit peaks they come from the published per-chip
-        tables keyed by ``device_kind``; a kind that is in neither table
+        table keyed by ``device_kind``; a kind that is not in it
         (CPU included) has no roofline and this returns None.
         """
         rec = self.get(label)
@@ -398,22 +414,14 @@ class ProgramRegistry:
         if peak_flops is None or peak_bytes_per_s is None:
             import jax
 
-            from ..benchmarks.measure import _peak_flops
-
             # record-only: a device kind with no published peak (CPU
             # included) has no roofline — None, never a default
-            device = jax.devices()[0]
-            kind = str(device.device_kind).lower()
             try:
-                peak_flops = peak_flops or _peak_flops(device)
+                row = device_peaks(jax.devices()[0].device_kind)
             except ValueError:
                 return None
-            if peak_bytes_per_s is None:
-                peak_bytes_per_s = next(
-                    (bw for name, bw in PEAK_HBM_BYTES_PER_S.items()
-                     if name.lower() in kind),
-                    None,
-                )
+            peak_flops = peak_flops or row["flops_per_s"]
+            peak_bytes_per_s = peak_bytes_per_s or row["hbm_bytes_per_s"]
         intensity = rec.arithmetic_intensity
         if intensity is None or not peak_flops or not peak_bytes_per_s:
             return None

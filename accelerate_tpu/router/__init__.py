@@ -37,7 +37,7 @@ against the destination's CACHED index, re-queue on a dead endpoint,
 
 Everything is default-OFF: nothing in the single-engine path imports or
 consults this package, and a :class:`FleetRouter` only exists where
-user code (or the ``fleet_soak`` bench) builds one. The router is
+user code builds one. The router is
 duck-type compatible with :class:`~accelerate_tpu.loadgen.SoakHarness`'s
 engine surface (``add_request`` / ``step`` / ``has_work`` / ...), so
 the PR 16 soak harness drives a fleet unchanged.

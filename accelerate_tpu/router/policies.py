@@ -5,8 +5,7 @@ A policy never touches replicas — the router builds a candidate list
 :class:`~accelerate_tpu.router.replica.ReplicaSnapshot` and, when the
 policy wants it, the request's cached-chain overlap) and the policy
 picks one. Keeping the policies pure makes them individually testable
-on fake snapshots and individually benchmarkable on the same trace
-(the ``fleet_soak`` bench's three arms).
+on fake snapshots and individually comparable on the same trace.
 """
 
 from __future__ import annotations
@@ -123,7 +122,7 @@ _POLICIES = {
 
 def make_policy(policy, load_penalty: Optional[float] = None):
     """Resolve a policy name (or pass an instance through). The string
-    form is what the bench/CLI use; ``load_penalty`` only applies to
+    form is what callers use; ``load_penalty`` only applies to
     ``prefix_affinity``."""
     if not isinstance(policy, str):
         return policy

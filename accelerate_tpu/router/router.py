@@ -69,7 +69,7 @@ class FleetRouter:
     ``policy``: ``"round_robin"`` | ``"least_loaded"`` |
     ``"prefix_affinity"`` or a policy instance. ``now`` must be the
     same injectable clock the replicas' engines stamp from (the soak
-    harness's virtual clock in tests/benches, ``time.monotonic`` in
+    harness's virtual clock in tests, ``time.monotonic`` in
     production).
     """
 

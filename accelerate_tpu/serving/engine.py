@@ -560,8 +560,7 @@ class ServingEngine:
         # padded prefill compute issued so far, in bucket tokens — the
         # pow2 bucket width of every prefill/chunk call, cumulative. A
         # per-step delta of this IS the step's prefill compute cost
-        # (padding included), which work-weighted virtual clocks charge
-        # time by (see loadgen.SoakConfig.step_cost)
+        # (padding included)
         self.prefill_bucket_tokens_total = 0
         if spec_decode is not None:
             self.set_speculation(spec_decode)
@@ -628,7 +627,7 @@ class ServingEngine:
     def set_role(self, role: str) -> None:
         """Switch the engine's disaggregation role on a WARM engine.
         Roles are pure host policy — the compiled programs are shared —
-        so a bench can prime an engine colocated (warming its prefill
+        so a caller can prime an engine colocated (warming its prefill
         buckets AND the decode program) and then assign it to a pool."""
         if role not in ("colocated", "prefill", "decode"):
             raise ValueError(
@@ -2150,7 +2149,7 @@ class ServingEngine:
 
     def audit_summary(self, registry: Any = None) -> dict:
         """Roll-up of the stored serving-program audits (ICI/DCN bytes,
-        violation count + details) for soak reports and BENCH records.
+        violation count + details) for soak reports.
         Empty dict when :meth:`audit_programs` has not run."""
         from ..profiling.registry import get_program_registry
 
@@ -2174,7 +2173,7 @@ class ServingEngine:
         spans: bool = True,
     ) -> None:
         """(Re)attach or detach the observability plane at runtime on a
-        WARM engine — the serve bench's A/B toggle: the same compiled
+        WARM engine — an A/B toggle: the same compiled
         programs replay the same trace with observability off, then on,
         so the measured delta is purely span/gauge/SLO host work.
         ``slo`` accepts an :class:`SLOConfig` or an existing
@@ -2198,7 +2197,7 @@ class ServingEngine:
     ) -> None:
         """Toggle prefix caching at runtime on a WARM engine. Caching is
         pure host policy — the compiled prefill/decode programs are
-        identical either way — so the serve bench can A/B cold vs warm
+        identical either way — so a caller can A/B cold vs warm
         on one engine without a single retrace. Disabling clears the
         content index (cached LRU blocks return to the free list;
         in-flight shared blocks keep their refcounts and drain
@@ -2223,8 +2222,7 @@ class ServingEngine:
         reservation); already-seated requests finish plainly, so an
         in-flight verify write can never outrun a reservation made
         before the toggle. Verify programs are cached per width and
-        proposers per config instance: an off→on→off→on A/B (the serve
-        bench's speculation axis) replays warm traces — the
+        proposers per config instance: an off→on→off→on A/B replays warm traces — the
         zero-retrace-after-warmup contract extends to the toggle."""
         if spec is None or spec.k == 0:
             self._spec = spec

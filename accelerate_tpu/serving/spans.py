@@ -121,8 +121,8 @@ class SpanLog:
             raise ValueError("maxlen must be >= 1")
         self._open: dict[str, RequestSpan] = {}
         self.closed: collections.deque = collections.deque(maxlen=maxlen)
-        # False turns every lifecycle hook into a no-op — the serve
-        # bench's observability-off arm of its overhead A/B
+        # False turns every lifecycle hook into a no-op — the
+        # observability-off arm of an overhead A/B
         self.enabled = True
 
     def __len__(self) -> int:

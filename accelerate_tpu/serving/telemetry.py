@@ -2,8 +2,7 @@
 
 The wire format is the collector's ``kind="serve"`` record (one per
 COMPLETED request — see ``telemetry/sinks.py`` for the schema); this
-module is the in-process aggregation the engine and the bench read
-back: p50/p95 TTFT, end-to-end latency, per-request decode tokens/s.
+module is the in-process aggregation the engine reads back: p50/p95 TTFT, end-to-end latency, per-request decode tokens/s.
 
 Memory is bounded for long-lived servers: :class:`ServeStats` keeps the
 last ``window`` records for the percentile math (the same rolling-window
@@ -37,8 +36,7 @@ PERCENTILE_FIELDS = ("ttft_s", "e2e_s", "queue_s", "decode_tokens_per_s")
 
 class ServeStats:
     """Accumulates per-request serve records; :meth:`summary` folds them
-    into the p50/p95 block the engine, the bench variant, and README's
-    schema all share.
+    into the p50/p95 block the engine and README's schema share.
 
     ``requests`` is a rolling window (``deque(maxlen=window)``) so a
     server that lives for millions of requests holds the memory of the

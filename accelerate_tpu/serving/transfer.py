@@ -13,8 +13,7 @@ only the tail blocks move.
 :class:`TransferPlane` is the byte mover + instrument:
 
 * ``inprocess`` backend — zero-copy: manifests carry numpy host arrays
-  by reference between engines in one process (CPU tests, the
-  ``disagg_soak`` bench on the virtual clock);
+  by reference between engines in one process (CPU tests);
 * ``host_buffer`` backend — the real-mesh shape: the prefill side's
   ``jax.device_get`` produced the images; delivery round-trips them
   through contiguous host buffers so a follow-up transport (RDMA, ICI

@@ -324,7 +324,7 @@ class TelemetrySink:
 
 class JSONLSink(TelemetrySink):
     """Zero-dependency append-only JSONL file, flushed per record so a
-    killed job keeps every completed step (the bench/driver-timeout
+    killed job keeps every completed step (the driver-timeout
     lesson). Greppable, rsyncable off a pod, ``pandas.read_json(...,
     lines=True)``-able."""
 

@@ -1,6 +1,6 @@
 """Profiling & measurement subsystem (SURVEY §5.1).
 
-Parity: reference ``benchmarks/measures_util.py`` (start/end_measure wall
+Parity: the reference's ``measures_util.py`` (start/end_measure wall
 time + CPU RSS + per-GPU peak memory, peak-CPU monitor thread) and the
 peak-memory CI gates (``test_utils/scripts/external_deps/
 test_peak_memory_usage.py``). TPU-native additions: the XLA profiler
@@ -123,7 +123,7 @@ class PeakHostMemory:
 
 def start_measure() -> dict[str, Any]:
     """Snapshot wall time + host RSS + per-device HBM (reference
-    ``start_measure`` benchmarks/measures_util.py:52)."""
+    ``start_measure``, measures_util.py:52)."""
     gc.collect()
     measures: dict[str, Any] = {"time": time.perf_counter()}
     measures["host"] = host_memory_rss()

@@ -297,16 +297,3 @@ def test_get_state_dict_full_host_copy():
     np.testing.assert_allclose(
         np.asarray(sd["layer//kernel"]), np.arange(64.0).reshape(8, 8)
     )
-
-
-def test_memory_utils_shim_warns():
-    import importlib
-    import warnings
-
-    import accelerate_tpu.memory_utils as mu
-
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        importlib.reload(mu)
-    assert any(issubclass(x.category, FutureWarning) for x in w)
-    assert hasattr(mu, "find_executable_batch_size")

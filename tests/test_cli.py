@@ -125,6 +125,24 @@ def test_launch_simple(tmp_path):
     assert env[ENV_PREFIX + "MIXED_PRECISION"] == "fp16"
 
 
+def test_parents_of_chip_children_never_import_jax():
+    # a process that touched JAX holds the chip: `accelerate-tpu launch`
+    # only SPAWNS the processes that need it, and must be able to do its
+    # whole job without (the package __init__s are lazy for exactly this)
+    code = (
+        "import sys\n"
+        "import accelerate_tpu\n"
+        "import accelerate_tpu.commands.accelerate_cli\n"
+        "import accelerate_tpu.commands.launch\n"
+        "bad = [m for m in sys.modules if m.split('.')[0] in ('jax', 'jaxlib', 'flax')]\n"
+        "assert not bad, bad[:5]\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=60, cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
 def test_in_package_test_script_single_process():
     from accelerate_tpu.test_utils import path_in_accelerate_package
 

@@ -135,15 +135,14 @@ def test_resolver_env_wins_over_plugin_with_one_log_line(monkeypatch, caplog):
 
 
 def test_env_cache_dir_is_the_only_one_any_entry_point_sets(tmp_path):
-    """With JAX_COMPILATION_CACHE_DIR set, Accelerator(), ServingEngine()
-    and the bench child's activation all leave jax pointing at it."""
+    """With JAX_COMPILATION_CACHE_DIR set, Accelerator() and
+    ServingEngine() both leave jax pointing at it."""
     import subprocess
     import sys
 
     code = (
         "import jax\n"
         "from accelerate_tpu import Accelerator, ServingEngine\n"
-        "from accelerate_tpu.benchmarks.cli import _activate_cache\n"
         "from accelerate_tpu.models import CausalLM, TransformerConfig\n"
         "from accelerate_tpu.utils.dataclasses import CompilePlugin\n"
         "seen = []\n"
@@ -152,8 +151,6 @@ def test_env_cache_dir_is_the_only_one_any_entry_point_sets(tmp_path):
         "model = CausalLM(TransformerConfig.tiny(num_layers=1))\n"
         "params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))\n"
         "ServingEngine(model, params, max_slots=1, block_size=8)\n"
-        "seen.append(jax.config.jax_compilation_cache_dir)\n"
-        "_activate_cache()\n"
         "seen.append(jax.config.jax_compilation_cache_dir)\n"
         "print('SEEN', *seen)\n"
     )
@@ -166,7 +163,7 @@ def test_env_cache_dir_is_the_only_one_any_entry_point_sets(tmp_path):
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("SEEN")][-1]
-    assert line.split()[1:] == [target] * 3
+    assert line.split()[1:] == [target] * 2
 
 
 def test_no_cache_path_is_built_from_tempfile():

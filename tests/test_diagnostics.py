@@ -251,6 +251,25 @@ def test_nan_grad_detected_through_collector_raw_scalars(tmp_path):
     tel.close()
 
 
+def test_anomaly_sample_every_bounds_baseline_folds():
+    det = AnomalyDetector(DiagnosticsConfig(anomaly_sample_every=4))
+    for i in range(32):
+        det.observe({"kind": "step", "step": i, "step_time_s": 0.01,
+                     "loss": 1.0}, {"loss": 1.0, "grad_norm": 1.0})
+    # only every 4th record entered the windows
+    assert len(det._windows["step_time_s"]) == 8
+    assert len(det._windows["loss"]) == 8
+    # NaN detection is exempt from sampling: fires on an off-sample step
+    out = det.observe({"kind": "step", "step": 33, "step_time_s": 0.01,
+                       "loss": float("nan")}, {"loss": float("nan")})
+    assert out and out[0]["anomaly_type"] == "nan_grad"
+
+
+def test_anomaly_sample_every_validation():
+    with pytest.raises(ValueError):
+        DiagnosticsConfig(anomaly_sample_every=0)
+
+
 # ---------------------------------------------------------------------- #
 # triggered trace capture
 # ---------------------------------------------------------------------- #

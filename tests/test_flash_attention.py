@@ -63,42 +63,6 @@ def test_backward_matches_xla():
     _assert_grads_close(g1, g2)
 
 
-@pytest.mark.parametrize("padded", [False, True])
-def test_fused_backward_matches_xla(padded):
-    """The single-pass fused backward (kept as the measured record of the
-    r5 attempt — 26x slower on-chip, see the FUSED_BWD comment block)
-    must stay numerically correct: dq/dk/dv vs the dense oracle, GQA and
-    kv-length padding included."""
-    import accelerate_tpu.ops.flash_attention as fa
-
-    q, k, v = _qkv(S=256)
-    lengths = jnp.asarray([160], jnp.int32) if padded else None
-
-    def loss_flash(q, k, v):
-        return jnp.sum(
-            flash_attention(
-                q, k, v, causal=True, block_q=64, block_k=64,
-                kv_lengths=lengths,
-            ) ** 2
-        )
-
-    def loss_ref(q, k, v):
-        from accelerate_tpu.ops.attention import lengths_to_mask
-
-        mask = lengths_to_mask(lengths, k.shape[1]) if padded else None
-        return jnp.sum(xla_attention(q, k, v, causal=True, mask=mask) ** 2)
-
-    old = fa.FUSED_BWD
-    fa.FUSED_BWD = True
-    try:
-        with _kernel_mode():
-            g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    finally:
-        fa.FUSED_BWD = old
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    _assert_grads_close(g1, g2)
-
-
 @pytest.mark.parametrize("window", [1, 7, 64, 200, 1000])
 def test_sliding_window_forward_matches_xla(window):
     """The banded causal mask (Mistral/Qwen2 sliding window, r5): the

@@ -17,7 +17,7 @@ import pytest
 
 from accelerate_tpu.models import CausalLM, TransformerConfig
 
-# bench.py's dense config scaled down 8x in width (hidden 4096 -> 512) so
+# the dense train cell's config scaled down 8x in width (hidden 4096 -> 512) so
 # the compile stays fast on one CPU core; the remat structure is identical
 _GATE_CFG = dict(
     vocab_size=4096, hidden_size=512, intermediate_size=1792,
@@ -54,8 +54,8 @@ def test_remat_policies_bound_activation_memory():
 
 
 def test_bench_model_peak_memory_gate():
-    """Absolute ceiling for the bench-shaped model with remat="dots" (the
-    shipping bench.py config): an HBM regression — e.g. a remat policy
+    """Absolute ceiling for the bench-shaped model with remat="dots" (what
+    the dense train cell runs): an HBM regression — e.g. a remat policy
     silently dropped in model or accelerator plumbing — ships loudly."""
     dots = _temp_bytes("dots")
     assert dots < _DOTS_TEMP_CEILING, (

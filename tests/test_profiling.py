@@ -1,5 +1,5 @@
 """Profiling subsystem tests (SURVEY §5.1; reference
-benchmarks/measures_util.py + ProfileKwargs handler shape)."""
+measures_util.py + ProfileKwargs handler shape)."""
 
 import glob
 import os
@@ -241,6 +241,45 @@ def test_roofline_unknown_label_or_missing_cost_is_none():
     reg.register_analysis("nocost", kind="train")  # CPU partial analysis
     assert reg.roofline("nope", peak_flops=1.0, peak_bytes_per_s=1.0) is None
     assert reg.roofline("nocost", peak_flops=1.0, peak_bytes_per_s=1.0) is None
+
+
+def test_unknown_device_kind_has_no_peak():
+    from accelerate_tpu.profiling.registry import DEVICE_PEAKS, device_peaks
+
+    assert device_peaks("TPU v5 lite") == {
+        "flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    # a v5p reports itself as plain "TPU v5": not the v5e's row
+    assert device_peaks("TPU v5")["flops_per_s"] == 459e12
+    for kind in ("TPU v99", "cpu"):
+        with pytest.raises(ValueError, match="no published peak"):
+            device_peaks(kind)
+    assert not any("cpu" in kind.lower() for kind in DEVICE_PEAKS)
+    # the record-only roofline answers None on a kind with no peak (this
+    # CPU backend) instead of inventing one
+    reg = ProgramRegistry()
+    reg.register_analysis("p", kind="train", flops=1e9, bytes_accessed=1e6)
+    assert reg.roofline("p") is None
+
+
+def test_the_programs_peaks_equal_the_benchmarks():
+    """Two tables by design (the benchmark imports nothing but the system
+    under test): for every kind both list, the same published numbers."""
+    import importlib.util
+
+    from accelerate_tpu.profiling.registry import DEVICE_PEAKS
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "harness", "peaks.py")
+    spec = importlib.util.spec_from_file_location("_benchmark_peaks", path)
+    peaks = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(peaks)
+    both = sorted(set(peaks.PEAKS) & set(DEVICE_PEAKS))
+    assert "TPU v5 lite" in both
+    for kind in both:
+        assert DEVICE_PEAKS[kind] == {
+            "flops_per_s": peaks.PEAKS[kind]["bf16_flops_per_s"],
+            "hbm_bytes_per_s": peaks.PEAKS[kind]["hbm_bytes_per_s"],
+        }, kind
 
 
 # --------------------------------------------------------------------- #
@@ -714,85 +753,6 @@ def test_engine_capture_programs_registers_without_new_traces(
     assert set(audits) == set(labels)
     assert engine.capture_compile_count == compiles_after_first
     assert dict(engine.trace_counts()) == counts_before
-
-
-# --------------------------------------------------------------------- #
-# bench regression trend
-# --------------------------------------------------------------------- #
-def test_stamp_trend_flags_regressions_in_both_directions():
-    from accelerate_tpu.benchmarks.runner import BenchRunner
-
-    logs = []
-    runner = BenchRunner(
-        None, None, None, None,
-        emit=lambda s: None, log=logs.append,
-        baseline={
-            "lat": {"value": 100.0, "unit": "s", "prev_round": "r06"},
-            "thru": {"value": 100.0, "unit": "tokens/s/chip"},
-        },
-    )
-    # lower-is-better metric got 20% slower: regression
-    rec = {"variant": "lat", "metric": "t", "value": 120.0, "unit": "s"}
-    runner._stamp_trend("lat", rec)
-    assert rec["prev_value"] == 100.0
-    assert rec["prev_round"] == "r06"
-    assert rec["prev_delta_pct"] == pytest.approx(20.0)
-    assert rec["regression"] is True
-    # lower-is-better metric improved: clean
-    rec = {"variant": "lat", "metric": "t", "value": 80.0, "unit": "s"}
-    runner._stamp_trend("lat", rec)
-    assert "regression" not in rec and rec["prev_delta_pct"] == -20.0
-    # higher-is-better throughput dropped 20%: regression
-    rec = {"variant": "thru", "metric": "t", "value": 80.0,
-           "unit": "tokens/s/chip"}
-    runner._stamp_trend("thru", rec)
-    assert rec["regression"] is True
-    # within the 10% band: stamped but never flagged
-    rec = {"variant": "thru", "metric": "t", "value": 95.0,
-           "unit": "tokens/s/chip"}
-    runner._stamp_trend("thru", rec)
-    assert "regression" not in rec
-    # a budget-killed partial is stamped but not evidence of regression
-    rec = {"variant": "lat", "metric": "t", "value": 200.0, "unit": "s",
-           "partial": True}
-    runner._stamp_trend("lat", rec)
-    assert rec["prev_value"] == 100.0 and "regression" not in rec
-    # unknown variant: untouched
-    rec = {"variant": "new", "metric": "t", "value": 1.0, "unit": "s"}
-    runner._stamp_trend("new", rec)
-    assert "prev_value" not in rec
-
-
-def test_parse_baseline_records_wrapper_and_final_wins(tmp_path, monkeypatch):
-    from accelerate_tpu.benchmarks.runner import (
-        load_baseline,
-        parse_baseline_records,
-    )
-
-    tail = "\n".join([
-        "bench: starting",  # non-JSON noise in the tail
-        json.dumps({"variant": "dense", "value": 50.0, "unit": "tokens/s",
-                    "provisional": True}),
-        json.dumps({"variant": "dense", "value": 55.0, "unit": "tokens/s"}),
-        json.dumps({"variant": "ckpt", "skipped": "budget"}),
-        json.dumps({"variant": "moe", "value": None}),
-        json.dumps({"variant": "serve", "value": 9.0, "unit": "x",
-                    "provisional": True}),
-    ])
-    wrapper = json.dumps({"n": "r06", "cmd": "bench", "rc": 0, "tail": tail})
-    base = parse_baseline_records(wrapper)
-    assert set(base) == {"dense", "serve"}  # skipped/null never a baseline
-    assert base["dense"]["value"] == 55.0  # final displaced provisional
-    assert base["dense"]["prev_round"] == "r06"
-    assert base["serve"]["value"] == 9.0  # provisional-only still counts
-
-    path = tmp_path / "BENCH_r06.json"
-    path.write_text(wrapper)
-    assert load_baseline(str(path))["dense"]["value"] == 55.0
-    # no implicit lookup: a record that merely sits in the working
-    # directory is never a baseline
-    monkeypatch.chdir(tmp_path)
-    assert load_baseline(None) == {}
 
 
 # --------------------------------------------------------------------- #
