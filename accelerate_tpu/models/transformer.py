@@ -611,9 +611,12 @@ class MoE(nn.Module):
     weights are normalised over all k choices, and the layer returns the
     part of the result that the experts held here give: a token with no
     choice here gets 0 and keeps its residual. No token is dropped at any
-    imbalance (``ops.moe.moe_ragged``: the choices of absent experts go
-    through one more group, of zero weights). What the absent experts would
-    add lies on other chips; nothing here stands in for them.
+    imbalance (``ops.moe.moe_ragged``: the sorted choices are cut at a
+    static row, twice the even share; the first window always runs, with
+    one more group, of zero weights, for the choices of absent experts in
+    it; the rest runs under a ``cond`` only when a held choice lies there).
+    What the absent experts would add lies on other chips; nothing here
+    stands in for them.
 
     Expert weights are stacked on a leading ``expert`` logical axis; with
     ``ep_size > 1`` GSPMD shards experts across the ``ep`` mesh axis and the
@@ -631,7 +634,9 @@ class MoE(nn.Module):
     Sown under ``intermediates`` (``CausalLM.loss_fn(model, with_aux=True)``
     returns their means over the expert layers): ``moe_aux_loss``, and from
     the ragged path ``moe_local_choice_share``,
-    ``moe_expert_load_max_over_mean`` and ``moe_rows_computed_over_needed``.
+    ``moe_expert_load_max_over_mean``, ``moe_rows_computed_over_needed``
+    and ``moe_rest_window_share`` (the share of the expert layers whose
+    rows past the first window ran: ``ops.moe.ragged_load_stats``).
     """
 
     config: TransformerConfig
@@ -751,7 +756,7 @@ class MoE(nn.Module):
                     router_width=R,
                 ).reshape(b, s, h)
                 for name, value in ragged_load_stats(
-                    sel, E, cfg.moe_expert_offset
+                    sel, E, cfg.moe_expert_offset, R
                 ).items():
                     self.sow("intermediates", name, value)
         elif dispatch == "capacity":
@@ -925,7 +930,9 @@ _REMAT_POLICIES = {
     # would re-run every ragged_dot (the expert FLOPs — the single biggest
     # matmul cost in an MoE block). Saving ragged_dot_general too keeps
     # the remat recompute down to elementwise ops, same as "dots" does
-    # for dense blocks.
+    # for dense blocks. A layer that holds a share of the experts saves
+    # them for both of moe_ragged's windows: the rest window's leave its
+    # cond as residuals, zeros when it was not taken — T*K rows in all.
     "dots_ragged": lambda: jax.checkpoint_policies.save_from_both_policies(
         jax.checkpoint_policies.checkpoint_dots,
         lambda prim, *_, **__: getattr(prim, "name", "")

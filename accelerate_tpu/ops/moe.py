@@ -54,6 +54,46 @@ def no_drop_capacity_factor(num_experts: int, num_selected: int) -> float:
     return num_experts / num_selected
 
 
+# Why twice the even share: on sound seeds a layer's held share reads up to
+# 1.12 x the even one (E / R of the choices) and the drift of an unbalanced
+# router lowers it from there, so a first window of 2 x holds every held row
+# of every step measured (PERF.md, PR 29) — and where it does not, the rest
+# window runs and the result is the same.
+_WINDOW_OVER_EVEN_SHARE = 2
+_WINDOW_MULTIPLE = 512  # rows: a window is whole tiles at any tiling XLA picks
+
+
+def share_window_rows(num_choices: int, num_experts: int, router_width: int) -> int:
+    """Rows of :func:`moe_ragged`'s first window when a layer holds
+    ``num_experts`` of a router ``router_width`` wide: the smallest multiple
+    of 512 that is at least twice the even share of the ``num_choices``
+    (T*K) sorted rows, and never more than all of them. A constant of the
+    shapes: the routing does not move it."""
+    even = num_choices * num_experts / router_width
+    rows = _WINDOW_MULTIPLE * math.ceil(
+        _WINDOW_OVER_EVEN_SHARE * even / _WINDOW_MULTIPLE)
+    return min(rows, num_choices)
+
+
+def _expert_rows(xs, tok, order, weights, group_sizes, w_gate, w_up, w_down,
+                 num_tokens):
+    """A run of sorted rows ``xs`` (every one in a group) through the three
+    grouped matmuls, scattered back onto their tokens ``tok`` with the
+    routing weights of the sorted choices ``order``: (num_tokens, h)."""
+    with jax.named_scope("experts"):
+        hidden = jax.nn.silu(
+            jax.lax.ragged_dot(xs, w_gate, group_sizes)
+        ) * jax.lax.ragged_dot(xs, w_up, group_sizes)  # (rows, f)
+        out = jax.lax.ragged_dot(hidden, w_down, group_sizes)  # (rows, h)
+
+    with jax.named_scope("combine"):
+        w_flat = weights.reshape(-1)[order].astype(out.dtype)
+        # weighted scatter-add back into token order (sums the K expert
+        # contributions per token)
+        return jnp.zeros((num_tokens, xs.shape[-1]), out.dtype).at[tok].add(
+            out * w_flat[:, None])
+
+
 def moe_ragged(
     x: jax.Array,
     sel: jax.Array,
@@ -74,21 +114,43 @@ def moe_ragged(
 
     **A share of the experts.** ``w_*`` hold experts ``[expert_offset,
     expert_offset + E)`` of a router ``router_width`` wide. A choice of an
-    expert that is not held sorts behind every held one and adds nothing:
-    those rows form one more group whose weights are ZERO. Shapes stay
-    static at ``T*K`` rows — the worst case, every choice held here — so
-    no choice of a held expert is dropped at any imbalance, and every row
-    a grouped matmul returns is defined. The price is stated, not hidden:
-    a layer that holds E of R experts multiplies about R / E times the
-    rows it needs, and its time does not follow the routing. (XLA:TPU's
-    kernel SKIPS rows of no group — group sizes that sum to the live rows
-    alone cost only those — but leaves stale memory in them, in the
-    backward kernel too: autodiff's ``d xs`` then has undefined rows that
-    no ``jnp.where`` on a forward output reaches, and the gather's
-    transpose adds them into ``dx``. A ``custom_vjp`` that also zeroes
-    cotangents and backward outputs is exact and half the time; it waits
-    for a routing that holds still, because a step whose time follows the
-    routing cannot be measured within a percent: PERF.md, PR 26.)
+    expert that is not held sorts behind every held one and adds nothing.
+    The sorted rows are cut at a STATIC row ``C`` (:func:`share_window_rows`:
+    twice the even share E / R of the ``T*K`` choices, a multiple of 512,
+    from shapes alone):
+
+    * the **first window**, rows ``[0, C)``, always runs: the gather, the
+      three grouped matmuls and the weighted scatter-add over ``C`` rows,
+      the held experts' groups clipped into the window and one more group,
+      of ZERO weights, for whatever else lies in it;
+    * the **rest window**, rows ``[C, T*K)``, is the same under a
+      ``lax.cond`` that is true only when a held row lies past ``C``; its
+      transpose is a ``cond`` on the same predicate, so the backward skips
+      it too. Otherwise it adds zeros.
+
+    Every row a grouped matmul is given is in a group, so every row it
+    returns is defined; no choice of a held expert is dropped at any
+    imbalance (all ``T*K`` on one held expert: both windows run); and while
+    the held rows fit the first window the step's time is a constant of
+    the shapes and does not follow the routing. When ``C == T*K`` (half
+    the router or more held) there is one window and no ``cond``; with every
+    expert held there is no zero group either: the program is the one it
+    always was. (Why not give ``ragged_dot`` group sizes that sum to the
+    live rows alone: XLA:TPU's kernel then SKIPS the other rows but leaves
+    stale memory in them, in the backward kernel too: autodiff's ``d xs``
+    then has undefined rows that no ``jnp.where`` on a forward output
+    reaches, and the gather's transpose adds them into ``dx``. A
+    ``custom_vjp`` that also zeroes cotangents and backward outputs is
+    exact, and its time follows the routing, which drifts: PERF.md, PR 26.)
+    On the v5e at LFM2-8B-A1B's widths (8 of 32 experts held, 4 x 4096
+    tokens, top 4: 65,536 sorted rows a layer, C = 32,768) all rows through
+    the grouped matmuls took 65.7 ms a layer forward + backward and the
+    masked quarter 34.4 (my chip runs, PR 26); in the training step of four
+    such layers the window took XLA's ``ragged-dot`` kernels from 166.5 to
+    81.3 ms and the step from 486.5 to 375.5 ms, the rest window never
+    taken in 256 steps; the 12 ``cond``s skipped cost 11.5 ms a step, the
+    zeros a branch not taken writes for its residuals (my chip runs, PR 29;
+    PERF.md section 6).
 
     Measured on v5e (bf16, B=16, S=1024, E=8, K=2, round-4 sweep): at
     Mixtral-width experts (h=4096, f=3584, L=1) ragged reaches 0.516 MFU
@@ -102,7 +164,7 @@ def moe_ragged(
     drop-rate/collective-bytes evidence).
 
     Fully differentiable (ragged_dot has grad rules; sort / gather /
-    scatter-add are linear).
+    scatter-add are linear; ``cond`` differentiates branch by branch).
 
     Use on single-chip / data-parallel meshes. With ``ep_size > 1``
     the per-expert group sizes are data-dependent, which GSPMD cannot
@@ -117,44 +179,67 @@ def moe_ragged(
     K = sel.shape[-1]
     E = w_gate.shape[0]
     TK = T * K
+    R = router_width or E
     with jax.named_scope("dispatch"):
         local = sel.reshape(TK) - expert_offset
         flat_sel = jnp.where((local >= 0) & (local < E), local, E)
         order = jnp.argsort(flat_sel)  # stable: ties keep token order
         tok = jnp.repeat(jnp.arange(T), K)[order]  # source token per sorted row
-        xs = jnp.take(x, tok, axis=0)  # (TK, h) rows grouped by expert
-        group_sizes = jnp.bincount(flat_sel, length=E + 1).astype(jnp.int32)
-        if (router_width or E) == E:
-            group_sizes = group_sizes[:E]  # every choice is of a held expert
-        else:  # one more group, of zero weights, for the choices that are not
-            w_gate, w_up, w_down = (
-                jnp.concatenate([w, jnp.zeros((1,) + w.shape[1:], w.dtype)])
-                for w in (w_gate, w_up, w_down)
-            )
+    if R == E:  # every choice is of a held expert: one run of T*K rows
+        with jax.named_scope("dispatch"):
+            xs = jnp.take(x, tok, axis=0)  # (TK, h) rows grouped by expert
+            group_sizes = jnp.bincount(flat_sel, length=E + 1).astype(jnp.int32)
+        return _expert_rows(xs, tok, order, weights, group_sizes[:E],
+                            w_gate, w_up, w_down, T)
 
-    with jax.named_scope("experts"):
-        hidden = jax.nn.silu(
-            jax.lax.ragged_dot(xs, w_gate, group_sizes)
-        ) * jax.lax.ragged_dot(xs, w_up, group_sizes)  # (TK, f)
-        out = jax.lax.ragged_dot(hidden, w_down, group_sizes)  # (TK, h)
+    with jax.named_scope("dispatch"):
+        # one more group, of zero weights, for the choices of absent experts
+        w_gate, w_up, w_down = (
+            jnp.concatenate([w, jnp.zeros((1,) + w.shape[1:], w.dtype)])
+            for w in (w_gate, w_up, w_down)
+        )
+        held = jnp.bincount(flat_sel, length=E + 1).astype(jnp.int32)[:E]
+        ends = jnp.cumsum(held)  # where each held expert's sorted rows end
+        starts = ends - held
 
-    with jax.named_scope("combine"):
-        w_flat = weights.reshape(TK)[order].astype(out.dtype)
-        # weighted scatter-add back into token order (sums the K expert
-        # contributions per token)
-        return jnp.zeros((T, h), out.dtype).at[tok].add(out * w_flat[:, None])
+    def window(lo: int, hi: int):
+        """Rows ``[lo, hi)`` of the sorted choices: the held experts' groups
+        clipped into them, the zero-weight group taking the remainder."""
+        rows = slice(lo, hi)
+        with jax.named_scope("dispatch"):
+            sizes = jnp.clip(ends, lo, hi) - jnp.clip(starts, lo, hi)
+            sizes = jnp.concatenate([sizes, (hi - lo - jnp.sum(sizes))[None]])
+            xs = jnp.take(x, tok[rows], axis=0)  # (hi - lo, h)
+        return _expert_rows(xs, tok[rows], order[rows], weights, sizes,
+                            w_gate, w_up, w_down, T)
+
+    C = share_window_rows(TK, E, R)
+    out = window(0, C)
+    if C < TK:
+        out = out + jax.lax.cond(
+            ends[-1] > C,  # a held row lies past the first window
+            lambda: window(C, TK),
+            lambda: jnp.zeros(out.shape, out.dtype),
+        )
+    return out
 
 
 def ragged_load_stats(
-    sel: jax.Array, num_experts: int, expert_offset: int = 0
+    sel: jax.Array,
+    num_experts: int,
+    expert_offset: int = 0,
+    router_width: Optional[int] = None,
 ) -> dict:
     """What the choices ``sel`` (..., K) say of the load of a layer that
-    holds experts ``[expert_offset, expert_offset + num_experts)``, as
-    float32 scalars: the share of the choices that fell on experts held
-    here (held / router width when routing is even), the fullest held
-    expert's load over the mean, and the rows that go through
-    :func:`moe_ragged`'s grouped matmuls — every choice, T*K rows — over
-    the rows that are needed."""
+    holds experts ``[expert_offset, expert_offset + num_experts)`` of a
+    router ``router_width`` wide (None: all of them), as float32 scalars:
+    the share of the choices that fell on experts held here (held / router
+    width when routing is even), the fullest held expert's load over the
+    mean, the rows that went through :func:`moe_ragged`'s grouped matmuls —
+    its first window, and the rest of the T*K when a held row lay past it —
+    over the rows that were needed, and whether that rest window ran (1.0
+    or 0.0: the mean over the expert layers is the share of them in which
+    it did)."""
     local = sel.reshape(-1) - expert_offset
     num_choices = local.shape[0]
     sizes = jnp.bincount(
@@ -162,11 +247,17 @@ def ragged_load_stats(
         length=num_experts + 1,
     )[:num_experts].astype(jnp.float32)
     needed = jnp.sum(sizes)
+    window = share_window_rows(
+        num_choices, num_experts, router_width or num_experts)
+    rest_ran = (needed > window).astype(jnp.float32)
     return {
         "moe_local_choice_share": needed / num_choices,
         "moe_expert_load_max_over_mean": jnp.max(sizes)
         / jnp.maximum(jnp.mean(sizes), 1.0),
-        "moe_rows_computed_over_needed": num_choices / jnp.maximum(needed, 1.0),
+        "moe_rows_computed_over_needed": (
+            window + (num_choices - window) * rest_ran
+        ) / jnp.maximum(needed, 1.0),
+        "moe_rest_window_share": rest_ran,
     }
 
 

@@ -661,7 +661,7 @@ def hybrid_phase(say, dry: bool) -> None:
         w = dict(vocab_size=512, hidden_size=64, intermediate_size=160,
                  moe_intermediate_size=48, num_heads=4, num_kv_heads=2,
                  head_dim=16)
-        rows, seq = 2, 64
+        rows, seq = 4, 64
     else:
         w = dict(vocab_size=16384, hidden_size=2048, intermediate_size=7168,
                  moe_intermediate_size=1792, num_heads=32, num_kv_heads=8,
@@ -706,14 +706,26 @@ def hybrid_phase(say, dry: bool) -> None:
     assert retraces == 0, f"{retraces} retraces after warmup"
     assert step.aot_fallbacks == 0, step.aot_fallbacks
     # a quarter of the router's experts are held: about a quarter of the
-    # choices fall here. Every choice, held or not, is a row of the grouped
-    # matmuls (those of absent experts in a group of zero weights, so that
-    # the step's time does not follow the routing): rows computed over rows
-    # needed is 1 / the local share, about 4 at an even routing
+    # choices fall here, sorted first. The grouped matmuls take a STATIC
+    # first window of the sorted rows, twice the even share (half of the
+    # T x k at 8 of 32), the choices of absent experts inside it in a
+    # group of zero weights, so that every row is defined and the step's time
+    # does not follow the routing; the rows past the window run only when a
+    # held one lies there, which it does not here: rows computed over rows
+    # needed is window / (T x k) / the local share, about 2 at an even routing
+    from accelerate_tpu.ops.moe import share_window_rows
+
+    choices = rows * seq * cfg.num_experts_per_tok
+    window = share_window_rows(choices, cfg.num_experts, cfg.moe_router_width)
     share = aux["moe_local_choice_share"]
+    say(f"hybrid: first window {window} of {choices} sorted rows a layer; "
+        f"moe_rest_window_share {aux['moe_rest_window_share']:.4f}, "
+        f"moe_rows_computed_over_needed {aux['moe_rows_computed_over_needed']:.4f}")
     assert 0.1 < share < 0.5, aux
     assert aux["moe_expert_load_max_over_mean"] >= 1.0, aux
-    assert abs(aux["moe_rows_computed_over_needed"] * share - 1.0) < 1e-3, aux
+    assert aux["moe_rest_window_share"] == 0.0, aux
+    assert abs(aux["moe_rows_computed_over_needed"] * share
+               - window / choices) < 1e-3, aux
     if not dry:
         calls = [line for line in step.compiled.as_text().splitlines()
                  if 'custom_call_target="tpu_custom_call"' in line]
@@ -728,9 +740,10 @@ def hybrid_phase(say, dry: bool) -> None:
             "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, (
             f"Mosaic calls {flash}: head_dim {cfg.head_dim} left the flash "
             "kernels for xla_attention")
-        assert len(grouped) >= 9, (
+        assert len(grouped) >= 18, (
             f"{len(grouped)} grouped-matmul kernels {grouped}: expected 3 "
-            "forward and 6 backward from XLA's ragged-dot lowering")
+            "forward and 6 backward from XLA's ragged-dot lowering, in the "
+            "first window and again in the rest window's conditional")
         say(f"hybrid: compiled step holds flash calls {flash} at head_dim "
             f"{cfg.head_dim} and {len(grouped)} grouped-matmul calls")
     acc.telemetry.close()

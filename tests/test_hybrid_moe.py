@@ -26,7 +26,8 @@ from harness import lfm2_weights as W  # noqa: E402
 from accelerate_tpu.models import CausalLM, TransformerConfig  # noqa: E402
 from accelerate_tpu.models.transformer import (  # noqa: E402
     MoE, ShortConv, layer_kinds, plan_layers)
-from accelerate_tpu.ops.moe import moe_ragged, ragged_load_stats  # noqa: E402
+from accelerate_tpu.ops.moe import (  # noqa: E402
+    moe_ragged, ragged_load_stats, share_window_rows)
 
 SEED = 2**31 + 5
 
@@ -293,9 +294,152 @@ def test_an_imbalanced_router_drops_nothing(target):
 
 
 # --------------------------------------------------------------------------- #
+# the static window of a layer that holds a share
+# --------------------------------------------------------------------------- #
+_WIN = dict(t=256, k=4, h=16, f=8, held=4, offset=4, width=16)  # T k = 1024, window 512
+
+
+def _window_case(where):
+    """Choices that put the held rows inside the first window, past it, on
+    no held expert, or all on one: ``(sel, held rows)``."""
+    c = _WIN
+    key = jax.random.PRNGKey(11)
+    lo, hi = c["offset"], c["offset"] + c["held"]
+    if where == "inside":  # an even router: about a quarter of the choices
+        sel = jax.random.randint(key, (c["t"], c["k"]), 0, c["width"])
+    elif where == "past":  # nine in ten held: the rest window has to run
+        sel = jax.random.randint(key, (c["t"], c["k"]), lo, hi).at[:24].set(0)
+    elif where == "none":
+        sel = jnp.zeros((c["t"], c["k"]), jnp.int32)
+    else:  # "one_expert": every choice on one held expert
+        sel = jnp.full((c["t"], c["k"]), lo + 2, jnp.int32)
+    return sel, int(jnp.sum((sel >= lo) & (sel < hi)))
+
+
+def _window_operands():
+    c = _WIN
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    return (jax.random.normal(ks[0], (c["t"], c["h"])),
+            jax.random.uniform(ks[1], (c["t"], c["k"])),
+            jax.random.normal(ks[2], (c["held"], c["h"], c["f"])) * 0.3,
+            jax.random.normal(ks[3], (c["held"], c["h"], c["f"])) * 0.3,
+            jax.random.normal(ks[4], (c["held"], c["f"], c["h"])) * 0.3,
+            jax.random.normal(ks[5], (c["t"], c["h"])))
+
+
+def _dense_oracle(x, weights, w_gate, w_up, w_down, sel):
+    """``moe_dispatch="dense"`` restricted to the held experts: each of them
+    computes every token, combined by the weights of the choices on it."""
+    out = 0.0
+    for e in range(_WIN["held"]):
+        y = (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]
+        on_e = jnp.sum(jnp.where(sel == e + _WIN["offset"], weights, 0.0), -1)
+        out = out + y * on_e[:, None]
+    return out
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "dots_ragged"])
+@pytest.mark.parametrize("where", ["inside", "past", "none", "one_expert"])
+def test_the_window_form_equals_the_dense_oracle(where, remat):
+    """Value, dx, dweights, dw_gate, dw_up, dw_down of a share-holding
+    ``moe_ragged`` (float32), whether or not the rest window runs, plain
+    and under ``jax.checkpoint`` with the policy the cell trains under."""
+    from accelerate_tpu.models.transformer import _REMAT_POLICIES
+
+    c = _WIN
+    sel, held_rows = _window_case(where)
+    *operands, cot = _window_operands()
+    window = share_window_rows(c["t"] * c["k"], c["held"], c["width"])
+    assert window == 512
+    stats = ragged_load_stats(sel, c["held"], c["offset"], c["width"])
+    ran = float(held_rows > window)
+    assert ran == {"inside": 0.0, "past": 1.0, "none": 0.0, "one_expert": 1.0}[where]
+    assert float(stats["moe_rest_window_share"]) == ran
+    np.testing.assert_allclose(
+        float(stats["moe_rows_computed_over_needed"]),
+        (window + (c["t"] * c["k"] - window) * ran) / max(held_rows, 1), rtol=1e-6)
+
+    def layer(*a):
+        return moe_ragged(a[0], sel, *a[1:], expert_offset=c["offset"],
+                          router_width=c["width"])
+
+    if remat:
+        layer = jax.checkpoint(layer, policy=_REMAT_POLICIES["dots_ragged"]())
+    names = ("value", "dx", "dweights", "dw_gate", "dw_up", "dw_down")
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(layer(*a) * cot), argnums=(0, 1, 2, 3, 4)))(*operands)
+        want = jax.value_and_grad(
+            lambda *a: jnp.sum(_dense_oracle(*a, sel) * cot),
+            argnums=(0, 1, 2, 3, 4))(*operands)
+    for name, g, w in zip(names, jax.tree_util.tree_leaves(got),
+                          jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.max(jnp.abs(w))) + 1e-6
+        np.testing.assert_allclose(g, w, atol=2e-5 * scale, rtol=2e-4, err_msg=name)
+        if where == "none":
+            assert not np.any(np.asarray(g)), name
+
+
+def _eqns(jaxpr, name):
+    return [e for e in jaxpr.eqns if e.primitive.name == name]
+
+
+@pytest.mark.parametrize("held,width,want", [
+    (8, 32, 1024), (8, 16, 2048), (4, 32, 512)])
+def test_a_share_holding_layer_is_one_window_and_one_cond(held, width, want):
+    """T k = 2048 sorted rows: the first window is the smallest multiple of
+    512 at or above twice the even share, capped at all of them; what lies
+    past it runs in the taken branch of exactly one ``cond``, three grouped
+    matmuls in each; with half the router held there is no ``cond``."""
+    t, k, h, f = 512, 4, 16, 8
+    even = t * k * held / width
+    assert want == min(t * k, 512 * -(-2 * even // 512))  # the stated rule
+    assert share_window_rows(t * k, held, width) == want
+    jaxpr = jax.make_jaxpr(lambda *a: moe_ragged(*a, router_width=width))(
+        jnp.zeros((t, h)), jnp.zeros((t, k), jnp.int32), jnp.zeros((t, k)),
+        jnp.zeros((held, h, f)), jnp.zeros((held, h, f)), jnp.zeros((held, f, h))).jaxpr
+    first = _eqns(jaxpr, "ragged_dot_general")
+    assert [e.invars[0].aval.shape[0] for e in first] == [want] * 3
+    conds = _eqns(jaxpr, "cond")
+    if want == t * k:
+        assert not conds
+        return
+    (cond,) = conds
+    dots = [_eqns(b.jaxpr, "ragged_dot_general") for b in cond.params["branches"]]
+    assert sorted(len(d) for d in dots) == [0, 3]  # skipped: zeros; taken: the rest
+    taken = max(dots, key=len)
+    assert [e.invars[0].aval.shape[0] for e in taken] == [t * k - want] * 3
+
+
+# sha256 of jit(moe_ragged).lower(...).as_text() at bf6ebce, the parent of the
+# PR that brought the window (T 64, k 2, 4 experts, h 16, f 8, float32)
+_ALL_HELD_LOWERED = "6c366365cc27260e9e310b8ff1fd0d693c8ecd3310060350c848df7ac3bdba61"
+
+
+@pytest.mark.parametrize("router_width", [None, 4])
+def test_with_every_expert_held_the_lowered_text_is_the_parents(router_width):
+    """No window, no zero group, no ``cond``: a Mixtral-style layer lowers
+    byte for byte as it did before a share could be held in part."""
+    t, k, e, h, f = 64, 2, 4, 16, 8
+    sds = jax.ShapeDtypeStruct
+    text = jax.jit(lambda *a: moe_ragged(*a, router_width=router_width)).lower(
+        sds((t, h), jnp.float32), sds((t, k), jnp.int32), sds((t, k), jnp.float32),
+        sds((e, h, f), jnp.float32), sds((e, h, f), jnp.float32),
+        sds((e, f, h), jnp.float32)).as_text()
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == _ALL_HELD_LOWERED
+
+
+# --------------------------------------------------------------------------- #
 # through the Accelerator, with the counters
 # --------------------------------------------------------------------------- #
-def test_unified_step_trains_the_stack_and_returns_its_counters():
+@pytest.mark.parametrize("rows,seq,window", [(8, 32, 512), (2, 32, 256)],
+                         ids=["first_window_of_two", "one_window"])
+def test_unified_step_trains_the_stack_and_returns_its_counters(rows, seq, window):
+    """2 of 8 experts held, 4 choices a token: 8 x 32 tokens are 1024 sorted
+    rows a layer, cut at 512 (twice the even share) with the held ones inside
+    the cut; 2 x 32 tokens are 256 rows, fewer than one multiple of 512, so
+    every row is in the one window."""
     import optax
 
     from accelerate_tpu import Accelerator
@@ -307,16 +451,24 @@ def test_unified_step_trains_the_stack_and_returns_its_counters():
         W.make_tree(cfg, SEED, jnp.float32), optax.adamw(1e-3))
     step = acc.unified_step(CausalLM.loss_fn(model, with_aux=True), has_aux=True)
     carry = acc.init_carry(params, optimizer)
-    rows = _ids(cfg, rows=8, seq=32, seed=1)
+    ids = _ids(cfg, rows=rows, seq=seq, seed=1)
     losses = []
     for _ in range(4):
-        carry, metrics = step(carry, {"input_ids": rows})
+        carry, metrics = step(carry, {"input_ids": ids})
         losses.append(float(metrics["loss"]))
     assert all(np.isfinite(losses)) and losses[-1] < losses[0]
     aux = {k: float(v) for k, v in metrics["aux"].items()}
-    assert 0.0 < aux["moe_local_choice_share"] < 1.0
+    choices = rows * seq * cfg["num_experts_per_tok"]
+    assert window == share_window_rows(
+        choices, cfg["num_experts"], cfg["router_width"]) <= choices
+    assert 0.0 < aux["moe_local_choice_share"] < window / choices
     assert aux["moe_expert_load_max_over_mean"] >= 1.0
-    assert aux["moe_rows_computed_over_needed"] >= 1.0
+    # the held rows of every expert layer lie inside the first window: no
+    # rest window ran, and each layer computed `window` rows for its needed
+    # ones (the mean of ratios is at least the ratio of the means)
+    assert aux["moe_rest_window_share"] == 0.0
+    assert aux["moe_rows_computed_over_needed"] * aux["moe_local_choice_share"] \
+        >= window / choices - 1e-6
     assert step.detector.retraces == 0 if hasattr(step, "detector") else True
 
 
