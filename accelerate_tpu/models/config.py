@@ -15,6 +15,10 @@ from typing import Optional
 SUPPORTED_ROPE_TYPES = ("default", "llama3", "linear")
 # per-layer operators a ``layer_types`` entry may name (config.json names)
 LAYER_TYPES = ("full_attention", "conv")
+# what a layer's attention computes: None is softmax over every earlier
+# position (under ``sliding_window`` if set); "eva" is chunk summaries beside
+# a window of exact keys and values (models/transformer.Attention)
+ATTENTION_CLASSES = (None, "eva")
 # required rope_scaling keys per type (beyond rope_type itself)
 _ROPE_REQUIRED_KEYS = {
     "default": (),
@@ -123,6 +127,31 @@ class TransformerConfig:
     # Gemma-2 block: norms AFTER attention and the MLP too (4 per block)
     post_norms: bool = False
     attention_impl: Optional[str] = None  # None=auto | xla | flash | ring
+    # "eva" (EvaByte; Zheng et al., ICLR 2023): positions are cut into
+    # windows of ``window_size`` and chunks of ``chunk_size``. A query sees
+    # the keys and values of its own window up to itself, exactly, and of
+    # every chunk in an EARLIER window one summary (k~, v~) — a softmax-
+    # weighted mean of the chunk's keys, resp. values, under one learned
+    # vector a head (``attn/mu``, ``attn/phi``) — all under ONE softmax. A
+    # request's cache is then O(window_size + n / chunk_size) rows, not n:
+    # ops/eva_attention.EvaLayout. Every layer is of this class.
+    attention_class: Optional[str] = None
+    chunk_size: int = 16
+    window_size: int = 2048
+    # the head predicts this many next tokens, head-major: columns
+    # [j * vocab_size, (j + 1) * vocab_size) are the (j + 1)-th next token's.
+    # The model returns the first head's logits, which is what sampling and
+    # the loss read; the others are computed for multi-token decoding, which
+    # nothing here runs yet.
+    num_pred_heads: int = 1
+    # where ``dtype`` is narrower than float32 (config.json names
+    # ``fp32_skip_add``, ``fp32_logits``): the residual stream is carried in
+    # float32 — every ``x + sublayer(norm(x))`` adds in float32 and stays
+    # there, the norms hand the compute dtype to the matmuls —, resp. the
+    # head's matmul keeps its float32 accumulator as the logits instead of
+    # rounding them to ``dtype``. Off: one activation dtype throughout.
+    fp32_residual: bool = False
+    fp32_logits: bool = False
     # MoE (Mixtral family); 0 experts = dense MLP
     num_experts: int = 0
     num_experts_per_tok: int = 2
@@ -240,6 +269,50 @@ class TransformerConfig:
                     "which only the xla attention path supports — use "
                     "attention_impl 'xla' or None"
                 )
+        if self.attention_class not in ATTENTION_CLASSES:
+            raise ValueError(
+                f"unknown attention_class {self.attention_class!r}; "
+                f"supported: {', '.join(map(repr, ATTENTION_CLASSES))}"
+            )
+        if self.attention_class == "eva":
+            if self.chunk_size < 1 or self.window_size % self.chunk_size:
+                raise ValueError(
+                    f"window_size {self.window_size} must be whole chunks of "
+                    f"chunk_size {self.chunk_size}"
+                )
+            clash = [
+                name for name, on in (
+                    ("causal=False", not self.causal),
+                    ("sliding_window", self.sliding_window is not None),
+                    ("layer_windows", self.layer_windows is not None),
+                    ("layer_types", self.layer_types is not None),
+                    ("attn_softcap", self.attn_softcap is not None),
+                    ("attention_impl='ring'", self.attention_impl == "ring"),
+                    ("fused_kernels", self.fused_kernels),
+                ) if on
+            ]
+            if clash:
+                raise ValueError(
+                    "attention_class 'eva' fixes what a query sees and how "
+                    f"it is scored; it cannot be combined with {clash}"
+                )
+        if self.num_pred_heads < 1 or (
+            self.num_pred_heads > 1 and self.tie_embeddings
+        ):
+            raise ValueError(
+                f"num_pred_heads {self.num_pred_heads}: at least 1, and more "
+                "than one only with an untied head"
+            )
+        if self.fp32_residual and self.fused_kernels:
+            raise ValueError(
+                "fp32_residual: the fused norm -> qkv prologue (fused_kernels) "
+                "reads a residual stream of the compute dtype"
+            )
+        if self.fp32_logits and self.tie_embeddings:
+            raise ValueError(
+                "fp32_logits is written for an untied head (lm_head); the "
+                "tied head's matmul is the embedding's own"
+            )
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             if len(self.layer_types) != self.num_layers:

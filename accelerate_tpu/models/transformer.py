@@ -19,6 +19,7 @@ TPU-native design decisions:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -74,7 +75,10 @@ class RMSNorm(nn.Module):
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
         y = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
         mult = (1.0 + scale) if cfg.norm_offset else scale
-        return (y * mult).astype(x.dtype)
+        # a float32 residual stream ends here: what the norm hands on is an
+        # operand of the layer's matmuls
+        return (y * mult).astype(
+            _dtype(cfg) if cfg.fp32_residual else x.dtype)
 
 
 def _scale_rope_freqs(freqs: jax.Array, scaling: Optional[dict]) -> jax.Array:
@@ -350,6 +354,28 @@ class Attention(nn.Module):
                     q = RMSNorm(cfg, name="q_norm")(q)
                     k = RMSNorm(cfg, name="k_norm")(k)
 
+        eva = cfg.attention_class == "eva"
+        if eva:
+            # one learned vector a KV head for each of a chunk's two
+            # summaries (ops/eva_attention.py). Unit normal: with unit-
+            # variance keys a chunk's softmax logits then spread by about 1
+            if mask is not None or kv_lengths is not None:
+                raise NotImplementedError(
+                    "attention_class 'eva' cuts windows and chunks by a "
+                    "token's index in the call: no mask or kv_lengths"
+                )
+            mu, phi = (
+                self.param(
+                    name,
+                    nn.with_partitioning(
+                        nn.initializers.normal(1.0), (None, None)),
+                    (cfg.num_kv_heads, cfg.head_dim), jnp.float32,
+                )
+                for name in ("mu", "phi")
+            )
+            mu, phi = (
+                p.unbox() if hasattr(p, "unbox") else p for p in (mu, phi))
+
         use_paged = False
         if decode and paged is not None:
             # Paged decode (vLLM block tables, static-shape XLA form): the
@@ -400,6 +426,13 @@ class Attention(nn.Module):
                 )
             use_paged = is_initialized
             decode = False
+        elif decode and eva:
+            raise NotImplementedError(
+                "attention_class 'eva' keeps chunk summaries beside a window "
+                "of rows: the dense decode cache (models/generation.py) holds "
+                "one row a position; serve it through ServingEngine's paged "
+                "cache"
+            )
         elif decode:
             # KV-cache decode (flax decode-cache pattern): a fixed-size
             # per-layer cache collection, updated in place at cache_index.
@@ -420,7 +453,54 @@ class Attention(nn.Module):
                 "cache", "cache_index", lambda: jnp.asarray(0, jnp.int32)
             )
             decode = is_initialized
-        if use_paged:
+        if use_paged and eva:
+            # the fourth cache regime: a slot at position n holds
+            # EvaLayout.rows(n) rows, summaries first. ``cache_len`` is that
+            # row count (write offset, last visible row) and the position
+            # rope turns by travels beside it.
+            from ..ops.eva_attention import eva_attention, eva_prefill_write
+
+            if kv_int8:
+                raise NotImplementedError(
+                    "attention_class 'eva' over int8 KV pools")
+            # no positions beside the rows: a call that starts its slot's
+            # cache from position 0 (the engine refuses what would make a
+            # prefill a continuation)
+            fresh = paged.positions is None
+            start = 0 if fresh else paged.positions[:, None]
+            positions = jnp.broadcast_to(start + jnp.arange(s)[None, :], (b, s))
+            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+            if fresh:
+                # prefill of a padded bucket from position 0: attends what
+                # it projected, and leaves in the pool only the completed
+                # windows' summaries and the last window's rows
+                out, kt, vt = eva_attention(
+                    q, k, v, mu, phi, chunk=cfg.chunk_size,
+                    window=cfg.window_size, scale=scale,
+                    kernel=paged.single_device,
+                )
+                new_k, new_v = eva_prefill_write(
+                    key_pool.value, value_pool.value, k, v, kt, vt, paged,
+                    chunk=cfg.chunk_size, window=cfg.window_size, layer=layer,
+                )
+            elif s != 1:
+                raise NotImplementedError(
+                    "attention_class 'eva': a call of several tokens onto an "
+                    "existing cache (chunked or prefix-cached prefill, "
+                    "speculative verification) may cross a window boundary "
+                    "and is not written"
+                )
+            else:
+                # decode: one row written at the slot's next row, one
+                # softmax over its summaries and window rows
+                new_k, new_v = paged_update(
+                    key_pool.value, value_pool.value, k, v, paged, layer=layer)
+                out = paged_attention(
+                    q, new_k, new_v, paged, scale=scale, layer=layer)
+            key_pool.value = new_k
+            value_pool.value = new_v
+        elif use_paged:
             # per-slot positions: slot b's token i sits at global position
             # cache_len[b] + i (heterogeneous across the batch — the dense
             # path's single scalar index cannot express a decode batch
@@ -477,6 +557,15 @@ class Attention(nn.Module):
                 q, key_cache, value_cache, mask=dec_mask, causal=False,
                 scale=scale, softcap=cfg.attn_softcap,
                 implementation="xla",
+            )
+        elif eva:
+            from ..ops.eva_attention import eva_attention
+
+            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+            out, _, _ = eva_attention(
+                q, k, v, mu, phi, chunk=cfg.chunk_size,
+                window=cfg.window_size, scale=scale,
             )
         else:
             if not fused_qkv:  # the fused prologue already applied rope
@@ -1155,6 +1244,31 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
     return x
 
 
+def eva_roll_over_cache(cfg: TransformerConfig, params, cache, src, dst):
+    """A window one request has just filled, in every layer's pools: the
+    rows of blocks ``src`` are summarised under that layer's ``mu``/``phi``
+    and the summaries written into blocks ``dst``
+    (``ops/eva_attention.eva_roll_over``). ``cache`` is the paged cache
+    collection of a ``CausalLM`` of ``attention_class`` "eva" and is
+    returned whole, its pools written in place when the caller donates it."""
+    from ..ops.eva_attention import eva_roll_over
+
+    scale = (cfg.query_pre_attn_scalar or cfg.head_dim) ** -0.5
+    cache = dict(cache)
+    for name, entry in cache.items():  # "layers", or "layer_<i>" unscanned
+        pools = entry.get("attn") if hasattr(entry, "get") else None
+        if pools is None or "key_pool" not in pools:
+            continue
+        learned = params[name]["attn"]
+        new_k, new_v = eva_roll_over(
+            pools["key_pool"], pools["value_pool"], learned["mu"],
+            learned["phi"], src, dst, chunk=cfg.chunk_size, scale=scale,
+        )
+        cache[name] = dict(entry, attn=dict(
+            pools, key_pool=new_k, value_pool=new_v))
+    return cache
+
+
 def _sown_means(sown) -> dict:
     """``{name: mean}`` over every layer's value of each scalar sowed under
     ``intermediates`` (a scan stacks them, ``sow`` wraps them in tuples)."""
@@ -1188,6 +1302,10 @@ class CausalLM(nn.Module):
         x = embed(input_ids)
         if cfg.embed_scale:  # Gemma scales embeddings by sqrt(hidden)
             x = x * jnp.asarray(np.sqrt(cfg.hidden_size), x.dtype)
+        if cfg.fp32_residual:
+            # the stream every layer adds into; each sublayer's output
+            # (compute dtype) is promoted by the add
+            x = x.astype(jnp.float32)
         x = constrain_activations(x)
         # the explicit Nones fill the block's kv_lengths/paged/lora slots
         # so the per-layer scanned pytree (window array and/or adapter
@@ -1224,15 +1342,24 @@ class CausalLM(nn.Module):
                 logits = embed.attend(x)
         else:
             logits = nn.Dense(
-                cfg.vocab_size,
+                cfg.vocab_size * cfg.num_pred_heads,
                 use_bias=False,
                 dtype=dtype,
                 param_dtype=jnp.float32,
                 kernel_init=nn.with_partitioning(
                     nn.initializers.lecun_normal(), ("embed", "vocab")
                 ),
+                # the same operands into the MXU; its float32 accumulator
+                # handed on as it is
+                dot_general=functools.partial(
+                    jax.lax.dot_general, preferred_element_type=jnp.float32,
+                ) if cfg.fp32_logits else None,
                 name="lm_head",
             )(x)
+            if cfg.num_pred_heads > 1:
+                # head-major: the first vocab_size columns are the next
+                # token's; the further heads' are for multi-token decoding
+                logits = logits[..., :cfg.vocab_size]
         if cfg.final_softcap is not None:
             # Gemma-2 final-logit soft-capping (in fp32: tanh saturates
             # quickly in bf16 and the caps exist to shape the tail)

@@ -64,6 +64,16 @@ class PagedKVState:
     when they may be sharded over a mesh. A Mosaic kernel cannot be
     auto-partitioned, so only True lets :func:`paged_attention` take
     the decode kernel.
+
+    A cache that is not one row a position (``attention_class`` "eva":
+    :mod:`.eva_attention`) makes three things of ``cache_len``. It stays
+    what the pools know — the slot's rows so far: the write offset, and
+    the last row a query sees — and the position that rope turns by
+    travels beside it: ``positions`` (B,), the position of this call's
+    first token. None is ``cache_len`` where a row is a position; for such
+    a cache it marks a call that starts a slot's cache from position 0 with
+    nothing before it — a prefill, which writes other rows than it was
+    given.
     """
 
     block_table: jax.Array
@@ -73,6 +83,7 @@ class PagedKVState:
     block_size: int = flax.struct.field(pytree_node=False)
     kv_dtype: str = flax.struct.field(pytree_node=False, default="native")
     single_device: bool = flax.struct.field(pytree_node=False, default=False)
+    positions: Optional[jax.Array] = None
 
 
 # floor on the per-token amax scale: keeps all-zero rows (garbage block,

@@ -139,6 +139,25 @@ class ServingEngine:
       span), never silently parked in an unbounded deque;
     * ``max_retained_results``: FIFO bound on retained generations —
       :meth:`result` returns None once a request's tokens age out.
+
+    ``decode_ahead``: dispatch decode step k + 1 BEFORE step k's tokens are
+    fetched. A slot's next position, rows and table are known on the host
+    without the token's value; the token itself is fed from step k's output
+    on the device (``_feed``, one more tiny compiled program, built with
+    the engine). The chip then runs step after step while the host fetches,
+    emits and schedules under it: the host's ~2.5 ms round trip leaves the
+    step, and a saturated engine's tokens/s stops following the host's
+    speed. ``step()`` still returns one token a decoding slot. What it
+    costs: a slot that a step ends by its count, or brings to a window's end
+    (eva), sits the next dispatch out; a request prefilled while a step is
+    in flight joins the one after (so sampled streams at temperature > 0
+    differ from ``decode_ahead=False``; greedy bytes are the same); a
+    request ended by its ``eos_token_id`` has decoded one row too many,
+    into its own blocks, never read. Written for plain decode only:
+    prefix cache, speculation, chunked prefill, preemption, adapters and
+    the hand-off roles are refused beside it, by name. ``None`` (default):
+    on where the engine refuses all of those anyway (``attention_class``
+    ``"eva"``), off elsewhere.
     """
 
     def __init__(
@@ -169,6 +188,7 @@ class ServingEngine:
         kv_dtype: str = "bf16",
         role: str = "colocated",
         transfer_plane: Any = None,
+        decode_ahead: Optional[bool] = None,
     ):
         from ..compilation import activate_persistent_cache
 
@@ -236,7 +256,40 @@ class ServingEngine:
         # change, so the zero-retrace contract holds across tenant churn.
         self.adapters = adapters
         cfg = model.config
-        self._max_table = -(-cfg.max_seq_len // block_size)
+        # a cache that is not one row a position (attention_class "eva":
+        # chunk summaries beside a window of rows, ops/eva_attention.py):
+        # a slot's position (``Slot.cache_len``, what rope turns by), its
+        # rows (``_rows``: the write offset and what a query sees) and its
+        # blocks part ways, the table and the pool are sized by the rows,
+        # and a slot gives blocks back before it ends (``_roll_over``)
+        self._eva = None
+        if getattr(cfg, "attention_class", None) == "eva":
+            from ..ops.eva_attention import EvaLayout
+
+            self._eva = EvaLayout(cfg.window_size, cfg.chunk_size, block_size)
+        if decode_ahead is None:
+            decode_ahead = self._eva is not None
+        self.decode_ahead = bool(decode_ahead)
+        # the step dispatched ahead and not yet fetched: (its tokens on
+        # the device, [(slot, request)] it decodes for)
+        self._ahead: Optional[tuple] = None
+        for feature, on in (
+            ("prefix_cache", prefix_cache),
+            ("spec_decode", spec_decode is not None),
+            ("prefill_chunk_tokens", prefill_chunk_tokens is not None),
+            ("preemption", preemption),
+            (f"role {role!r}", role != "colocated"),
+            ("adapters", adapters is not None),
+        ):
+            if on:
+                self._refuse_for_eva(feature)
+                self._refuse_beside_decode_ahead(feature)
+        if kv_dtype == "int8":
+            self._refuse_for_eva("kv_dtype 'int8'")
+        self._max_table = (
+            self._eva.peak_blocks(cfg.max_seq_len) if self._eva is not None
+            else -(-cfg.max_seq_len // block_size)
+        )
         if num_blocks is None:
             num_blocks = max_slots * self._max_table + 1
         self.num_blocks = num_blocks
@@ -267,6 +320,7 @@ class ServingEngine:
         # when preemption provides the can't-grow escape hatch: without
         # it admission keeps the full-footprint reservation that makes
         # mid-flight OOM impossible by construction.
+        self.scheduler.layout = self._eva
         self.scheduler.chunk_tokens = prefill_chunk_tokens
         self.scheduler.chunked_reserve = (
             prefill_chunk_tokens is not None and preemption
@@ -303,10 +357,14 @@ class ServingEngine:
         # kv_in_place: of the traced prefill, decode and verify programs,
         # how many hold each pool as ONE buffer from their (donated)
         # input through the layer loop to their output
+        # eva: of the traced prefill, decode and roll-over programs, how
+        # many ran the cache of summaries beside a window (all or none)
         self._traces = {
             "prefill": 0, "decode": 0, "decode_attn_kernel": 0, "cow": 0,
             "verify": 0, "swap_out": 0, "swap_in": 0, "kv_in_place": 0,
+            "eva": 0,
         }
+        self._rollovers_total = 0
         # every bucket width a prefill ever ran at — the set
         # capture_programs() reconstructs abstract specs from
         self._prefill_buckets: set[int] = set()
@@ -366,6 +424,7 @@ class ServingEngine:
         self.kv_pool_bytes = kv_bytes
 
         traces = self._traces
+        eva = self._eva is not None
         # what the engine knows about its pools and attention cannot see
         # from inside a trace: they sit whole on the weights' one device
         single_device = self._device is not None
@@ -397,6 +456,7 @@ class ServingEngine:
                      temp, *lora_args):
             traces["prefill"] += 1  # trace-time counter (not per call)
             traces["kv_in_place"] += 1
+            traces["eva"] += eva
             # cached_len > 0 is the warm-hit path: ``ids`` holds only the
             # UNCACHED tail and the paged cache already contains KV for
             # the first cached_len positions (shared prefix blocks in
@@ -426,9 +486,12 @@ class ServingEngine:
             return mutated["cache"], token
 
         def _decode(params, cache, tokens, tables, cache_lens, lengths,
-                    temps, key, *lora_args):
+                    temps, key, positions=None, *lora_args):
             traces["decode"] += 1  # zero-retrace contract rides on this
             traces["kv_in_place"] += 1
+            traces["eva"] += eva
+            # eva: ``cache_lens`` are the slots' ROWS and ``positions`` what
+            # they stand for (None for every other model: one and the same)
             state = PagedKVState(
                 block_table=tables,
                 cache_len=cache_lens,
@@ -437,6 +500,7 @@ class ServingEngine:
                 block_size=block_size,
                 kv_dtype=kv_state_dtype,
                 single_device=single_device,
+                positions=positions,
             )
             traces["decode_attn_kernel"] += decode_kernel_eligible(
                 state, tokens.shape[1], jax.tree.leaves(cache)[pool_leaf]
@@ -450,6 +514,12 @@ class ServingEngine:
                     logits[:, -1], key, temps, top_k=top_k, top_p=top_p
                 )
             return mutated["cache"], token
+
+        def _rollover(params, cache, src, dst):
+            traces["eva"] += 1
+            from ..models.transformer import eva_roll_over_cache
+
+            return eva_roll_over_cache(cfg, params, cache, src, dst)
 
         def _key_chain(key):
             # 16 sequential (key, sub) = split(key) steps in ONE compiled
@@ -534,6 +604,36 @@ class ServingEngine:
         self._cow_fn = jax.jit(_cow, donate_argnums=0)
         self._key_chain_fn = jax.jit(_key_chain)
         self._key_buf: collections.deque = collections.deque()
+        self._feed_fn = None
+        if self.decode_ahead:
+            # a step's tokens: the step before's, still on the device, for
+            # the slots it decoded; the host's for the others. Compiled
+            # now, and every decode call of this engine takes its tokens
+            # from it, so the decode program sees ONE kind of argument
+            def _feed(prev, tokens, from_prev):
+                return jnp.where(from_prev[:, None], prev[:, None], tokens)
+
+            self._feed_fn = jax.jit(_feed)
+            none = np.zeros(max_slots, np.int32)
+            self._no_tokens = (
+                jnp.asarray(none) if self._device is None
+                else jax.device_put(none, self._device)
+            )
+            with self._placed():
+                self._feed_fn(self._no_tokens, none[:, None],
+                              np.zeros(max_slots, bool))
+        self._rollover_fn = None
+        if eva:
+            # what a filling window runs is compiled now, not at the first
+            # window that fills mid-traffic: one call over the garbage
+            # block (reads it, writes its summaries back into it)
+            self._rollover_fn = jax.jit(_rollover, donate_argnums=1)
+            with self._placed():
+                self.cache = self._rollover_fn(
+                    self.params, self.cache,
+                    np.zeros(self._eva.window_blocks, np.int32),
+                    np.zeros(self._eva.summary_blocks, np.int32),
+                )
         # speculative decoding: verify programs cached by width (k + 1)
         # and warm proposers cached by config identity, so set_speculation
         # toggles on a warm engine never retrace
@@ -592,6 +692,15 @@ class ServingEngine:
                 f"request names adapter {adapter!r} but the engine was "
                 "built without an AdapterRegistry (pass adapters=...)"
             )
+        n_prompt = int(np.asarray(prompt).size)
+        if n_prompt + max_new_tokens > self.model.config.max_seq_len:
+            # the block table is as wide as max_seq_len needs: a longer
+            # request would write past it
+            raise ValueError(
+                f"request of {n_prompt} prompt + {max_new_tokens} new tokens "
+                f"is longer than the model's max_seq_len "
+                f"{self.model.config.max_seq_len}"
+            )
         req = Request(
             prompt=[int(t) for t in np.asarray(prompt).reshape(-1)],
             max_new_tokens=max_new_tokens,
@@ -634,7 +743,41 @@ class ServingEngine:
                 "role must be 'colocated', 'prefill' or 'decode', "
                 f"got {role!r}"
             )
+        if role != "colocated":
+            self._refuse_for_eva(f"role {role!r}")
+            self._refuse_beside_decode_ahead(f"role {role!r}")
         self._role = role
+
+    def _refuse_for_eva(self, feature: str) -> None:
+        """Engine features that take a request's state for a list of
+        blocks holding one row a position each are refused, by name, for a
+        model whose cache is not that."""
+        if self._eva is not None:
+            raise NotImplementedError(
+                f"{feature} is not written for attention_class 'eva': a "
+                "request's cache is chunk summaries beside a window of "
+                "rows, not one row a position (ROADMAP Reach A4)"
+            )
+
+    def _refuse_beside_decode_ahead(self, feature: str) -> None:
+        """Engine features that change a slot's blocks, its place in the
+        batch or the program it decodes through between two steps are
+        refused, by name, beside ``decode_ahead``: the step after the
+        next is already on the device when they would act."""
+        if self.decode_ahead:
+            raise NotImplementedError(
+                f"{feature} is not written beside decode_ahead: the next "
+                "decode step is dispatched before this one's tokens are "
+                "fetched (build the engine with decode_ahead=False)"
+            )
+
+    def _rows(self, slot: Slot, ahead: int = 0) -> int:
+        """Cache rows ``slot`` holds (``ahead`` positions from now): its
+        write offset, and the last row its next query sees. One a
+        position, or the layout's count."""
+        if self._eva is None:
+            return slot.cache_len + ahead
+        return int(self._eva.rows(slot.cache_len + ahead))
 
     def trace_counts(self) -> dict:
         """Compiled-program counts, bumped at trace time. After warmup,
@@ -763,6 +906,9 @@ class ServingEngine:
                 emit = self._spec_step(active)
             else:
                 emit = self._decode_step(active)
+        else:
+            # whoever it decoded for ended meanwhile (an eos)
+            self._ahead = None
         with annotate("atpu:serve.emit") as phase:
             if emit is not None:
                 emit(events)
@@ -948,6 +1094,8 @@ class ServingEngine:
             )
             token = int(np.asarray(token)[0])
             slot.cache_len = prompt_len
+            if self._eva is not None:
+                slot.windows_done = prompt_len // self._eva.window
             slot.pending = token
             slot.generated = [token]
             # index every FULL prompt block we freshly prefilled so the next
@@ -1459,6 +1607,8 @@ class ServingEngine:
         seated, the dedup split (``reused_blocks`` found warm in the
         local CACHED index vs ``moved_blocks`` scatter-restored from the
         manifest's host images and their ``moved_bytes``)."""
+        self._refuse_for_eva("hand-off (acquire)")
+        self._refuse_beside_decode_ahead("hand-off (acquire)")
         res = self._try_seat_manifest(manifest)
         if res is None:
             self._inbox.append(manifest)
@@ -1591,12 +1741,74 @@ class ServingEngine:
         """The device half of one decode step over the seated batch: the
         inputs, the dispatch and the fetch of the sampled tokens. Returns
         the host half, ``emit(events)``, which :meth:`_step_inner` runs
-        in its emit phase."""
-        with annotate("atpu:serve.decode.inputs", seated=len(active)):
+        in its emit phase. With ``decode_ahead`` the step fetched here was
+        dispatched during the step before, and the one after it goes to
+        the device before the fetch."""
+        ahead, self._ahead = self._ahead, None
+        if ahead is None:
+            out, now = self._dispatch_decode(active), active
+        else:
+            # a slot of it may have ended since, by its eos, and its seat
+            # may hold another request: the row it wrote is never read
+            out = ahead[0]
+            now = [s for s, req in ahead[1] if s.request is req and not s.done]
+        if self.decode_ahead:
+            # the step after this one: every decoding slot but those this
+            # step ends (by their count) or brings to a window's end (the
+            # roll-over runs in emit, before their next row)
+            flying = {s.index for s in now}
+            nxt = [
+                s for s in active
+                if s.index not in flying or (
+                    len(s.generated) + 1 < s.request.max_new_tokens
+                    and not self._fills_window(s, ahead=1)
+                )
+            ]
+            if nxt:
+                self._ahead = (
+                    self._dispatch_decode(nxt, prev=(out, flying)),
+                    [(s, s.request) for s in nxt],
+                )
+        with annotate("atpu:serve.decode.fetch"):
+            out = np.asarray(out)
+
+        def emit(events: list[TokenEvent]) -> None:
+            for slot in now:
+                token = int(out[slot.index])
+                slot.cache_len += 1  # the fed token was written this step
+                slot.pending = token
+                slot.generated.append(token)
+                self._note_token(slot, token, events)
+                if not slot.done and self._fills_window(slot):
+                    self._roll_over(slot)
+
+        return emit
+
+    def _fills_window(self, slot: Slot, ahead: int = 0) -> bool:
+        """eva: ``slot`` (``ahead`` positions from now) stands past a
+        window whose summaries are not in its table yet."""
+        return self._eva is not None and (
+            (slot.cache_len + ahead) // self._eva.window > slot.windows_done
+        )
+
+    def _dispatch_decode(self, slots: list[Slot], prev=None) -> jax.Array:
+        """Put one decode step over ``slots`` on the device; returns its
+        sampled tokens, not fetched. ``prev`` (``decode_ahead``): the step
+        before's tokens, on the device and not fetched either, and the
+        indices of the slots it decodes: each of them stands one position
+        past what the host has emitted, and feeds on that step's token."""
+        prev_out, flying = prev if prev is not None else (None, ())
+        with annotate("atpu:serve.decode.inputs", seated=len(slots)) as phase:
             tokens = np.zeros((self.max_slots, 1), np.int32)
             cache_lens = np.zeros(self.max_slots, np.int32)
             lengths = np.zeros(self.max_slots, np.int32)
-            for slot in active:
+            from_prev = np.zeros(self.max_slots, bool)
+            positions = None
+            if self._eva is not None:
+                positions = np.zeros(self.max_slots, np.int32)
+            at = 0
+            for slot in slots:
+                ahead = int(slot.index in flying)
                 # shared blocks are immutable: a decode step about to write
                 # into one (the pending token lands at cache_len) copies it
                 # private first. Block-aligned hits mean this only fires when
@@ -1605,27 +1817,65 @@ class ServingEngine:
                 if t in slot.shared:
                     self._cow_block(slot, t)
                 tokens[slot.index, 0] = slot.pending
-                cache_lens[slot.index] = slot.cache_len
+                from_prev[slot.index] = ahead
+                cache_lens[slot.index] = self._rows(slot, ahead)
                 lengths[slot.index] = 1
+                at += slot.cache_len + ahead
+                if positions is not None:
+                    positions[slot.index] = slot.cache_len + ahead
+            # what this step's attention reads: the rows the seated slots
+            # hold, their new one included, and the positions they stand for
+            phase.set_metadata(
+                rows=int(cache_lens.sum()) + len(slots),
+                positions=at + len(slots),
+            )
+            if self._feed_fn is None:
+                fed = jnp.asarray(tokens)
+            else:
+                fed = self._feed_fn(
+                    self._no_tokens if prev_out is None else prev_out,
+                    tokens, from_prev,
+                )
             self.cache, out = self._decode_fn(
-                self.params, self.cache, jnp.asarray(tokens),
+                self.params, self.cache, fed,
                 self._tables_device(), jnp.asarray(cache_lens),
                 jnp.asarray(lengths), self.sampling.temperatures(),
                 self._split_key(),
+                None if positions is None else jnp.asarray(positions),
                 *self._lora_call_args(self._slot_adapter),
             )
-        with annotate("atpu:serve.decode.fetch"):
-            out = np.asarray(out)
+            if self._feed_fn is not None:
+                out.copy_to_host_async()  # behind the step, no round trip
+        return out
 
-        def emit(events: list[TokenEvent]) -> None:
-            for slot in active:
-                token = int(out[slot.index])
-                slot.cache_len += 1  # the fed token was written this step
-                slot.pending = token
-                slot.generated.append(token)
-                self._note_token(slot, token, events)
-
-        return emit
+    def _roll_over(self, slot: Slot) -> None:
+        """eva: ``slot`` has just filled a window. Its ``window_blocks``
+        blocks of rows become ``summary_blocks`` blocks of summaries in the
+        pool (one compiled program, built with the engine), written over
+        the first of them: the table stands as it is and the window's other
+        blocks take the next window's rows. After a request's last
+        roll-over the blocks its remaining positions cannot need go back."""
+        lay = self._eva
+        first = slot.windows_done * lay.summary_blocks
+        src = slot.blocks[first:first + lay.window_blocks]
+        assert len(src) == lay.window_blocks, (len(src), slot.cache_len)
+        with annotate("atpu:serve.roll_over", slot=slot.index,
+                      window=slot.windows_done):
+            # numpy rows, as every other call's host inputs: jnp.asarray of
+            # a Python list compiles a conversion, once a shape
+            src = np.asarray(src, np.int32)
+            self.cache = self._rollover_fn(
+                self.params, self.cache, src, src[:lay.summary_blocks])
+        slot.windows_done += 1
+        self._rollovers_total += 1
+        req = slot.request
+        keep = lay.peak_blocks(
+            len(req.prompt) + req.max_new_tokens, start=slot.cache_len)
+        if keep < len(slot.blocks):
+            self.pool.free(slot.blocks[keep:])
+            del slot.blocks[keep:]
+            self._tables[slot.index, keep:] = 0
+            self._tables_dev = None
 
     def _spec_step(self, active: list[Slot]) -> Callable:
         """One speculative iteration: propose up to k tokens per slot,
@@ -1877,7 +2127,7 @@ class ServingEngine:
             # over the max_slots x max_blocks a gather reads: the share
             # of that read the decode kernel's live-block walk still makes
             "live_block_share": sum(
-                s.cache_len // self.block_size + 1 for s in active
+                self._rows(s) // self.block_size + 1 for s in active
             ) / (self.max_slots * self._max_table),
             "admission_blocked_no_free_slot_total":
                 sched.blocked_reasons["no_free_slot"],
@@ -1912,6 +2162,21 @@ class ServingEngine:
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "pool_alias_bytes": self.pool_alias_bytes,
         }
+        if self._eva is not None:
+            # a cache that is not one row a position: what it holds beside
+            # what it stands for (other engines' records keep their schema)
+            rows = sum(self._rows(s) for s in active)
+            summary = sum(
+                s.windows_done * self._eva.summary_blocks for s in active)
+            fields.update(
+                cache_rows_live=rows,
+                cache_rows_per_token=(
+                    rows / max(1, fields["tokens_in_flight"])),
+                window_rollovers_total=self._rollovers_total,
+                summary_blocks=summary,
+                window_blocks=sum(
+                    self._eva.blocks(s.cache_len) for s in active) - summary,
+            )
         if self._role != "colocated":
             # PR 19 disaggregation plane: hand-off accounting only for
             # pool members — a colocated engine's gauge records stay
@@ -2067,8 +2332,18 @@ class ServingEngine:
                 jax.ShapeDtypeStruct((self.max_slots, self._max_table), i32),
                 jax.ShapeDtypeStruct((self.max_slots,), i32),
                 jax.ShapeDtypeStruct((self.max_slots,), i32),
-                temps_s, key_s, *lora_n,
+                temps_s, key_s,
+                # eva: the slots' positions beside their rows
+                (jax.ShapeDtypeStruct((self.max_slots,), i32)
+                 if self._eva is not None else None),
+                *lora_n,
             )
+            if self._eva is not None:
+                _one(
+                    "serve_rollover", self._rollover_fn, params_s, cache_s,
+                    jax.ShapeDtypeStruct((self._eva.window_blocks,), i32),
+                    jax.ShapeDtypeStruct((self._eva.summary_blocks,), i32),
+                )
             for width, vfn in sorted(self._verify_fns.items()):
                 keys_s = jax.ShapeDtypeStruct(
                     (width,) + tuple(jnp.shape(self._key)),
@@ -2102,7 +2377,8 @@ class ServingEngine:
             (getattr(compiled.memory_analysis(), "alias_size_in_bytes", 0)
              for label, compiled in self._captured_programs.items()
              if label.startswith(
-                 ("serve_prefill", "serve_decode", "serve_verify"))),
+                 ("serve_prefill", "serve_decode", "serve_verify",
+                  "serve_rollover"))),
             default=0,
         ))
         return labels
@@ -2203,6 +2479,8 @@ class ServingEngine:
         in-flight shared blocks keep their refcounts and drain
         normally)."""
         if enabled:
+            self._refuse_for_eva("prefix_cache")
+            self._refuse_beside_decode_ahead("prefix_cache")
             if model_fingerprint is not None:
                 self._model_fingerprint = model_fingerprint
             if self.prefix_cache is None:
@@ -2229,6 +2507,8 @@ class ServingEngine:
             self._proposer = None
             self.scheduler.lookahead_tokens = 0
             return
+        self._refuse_for_eva("spec_decode")
+        self._refuse_beside_decode_ahead("spec_decode")
         proposer = self._proposers.get(id(spec))
         if proposer is None:
             if spec.method == "draft_model":

@@ -117,6 +117,10 @@ class Slot:
     # — the anti-thrash rule)
     preempted_count: int = 0
     resumed: bool = False
+    # a cache that is not one row a position (the scheduler's ``layout``):
+    # ``cache_len`` stays the request's POSITION; ``windows_done`` counts
+    # the windows whose summaries stand in the table
+    windows_done: int = 0
 
     @property
     def busy(self) -> bool:
@@ -151,6 +155,7 @@ class Slot:
         self.chunks = 0
         self.preempted_count = 0
         self.resumed = False
+        self.windows_done = 0
 
 
 class ContinuousScheduler:
@@ -216,6 +221,11 @@ class ContinuousScheduler:
         # new submits are refused with shed_reason="draining", seated
         # work finishes — the router's graceful-rotation state
         self.draining = False
+        # set by the engine for a model whose cache is not one row a
+        # position (ops/eva_attention.EvaLayout): a request's footprint is
+        # then ``layout.peak_blocks``, allocated at admission like any
+        # other; the engine gives back what the last filled window frees
+        self.layout = None
         self.shed_counts = {"queue_full": 0, "queue_deadline": 0}
         self.blocked_reasons = {
             "no_free_slot": 0,
@@ -225,10 +235,15 @@ class ContinuousScheduler:
         max_tokens = (pool.num_blocks - 1) * pool.block_size
         self.max_request_tokens = max_tokens
 
+    def footprint(self, tokens: int, start: int = 0) -> int:
+        """The most blocks a request holds at once on its way from
+        ``start`` positions to ``tokens``."""
+        if self.layout is not None:
+            return self.layout.peak_blocks(tokens, start)
+        return self.pool.blocks_for_tokens(tokens)
+
     def submit(self, request: Request) -> str:
-        need = self.pool.blocks_for_tokens(
-            len(request.prompt) + request.max_new_tokens
-        )
+        need = self.footprint(len(request.prompt) + request.max_new_tokens)
         if need > self.pool.num_blocks - 1:
             raise ValueError(
                 f"request needs {need} blocks "
@@ -397,7 +412,9 @@ class ContinuousScheduler:
                 )
             else:
                 reserve_tokens = total_tokens
-            need = self.pool.blocks_for_tokens(reserve_tokens)
+            # (a layout's engine refuses lookahead, sharing and chunking:
+            # its prefill starts at the prompt's rows, summaries in place)
+            need = self.footprint(reserve_tokens, start=len(req.prompt))
             if shared:
                 # pin the chain BEFORE any allocation can LRU-evict it
                 self.pool.acquire(shared)

@@ -14,7 +14,9 @@ is, and the cut is printed), with random weights made from a seed:
 * hybrid  — the same path over layers of two kinds at LFM2-8B-A1B's widths:
   a conv layer, an attention layer (head_dim 64) with 8 of 32 experts held;
 * serve   — ``ServingEngine`` answering more requests than it has slots (one
-  engine per chip behind ``FleetRouter`` when the host has several).
+  engine per chip behind ``FleetRouter`` when the host has several);
+* eva     — the same engine over EvaByte's layer (chunk summaries beside a
+  2,048-byte window in the one pool), with windows that fill.
 
 Everything runs in THIS one process, which holds the chip(s); the phases key
 off ``jax.device_count()``. A failed assertion is an exception and a non-zero
@@ -46,7 +48,7 @@ import sys
 import time
 
 SEED = 20260926
-PHASES = ("kernels", "train", "hybrid", "serve")
+PHASES = ("kernels", "train", "hybrid", "serve", "eva")
 
 
 # --------------------------------------------------------------------------- #
@@ -1025,6 +1027,86 @@ def serve_phase(say, sz: Sizes, dry: bool) -> None:
     say("serve phase PASSED")
 
 
+def eva_phase(say, dry: bool) -> None:
+    """``ServingEngine`` over three layers at EvaByte's published widths
+    (hidden 4096, 32 heads of 128, SwiGLU 11,008, byte vocabulary 320, 8
+    prediction heads, chunks of 16 in windows of 2,048; depth cut 32 -> 3) on
+    ONE chip: prompts that end inside a second window, exactly on a window
+    and inside the first, two of them decoding across a window's end — so the flash
+    forward kernel runs inside and across windows, the decode kernel walks
+    summaries and window rows, and windows roll over. The served bytes are
+    held against the model's OWN plain forward pass of prompt + answer (the
+    XLA form of the same attention, no cache)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu import ServingEngine
+    from accelerate_tpu.models import CausalLM, TransformerConfig, count_params
+
+    if dry:
+        w = dict(vocab_size=40, hidden_size=64, intermediate_size=160,
+                 num_heads=4, num_kv_heads=4, head_dim=16, chunk_size=4,
+                 window_size=16, num_pred_heads=3)
+        block, max_seq, new = 4, 128, 12
+        prompts = (16 + 10, 16, 9)
+    else:
+        w = dict(vocab_size=320, hidden_size=4096, intermediate_size=11008,
+                 num_heads=32, num_kv_heads=32, head_dim=128, chunk_size=16,
+                 window_size=2048, num_pred_heads=8)
+        block, max_seq, new = 16, 8192, 24
+        prompts = (2048 + 2040, 2048, 2030)
+    cfg = TransformerConfig(
+        **w, num_layers=3, attention_class="eva", norm_offset=True,
+        rope_theta=100000.0, max_seq_len=max_seq, dtype="bfloat16",
+        # as the published file states: the stream and the logits float32
+        fp32_residual=True, fp32_logits=True)
+    window = cfg.window_size
+    model = CausalLM(cfg)
+    device = jax.devices()[0]
+    params = random_bf16_params(model, device)
+    eng = ServingEngine(model, params, max_slots=2, block_size=block)
+    say(f"eva: depth cut 32 -> {cfg.num_layers} layers, "
+        f"{count_params(params) / 1e6:.0f}M bf16 parameters, 2 slots x "
+        f"{max_seq} bytes: table {eng._max_table} blocks, pool "
+        f"{eng.num_blocks} blocks, {eng.kv_bytes_per_token / 1024:.1f} KiB "
+        "a cache row")
+    rng = np.random.default_rng(SEED + 5)
+    asked = [rng.integers(0, cfg.vocab_size, n).astype(np.int32) for n in prompts]
+    rids = [eng.add_request(p, max_new_tokens=new) for p in asked]
+    while eng.has_work:
+        eng.step()
+    counts, stats, gauges = eng.trace_counts(), eng.pool.stats(), eng._gauge_fields()
+    say(f"eva: trace_counts {counts} pool allocated={stats['allocated']} "
+        f"roll-overs {gauges['window_rollovers_total']}")
+    assert counts["decode"] == 1, counts
+    assert counts["eva"] == counts["prefill"] + counts["decode"] + 1, counts
+    assert stats["allocated"] == 0, stats
+    # a window rolls over when a request that goes on decoding fills it
+    filled = sum((len(p) + new - 2) // window - len(p) // window for p in asked)
+    assert gauges["window_rollovers_total"] == filled >= 2, (gauges, filled)
+    if not dry:
+        assert counts["decode_attn_kernel"] == 1, counts
+    assert_pool_in_place(say, eng)
+    apply = jax.jit(lambda p, ids: model.apply({"params": p}, ids))
+    gaps = []
+    for rid, prompt in zip(rids, asked):
+        out = np.asarray(eng.result(rid))
+        assert len(out) == new and ((0 <= out) & (out < cfg.vocab_size)).all()
+        seq = np.concatenate([prompt, out])
+        logits = np.asarray(apply(params, jnp.asarray(seq)[None])[0].astype(
+            jnp.float32))[len(prompt) - 1:len(seq) - 1]
+        gaps.append(logits.max(-1) - logits[np.arange(new), out])
+    gaps = np.concatenate(gaps)
+    say(f"eva: served bytes against the plain forward pass: mean logit gap "
+        f"{gaps.mean():.4g}, widest {gaps.max():.4g}, "
+        f"{int((gaps > 0).sum())} of {gaps.size} off its best")
+    # two bf16 programs of one arithmetic: a near-tie may fall otherwise, a
+    # byte from elsewhere reads 2 and more (PERF.md section 4)
+    assert gaps.mean() < 0.05 and gaps.max() < 1.0, (gaps.mean(), gaps.max())
+    say("eva phase PASSED")
+
+
 # --------------------------------------------------------------------------- #
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -1096,7 +1178,8 @@ def main(argv=None) -> int:
     run = {"kernels": lambda: kernel_phase(say, sz, dry),
            "train": lambda: train_phase(say, sz, dry),
            "hybrid": lambda: hybrid_phase(say, dry),
-           "serve": lambda: serve_phase(say, sz, dry)}
+           "serve": lambda: serve_phase(say, sz, dry),
+           "eva": lambda: eva_phase(say, dry)}
     wall = []
     for phase in phases:
         t0 = time.perf_counter()

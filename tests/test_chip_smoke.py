@@ -62,7 +62,7 @@ def test_cpu_dry_run_rehearses_every_phase_but_never_passes():
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = proc.stdout.splitlines()
     assert lines and all(l.startswith("[DRY RUN cpu x1]") for l in lines)
-    for phase in ("kernel", "train", "hybrid", "serve"):
+    for phase in ("kernel", "train", "hybrid", "serve", "eva"):
         assert any(f"{phase} phase PASSED" in l for l in lines), phase
     assert _result_lines(proc.stdout) == []
     assert "NOT a pass" in lines[-1]
