@@ -247,6 +247,20 @@ def _lora_delta_fn(module: nn.Module, lora, lora_stacks):
     return delta
 
 
+def qkv_in_place(decode: bool, q_len: int) -> bool:
+    """Whether :class:`Attention` pins its three projection outputs
+    two-dimensional (an ``optimization_barrier`` before the head split):
+    a decode call of ONE position. XLA:TPU otherwise folds the split into
+    the dot and asks for the layer's kernel transposed — a copy of every
+    q/k/v weight, every layer of every step, for 8-16 rows; pinned, each
+    projection reads its kernel out of the stacked parameter where it
+    lies, as o_proj does. A wider call amortises the copy over its rows
+    (and pinned would transpose activations that grow with them), so
+    prefill, verify and training keep the text they had. ONE predicate:
+    the serving engine asks it too, for its ``qkv_in_place`` trace count."""
+    return decode and q_len == 1
+
+
 class Attention(nn.Module):
     config: TransformerConfig
     decode: bool = False
@@ -344,6 +358,8 @@ class Attention(nn.Module):
             dv = delta(x, "v_proj")
             if dv is not None:
                 v = v + dv
+            if qkv_in_place(self.decode, s):
+                q, k, v = jax.lax.optimization_barrier((q, k, v))
             q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
             k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
             v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)

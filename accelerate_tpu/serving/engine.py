@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..logging import get_logger
+from ..models.transformer import qkv_in_place
 from ..ops.attention import PagedKVState, decode_kernel_eligible
 from ..utils.profiling import annotate
 from .block_pool import BlockPool, PrefixCache, prefix_keys
@@ -359,10 +360,13 @@ class ServingEngine:
         # input through the layer loop to their output
         # eva: of the traced prefill, decode and roll-over programs, how
         # many ran the cache of summaries beside a window (all or none)
+        # qkv_in_place: of the traced decode programs, how many read each
+        # layer's q/k/v kernels where they lie in the stacked parameters
+        # (models/transformer.py::qkv_in_place: one position a slot)
         self._traces = {
             "prefill": 0, "decode": 0, "decode_attn_kernel": 0, "cow": 0,
             "verify": 0, "swap_out": 0, "swap_in": 0, "kv_in_place": 0,
-            "eva": 0,
+            "eva": 0, "qkv_in_place": 0,
         }
         self._rollovers_total = 0
         # every bucket width a prefill ever ran at — the set
@@ -505,6 +509,7 @@ class ServingEngine:
             traces["decode_attn_kernel"] += decode_kernel_eligible(
                 state, tokens.shape[1], jax.tree.leaves(cache)[pool_leaf]
             )
+            traces["qkv_in_place"] += qkv_in_place(True, tokens.shape[1])
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, tokens, decode=True,
                 paged=state, mutable=["cache"], **_lora_kwargs(lora_args),

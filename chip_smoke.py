@@ -1007,6 +1007,9 @@ def serve_phase(say, sz: Sizes, dry: bool) -> None:
         say(f"serve: engine on device {dev.id} trace_counts {counts} "
             f"pool allocated={stats['allocated']} free={stats['free']}")
         assert counts["decode"] == 1, f"decode traced {counts['decode']}x"
+        assert counts["qkv_in_place"] == 1, (
+            "the decode program did not pin its q/k/v projections flat"
+        )
         assert 1 <= counts["prefill"] <= len(buckets), (
             f"{counts['prefill']} prefill traces for buckets {buckets}: "
             "a retrace"
@@ -1079,7 +1082,7 @@ def eva_phase(say, dry: bool) -> None:
     counts, stats, gauges = eng.trace_counts(), eng.pool.stats(), eng._gauge_fields()
     say(f"eva: trace_counts {counts} pool allocated={stats['allocated']} "
         f"roll-overs {gauges['window_rollovers_total']}")
-    assert counts["decode"] == 1, counts
+    assert counts["decode"] == counts["qkv_in_place"] == 1, counts
     assert counts["eva"] == counts["prefill"] + counts["decode"] + 1, counts
     assert stats["allocated"] == 0, stats
     # a window rolls over when a request that goes on decoding fills it

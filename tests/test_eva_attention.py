@@ -493,12 +493,24 @@ def test_a_request_longer_than_max_seq_len_is_refused_at_add_request(
 # --------------------------------------------------------------------------- #
 # sha256 of the lowered text at commit 276d936 (the parent of ISSUE 30), as
 # this test lowers them: the tiny dense model's forward, and the engine's
-# decode and 16-wide prefill over abstract weights
+# decode and 16-wide prefill over abstract weights. "decode" was re-pinned
+# at ISSUE 31 (parent b456505, where it read e0b6a19d9582e3dd): a decode call
+# of one position pins its q/k/v projections two-dimensional
+# (``transformer.qkv_in_place``), one ``optimization_barrier`` a layer more;
+# "forward" and "prefill" are the ones of 276d936 still. The two train
+# cells' own configurations (the gradient of ``CausalLM.loss_fn`` at the
+# cells' rows and widths, abstract weights) were pinned at b456505
 PARENT = {
     "forward": "185f96c4ca8bf1ef",
-    "decode": "e0b6a19d9582e3dd",
+    "decode": "afba1e62f208a5b5",
     "prefill": "0e358c278cc3492e",
+    "train-dense-1chip": "9ce6fdb3752bf4cd",
+    "train-moe-conv-1chip": "53210cc0beedf81c",
 }
+
+
+def _sha(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
 def _dense_programs() -> dict:
@@ -524,9 +536,30 @@ def _dense_programs() -> dict:
             shapes, cache, i32(1, 16), i32(1, table), i32(1), i32(1), key,
             f32(1)).as_text(),
     }
-    return {name: hashlib.sha256(text.encode()).hexdigest()[:16]
-            for name, text in texts.items()}
+    return {name: _sha(text) for name, text in texts.items()}
+
+
+def _train_gradient(name: str) -> str:
+    from harness import cell as cells
+
+    cell = cells.load_cell(name)
+    spec, cfg = cell["spec"], cell["config"]
+    _, weights = common.modules_of(cfg)
+    seq = spec["traffic"]["seq_len"]
+    model = CausalLM(common.program_config(
+        cfg, max_seq_len=seq, remat=spec["remat"], dtype=spec["compute_dtype"]))
+    ids = jax.ShapeDtypeStruct((spec["rows_per_chip"], seq), jnp.int32)
+    return _sha(jax.jit(jax.grad(CausalLM.loss_fn(model))).lower(
+        weights.abstract_tree(cfg, jnp.float32), {"input_ids": ids}).as_text())
 
 
 def test_without_the_class_the_dense_programs_are_the_parents():
-    assert _dense_programs() == PARENT
+    got = _dense_programs()
+    assert got == {name: PARENT[name] for name in got}
+
+
+@pytest.mark.parametrize("name", ["train-dense-1chip", "train-moe-conv-1chip"])
+def test_a_train_cells_gradient_lowers_to_the_parents_text(name):
+    """Neither train step takes a decode branch: the gradient of the cell's
+    own loss over its own configuration is the text it was."""
+    assert _train_gradient(name) == PARENT[name]

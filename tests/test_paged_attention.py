@@ -316,29 +316,35 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled_for_the_chip(lowered):
+    """Compiled with the persistent cache off: an entry written for a
+    described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 def test_decode_kernel_compiles_for_v5e_with_no_copy_of_the_pools(one_chip):
     """16 slots x 32/8 heads x D 128 over a 1025-block bf16 pool, as the
     serve cells run it: Mosaic accepts the kernel, and the pools reach it
     as bitcasts — no relayout, no temporaries."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     pool = sds((1025, 16, 8, 128), jnp.bfloat16)
     args = (sds((16, 1, 32, 128), jnp.bfloat16), pool, pool,
             sds((16, 64), jnp.int32), sds((16,), jnp.int32))
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(
-            lambda *a: paged_attention_kernel.paged_decode_attention(
-                *a, window=4096)
-        ).lower(*args).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    compiled = _compiled_for_the_chip(jax.jit(
+        lambda *a: paged_attention_kernel.paged_decode_attention(
+            *a, window=4096)
+    ).lower(*args))
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert " copy(" not in text and "transpose(" not in text
@@ -618,6 +624,55 @@ _CELL = dict(vocab_size=32000, hidden_size=4096, intermediate_size=14336,
              sliding_window=4096, max_seq_len=1024, dtype="bfloat16")
 
 
+# EvaByte at the widths of its serve cell (MHA: q, k and v kernels all
+# 4096 x 4096), 16 of 32 layers, 8 slots a step
+_EVA_CELL = dict(vocab_size=320, hidden_size=4096, intermediate_size=11008,
+                 num_layers=16, num_heads=32, num_kv_heads=32, head_dim=128,
+                 rope_theta=100000.0, norm_offset=True, num_pred_heads=8,
+                 attention_class="eva", chunk_size=16, window_size=2048,
+                 fp32_residual=True, fp32_logits=True, max_seq_len=16384,
+                 dtype="bfloat16")
+
+
+def _engine_on_the_v5e(cfg, one_chip, **engine_kw):
+    """An engine at a serve cell's widths and what its programs are lowered
+    over, all placed on the described chip: abstract bf16 ``params``, its
+    ``cache``'s and its ``key``'s shapes, and ``spec(dtype, *shape)`` for
+    the rest. The engine lives where its weights do, one (CPU) device here:
+    it is handed the two leaves an eva engine's roll-over program reads when
+    the engine is built, no more."""
+    from types import SimpleNamespace
+
+    model = CausalLM(cfg)
+    vec = jnp.zeros((cfg.num_layers, cfg.num_kv_heads, cfg.head_dim))
+    held = jax.device_put(
+        {"layers": {"attn": {"mu": vec, "phi": vec}}}, jax.devices()[0])
+    engine = ServingEngine(model, held, block_size=16, **engine_kw)
+
+    def spec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def like(x):
+        return spec(x.dtype, *x.shape)
+
+    params = jax.tree.map(
+        lambda x: spec(jnp.bfloat16, *x.shape),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"])
+    return SimpleNamespace(
+        engine=engine, params=params, cache=jax.tree.map(like, engine.cache),
+        key=like(engine._key), spec=spec)
+
+
+def _decode_args(on):
+    """``_decode_fn``'s arguments at the engine's slots (an eva engine takes
+    the slots' positions after them)."""
+    n, i32 = on.engine.max_slots, jnp.int32
+    return (on.params, on.cache, on.spec(i32, n, 1),
+            on.spec(i32, n, on.engine._max_table), on.spec(i32, n),
+            on.spec(i32, n), on.spec(jnp.float32, n), on.key)
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill_widest"])
 def test_the_engine_program_holds_one_pool_on_the_v5e(
         one_chip, program, monkeypatch):
@@ -627,50 +682,25 @@ def test_the_engine_program_holds_one_pool_on_the_v5e(
     in, and no operation is left whose result is one layer's pool."""
     import re
 
-    from jax.experimental.compilation_cache import compilation_cache
-
-    cfg = TransformerConfig(**_CELL)
-    model = CausalLM(cfg)
-    # the engine lives where its weights do: one (CPU) device here, and
-    # the programs are lowered for the described chip below
-    held = {"w": jax.device_put(jnp.zeros(()), jax.devices()[0])}
-    engine = ServingEngine(model, held, max_slots=16, block_size=16)
+    on = _engine_on_the_v5e(TransformerConfig(**_CELL), one_chip, max_slots=16)
+    engine, spec = on.engine, on.spec
     assert engine.num_blocks == 1025
-
-    def sds(x, dtype=None):
-        return jax.ShapeDtypeStruct(
-            jnp.shape(x), dtype or jnp.result_type(x), sharding=one_chip)
-
-    params = jax.tree.map(
-        lambda x: sds(x, jnp.bfloat16),
-        jax.eval_shape(lambda: model.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"])
-    cache = jax.tree.map(sds, engine.cache)
     layer_pool = jax.tree.leaves(engine.cache)[0].shape[1:]
     assert layer_pool == (1025, 16, 8, 128)
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-    n, table, key = engine.max_slots, engine._max_table, sds(engine._key)
     # dispatch asks the default backend, the CPU here: answer for the chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if program == "decode":
-        lowered = engine._decode_fn.lower(
-            params, cache, i32(n, 1), i32(n, table), i32(n), i32(n), f32(n), key)
+        lowered = engine._decode_fn.lower(*_decode_args(on))
         assert engine.trace_counts()["decode_attn_kernel"] == 1
     else:
+        i32 = jnp.int32
         lowered = engine._prefill_fn.lower(
-            params, cache, i32(1, 1024), i32(1, table), i32(1), i32(1), key,
-            f32(1))
+            on.params, on.cache, spec(i32, 1, 1024),
+            spec(i32, 1, engine._max_table), spec(i32, 1), spec(i32, 1),
+            on.key, spec(jnp.float32, 1))
     monkeypatch.undo()
     assert engine.trace_counts()["kv_in_place"] == 1
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = lowered.compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    compiled = _compiled_for_the_chip(lowered)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == engine.kv_pool_bytes == 1612185600
     if program == "decode":  # under one layer's K pool (it was 471 MB)
@@ -684,6 +714,55 @@ def test_the_engine_program_holds_one_pool_on_the_v5e(
     assert not [line for line in text.splitlines()
                 if a_layers_pool.search(line)]
     assert not re.search(r"= bf16\[24,1025,16,8,128\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("name", ["mistral", "eva"])
+def test_a_decode_step_reads_the_qkv_kernels_where_they_lie_on_the_v5e(
+        one_chip, name, monkeypatch):
+    """PR 31. The engine's own ``jit__decode`` at a serve cell's widths,
+    compiled for the described chip: no operation's result is ONE layer's
+    q, k or v kernel (the parent sliced each out of the stacked parameter and
+    copied it transposed, every layer of every step), and the three
+    projections are fusions that take the stacked kernel itself, as o_proj's
+    always did. The pool stays one buffer and the temporaries stay small.
+    The eva engine steps its cell's 8 slots over a pool of ONE slot's blocks
+    (0.8 GB of zeros here, not 6.2): at one slot XLA multiplies a vector,
+    and never made the copies."""
+    import re
+
+    eva = name == "eva"
+    cfg = TransformerConfig(**(_EVA_CELL if eva else _CELL))
+    on = _engine_on_the_v5e(
+        cfg, one_chip,
+        **(dict(max_slots=8, num_blocks=185) if eva else dict(max_slots=16)))
+    engine, args = on.engine, _decode_args(on)
+    if eva:  # the positions the slots' rows stand for
+        args += (on.spec(jnp.int32, engine.max_slots),)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered = engine._decode_fn.lower(*args)
+    monkeypatch.undo()
+    counts = engine.trace_counts()
+    assert counts["qkv_in_place"] == counts["decode"] == 1
+    compiled = _compiled_for_the_chip(lowered)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == engine.kv_pool_bytes
+    assert memory.temp_size_in_bytes < 1 << 20
+    text = compiled.as_text()
+    one_layers_kernel = re.compile(
+        r"= bf16\[1,4096,(4096|1024)\]\S* (copy|fusion)\(")
+    assert not [line for line in text.splitlines()
+                if one_layers_kernel.search(line)]
+    defined = dict(re.findall(r"^\s*(?:ROOT )?(%\S+) = (\S+) ", text, re.M))
+    stacked = re.compile(rf"bf16\[{cfg.num_layers},4096,(4096|1024)\]")
+    for proj in ("q_proj", "k_proj", "v_proj"):
+        fusions = [
+            line for line in text.splitlines()
+            if f"attn/{proj}/dot_general" in line and " fusion(" in line
+            and "kind=kOutput" in line]
+        assert len(fusions) == 1, (proj, fusions)
+        operands = re.search(r" fusion\(([^)]*)\)", fusions[0]).group(1)
+        assert [op for op in operands.split(", ")
+                if stacked.match(defined.get(op, ""))], (proj, fusions[0])
 
 
 # ---------------------------------------------------------------------- #
