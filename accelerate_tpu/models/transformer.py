@@ -866,9 +866,12 @@ class MoE(nn.Module):
     Sown under ``intermediates`` (``CausalLM.loss_fn(model, with_aux=True)``
     returns their means over the expert layers): ``moe_aux_loss``, and from
     the ragged path ``moe_local_choice_share``,
-    ``moe_expert_load_max_over_mean``, ``moe_rows_computed_over_needed``
-    and ``moe_rest_window_share`` (the share of the expert layers whose
-    rows past the first window ran: ``ops.moe.ragged_load_stats``).
+    ``moe_expert_load_max_over_mean``, ``moe_rows_computed_over_needed``,
+    ``moe_rest_window_share`` (the share of the expert layers whose
+    rows past the first window ran: ``ops.moe.ragged_load_stats``) and
+    ``moe_width_computed_over_published`` (the width the grouped matmuls
+    are given over the experts' own: 1.0 unless ``ops.moe.
+    padded_expert_shape`` puts it on whole tiles; 1.103 at 1856).
     """
 
     config: TransformerConfig
@@ -964,7 +967,9 @@ class MoE(nn.Module):
                 "live ep axis"
             )
         if dispatch == "ragged":
-            from ..ops.moe import moe_ragged, moe_ragged_ep
+            from ..ops.moe import (
+                moe_ragged, moe_ragged_ep, padded_expert_shape,
+            )
 
             if ep_live and not gated:
                 raise NotImplementedError(
@@ -1001,6 +1006,9 @@ class MoE(nn.Module):
                     sel, E, cfg.moe_expert_offset, R
                 ).items():
                     self.sow("intermediates", name, value)
+                self.sow(
+                    "intermediates", "moe_width_computed_over_published",
+                    jnp.float32(padded_expert_shape(h, f)[1] / f))
         elif dispatch == "capacity":
             def experts_fn(buf):  # (E, C, h) -> (E, C, h)
                 hidden = jnp.einsum("ech,ehf->ecf", buf, w_gate.astype(dtype))

@@ -70,6 +70,66 @@ _WINDOW_OVER_EVEN_SHARE = 2
 _WINDOW_LEAST_SHARE = 0.25  # of the T*K sorted rows; a thinner window: none
 _WINDOW_MULTIPLE = 512  # rows: a window is whole tiles at any tiling XLA picks
 
+# XLA:TPU's grouped matmul wants both sides of an expert's kernel in whole
+# tiles of 512. ``ragged_dot`` alone on the v5e, bf16, 9 groups over 98,304
+# rows, the six kernels of a non-gated expert (up and down, forward and to
+# rows and to weights) together: hidden 2688 x width 1856 (14.5 lanes of 128)
+# 202.3 ms = 15 % of the peak; 2688 x 1920 (15 lanes) 198.6; 2688 x 2048
+# 90.4; 2816 x 2048 (whole 256s) 63.7; 3072 x 2048 (whole 512s) 53.1 = 71 %
+# — fewer milliseconds for a seventh more columns and a tenth more width
+# (``ragged_width_on_chip.py``, PERF.md section 6, PR 35). So 512 and not 128
+# or 256: 1920 buys nothing and 2816 is a fifth slower than 3072. A side is
+# padded only where the zeros make it at most a seventh wider: the widest
+# pad measured (2688 -> 3072), not a break-even — past it nothing was read.
+_TILE = 512
+_PAD_AT_MOST = 7  # a side grows by at most one part in this many
+
+
+def _tiled(n: int) -> int:
+    """``n`` on whole tiles where that costs at most a seventh more of it."""
+    padded = _TILE * math.ceil(n / _TILE)
+    return padded if _PAD_AT_MOST * padded <= (_PAD_AT_MOST + 1) * n else n
+
+
+def padded_expert_shape(h: int, f: int) -> tuple:
+    """``(h', f')``: the sides :func:`moe_ragged`'s grouped matmuls are given
+    for experts of width ``f`` over a hidden size ``h``. A width that is a
+    whole number of 128 lanes is left as it is, and the hidden size with it:
+    such a layer's program does not move. Any other width goes to the next
+    multiple of 512, and then the hidden size too, each only where the zeros
+    make it at most a seventh wider (1856 -> 2048 and 2688 -> 3072; 8, 16 or
+    130 stay). A constant of the shapes."""
+    wide = _tiled(f)
+    if f % 128 == 0 or wide == f:
+        return h, f
+    return _tiled(h), wide
+
+
+def _on_whole_tiles(x, w_gate, w_up, w_down, zero_groups: int = 0):
+    """Tokens ``x`` (T, h) and the stacked expert kernels as the grouped
+    matmuls get them: ``zero_groups`` more experts of zero weights behind the
+    held ones, and both sides on :func:`padded_expert_shape` by zeros — one
+    ``jnp.pad`` a kernel over the expert axis and the sides together. Exact:
+    a padded column of ``x`` meets zero rows of ``w_up``, a padded column of
+    ``up x`` is 0 and ``activation(0)`` meets zero rows of ``w_down``; the
+    pad's transpose is a slice, so the zeros' cotangents are dropped. Where
+    nothing is padded the zero group is the ``concatenate`` it always was."""
+    h, f = w_up.shape[1:]
+    more_h, more_f = (p - n for p, n in zip(padded_expert_shape(h, f), (h, f)))
+    if more_f:
+        if more_h:
+            x = jnp.pad(x, ((0, 0), (0, more_h)))
+        up = ((0, zero_groups), (0, more_h), (0, more_f))
+        down = ((0, zero_groups), (0, more_f), (0, more_h))
+        return (x, None if w_gate is None else jnp.pad(w_gate, up),
+                jnp.pad(w_up, up), jnp.pad(w_down, down))
+    if zero_groups:
+        w_gate, w_up, w_down = (
+            None if w is None else jnp.concatenate(
+                [w, jnp.zeros((zero_groups,) + w.shape[1:], w.dtype)])
+            for w in (w_gate, w_up, w_down))
+    return x, w_gate, w_up, w_down
+
 
 def share_window_rows(num_choices: int, num_experts: int, router_width: int) -> int:
     """Rows of :func:`moe_ragged`'s first window when a layer holds
@@ -86,12 +146,14 @@ def share_window_rows(num_choices: int, num_experts: int, router_width: int) -> 
 
 
 def _expert_rows(xs, tok, order, weights, group_sizes, w_gate, w_up, w_down,
-                 num_tokens, activation=None):
+                 shape, activation=None):
     """A run of sorted rows ``xs`` (every one in a group) through the three
     grouped matmuls — two where the experts have no gate matrix (``w_gate``
     None: ``down(activation(up x))``) —, scattered back onto their tokens
     ``tok`` with the routing weights of the sorted choices ``order``:
-    (num_tokens, h)."""
+    ``shape`` = (num_tokens, h). Rows and kernels come as
+    :func:`_on_whole_tiles` made them: columns past ``h`` are zeros and are
+    dropped before the scatter."""
     with jax.named_scope("experts"):
         if w_gate is None:
             hidden = activation(jax.lax.ragged_dot(xs, w_up, group_sizes))
@@ -103,10 +165,10 @@ def _expert_rows(xs, tok, order, weights, group_sizes, w_gate, w_up, w_down,
 
     with jax.named_scope("combine"):
         w_flat = weights.reshape(-1)[order].astype(out.dtype)
+        out = out[:, :shape[1]]  # a slice of every column traces to nothing
         # weighted scatter-add back into token order (sums the K expert
         # contributions per token)
-        return jnp.zeros((num_tokens, xs.shape[-1]), out.dtype).at[tok].add(
-            out * w_flat[:, None])
+        return jnp.zeros(shape, out.dtype).at[tok].add(out * w_flat[:, None])
 
 
 def moe_ragged(
@@ -173,6 +235,24 @@ def moe_ragged(
     zeros a branch not taken writes for its residuals (my chip runs, PR 29;
     PERF.md section 6).
 
+    **Whole tiles.** Where the experts' width ``f`` is no whole number of
+    128 lanes, tokens and kernels reach the grouped matmuls padded by zeros
+    to :func:`padded_expert_shape` — ``f`` to the next multiple of 512 and
+    then ``h`` too, each where that is at most a seventh wider; one
+    ``jnp.pad`` a kernel in the rebuild that adds the zero group, one of
+    ``x`` before the gather, the padded columns dropped before the
+    scatter-add. The parameters, their gradients and the result keep the
+    published shapes and values (zeros meet zeros; the sums may be taken in
+    another order); a width of whole lanes lowers to the text it had. On the
+    v5e at Nemotron-3-Nano's widths (h 2688, f 1856 = 14.5 lanes; 8 of 128
+    experts held, 2 x 8192 tokens, top 6: all 98,304 sorted rows a layer)
+    ``ragged_dot``'s six kernels alone took 202.3 ms at 2688 x 1856, 90.4 at
+    2688 x 2048 and 53.1 at 3072 x 2048 (my chip runs, PR 35, calls 1-2);
+    in the training step of four such layers the pad took XLA's
+    ``ragged-dot`` kernels from 1,021 to 271.5 ms and the step
+    from 1,710.6 to 987.4 ms (my chip runs, PR 35, call 3;
+    PERF.md section 6).
+
     Measured on v5e (bf16, B=16, S=1024, E=8, K=2, round-4 sweep): at
     Mixtral-width experts (h=4096, f=3584, L=1) ragged reaches 0.516 MFU
     vs capacity-1.25's 0.490 (no remat) / 0.475 (remat="dots") — ~5-9%
@@ -196,7 +276,7 @@ def moe_ragged(
     ``x``: (T, h); ``sel``/``weights``: (T, K); ``w_gate``/``w_up``:
     (E, h, f); ``w_down``: (E, f, h). Returns (T, h).
     """
-    T, h = x.shape
+    T, h = x.shape  # the published h: what comes back, whatever is multiplied
     K = sel.shape[-1]
     E = w_up.shape[0]
     TK = T * K
@@ -208,18 +288,16 @@ def moe_ragged(
         tok = jnp.repeat(jnp.arange(T), K)[order]  # source token per sorted row
     if R == E:  # every choice is of a held expert: one run of T*K rows
         with jax.named_scope("dispatch"):
+            x, w_gate, w_up, w_down = _on_whole_tiles(x, w_gate, w_up, w_down)
             xs = jnp.take(x, tok, axis=0)  # (TK, h) rows grouped by expert
             group_sizes = jnp.bincount(flat_sel, length=E + 1).astype(jnp.int32)
         return _expert_rows(xs, tok, order, weights, group_sizes[:E],
-                            w_gate, w_up, w_down, T, activation)
+                            w_gate, w_up, w_down, (T, h), activation)
 
     with jax.named_scope("dispatch"):
         # one more group, of zero weights, for the choices of absent experts
-        w_gate, w_up, w_down = (
-            None if w is None else
-            jnp.concatenate([w, jnp.zeros((1,) + w.shape[1:], w.dtype)])
-            for w in (w_gate, w_up, w_down)
-        )
+        x, w_gate, w_up, w_down = _on_whole_tiles(
+            x, w_gate, w_up, w_down, zero_groups=1)
         held = jnp.bincount(flat_sel, length=E + 1).astype(jnp.int32)[:E]
         ends = jnp.cumsum(held)  # where each held expert's sorted rows end
         starts = ends - held
@@ -233,7 +311,7 @@ def moe_ragged(
             sizes = jnp.concatenate([sizes, (hi - lo - jnp.sum(sizes))[None]])
             xs = jnp.take(x, tok[rows], axis=0)  # (hi - lo, h)
         return _expert_rows(xs, tok[rows], order[rows], weights, sizes,
-                            w_gate, w_up, w_down, T, activation)
+                            w_gate, w_up, w_down, (T, h), activation)
 
     C = share_window_rows(TK, E, R)
     out = window(0, C)
@@ -331,7 +409,10 @@ def moe_ragged_ep(
     context-mesh pattern as ring attention under pp, with
     ``check_vma=True`` — its transpose is what makes the backward
     correct). ``x``: (T, h) global; ``w_*``: (E, h, f)/(E, f, h) with E
-    sharded over ep; returns (T, h).
+    sharded over ep; returns (T, h). Rows and kernels reach the three
+    grouped matmuls on whole tiles where ``moe_ragged``'s would
+    (:func:`padded_expert_shape`; no cell runs this schedule, so the gain
+    there is the single-chip one's, not measured here).
     """
     from jax.sharding import PartitionSpec as P
 
@@ -364,6 +445,9 @@ def moe_ragged_ep(
         )
         tok_win = jax.lax.dynamic_slice(pad(tok), (off_s,), (C_s,))
         w_win = jax.lax.dynamic_slice(pad(w_flat), (off_s,), (C_s,))
+        # the dummy group's zero weights, and both sides of the kernels on
+        # whole tiles where moe_ragged would put them there
+        xl, wg, wu, wd = _on_whole_tiles(xl, wg, wu, wd, zero_groups=1)
         xs = jnp.take(xl, tok_win, axis=0)  # (C_s, h)
 
         # local group sizes clipped into the window + dummy tail group
@@ -377,14 +461,11 @@ def moe_ragged_ep(
         gs = (ends - starts).astype(jnp.int32)
         gs = jnp.concatenate([gs, (C_s - jnp.sum(gs))[None].astype(jnp.int32)])
 
-        zed = jnp.zeros((1,) + wg.shape[1:], wg.dtype)
         hidden = jax.nn.silu(
-            jax.lax.ragged_dot(xs, jnp.concatenate([wg, zed]), gs)
-        ) * jax.lax.ragged_dot(xs, jnp.concatenate([wu, zed]), gs)
-        out = jax.lax.ragged_dot(
-            hidden, jnp.concatenate([wd, jnp.zeros((1,) + wd.shape[1:], wd.dtype)]),
-            gs,
-        )  # (C_s, h); dummy-group rows are exact zeros
+            jax.lax.ragged_dot(xs, wg, gs)
+        ) * jax.lax.ragged_dot(xs, wu, gs)
+        # (C_s, h): dummy-group rows are exact zeros, padded columns dropped
+        out = jax.lax.ragged_dot(hidden, wd, gs)[:, :h]
 
         contrib = jnp.zeros((T, h), out.dtype).at[tok_win].add(
             out * w_win[:, None].astype(out.dtype)

@@ -582,3 +582,213 @@ def test_first_gradient_norms_leave_out_the_first_expert_layer_where_it_stands_a
     assert len(left_out) == (5 if layout == "cut" else 0)
     assert kept == every - left_out
     assert set(ref.param_change_leaf_norms(cfg, SEED, tree)) == every
+
+
+# --------------------------------------------------------------------------- #
+# an expert width that is no whole number of lanes, on whole tiles
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("h,f,want", [
+    (2688, 1856, (3072, 2048)),   # train-ssm-moe-1chip: both sides padded
+    (16, 464, (16, 512)),         # the cell's own ratio, 29 : 32; h 16 stays
+    (448, 464, (512, 512)),       # ... and 7 : 8 on the hidden side: the widest pad
+    (440, 464, (440, 512)),       # a hidden side that would grow by more than a seventh
+    (2048, 1792, (2048, 1792)),   # train-moe-conv-1chip: whole lanes, 3.5 tiles
+    (4096, 14336, (4096, 14336)),  # Mixtral
+    (2688, 1920, (2688, 1920)),   # whole lanes: left, and the hidden size with it
+    (2688, 3712, (2688, 3712)),
+    (16, 256, (16, 256)), (16, 512, (16, 512)),
+    (16, 8, (16, 8)), (16, 16, (16, 16)), (48, 40, (48, 40)),
+    (16, 130, (16, 130)),         # more than a seventh to pad
+    (16, 447, (16, 447)), (16, 448, (16, 512)), (16, 1800, (16, 2048)),
+])
+def test_only_a_width_off_the_lanes_and_near_a_tile_is_padded(h, f, want):
+    """A rule of the two sides alone: whole lanes (any multiple of 128) stay
+    as they are and so does their hidden size; any other width goes to the
+    next multiple of 512 where that is at most a seventh wider, and then
+    the hidden size by the same bound."""
+    from accelerate_tpu.ops.moe import padded_expert_shape
+
+    assert padded_expert_shape(h, f) == want
+
+
+def _plain_experts(x, sel, weights, w_gate, w_up, w_down, offset, activation):
+    """The published product written out: each held expert over every token
+    at its own width, combined by the weights of the choices on it."""
+    out = 0.0
+    for e in range(w_up.shape[0]):
+        up = x @ w_up[e]
+        hidden = activation(up) if w_gate is None else jax.nn.silu(x @ w_gate[e]) * up
+        on_e = jnp.sum(jnp.where(sel == e + offset, weights, 0.0), -1)
+        out = out + (hidden @ w_down[e]) * on_e[:, None]
+    return out
+
+
+def _relu2(v):
+    return jnp.square(jax.nn.relu(v))
+
+
+def _off_lane_case(h, f, held, width, gated, t=96, k=4, seed=3):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    sel = jax.random.randint(ks[0], (t, k), 0, width)
+    w_gate = jax.random.normal(ks[1], (held, h, f)) * h ** -0.5 if gated else None
+    return sel, (jax.random.normal(ks[2], (t, h)), jax.random.uniform(ks[3], (t, k)),
+                 w_gate, jax.random.normal(ks[4], (held, h, f)) * h ** -0.5,
+                 jax.random.normal(ks[5], (held, f, h)) * f ** -0.5), \
+        jax.random.normal(ks[6], (t, h))
+
+
+@pytest.mark.parametrize("h,f", [(16, 464), (448, 464)], ids=["width", "both_sides"])
+@pytest.mark.parametrize("held,width", [(4, 16), (4, 4)], ids=["a_share", "every_expert"])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_the_padded_product_equals_the_published_one(gated, held, width, h, f, monkeypatch):
+    """Value and ``jax.grad`` to x, the routing weights, w_up, w_down (and
+    w_gate) of ``moe_ragged`` at a width the rule pads (464 -> 512, with the
+    hidden side 448 -> 512 or 16 left), float32: equal to the dense oracle
+    at the published width, and to the SAME program with the pad switched
+    off within 2e-6 of the largest entry (float32 sums of 512 terms for 448
+    or 464, in another order); the gradients keep the operands' shapes (the
+    pad's transpose is a slice)."""
+    from accelerate_tpu.ops import moe
+
+    sel, operands, cot = _off_lane_case(h, f, held, width, gated)
+    act = None if gated else _relu2
+    assert moe.padded_expert_shape(h, f) == (512 if h == 448 else h, 512)
+    argnums = tuple(i for i, a in enumerate(operands) if a is not None)
+
+    def run(fn):  # the layer's output, then its gradients under one cotangent
+        return jax.jit(lambda *a: (fn(*a), jax.grad(
+            lambda *a: jnp.sum(fn(*a) * cot), argnums=argnums)(*a)))(*operands)
+
+    def program(x, weights, w_gate, w_up, w_down):
+        return moe_ragged(x, sel, weights, w_gate, w_up, w_down,
+                          router_width=width, activation=act)
+
+    def oracle(x, weights, w_gate, w_up, w_down):
+        return _plain_experts(x, sel, weights, w_gate, w_up, w_down, 0, act or _relu2)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = run(program), run(oracle)
+        monkeypatch.setattr(moe, "padded_expert_shape", lambda h, f: (h, f))
+        unpadded = run(program)
+    names = ["value", "dx", "dweights"] + ["dw_gate"] * gated + ["dw_up", "dw_down"]
+    for name, g, w, u, a in zip(names, jax.tree.leaves(got), jax.tree.leaves(want),
+                                jax.tree.leaves(unpadded),
+                                [operands[0]] + [operands[i] for i in argnums]):
+        scale = float(jnp.max(jnp.abs(w))) + 1e-9
+        np.testing.assert_allclose(g / scale, u / scale, atol=2e-6, err_msg=name)
+        np.testing.assert_allclose(g / scale, w / scale, atol=2e-5, err_msg=name)
+        assert g.shape == a.shape, name
+
+
+@pytest.mark.parametrize("held,width,rows", [
+    (8, 128, [3072]), (8, 32, [1536, 1536]), (8, 8, [3072])],
+    ids=["8_of_128", "8_of_32", "every_expert"])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_the_grouped_matmuls_are_given_whole_tiles_and_the_same_rows(
+        gated, held, width, rows):
+    """T k = 3072 sorted rows at h 448, f 464: every ``ragged_dot_general``
+    multiplies 512-wide sides — rows (r, 512), kernels (groups, 512, 512) —
+    over the row counts the window rule gives (all of them at 8 of 128 and
+    with every expert held; 1536 and, under the ``cond``, the other 1536 at
+    8 of 32), with ONE pad a kernel and one of the tokens, and no
+    ``concatenate`` of a zero group beside them."""
+    t, k, h, f = 512, 6, 448, 464
+    n = 3 if gated else 2
+    jaxpr = jax.make_jaxpr(lambda x, s, w, *ws: moe_ragged(
+        x, s, w, ws[0] if gated else None, *ws[-2:], router_width=width,
+        activation=None if gated else _relu2))(
+        jnp.zeros((t, h)), jnp.zeros((t, k), jnp.int32), jnp.zeros((t, k)),
+        *[jnp.zeros((held, h, f))] * (n - 1), jnp.zeros((held, f, h))).jaxpr
+    groups = held + (width != held)
+
+    def shapes(j):
+        return [(e.invars[0].aval.shape, e.invars[1].aval.shape)
+                for e in _eqns(j, "ragged_dot_general")]
+
+    assert shapes(jaxpr) == [((rows[0], 512), (groups, 512, 512))] * n
+    conds = _eqns(jaxpr, "cond")
+    assert len(conds) == len(rows) - 1
+    for cond, r in zip(conds, rows[1:]):
+        taken = max((b.jaxpr for b in cond.params["branches"]),
+                    key=lambda j: len(j.eqns))
+        assert shapes(taken) == [((r, 512), (groups, 512, 512))] * n
+    pads = [e for e in jaxpr.eqns
+            if "pad" in (e.primitive.name, str(e.params.get("name", "")).strip("_"))]
+    assert len(pads) == n + 1  # the kernels and the tokens, once a call
+    kernel_concats = [e for e in _eqns(jaxpr, "concatenate")
+                      if len(e.outvars[0].aval.shape) == 3]
+    assert not kernel_concats
+
+
+# sha256[:16] of jit(moe_ragged).lower(...).as_text() at bdfc916, the parent of
+# the PR that brought the pad (T 64, k 2, 4 experts, h 16, float32), by
+# (router width, gated, expert width); ungated with activation=jax.nn.relu
+_UNPADDED_LOWERED = {
+    (None, True, 8): "6c366365cc27260e", (None, True, 130): "4ea19eb26dfe0c1d",
+    (None, True, 256): "74802344c815b38d", (None, False, 8): "876c302639b0eb45",
+    (None, False, 130): "6b295dc839f6d3dd", (None, False, 256): "10fc94b392bd4e51",
+    (16, True, 8): "a3e56a86948700dd", (16, True, 130): "fd9c69cab2e5c970",
+    (16, True, 256): "3fce1014377733be", (16, False, 8): "808089419c781cff",
+    (16, False, 130): "5f1c8499b402b2bc", (16, False, 256): "107c8077ee1acc6e",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_UNPADDED_LOWERED, key=str), ids=str)
+def test_a_width_the_rule_leaves_lowers_to_the_parents_text(case):
+    """Whole lanes (256), and widths too far from a tile (8, 130): with a
+    share held (the zero group is still a ``concatenate``) and with every
+    expert held, gated and not, the lowered text is byte for byte the
+    parent's."""
+    router_width, gated, f = case
+    t, k, e, h = 64, 2, 4, 16
+    sds = jax.ShapeDtypeStruct
+    kernels = [sds((e, h, f), jnp.float32)] * (2 if gated else 1) + [
+        sds((e, f, h), jnp.float32)]
+    if gated:
+        fn = lambda *a: moe_ragged(*a, router_width=router_width)  # noqa: E731
+    else:
+        fn = lambda x, s, w, wu, wd: moe_ragged(  # noqa: E731
+            x, s, w, None, wu, wd, router_width=router_width, activation=jax.nn.relu)
+    text = jax.jit(fn).lower(
+        sds((t, h), jnp.float32), sds((t, k), jnp.int32), sds((t, k), jnp.float32),
+        *kernels).as_text()
+    assert "stablehlo.pad" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == _UNPADDED_LOWERED[case]
+    assert _ALL_HELD_LOWERED.startswith(_UNPADDED_LOWERED[(None, True, 8)])
+
+
+@pytest.mark.parametrize("f,ratio", [(464, 512 / 464), (8, 1.0), (256, 1.0)])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_the_layer_keeps_its_tree_and_sows_the_width_it_computed(gated, f, ratio):
+    """Through the ``MoE`` module: the parameters and their gradients keep
+    the published shapes (the pad lives between the parameters and the
+    grouped matmuls), and ``moe_width_computed_over_published`` is sown
+    beside ``ragged_load_stats``' four: 512 / 464 where the rule pads, 1.0
+    where it does not."""
+    h, held = 16, 2
+    cfg = TransformerConfig.tiny(
+        hidden_size=h, moe_intermediate_size=f, num_experts=held,
+        moe_router_width=8, moe_expert_offset=2, num_experts_per_tok=4,
+        moe_router="sigmoid", mlp_gated=gated,
+        mlp_activation="silu" if gated else "relu2")
+    moe = MoE(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, h))
+    params = nn.unbox(moe.init(jax.random.PRNGKey(1), x)["params"])
+    want = {"router": {"kernel": (h, 8)}, "up_proj": (held, h, f),
+            "down_proj": (held, f, h)}
+    if gated:
+        want["gate_proj"] = (held, h, f)
+    assert jax.tree.map(lambda p: p.shape, params) == want
+
+    def loss(p):
+        out, sown = moe.apply({"params": p}, x, mutable=["intermediates"])
+        return jnp.sum(out ** 2), sown["intermediates"]
+
+    (_, sown), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    assert jax.tree.map(lambda g: g.shape, grads) == want
+    assert all(np.any(np.asarray(g)) for g in jax.tree.leaves(grads))
+    assert {"moe_local_choice_share", "moe_expert_load_max_over_mean",
+            "moe_rows_computed_over_needed", "moe_rest_window_share",
+            "moe_width_computed_over_published"} <= set(sown)
+    np.testing.assert_allclose(
+        float(sown["moe_width_computed_over_published"][0]), ratio, rtol=1e-6)

@@ -294,6 +294,69 @@ def test_auto_dispatch_resolves_to_ragged_under_ep():
     PartialState._reset_state()
 
 
+@pytest.mark.parametrize("h,f,sides", [(16, 464, (16, 512)), (448, 464, (512, 512)),
+                                       (16, 32, (16, 32))])
+def test_ragged_ep_runs_a_width_off_the_lanes_on_whole_tiles(h, f, sides):
+    """moe_ragged_ep puts its three grouped matmuls on the sides moe_ragged
+    would (``ops.moe.padded_expert_shape``: 464 -> 512, and 448 -> 512 with
+    it; 32 is left), zeros that change nothing: with the window covering
+    every row the result and the gradients equal the dense oracle at the
+    published width, and the gradients keep the kernels' shapes."""
+    from accelerate_tpu import Accelerator
+    from accelerate_tpu.ops.moe import moe_ragged_ep, padded_expert_shape
+    from accelerate_tpu.state import AcceleratorState, GradientState, PartialState
+    from accelerate_tpu.utils.dataclasses import ParallelismPlugin, ShardingStrategy
+
+    AcceleratorState._reset_state()
+    GradientState._reset_state()
+    PartialState._reset_state()
+    acc = Accelerator(
+        parallelism_plugin=ParallelismPlugin(
+            dp_size=4, ep_size=2,
+            sharding_strategy=ShardingStrategy.NO_SHARD,
+        )
+    )
+    assert padded_expert_shape(h, f) == sides
+    T, E, K = 64, 4, 2
+    ks = jax.random.split(jax.random.PRNGKey(1), 6)
+    x = jax.random.normal(ks[0], (T, h))
+    sel = jax.random.randint(ks[1], (T, K), 0, E)
+    weights = jax.random.uniform(ks[2], (T, K))
+    wg = jax.random.normal(ks[3], (E, h, f)) / np.sqrt(h)
+    wu = jax.random.normal(ks[4], (E, h, f)) / np.sqrt(h)
+    wd = jax.random.normal(ks[5], (E, f, h)) / np.sqrt(f)
+
+    def ragged(x, wg, wu, wd):
+        return moe_ragged_ep(x, sel, weights, wg, wu, wd, mesh=acc.mesh,
+                             capacity_factor=2.0)  # == ep: nothing can drop
+
+    def oracle(x, wg, wu, wd):
+        out = 0.0
+        for e in range(E):
+            on_e = jnp.sum(jnp.where(sel == e, weights, 0.0), -1)
+            out = out + on_e[:, None] * (
+                (jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
+        return out
+
+    def both(fn):
+        return jax.jit(lambda *a: (fn(*a), jax.grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2, 3))(*a)))(
+                x, wg, wu, wd)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = both(ragged), both(oracle)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape
+        scale = float(jnp.max(jnp.abs(w))) + 1e-8
+        np.testing.assert_allclose(
+            np.asarray(g) / scale, np.asarray(w) / scale, atol=2e-5)
+    text = jax.jit(ragged).lower(x, wg, wu, wd).as_text()
+    assert ("stablehlo.pad" in text) == (sides != (h, f))
+    AcceleratorState._reset_state()
+    GradientState._reset_state()
+    PartialState._reset_state()
+
+
 def test_ragged_ep_shard_capacity_drops_overflow():
     """With a tight window (capacity_factor < needed) overflow rows drop
     to zero contribution — graceful degradation, not corruption."""

@@ -657,3 +657,65 @@ def test_the_flash_share_reads_the_flash_kernels_and_counts_one_attention_layer(
     # seven causal matmuls of 2 x 4096.5 keys x 128 a query row and head
     assert need["flops"] == 7 * 2.0 * 2 * 8192 * 32 * 128 * 4096.5
     assert need["bytes"] == 6.0 * 2 * 8192 * (32 + 2) * 128 * 2
+
+
+# --------------------------------------------------------------------------- #
+# the experts' width on whole tiles (ops.moe.padded_expert_shape)
+# --------------------------------------------------------------------------- #
+def test_the_cell_pads_both_sides_and_the_tiny_stack_neither():
+    """The published 2688 x 1856 goes to 3072 x 2048 (the width is 14.5
+    lanes; a tenth and a seventh of zeros); the shared expert's 3712 and the
+    tiny stack's 48 x 40 are left, so every test above runs the program it
+    ran; the needed work counts the published width whatever is multiplied."""
+    from accelerate_tpu.ops.moe import padded_expert_shape
+
+    real = tiny.real()
+    h, f = real["hidden_size"], real["moe_intermediate_size"]
+    assert padded_expert_shape(h, f) == (3072, 2048)
+    assert padded_expert_shape(h, real["moe_shared_expert_intermediate_size"])[1] == 3712
+    cfg = tiny.config()
+    assert padded_expert_shape(cfg["hidden_size"], cfg["moe_intermediate_size"]) == (48, 40)
+    needed = work.moe_experts_work(real, {"tokens_per_step_per_chip": 16384})
+    # 4 expert layers, 6 x 8 / 128 local choices a token, two products, x 3
+    # for the backward pass: at the published 2688 x 1856
+    assert needed["flops"] == 3 * 4 * (6 * 8 / 128) * 2 * (2 * 2688 * 1856) * 16384
+
+
+@pytest.mark.parametrize("f,ratio", [(40, 1.0), (464, 512 / 464)])
+def test_the_stack_returns_the_width_it_computed_beside_the_other_counters(f, ratio):
+    """``loss_fn(with_aux=True)`` hands ``moe_width_computed_over_published``
+    out with the load counters: 1.0 at the tiny width, 512 / 464 at a width
+    the rule pads — the same value in every expert layer, so its mean —, and
+    the padded stack's loss and gradients are finite with the seeded tree's
+    shapes."""
+    cfg = tiny.config(pattern="ME", moe_intermediate_size=f)
+    model = _model(cfg)
+    params = W.make_tree(cfg, SEED, jnp.float32)
+    batch = {"input_ids": _ids(cfg, rows=2, seq=24)}
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        CausalLM.loss_fn(model, with_aux=True), has_aux=True))(params, batch)
+    assert np.isfinite(float(loss))
+    np.testing.assert_allclose(
+        float(aux["moe_width_computed_over_published"]), ratio, rtol=1e-6)
+    assert {"moe_local_choice_share", "moe_rows_computed_over_needed"} <= set(aux)
+    assert jax.tree.map(jnp.shape, grads) == jax.tree.map(jnp.shape, params)
+    assert all(np.all(np.isfinite(np.asarray(g))) for g in jax.tree.leaves(grads))
+
+
+def test_the_cells_grouped_matmuls_are_given_whole_tiles_at_its_real_shapes():
+    """``train-ssm-moe-1chip``'s own gradient, lowered at 2 x 8192 tokens
+    over abstract weights: every stack of expert kernels a grouped matmul
+    sees — the eight held experts' and the zero group's, forward and
+    backward — is 3072 x 2048 or 2048 x 3072, none the published 2688 x
+    1856; the parameters and their gradients keep the published shape."""
+    cell = cells.load_cell(tiny.TWIN)
+    spec, cfg = cell["spec"], cell["config"]
+    seq = spec["traffic"]["seq_len"]
+    model = CausalLM(common.program_config(
+        cfg, max_seq_len=seq, remat=spec["remat"], dtype=spec["compute_dtype"]))
+    ids = jax.ShapeDtypeStruct((spec["rows_per_chip"], seq), jnp.int32)
+    text = jax.jit(jax.grad(CausalLM.loss_fn(model))).lower(
+        W.abstract_tree(cfg, jnp.float32), {"input_ids": ids}).as_text()
+    stacks = set(re.findall(r"tensor<9x(\d{4})x(\d{4})xbf16>", text))
+    assert stacks == {("3072", "2048"), ("2048", "3072")}, stacks
+    assert "tensor<8x2688x1856xf32>" in text and "tensor<8x1856x2688xf32>" in text
