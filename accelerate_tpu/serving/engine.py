@@ -271,9 +271,11 @@ class ServingEngine:
         if decode_ahead is None:
             decode_ahead = self._eva is not None
         self.decode_ahead = bool(decode_ahead)
-        # the step dispatched ahead and not yet fetched: (its tokens on
-        # the device, [(slot, request)] it decodes for)
+        # the step dispatched ahead and not yet fetched: ((its tokens on
+        # the device, its dispatch's count), [(slot, request)] it decodes for)
         self._ahead: Optional[tuple] = None
+        # dispatches made so far, by the program's name in the device trace
+        self._dispatched = {"jit__decode": 0, "jit__verify": 0}
         for feature, on in (
             ("prefix_cache", prefix_cache),
             ("spec_decode", spec_decode is not None),
@@ -848,8 +850,8 @@ class ServingEngine:
 
     def _step_inner(self) -> list[TokenEvent]:
         # the phases below are host spans in the profiler's trace
-        # (schedule, prefill per request, decode.inputs, decode.fetch,
-        # emit): they tile the step, so a gap of the chip falls in one
+        # (schedule, prefill per request, decode.inputs, .dispatch, .wait,
+        # .fetch, emit): they tile the step, so a gap of the chip falls in one
         with annotate("atpu:serve.schedule") as phase:
             had_work = self.has_work
             events: list[TokenEvent] = []
@@ -1744,18 +1746,18 @@ class ServingEngine:
 
     def _decode_step(self, active: list[Slot]) -> Callable:
         """The device half of one decode step over the seated batch: the
-        inputs, the dispatch and the fetch of the sampled tokens. Returns
-        the host half, ``emit(events)``, which :meth:`_step_inner` runs
-        in its emit phase. With ``decode_ahead`` the step fetched here was
-        dispatched during the step before, and the one after it goes to
-        the device before the fetch."""
+        inputs, the dispatch, the wait and the fetch of the sampled tokens.
+        Returns the host half, ``emit(events)``, which :meth:`_step_inner`
+        runs in its emit phase. With ``decode_ahead`` the step fetched here
+        was dispatched during the step before, and the one after it goes to
+        the device before the wait."""
         ahead, self._ahead = self._ahead, None
         if ahead is None:
-            out, now = self._dispatch_decode(active), active
+            (out, n), now = self._dispatch_decode(active), active
         else:
             # a slot of it may have ended since, by its eos, and its seat
             # may hold another request: the row it wrote is never read
-            out = ahead[0]
+            out, n = ahead[0]
             now = [s for s, req in ahead[1] if s.request is req and not s.done]
         if self.decode_ahead:
             # the step after this one: every decoding slot but those this
@@ -1774,8 +1776,7 @@ class ServingEngine:
                     self._dispatch_decode(nxt, prev=(out, flying)),
                     [(s, s.request) for s in nxt],
                 )
-        with annotate("atpu:serve.decode.fetch"):
-            out = np.asarray(out)
+        out = self._fetch(out, "jit__decode", n)
 
         def emit(events: list[TokenEvent]) -> None:
             for slot in now:
@@ -1789,6 +1790,32 @@ class ServingEngine:
 
         return emit
 
+    def _dispatch(self, program: str, fn, *args, to_host: bool = False):
+        """Hand one decode step to the device: ``fn`` (the compiled
+        ``program``, as the device trace names it) over the pool and
+        ``args``. Returns its sampled tokens, not fetched, and ``n``, how
+        many dispatches of ``program`` this engine had made before: the
+        span of the call and the span of the wait for it carry both, which
+        is what pairs them with each other and with the program's n-th
+        execution on the device."""
+        n = self._dispatched[program]
+        self._dispatched[program] = n + 1
+        with annotate("atpu:serve.decode.dispatch", program=program, n=n):
+            self.cache, out = fn(self.params, self.cache, *args)
+            if to_host:
+                out.copy_to_host_async()  # behind the step, no round trip
+        return out, n
+
+    def _fetch(self, out: jax.Array, program: str, n: int) -> np.ndarray:
+        """The sampled tokens of dispatch ``n`` of ``program`` on the host:
+        the wait for the device (it finishes, the runtime tells the host,
+        the thread wakes), then the copy of an array that is ready."""
+        with annotate("atpu:serve.decode.wait", program=program, n=n) as span:
+            if span.is_enabled():
+                out.block_until_ready()
+        with annotate("atpu:serve.decode.fetch"):
+            return np.asarray(out)
+
     def _fills_window(self, slot: Slot, ahead: int = 0) -> bool:
         """eva: ``slot`` (``ahead`` positions from now) stands past a
         window whose summaries are not in its table yet."""
@@ -1796,13 +1823,15 @@ class ServingEngine:
             (slot.cache_len + ahead) // self._eva.window > slot.windows_done
         )
 
-    def _dispatch_decode(self, slots: list[Slot], prev=None) -> jax.Array:
+    def _dispatch_decode(self, slots: list[Slot], prev=None) -> tuple:
         """Put one decode step over ``slots`` on the device; returns its
-        sampled tokens, not fetched. ``prev`` (``decode_ahead``): the step
+        sampled tokens, not fetched, and the dispatch's count
+        (:meth:`_dispatch`). ``prev`` (``decode_ahead``): the step
         before's tokens, on the device and not fetched either, and the
         indices of the slots it decodes: each of them stands one position
         past what the host has emitted, and feeds on that step's token."""
         prev_out, flying = prev if prev is not None else (None, ())
+        # everything before the call: the rows, the puts, the fed tokens
         with annotate("atpu:serve.decode.inputs", seated=len(slots)) as phase:
             tokens = np.zeros((self.max_slots, 1), np.int32)
             cache_lens = np.zeros(self.max_slots, np.int32)
@@ -1841,17 +1870,17 @@ class ServingEngine:
                     self._no_tokens if prev_out is None else prev_out,
                     tokens, from_prev,
                 )
-            self.cache, out = self._decode_fn(
-                self.params, self.cache, fed,
-                self._tables_device(), jnp.asarray(cache_lens),
+            args = (
+                fed, self._tables_device(), jnp.asarray(cache_lens),
                 jnp.asarray(lengths), self.sampling.temperatures(),
                 self._split_key(),
                 None if positions is None else jnp.asarray(positions),
                 *self._lora_call_args(self._slot_adapter),
             )
-            if self._feed_fn is not None:
-                out.copy_to_host_async()  # behind the step, no round trip
-        return out
+        return self._dispatch(
+            "jit__decode", self._decode_fn, *args,
+            to_host=self._feed_fn is not None,
+        )
 
     def _roll_over(self, slot: Slot) -> None:
         """eva: ``slot`` has just filled a window. Its ``window_blocks``
@@ -1934,21 +1963,21 @@ class ServingEngine:
                 # would cost width+1 dispatches on the hottest loop in
                 # serving)
                 keys = np.stack(self._peek_keys(width))
-                self.cache, out = vfn(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    self._tables_device(), jnp.asarray(cache_lens),
-                    jnp.asarray(lengths), self.sampling.temperatures(),
-                    jnp.asarray(keys),
+                args = (
+                    jnp.asarray(tokens), self._tables_device(),
+                    jnp.asarray(cache_lens), jnp.asarray(lengths),
+                    self.sampling.temperatures(), jnp.asarray(keys),
                     *self._lora_call_args(self._slot_adapter),
                 )
         if not drafted_any:
             # nothing proposed this round (n-gram miss everywhere): the
             # plain decode program is the cheaper identical-output path,
             # and it consumes one chain key exactly like a 0-draft verify
+            # (its dispatch is jit__decode's and counts there)
             self._spec_rounds_total += 1
             return self._decode_step(active)
-        with annotate("atpu:serve.decode.fetch"):
-            out = np.asarray(out)
+        out, n = self._dispatch("jit__verify", vfn, *args)
+        out = self._fetch(out, "jit__verify", n)
 
         def emit(events: list[TokenEvent]) -> None:
             max_emitted = 1
@@ -2191,6 +2220,8 @@ class ServingEngine:
         return fields
 
     def _sample_gauges(self) -> None:
+        if self._telemetry is None:
+            return  # no collector: no record is built
         self._tele("record_serve_gauge", **self._gauge_fields())
         # piggy-back the HBM census on the gauge cadence (the census's
         # own wall-clock throttle bounds the walk rate)

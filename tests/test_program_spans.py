@@ -2,15 +2,20 @@
 ``ServingEngine.step`` (``atpu:serve.*``, ``utils.profiling.annotate``) and
 the scopes and kernel names on its device operations.
 
-One profiler session for the whole module: three tiny engines (plain, chunked
-prefill, speculative) are stepped inside it and every span test reads the one
-``.xplane.pb`` it left. Spans exist only while a session does; names on
-device operations are metadata and change no program.
+One profiler session for the whole module: four tiny engines (plain, chunked
+prefill, speculative, decoding one step ahead) are stepped inside it and every
+span test reads the one ``.xplane.pb`` it left. Spans exist only while a
+session does; names on device operations are metadata and change no program.
+The benchmark's reader of the decode round trip (``benchmark/readers/
+round_trip.py``) is held here too, on its hand-written trace with the device
+plane moved against the host plane: ``benchmark/tests`` run in no gate.
 """
 
 import collections
 import glob
+import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -18,14 +23,25 @@ import numpy as np
 import optax
 import pytest
 
-from accelerate_tpu.models import CausalLM, TransformerConfig
-from accelerate_tpu.serving import ServingEngine, SpecConfig
-from accelerate_tpu.utils.profiling import annotate
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+sys.path.insert(0, os.path.join(ROOT, "benchmark", "tests"))
 
-PHASES = ("atpu:serve.schedule", "atpu:serve.prefill",
-          "atpu:serve.decode.inputs", "atpu:serve.decode.fetch",
-          "atpu:serve.emit")
+import round_trip_traces  # noqa: E402
+from harness import program_trace  # noqa: E402
+from readers import round_trip  # noqa: E402
+
+from accelerate_tpu.models import CausalLM, TransformerConfig  # noqa: E402
+from accelerate_tpu.serving import ServingEngine, SpecConfig  # noqa: E402
+from accelerate_tpu.utils.profiling import annotate  # noqa: E402
+
+INPUTS, DISPATCH, WAIT, FETCH = (
+    "atpu:serve.decode.inputs", "atpu:serve.decode.dispatch",
+    "atpu:serve.decode.wait", "atpu:serve.decode.fetch")
+PHASES = ("atpu:serve.schedule", "atpu:serve.prefill", INPUTS, DISPATCH, WAIT,
+          FETCH, "atpu:serve.emit")
 STEP = "atpu:serve.step"
+ENGINES = ("plain", "chunked", "spec", "ahead")
 Span = collections.namedtuple("Span", "name start end stats")
 
 
@@ -59,23 +75,21 @@ def session(tmp_path_factory):
     quiet = engine()  # never sees a session
     _, quiet_tokens, _ = _drive(quiet, prompts)
     plain, chunked = engine(), engine(prefill_chunk_tokens=8)
-    spec = engine(spec_decode=SpecConfig(k=2))
-    # warm every engine outside the session: these steps must leave no span
-    before = {}
-    for name, eng, warm in (("plain", plain, prompts[:2]),
-                            ("chunked", chunked, prompts[:2]),
-                            ("spec", spec, echo)):
-        before[name] = _drive(eng, warm)[2]
-    trace_dir = tmp_path_factory.mktemp("trace")
+    spec, ahead = engine(spec_decode=SpecConfig(k=2)), engine(decode_ahead=True)
+    work = {"plain": (plain, prompts, 4), "chunked": (chunked, prompts, 4),
+            "spec": (spec, echo + prompts[:1], 6), "ahead": (ahead, prompts, 4)}
+    # every engine does its work once outside the session: these steps must
+    # leave no span, and they trace every program the session's steps run
     run = {}
+    for name, (eng, asks, new) in work.items():
+        run[name] = {"engine": eng, "steps_before": _drive(eng, asks, new)[2],
+                     "dispatched_before": dict(eng._dispatched),
+                     "traced_before": eng.trace_counts()}
+    trace_dir = tmp_path_factory.mktemp("trace")
     with jax.profiler.trace(str(trace_dir)):
-        for name, eng, work in (("plain", plain, prompts),
-                                ("chunked", chunked, prompts),
-                                ("spec", spec, echo + prompts[:1])):
-            ids, tokens, steps = _drive(eng, work, max_new_tokens=6
-                                        if name == "spec" else 4)
-            run[name] = {"engine": eng, "ids": ids, "tokens": tokens,
-                         "steps": steps, "steps_before": before[name]}
+        for name, (eng, asks, new) in work.items():
+            ids, tokens, steps = _drive(eng, asks, new)
+            run[name].update(ids=ids, tokens=tokens, steps=steps)
     (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
     spans = []
     for plane in jax.profiler.ProfileData.from_file(path).planes:
@@ -86,17 +100,17 @@ def session(tmp_path_factory):
                                       ev.start_ns + ev.duration_ns,
                                       dict(ev.stats)))
     spans.sort(key=lambda s: (s.start, -s.end))
-    # the three engines ran one after the other: split the steps among them
+    # the engines ran one after the other: split the steps among them
     steps = [s for s in spans if s.name == STEP]
     at = 0
-    for name in ("plain", "chunked", "spec"):
+    for name in ENGINES:
         mine = steps[at:at + run[name]["steps"]]
         at += run[name]["steps"]
         run[name]["by_step"] = [
             (st, [s for s in spans if s.name != STEP
                   and st.start <= s.start and s.end <= st.end])
             for st in mine]
-    return {"run": run, "spans": spans, "steps": steps,
+    return {"run": run, "spans": spans, "steps": steps, "path": path,
             "quiet_tokens": quiet_tokens, "prompts": prompts}
 
 
@@ -112,7 +126,28 @@ def test_every_step_of_the_session_left_one_step_span(session):
                                     r["steps_before"] + r["steps"]))
 
 
-@pytest.mark.parametrize("which", ["plain", "chunked", "spec"])
+ONE_STEP = [INPUTS, DISPATCH, WAIT, FETCH]
+DECODES = {
+    "plain": ([], ONE_STEP), "chunked": ([], ONE_STEP),
+    # a round in which nothing was drafted decodes plainly, after its own
+    # inputs span (the COW and the proposer's work)
+    "spec": ([], ONE_STEP, [INPUTS] + ONE_STEP),
+    # one step ahead: the first step dispatches two, the last none
+    "ahead": ([], [INPUTS, DISPATCH] + ONE_STEP, ONE_STEP, [WAIT, FETCH]),
+}
+
+
+def _decode_spans(session, which):
+    """Per step of ``which`` that decoded: its decode spans, in order."""
+    out = []
+    for _, inner in session["run"][which]["by_step"]:
+        decode = [s for s in inner if s.name in ONE_STEP]
+        if decode:
+            out.append(decode)
+    return out
+
+
+@pytest.mark.parametrize("which", ENGINES)
 def test_a_step_holds_exactly_the_phases_nested_and_in_order(session, which):
     for st, inner in session["run"][which]["by_step"]:
         names = [s.name for s in inner]
@@ -123,22 +158,200 @@ def test_a_step_holds_exactly_the_phases_nested_and_in_order(session, which):
         prefills = [n for n in middle if n == "atpu:serve.prefill"]
         assert middle[:len(prefills)] == prefills  # prefills, then the decode
         decode = middle[len(prefills):]
-        assert decode in ([], ["atpu:serve.decode.inputs", "atpu:serve.decode.fetch"],
-                          # a round in which nothing was drafted decodes plainly
-                          ["atpu:serve.decode.inputs", "atpu:serve.decode.inputs",
-                           "atpu:serve.decode.fetch"])
+        # inputs, dispatch, wait, fetch, in that order
+        assert decode in DECODES[which], decode
         # the phases lie inside the step, one after the other: none overlaps
+        assert st.start <= inner[0].start and inner[-1].end <= st.end
         for a, b in zip(inner, inner[1:]):
             assert a.end <= b.start
         if which == "plain":
             assert len(prefills) == inner[0].stats["admitted"]
-        if decode:
-            assert decode[0] == "atpu:serve.decode.inputs"
-            seated = [s for s in inner if s.name == "atpu:serve.decode.inputs"]
-            assert all(1 <= s.stats["seated"] <= 4 for s in seated)
+        seated = [s for s in inner if s.name == INPUTS]
+        assert all(1 <= s.stats["seated"] <= 4 for s in seated)
     emitted = sum(inner[-1].stats["tokens"]
                   for _, inner in session["run"][which]["by_step"])
     assert emitted == sum(len(t) for t in session["run"][which]["tokens"])
+    seen = {tuple(s.name for s in d) for d in _decode_spans(session, which)}
+    assert seen == {tuple(d) for d in DECODES[which] if d}  # every form ran
+
+
+@pytest.mark.parametrize("which", ENGINES)
+def test_dispatches_count_up_from_the_engines_count_before_the_session(
+        session, which):
+    r = session["run"][which]
+    by_program = collections.defaultdict(list)
+    for decode in _decode_spans(session, which):
+        for s in decode:
+            if s.name == DISPATCH:
+                by_program[s.stats["program"]].append(s.stats["n"])
+    assert set(by_program) == ({"jit__decode", "jit__verify"} if which == "spec"
+                               else {"jit__decode"})
+    for program, ns in by_program.items():
+        first = r["dispatched_before"][program]
+        assert ns == list(range(first, first + len(ns)))
+        assert r["engine"]._dispatched[program] == first + len(ns)
+    # the session's steps ran the programs the engine had: nothing retraced
+    assert r["engine"].trace_counts() == r["traced_before"]
+
+
+@pytest.mark.parametrize("which", ENGINES)
+def test_every_wait_names_a_dispatch_that_began_before_it(session, which):
+    spans = [s for decode in _decode_spans(session, which) for s in decode]
+    calls = {(s.stats["program"], s.stats["n"]): s
+             for s in spans if s.name == DISPATCH}
+    waits = [s for s in spans if s.name == WAIT]
+    assert len(waits) == len(calls) > 3  # each dispatch is waited for, once
+    for i, w in enumerate(spans):
+        if w.name != WAIT:
+            continue
+        call = calls.pop((w.stats["program"], w.stats["n"]))
+        assert call.end <= w.start
+        assert spans[i + 1].name == FETCH  # the copy of an array that is ready
+        if which != "ahead":
+            assert spans[i - 1] is call  # nothing between the call and its wait
+            continue
+        # one step ahead: where a next step was dispatched at all, its
+        # dispatch began before the wait for this one
+        nxt = calls.get((w.stats["program"], w.stats["n"] + 1))
+        in_step = [s for s in spans if s.name == DISPATCH
+                   and call.end <= s.start and s.end <= w.start]
+        assert in_step == ([nxt] if nxt is not None and nxt.start < w.start
+                           else [])
+    assert not calls
+    if which == "ahead":
+        ahead_of = [s for d in _decode_spans(session, which)
+                    if [x.name for x in d] == ONE_STEP for s in d]
+        assert ahead_of and all(  # the steady step: dispatch n + 1, wait n
+            d.stats["n"] == w.stats["n"] + 1
+            for d, w in zip(ahead_of[1::4], ahead_of[2::4]))
+
+
+def test_a_speculative_round_names_the_program_it_dispatched(session):
+    rounds = _decode_spans(session, "spec")
+    said = collections.Counter()
+    for decode in rounds:
+        (call,) = [s for s in decode if s.name == DISPATCH]
+        (wait,) = [s for s in decode if s.name == WAIT]
+        fell_back = [s.name for s in decode].count(INPUTS) == 2
+        assert call.stats["program"] == wait.stats["program"] == (
+            "jit__decode" if fell_back else "jit__verify")
+        said[call.stats["program"]] += 1
+    assert said["jit__verify"] and said["jit__decode"]
+
+
+@pytest.mark.parametrize("which", ENGINES)
+def test_what_a_step_reads_still_sits_on_its_inputs_span(session, which):
+    """``rows`` / ``positions`` / ``seated`` (``decode_roofline.eva``,
+    ``cache_rows_per_token.eva``) stay on the span of the step's inputs."""
+    for decode in _decode_spans(session, which):
+        for i, s in enumerate(decode):
+            if s.name == DISPATCH:
+                assert set(s.stats) == {"program", "n"}
+                fed = decode[i - 1]
+                assert fed.name == INPUTS
+                if s.stats["program"] == "jit__decode":
+                    # one row a position on these models
+                    assert fed.stats["rows"] == fed.stats["positions"] > 0
+                    assert 1 <= fed.stats["seated"] <= 4
+            elif s.name == WAIT:
+                assert set(s.stats) == {"program", "n"}
+            elif s.name == FETCH:
+                assert not s.stats
+
+
+def test_the_benchmarks_loader_reads_the_new_stats_as_the_reader_needs_them(
+        session):
+    spans = program_trace.load(session["path"])["spans"]
+    calls = [st for name, _, _, st in spans if name == DISPATCH]
+    assert len(calls) == sum(
+        s.name == DISPATCH for s in session["spans"])
+    assert {st["program"] for st in calls} == {"jit__decode", "jit__verify"}
+    assert all(isinstance(st["n"], int) for st in calls)
+    # a CPU trace holds no device plane: nothing to read, and no error
+    assert round_trip.table(session["path"], "jit__decode") is None
+
+
+@pytest.mark.parametrize("shift_ms", [0.8, -0.8])
+def test_the_round_trip_reader_does_not_need_the_two_planes_to_agree(
+        tmp_path, capsys, shift_ms):
+    """The same five parts with the device plane 0.8 ms late or early, and
+    an interval of offsets that holds what undoes the shift."""
+    def read(shift):
+        return round_trip_traces.read(round_trip_traces.cell_over(
+            tmp_path, round_trip_traces.text(shift), f"shift{shift}"),
+            "jit__decode")
+
+    parts, (lo, hi) = read(0.0)
+    assert parts == pytest.approx(round_trip_traces.ONE_AT_A_TIME, abs=1e-3)
+    assert lo <= 0.0 <= hi
+    moved, (lo, hi) = read(shift_ms)
+    assert moved == pytest.approx(parts, abs=1e-6)
+    assert lo <= -shift_ms <= hi and not lo <= 0.0 <= hi
+    assert "the planes disagree by at least 0.5" in capsys.readouterr().out
+
+
+NEW_METRICS = (
+    "step_exposed_ms.tput", "step_exposed_ms.itl", "launch_wake_ms.tput",
+    "launch_wake_ms.itl", "step_host_ms.tput", "decode_inputs_ms.tput",
+    "decode_dispatch_ms.tput", "decode_fetch_copy_ms.tput",
+    "idle_dispatch_share.tput", "idle_wait_share.tput")
+
+
+@pytest.mark.parametrize("metric", NEW_METRICS)
+def test_a_new_metric_is_absent_for_a_program_that_draws_no_dispatch_span(
+        tmp_path, metric):
+    """Each metric file through its reader, as ``harness/cell.evaluate``
+    does: a number on the hand-written trace of a program that draws the
+    four decode spans, NOTHING (not 0, and not the wider span's duration)
+    on the one of a program that draws ``inputs`` and ``fetch`` alone."""
+    import json
+
+    from harness import cell as cells
+
+    with open(os.path.join(ROOT, "benchmark", "metrics", f"{metric}.json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(ROOT, "benchmark", "tests",
+                           "synthetic_spans.xplane.textproto")) as f:
+        older = round_trip_traces.cell_over(tmp_path, f.read(), "older")
+    newer = round_trip_traces.cell_over(tmp_path, round_trip_traces.text(), "newer")
+    reader = cells.named(f"readers.{spec['reader']}")
+    assert reader.read({}, {}, older, **spec["args"]) is None
+    assert reader.read({}, {}, newer, **spec["args"]) > 0.0
+    entry = next(m for m in json.load(open(os.path.join(ROOT, "BENCHMARK.json")))[
+        "per_layer"] if m["name"] == metric)
+    assert (entry["unit"], entry["layer"], entry["moves"]) == (
+        spec["unit"], spec["layer"], spec["moves"])
+
+
+def test_an_engine_with_no_collector_builds_no_gauge_record(session):
+    class Collector:
+        def __init__(self):
+            self.gauges = []
+
+        def record_serve_gauge(self, **fields):
+            self.gauges.append(fields)
+
+    plain = session["run"]["plain"]["engine"]
+    model, params = plain.model, plain.params
+
+    def serve(telemetry):
+        eng = ServingEngine(model, params, max_slots=4, num_blocks=48,
+                            block_size=8, telemetry=telemetry)
+        built = []
+        fields = eng._gauge_fields
+        eng._gauge_fields = lambda: built.append(fields()) or built[-1]
+        _, tokens, steps = _drive(eng, session["prompts"][:3])
+        return tokens, steps, built
+
+    tokens, steps, built = serve(None)
+    assert built == [] and steps >= 4
+    collector = Collector()
+    again, steps_again, built = serve(collector)
+    assert again == tokens and steps_again == steps
+    # one record a step (gauge_interval 1), each what _gauge_fields gave
+    assert collector.gauges == built and len(built) == steps
+    assert [g["engine_steps"] for g in built] == list(range(1, steps + 1))
+    assert len(built[0]) == 36 and built[0]["slots_active"] == 3  # as before
 
 
 def test_one_prefill_span_per_admitted_request_joins_its_request_span(session):
@@ -175,6 +388,7 @@ def test_chunked_prefill_draws_one_prefill_span_per_chunk(session):
 def test_tokens_are_the_same_with_and_without_a_session(session):
     assert session["run"]["plain"]["tokens"] == session["quiet_tokens"]
     assert session["run"]["chunked"]["tokens"] == session["quiet_tokens"]
+    assert session["run"]["ahead"]["tokens"] == session["quiet_tokens"]
 
 
 def test_annotate_is_inert_without_a_session():
