@@ -39,6 +39,7 @@ from typing import Any, Callable, Iterator, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..logging import get_logger
 from ..models.transformer import qkv_in_place
@@ -75,6 +76,17 @@ def _single_device_of(params: Any) -> Optional[jax.Device]:
             if len(devices) > 1:
                 return None
     return devices.pop() if devices else None
+
+
+def _whole_on_every_device_of(params: Any) -> Optional[NamedSharding]:
+    """Where weights sharded over a mesh keep a value that every one of
+    their devices holds whole, else None (no leaf names its mesh)."""
+    for leaf in jax.tree.leaves(params):
+        if isinstance(leaf, jax.Array) and isinstance(
+            leaf.sharding, NamedSharding
+        ):
+            return NamedSharding(leaf.sharding.mesh, PartitionSpec())
+    return None
 
 
 def _next_pow2(n: int) -> int:
@@ -151,14 +163,31 @@ class ServingEngine:
     speed. ``step()`` still returns one token a decoding slot. What it
     costs: a slot that a step ends by its count, or brings to a window's end
     (eva), sits the next dispatch out; a request prefilled while a step is
-    in flight joins the one after (so sampled streams at temperature > 0
-    differ from ``decode_ahead=False``; greedy bytes are the same); a
-    request ended by its ``eos_token_id`` has decoded one row too many,
-    into its own blocks, never read. Written for plain decode only:
-    prefix cache, speculation, chunked prefill, preemption, adapters and
-    the hand-off roles are refused beside it, by name. ``None`` (default):
-    on where the engine refuses all of those anyway (``attention_class``
-    ``"eva"``), off elsewhere.
+    in flight joins the one after (its prefill queues behind that step on
+    the device, and its second token comes one ``step()`` after its first);
+    a request ended by its ``eos_token_id`` has decoded one row too many,
+    into its own blocks, never read. Greedy tokens are those of one step at
+    a time. Sampled streams at temperature > 0 are as valid, but a request
+    joins other dispatches and so draws other keys than with
+    ``decode_ahead=False`` — and than a default dense engine drew before it
+    decoded ahead (PR 37). Written for plain decode only, so:
+
+    * ``None`` (default): ahead wherever the engine runs plain decode,
+      whatever the model — none of prefix cache, speculation, chunked
+      prefill, preemption, adapters or a hand-off role asked for when it is
+      built; one step at a time beside any of them. An engine that decodes
+      ahead by this choice LANDS when a warm toggle asks for one of them
+      (``set_prefix_cache(True)``, ``set_speculation(spec)``, ``set_role``
+      to a pool, ``acquire``): the step in flight is fetched as it is by
+      the next ``step()``, none goes behind it, the feature acts from that
+      step boundary on, and nothing compiles (the decode program goes on
+      taking its tokens from ``_feed``). It stays landed.
+    * ``True``: ahead, and each of those features is refused, by name, when
+      the engine is built and on the warm toggles.
+    * ``False``: one step at a time (the tests' reference).
+
+    ``decode_ahead_share`` (also a gauge): of the decode steps fetched, the
+    share whose tokens were on the device before their ``step()`` began.
     """
 
     def __init__(
@@ -268,25 +297,31 @@ class ServingEngine:
             from ..ops.eva_attention import EvaLayout
 
             self._eva = EvaLayout(cfg.window_size, cfg.chunk_size, block_size)
-        if decode_ahead is None:
-            decode_ahead = self._eva is not None
-        self.decode_ahead = bool(decode_ahead)
-        # the step dispatched ahead and not yet fetched: ((its tokens on
-        # the device, its dispatch's count), [(slot, request)] it decodes for)
-        self._ahead: Optional[tuple] = None
-        # dispatches made so far, by the program's name in the device trace
-        self._dispatched = {"jit__decode": 0, "jit__verify": 0}
-        for feature, on in (
+        features = [name for name, on in (
             ("prefix_cache", prefix_cache),
             ("spec_decode", spec_decode is not None),
             ("prefill_chunk_tokens", prefill_chunk_tokens is not None),
             ("preemption", preemption),
             (f"role {role!r}", role != "colocated"),
             ("adapters", adapters is not None),
-        ):
-            if on:
-                self._refuse_for_eva(feature)
-                self._refuse_beside_decode_ahead(feature)
+        ) if on]
+        # an engine left to choose (None) decodes ahead where it runs plain
+        # decode, and LANDS if a warm toggle later asks for a feature; one
+        # told to (True) refuses the feature instead
+        self._lands = decode_ahead is None
+        self.decode_ahead = not features if self._lands else bool(decode_ahead)
+        # the step dispatched ahead and not yet fetched: ((its tokens on
+        # the device, its dispatch's count), [(slot, request)] it decodes for)
+        self._ahead: Optional[tuple] = None
+        # dispatches made so far, by the program's name in the device trace
+        self._dispatched = {"jit__decode": 0, "jit__verify": 0}
+        # plain decode steps fetched, and those of them whose tokens were on
+        # the device before their ``step()`` began (``decode_ahead_share``)
+        self._fetched = 0
+        self._fetched_ahead = 0
+        for feature in features:
+            self._refuse_for_eva(feature)
+            self._land_or_refuse(feature)
         if kv_dtype == "int8":
             self._refuse_for_eva("kv_dtype 'int8'")
         self._max_table = (
@@ -616,15 +651,22 @@ class ServingEngine:
             # a step's tokens: the step before's, still on the device, for
             # the slots it decoded; the host's for the others. Compiled
             # now, and every decode call of this engine takes its tokens
-            # from it, so the decode program sees ONE kind of argument
+            # from it, after it has landed too, so the decode program sees
+            # ONE kind of argument
             def _feed(prev, tokens, from_prev):
                 return jnp.where(from_prev[:, None], prev[:, None], tokens)
 
-            self._feed_fn = jax.jit(_feed)
+            # the fed tokens lie where a step's own do: on the weights' one
+            # device, or whole on every device of their mesh (else a first
+            # step's and a fed step's would be two kinds after all)
+            on_mesh = (
+                _whole_on_every_device_of(params) if self._device is None
+                else None
+            )
+            self._feed_fn = jax.jit(_feed, out_shardings=on_mesh)
             none = np.zeros(max_slots, np.int32)
-            self._no_tokens = (
-                jnp.asarray(none) if self._device is None
-                else jax.device_put(none, self._device)
+            self._no_tokens = jax.device_put(
+                none, on_mesh if self._device is None else self._device
             )
             with self._placed():
                 self._feed_fn(self._no_tokens, none[:, None],
@@ -752,7 +794,7 @@ class ServingEngine:
             )
         if role != "colocated":
             self._refuse_for_eva(f"role {role!r}")
-            self._refuse_beside_decode_ahead(f"role {role!r}")
+            self._land_or_refuse(f"role {role!r}")
         self._role = role
 
     def _refuse_for_eva(self, feature: str) -> None:
@@ -766,17 +808,36 @@ class ServingEngine:
                 "rows, not one row a position (ROADMAP Reach A4)"
             )
 
-    def _refuse_beside_decode_ahead(self, feature: str) -> None:
+    def _land_or_refuse(self, feature: str) -> None:
         """Engine features that change a slot's blocks, its place in the
-        batch or the program it decodes through between two steps are
-        refused, by name, beside ``decode_ahead``: the step after the
-        next is already on the device when they would act."""
-        if self.decode_ahead:
+        batch or the program it decodes through between two steps are not
+        written beside ``decode_ahead``: the step after the next is already
+        on the device when they would act. An engine that decodes ahead by
+        its own choice LANDS: the step in flight is fetched as it is by the
+        next ``step()``, none is dispatched behind it, and ``feature`` acts
+        from that step boundary on (for good: turning the feature off again
+        does not take off). One built with ``decode_ahead=True`` refuses
+        ``feature``, by name."""
+        if not self.decode_ahead:
+            return
+        if not self._lands:
             raise NotImplementedError(
                 f"{feature} is not written beside decode_ahead: the next "
                 "decode step is dispatched before this one's tokens are "
-                "fetched (build the engine with decode_ahead=False)"
+                "fetched (build the engine with decode_ahead=None or False)"
             )
+        self.decode_ahead = False
+        # the share says how the engine decodes now
+        self._fetched = self._fetched_ahead = 0
+
+    @property
+    def decode_ahead_share(self) -> float:
+        """Of the decode steps fetched so far (since it landed, for an
+        engine that did), the share whose tokens were on the device before
+        their ``step()`` began: ~1.0 for a saturated engine that decodes
+        ahead (only a step behind an idle one is dispatched and awaited in
+        one ``step()``), 0.0 for one that takes a step at a time."""
+        return self._fetched_ahead / self._fetched if self._fetched else 0.0
 
     def _rows(self, slot: Slot, ahead: int = 0) -> int:
         """Cache rows ``slot`` holds (``ahead`` positions from now): its
@@ -907,7 +968,9 @@ class ServingEngine:
             # speculate only when some slot holds a +k block reservation
             # (granted at admission) — slots seated before speculation
             # was enabled have no verify headroom and decode plainly
-            if self._proposer is not None and any(
+            # (and not over a step in flight: an engine that has just
+            # landed fetches it first, the slots seated since sit it out)
+            if self._ahead is None and self._proposer is not None and any(
                 s.lookahead > 0 for s in active
             ):
                 emit = self._spec_step(active)
@@ -1615,7 +1678,7 @@ class ServingEngine:
         local CACHED index vs ``moved_blocks`` scatter-restored from the
         manifest's host images and their ``moved_bytes``)."""
         self._refuse_for_eva("hand-off (acquire)")
-        self._refuse_beside_decode_ahead("hand-off (acquire)")
+        self._land_or_refuse("hand-off (acquire)")
         res = self._try_seat_manifest(manifest)
         if res is None:
             self._inbox.append(manifest)
@@ -1750,8 +1813,12 @@ class ServingEngine:
         Returns the host half, ``emit(events)``, which :meth:`_step_inner`
         runs in its emit phase. With ``decode_ahead`` the step fetched here
         was dispatched during the step before, and the one after it goes to
-        the device before the wait."""
+        the device before the wait. ``decode_ahead`` is read here, at the
+        step boundary: an engine that has landed since fetches the step in
+        flight and dispatches none behind it."""
         ahead, self._ahead = self._ahead, None
+        self._fetched += 1
+        self._fetched_ahead += ahead is not None
         if ahead is None:
             (out, n), now = self._dispatch_decode(active), active
         else:
@@ -2195,6 +2262,7 @@ class ServingEngine:
             "prefill_chunks_total": self._prefill_chunks_total,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "pool_alias_bytes": self.pool_alias_bytes,
+            "decode_ahead_share": self.decode_ahead_share,
         }
         if self._eva is not None:
             # a cache that is not one row a position: what it holds beside
@@ -2516,7 +2584,7 @@ class ServingEngine:
         normally)."""
         if enabled:
             self._refuse_for_eva("prefix_cache")
-            self._refuse_beside_decode_ahead("prefix_cache")
+            self._land_or_refuse("prefix_cache")
             if model_fingerprint is not None:
                 self._model_fingerprint = model_fingerprint
             if self.prefix_cache is None:
@@ -2544,7 +2612,7 @@ class ServingEngine:
             self.scheduler.lookahead_tokens = 0
             return
         self._refuse_for_eva("spec_decode")
-        self._refuse_beside_decode_ahead("spec_decode")
+        self._land_or_refuse("spec_decode")
         proposer = self._proposers.get(id(spec))
         if proposer is None:
             if spec.method == "draft_model":

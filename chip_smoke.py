@@ -986,6 +986,14 @@ def serve_phase(say, sz: Sizes, dry: bool) -> None:
         front.step()  # ends in a host fetch of the sampled tokens: a fence
         dt = time.perf_counter() - t0
         (prefill_s if prefilled() > before else decode_s).append(dt)
+        for dev, eng in zip(devices, engines):
+            # a plain engine decodes ahead: whoever goes on decoding has its
+            # next step on the device when step() returns
+            goes_on = any(s.busy and not s.done for s in eng.scheduler.slots)
+            assert (eng._ahead is not None) == goes_on, (
+                f"engine of device {dev.id}: step in flight "
+                f"{eng._ahead is not None}, slots that go on decoding {goes_on}"
+            )
     wall = time.perf_counter() - t_all
     warm_decode = decode_s[len(decode_s) // 2:]  # all programs compiled
     say(f"serve: {n_req} requests, prompt lengths {lo}..{hi} in prefill "
@@ -1015,6 +1023,11 @@ def serve_phase(say, sz: Sizes, dry: bool) -> None:
             "a retrace"
         )
         assert stats["allocated"] == 0, f"leaked blocks: {stats}"
+        # of its decode steps, those whose tokens were on the device before
+        # their step() began: all but the first behind each wave of prefills
+        share = eng.decode_ahead_share
+        say(f"serve: engine on device {dev.id} decode_ahead_share {share:.3f}")
+        assert eng.decode_ahead and share >= (0.5 if dry else 0.9), share
         if not dry:
             assert counts["decode_attn_kernel"] == 1, (
                 "the decode program took the gather form of paged_attention"

@@ -7,6 +7,12 @@ their count, by an eos, and across a window's end); the step ahead really is
 in flight when ``step()`` returns; one decode program and one feed program
 whatever the source of a step's tokens; the pool comes back whole; and every
 feature the lookahead is not written for is refused beside it, by name.
+
+The default (``decode_ahead=None``): ahead wherever the engine runs plain decode,
+whatever the model; one step at a time where it was built with a feature; and a
+default engine that a warm toggle asks for a feature LANDS (the step in flight
+is fetched, none follows, nothing compiles) where one told to decode ahead
+refuses. ``decode_ahead_share`` says which of the two an engine does.
 """
 
 import os
@@ -27,6 +33,7 @@ from harness import evabyte_weights as W  # noqa: E402
 
 from accelerate_tpu.compilation import get_compile_monitor  # noqa: E402
 from accelerate_tpu.models import CausalLM, TransformerConfig  # noqa: E402
+from accelerate_tpu.router import FleetRouter, InProcessReplica  # noqa: E402
 from accelerate_tpu.serving import ServingEngine, SpecConfig  # noqa: E402
 
 EVA = tiny_eva.config()
@@ -98,9 +105,49 @@ def test_greedy_bytes_are_those_of_one_step_at_a_time(which, request):
     assert eng._decode_fn._cache_size() == 1 and eng._feed_fn._cache_size() == 1
 
 
-def test_the_default_is_on_for_eva_and_off_elsewhere(dense, eva):
-    assert ServingEngine(eva[0], eva[1], block_size=4).decode_ahead is True
-    assert ServingEngine(dense[0], dense[1], block_size=8).decode_ahead is False
+def _adapters(model):
+    from accelerate_tpu.adapters import AdapterRegistry
+
+    return AdapterRegistry(model.config, capacity=1, max_rank=2,
+                           target_modules=("q_proj", "v_proj"))
+
+
+# what the lookahead is not written for: (its name in the refusal, how an
+# engine is built with it)
+FEATURES = [
+    ("prefix_cache", lambda m: {"prefix_cache": True}),
+    ("spec_decode", lambda m: {"spec_decode": SpecConfig(k=2)}),
+    ("prefill_chunk_tokens", lambda m: {"prefill_chunk_tokens": 16}),
+    ("preemption", lambda m: {"preemption": True}),
+    ("adapters", lambda m: {"adapters": _adapters(m)}),
+    ("role 'prefill'", lambda m: {"role": "prefill"}),
+    ("role 'decode'", lambda m: {"role": "decode"}),
+]
+
+
+@pytest.mark.parametrize("which", ["dense", "eva"])
+def test_the_default_is_on_wherever_the_engine_runs_plain_decode(which, request):
+    model, params, block, _ = request.getfixturevalue(which)
+    eng = ServingEngine(model, params, block_size=block)
+    assert eng.decode_ahead is True and eng._feed_fn is not None
+    assert eng.decode_ahead_share == 0.0  # nothing fetched yet
+
+
+@pytest.mark.parametrize("feature,kwargs", FEATURES, ids=[f for f, _ in FEATURES])
+def test_the_default_is_off_beside_a_feature(dense, feature, kwargs):
+    """Left to choose, an engine built with a feature takes one step at a
+    time, as before: no feed program, nothing ever in flight."""
+    model, params, block, vocab = dense
+    eng = ServingEngine(model, params, block_size=block, **kwargs(model))
+    assert eng.decode_ahead is False and eng._feed_fn is None
+    if feature.startswith("role"):
+        return  # half an engine: the hand-off tests serve through a pair
+    eng.add_request(_ids(9, 1, vocab), max_new_tokens=4)
+    eng.add_request(_ids(5, 2, vocab), max_new_tokens=3)
+    while eng.has_work:
+        eng.step()
+        assert eng._ahead is None
+    assert eng.decode_ahead_share == 0.0 and eng._fetched >= 3
 
 
 def test_the_next_step_is_on_the_device_when_step_returns(eva):
@@ -165,21 +212,16 @@ def test_nothing_compiles_once_both_sources_of_tokens_have_run(eva):
     assert common.compiles_in(delta) == 0 and delta["compile_time_s"] == 0
 
 
-@pytest.mark.parametrize("feature,kwargs", [
-    ("prefix_cache", {"prefix_cache": True}),
-    ("spec_decode", {"spec_decode": SpecConfig(k=2)}),
-    ("prefill_chunk_tokens", {"prefill_chunk_tokens": 16}),
-    ("preemption", {"preemption": True}),
-    ("role 'prefill'", {"role": "prefill"}),
-    ("role 'decode'", {"role": "decode"}),
-])
+@pytest.mark.parametrize("feature,kwargs", FEATURES, ids=[f for f, _ in FEATURES])
 def test_what_it_is_not_written_for_is_refused_beside_it(dense, feature, kwargs):
     model, params, block, _ = dense
     with pytest.raises(NotImplementedError) as err:
-        ServingEngine(model, params, block_size=block, decode_ahead=True, **kwargs)
+        ServingEngine(model, params, block_size=block, decode_ahead=True,
+                      **kwargs(model))
     assert feature in str(err.value) and "decode_ahead" in str(err.value)
     # and stands where it is off
-    ServingEngine(model, params, block_size=block, **kwargs)
+    ServingEngine(model, params, block_size=block, decode_ahead=False,
+                  **kwargs(model))
 
 
 def test_the_same_is_refused_on_a_warm_engine(dense):
@@ -197,3 +239,297 @@ def test_the_same_is_refused_on_a_warm_engine(dense):
     eng.set_prefix_cache(False)
     eng.set_speculation(None)
     eng.set_role("colocated")
+
+
+# ---------------------------------------------------------------------- #
+# the default dense engine: what the eva cell never exercised
+# ---------------------------------------------------------------------- #
+def _bytes_with_late_prefills(dense, **kw):
+    asks = [(p, min(new, 64 - p)) for p, new in ASKS]
+    eng, got, _ = _serve(dense, asks, LATE, **kw)
+    return eng, got
+
+
+def _bytes_with_a_late_eos(dense, **kw):
+    asks = [(8, 20), (5, 20), (6, 20)]
+    _, plain, _ = _serve(dense, asks, [(7, 10)], decode_ahead=False)
+    eos = plain[1][len(plain[1]) // 2]  # a byte a plain answer holds midway
+    eng, got, _ = _serve(dense, asks, [(7, 10)], eos=eos, **kw)
+    assert any(len(t) < 20 for t in got[:3])
+    return eng, got
+
+
+def _bytes_of_one_and_two(dense, **kw):
+    # answers of one byte (the prefill's own) and of two (the prefill's and
+    # one decoded, which ends it: in no step dispatched after that), more of
+    # them than seats, beside a long one that keeps steps in flight
+    asks = [(9, 24), (5, 1), (7, 2), (12, 2), (3, 1)]
+    eng, got, _ = _serve(dense, asks, [(6, 1), (8, 2), (4, 2)], **kw)
+    assert [len(t) for t in got] == [24, 1, 2, 2, 1, 1, 2, 2]
+    return eng, got
+
+
+def _bytes_of_generate(dense, **kw):
+    model, params, block, vocab = dense
+    eng = ServingEngine(model, params, max_slots=3, block_size=block, **kw)
+    rows = np.stack([_ids(10, 70 + i, vocab) for i in range(5)])  # > seats
+    out = np.asarray(eng.generate(rows, max_new_tokens=7))
+    assert out.shape == (5, 17) and (out[:, :10] == rows).all()
+    # and with an eos that ends some rows early: padded with it
+    eos = int(out[2, 13])
+    padded = np.asarray(eng.generate(rows, max_new_tokens=7, eos_token_id=eos))
+    assert (padded[2, 14:] == eos).all()
+    return eng, [out.tolist(), padded.tolist()]
+
+
+def _bytes_over_a_mesh(dense, **kw):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    model, params, block, vocab = dense
+    mesh = Mesh(np.array(jax.devices()[:2]), ("fsdp",))
+    spread = jax.device_put(params, NamedSharding(mesh, P()))
+    eng, got = _bytes_with_late_prefills((model, spread, block, vocab), **kw)
+    assert eng._device is None  # no one device: placement is the mesh's
+    return eng, got
+
+
+def _bytes_behind_a_router(dense, **kw):
+    model, params, block, vocab = dense
+    engines = [ServingEngine(model, params, max_slots=2, block_size=block, **kw)
+               for _ in range(2)]
+    router = FleetRouter([InProcessReplica(f"r{i}", e)
+                          for i, e in enumerate(engines)], policy="round_robin")
+    rids = [router.add_request(_ids(p, 90 + i, vocab).tolist(), max_new_tokens=new)
+            for i, (p, new) in enumerate([(9, 12), (5, 1), (14, 7), (6, 2),
+                                          (11, 9), (4, 5)])]
+    for _ in range(200):
+        if not router.has_work:
+            break
+        router.step()
+    assert not router.has_work
+    assert all(e.pool.stats()["allocated"] == 0 for e in engines)
+    return engines[0], [router.result(r) for r in rids]
+
+
+MIXES = {
+    "a_prefill_joins_mid_flight": _bytes_with_late_prefills,
+    "an_eos_one_step_late": _bytes_with_a_late_eos,
+    "answers_of_one_and_two": _bytes_of_one_and_two,
+    "generate": _bytes_of_generate,
+    "weights_over_a_mesh": _bytes_over_a_mesh,
+    "replicas_of_a_router": _bytes_behind_a_router,
+}
+
+
+@pytest.mark.parametrize("mix", list(MIXES))
+def test_a_default_dense_engine_serves_the_bytes_of_one_step_at_a_time(dense, mix):
+    plain, want = MIXES[mix](dense, decode_ahead=False)
+    eng, got = MIXES[mix](dense)
+    assert plain.decode_ahead is False and plain._fetched_ahead == 0
+    assert eng.decode_ahead is True and eng._fetched_ahead > 0
+    assert got == want
+    assert eng._ahead is None and eng.pool.stats()["allocated"] == 0
+    # one executable each, on one device and over a mesh, whether a step's
+    # tokens came from the host, from the step before, or from both
+    assert eng.trace_counts()["decode"] == 1
+    assert eng._decode_fn._cache_size() == 1 and eng._feed_fn._cache_size() == 1
+
+
+# ---------------------------------------------------------------------- #
+# landing: a default engine that a warm toggle asks for a feature
+# ---------------------------------------------------------------------- #
+# every prefill width the traffic after it runs, cold (32) and behind a
+# cached prefix (2, 4, 8)
+WARM = [(20, 14), (3, 16), (12, 2), (7, 12), (2, 3)]
+# shared first blocks (a prefix cache finds them), and echoes (an n-gram
+# proposer drafts from them)
+SHARED = np.tile(np.arange(3, 11, dtype=np.int32), 3)
+
+
+def _after(vocab):
+    return [(np.concatenate([SHARED[:16], _ids(n, 80 + n, vocab)]), new)
+            for n, new in ((3, 9), (5, 12), (2, 1), (4, 7), (3, 10), (6, 5),
+                           (2, 8), (5, 3))]
+
+
+def _serve_after(eng, vocab, on_the_way=None):
+    """Warm ``eng`` up, then serve ``_after``; ``on_the_way`` is called once,
+    between two steps, while the first of them are decoding."""
+    for i, (p, new) in enumerate(WARM):
+        eng.add_request(_ids(p, 60 + i, vocab), max_new_tokens=new)
+    while eng.has_work:
+        eng.step()
+    rids = [eng.add_request(p, max_new_tokens=new) for p, new in _after(vocab)]
+    eng.step()
+    eng.step()
+    if on_the_way is not None:
+        on_the_way(eng)
+    while eng.has_work:
+        eng.step()
+    return [eng.result(r) for r in rids]
+
+
+@pytest.mark.parametrize("feature,built,toggle", [
+    ("prefix_cache", {"prefix_cache": True},
+     lambda eng: eng.set_prefix_cache(True)),
+    ("spec_decode", {"spec_decode": SpecConfig(k=2)},
+     lambda eng: eng.set_speculation(SpecConfig(k=2))),
+])
+def test_a_default_engine_lands_on_a_warm_toggle(dense, feature, built, toggle):
+    """... and serves the greedy bytes of an engine built with the feature
+    (and of one that takes a step at a time), compiling nothing it had."""
+    model, params, block, vocab = dense
+    want = _serve_after(ServingEngine(
+        model, params, max_slots=3, block_size=block, **built), vocab)
+    assert want == _serve_after(ServingEngine(
+        model, params, max_slots=3, block_size=block, decode_ahead=False), vocab)
+    eng = ServingEngine(model, params, max_slots=3, block_size=block)
+    seen = {}
+
+    def land(eng):
+        assert eng.decode_ahead and eng._ahead is not None  # a step in flight
+        assert eng.decode_ahead_share > 0.8
+        seen["monitor"] = get_compile_monitor().snapshot()
+        seen["traced"] = eng.trace_counts()
+        seen["prefill"] = eng._prefill_fn._cache_size()
+        toggle(eng)
+        # landed: that step is still to be fetched, as it is ...
+        assert eng.decode_ahead is False and eng._ahead is not None
+        assert eng.decode_ahead_share == 0.0
+        events = eng.step()
+        # ... and none went behind it
+        assert eng._ahead is None and events
+
+    got = _serve_after(eng, vocab, land)
+    assert got == want
+    assert eng.pool.stats()["allocated"] == 0
+    # the programs it had are the programs it has: the feed goes on handing
+    # the decode program its tokens, so that one sees ONE kind of argument
+    traced = eng.trace_counts()
+    assert all(traced[k] == seen["traced"][k] for k in (
+        "prefill", "decode", "decode_attn_kernel", "qkv_in_place"))
+    assert eng._decode_fn._cache_size() == 1 and eng._feed_fn._cache_size() == 1
+    assert eng._prefill_fn._cache_size() == seen["prefill"]
+    if feature == "prefix_cache":
+        delta = get_compile_monitor().delta(seen["monitor"])
+        assert common.compiles_in(delta) == 0 and delta["compile_time_s"] == 0
+        assert eng.prefix_cache.stats()["hits"] >= 2  # and the feature acts
+    else:
+        assert traced["verify"] == 1 and eng._spec_rounds_total > 0
+    # the share of a landed engine: one step was in flight, the rest were not
+    assert 0.0 < eng.decode_ahead_share < 0.2
+    # turning the feature off again does not take off
+    eng.set_prefix_cache(False)
+    eng.set_speculation(None)
+    assert eng.decode_ahead is False
+
+
+def test_a_default_engine_lands_on_a_hand_off_and_on_a_role(dense):
+    from accelerate_tpu.serving import TransferPlane
+
+    model, params, block, vocab = dense
+    asks = [(_ids(12, 31, vocab), 9), (_ids(16, 32, vocab), 6)]
+    own = (_ids(7, 33, vocab), 14)
+
+    def colocated(**kw):
+        eng = ServingEngine(model, params, max_slots=3, block_size=block, **kw)
+        rids = [eng.add_request(p, max_new_tokens=n, request_id=f"r{i}")
+                for i, (p, n) in enumerate([own] + asks)]
+        while eng.has_work:
+            eng.step()
+        return [eng.result(r) for r in rids]
+
+    want = colocated(decode_ahead=False)
+    assert colocated() == want
+    plane = TransferPlane("inprocess")
+    pre = ServingEngine(model, params, max_slots=2, block_size=block,
+                        role="prefill", transfer_plane=plane)
+    dec = ServingEngine(model, params, max_slots=3, block_size=block,
+                        transfer_plane=plane)
+    assert pre.decode_ahead is False and dec.decode_ahead is True
+    dec.add_request(own[0], max_new_tokens=own[1], request_id="r0")
+    dec.step()
+    dec.step()
+    assert dec._ahead is not None
+    for i, (p, n) in enumerate(asks):
+        pre.add_request(p, max_new_tokens=n, request_id=f"r{i + 1}")
+    while pre.has_work or dec.has_work:
+        pre.step()
+        for m in pre.pop_manifests():
+            assert dec.acquire(m)["seated"]
+            assert dec.decode_ahead is False  # landed, by the first of them
+        dec.step()
+    assert [dec.result(f"r{i}") for i in range(3)] == want
+    assert dec.trace_counts()["prefill"] == 1 and dec.trace_counts()["decode"] == 1
+    assert dec._decode_fn._cache_size() == 1
+    # a role: engines primed colocated (they decoded ahead), then assigned
+    primed = []
+    for role in ("prefill", "decode"):
+        eng = ServingEngine(model, params, max_slots=3, block_size=block,
+                            transfer_plane=plane)
+        eng.add_request(own[0], max_new_tokens=own[1], request_id="r0")
+        while eng.has_work:
+            eng.step()
+        assert eng.result("r0") == want[0] and eng.decode_ahead_share > 0.8
+        eng.set_role(role)
+        assert eng.decode_ahead is False and eng.decode_ahead_share == 0.0
+        primed.append(eng)
+    pre, dec = primed
+    for i, (p, n) in enumerate(asks):
+        pre.add_request(p, max_new_tokens=n, request_id=f"r{i + 1}")
+    while pre.has_work or dec.has_work:
+        pre.step()
+        for m in pre.pop_manifests():
+            dec.acquire(m)
+        dec.step()
+        assert pre._ahead is None and dec._ahead is None
+    assert [dec.result(f"r{i}") for i in range(3)] == want
+    assert dec._decode_fn._cache_size() == 1 and dec._feed_fn._cache_size() == 1
+
+
+# ---------------------------------------------------------------------- #
+# the engage counter
+# ---------------------------------------------------------------------- #
+def test_the_share_of_steps_decoded_ahead(dense):
+    class Collector:
+        def __init__(self):
+            self.gauges = []
+
+        def record_serve_gauge(self, **fields):
+            self.gauges.append(fields)
+
+    model, params, block, vocab = dense
+    collector = Collector()
+    eng = ServingEngine(model, params, max_slots=3, block_size=block,
+                        telemetry=collector)
+    for i in range(3):  # every seat taken, and held: a saturated engine
+        eng.add_request(_ids(6 + i, i, vocab), max_new_tokens=30)
+    eng.step()
+    # the first step dispatched, waited for and fetched its own decode step
+    assert (eng._fetched, eng._fetched_ahead) == (1, 0)
+    assert eng.decode_ahead_share == 0.0
+    for _ in range(12):
+        eng.step()
+        assert eng._ahead is not None
+    # every step since was on the device before its step() began
+    assert (eng._fetched, eng._fetched_ahead) == (13, 12)
+    assert eng.decode_ahead_share == 12 / 13
+    # in the gauges record, beside pool_alias_bytes, a record a step
+    shares = [g["decode_ahead_share"] for g in collector.gauges]
+    assert shares == [n / (n + 1) for n in range(13)]
+    assert list(collector.gauges[-1])[-2:] == [
+        "pool_alias_bytes", "decode_ahead_share"]
+    # and with no telemetry attached it reads the same
+    assert eng._gauge_fields()["decode_ahead_share"] == eng.decode_ahead_share
+    eng.set_prefix_cache(True)
+    assert eng.decode_ahead_share == 0.0  # landed: counted anew
+    eng.step()  # the step that was in flight
+    while eng.has_work:
+        eng.step()
+    assert (eng._fetched, eng._fetched_ahead) == (30 - 1 - 13, 1)
+    one = ServingEngine(model, params, max_slots=3, block_size=block,
+                        decode_ahead=False)
+    one.add_request(_ids(6, 0, vocab), max_new_tokens=8)
+    while one.has_work:
+        one.step()
+    assert one.decode_ahead_share == 0.0 and one._fetched == 7

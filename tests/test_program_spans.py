@@ -2,8 +2,9 @@
 ``ServingEngine.step`` (``atpu:serve.*``, ``utils.profiling.annotate``) and
 the scopes and kernel names on its device operations.
 
-One profiler session for the whole module: four tiny engines (plain, chunked
-prefill, speculative, decoding one step ahead) are stepped inside it and every
+One profiler session for the whole module: five tiny engines (plain, which
+decodes one step ahead by its own choice; chunked prefill; speculative; told to
+decode ahead; told to take one step at a time) are stepped inside it and every
 span test reads the one ``.xplane.pb`` it left. Spans exist only while a
 session does; names on device operations are metadata and change no program.
 The benchmark's reader of the decode round trip (``benchmark/readers/
@@ -41,7 +42,8 @@ INPUTS, DISPATCH, WAIT, FETCH = (
 PHASES = ("atpu:serve.schedule", "atpu:serve.prefill", INPUTS, DISPATCH, WAIT,
           FETCH, "atpu:serve.emit")
 STEP = "atpu:serve.step"
-ENGINES = ("plain", "chunked", "spec", "ahead")
+ENGINES = ("plain", "chunked", "spec", "ahead", "one")
+AHEAD = ("plain", "ahead")  # by default (a dense engine with no feature), or told
 Span = collections.namedtuple("Span", "name start end stats")
 
 
@@ -76,8 +78,12 @@ def session(tmp_path_factory):
     _, quiet_tokens, _ = _drive(quiet, prompts)
     plain, chunked = engine(), engine(prefill_chunk_tokens=8)
     spec, ahead = engine(spec_decode=SpecConfig(k=2)), engine(decode_ahead=True)
+    one = engine(decode_ahead=False)
+    assert [e.decode_ahead for e in (plain, chunked, spec, ahead, one)] == [
+        True, False, False, True, False]
     work = {"plain": (plain, prompts, 4), "chunked": (chunked, prompts, 4),
-            "spec": (spec, echo + prompts[:1], 6), "ahead": (ahead, prompts, 4)}
+            "spec": (spec, echo + prompts[:1], 6), "ahead": (ahead, prompts, 4),
+            "one": (one, prompts, 4)}
     # every engine does its work once outside the session: these steps must
     # leave no span, and they trace every program the session's steps run
     run = {}
@@ -127,13 +133,14 @@ def test_every_step_of_the_session_left_one_step_span(session):
 
 
 ONE_STEP = [INPUTS, DISPATCH, WAIT, FETCH]
+# one step ahead: the first step dispatches two, the last none
+AHEAD_STEPS = ([], [INPUTS, DISPATCH] + ONE_STEP, ONE_STEP, [WAIT, FETCH])
 DECODES = {
-    "plain": ([], ONE_STEP), "chunked": ([], ONE_STEP),
+    "plain": AHEAD_STEPS, "chunked": ([], ONE_STEP), "one": ([], ONE_STEP),
     # a round in which nothing was drafted decodes plainly, after its own
     # inputs span (the COW and the proposer's work)
     "spec": ([], ONE_STEP, [INPUTS] + ONE_STEP),
-    # one step ahead: the first step dispatches two, the last none
-    "ahead": ([], [INPUTS, DISPATCH] + ONE_STEP, ONE_STEP, [WAIT, FETCH]),
+    "ahead": AHEAD_STEPS,
 }
 
 
@@ -164,7 +171,7 @@ def test_a_step_holds_exactly_the_phases_nested_and_in_order(session, which):
         assert st.start <= inner[0].start and inner[-1].end <= st.end
         for a, b in zip(inner, inner[1:]):
             assert a.end <= b.start
-        if which == "plain":
+        if which in ("plain", "one"):
             assert len(prefills) == inner[0].stats["admitted"]
         seated = [s for s in inner if s.name == INPUTS]
         assert all(1 <= s.stats["seated"] <= 4 for s in seated)
@@ -207,7 +214,7 @@ def test_every_wait_names_a_dispatch_that_began_before_it(session, which):
         call = calls.pop((w.stats["program"], w.stats["n"]))
         assert call.end <= w.start
         assert spans[i + 1].name == FETCH  # the copy of an array that is ready
-        if which != "ahead":
+        if which not in AHEAD:
             assert spans[i - 1] is call  # nothing between the call and its wait
             continue
         # one step ahead: where a next step was dispatched at all, its
@@ -218,7 +225,7 @@ def test_every_wait_names_a_dispatch_that_began_before_it(session, which):
         assert in_step == ([nxt] if nxt is not None and nxt.start < w.start
                            else [])
     assert not calls
-    if which == "ahead":
+    if which in AHEAD:
         ahead_of = [s for d in _decode_spans(session, which)
                     if [x.name for x in d] == ONE_STEP for s in d]
         assert ahead_of and all(  # the steady step: dispatch n + 1, wait n
@@ -351,7 +358,9 @@ def test_an_engine_with_no_collector_builds_no_gauge_record(session):
     # one record a step (gauge_interval 1), each what _gauge_fields gave
     assert collector.gauges == built and len(built) == steps
     assert [g["engine_steps"] for g in built] == list(range(1, steps + 1))
-    assert len(built[0]) == 36 and built[0]["slots_active"] == 3  # as before
+    # as before, and the share of its steps that were decoded ahead
+    assert len(built[0]) == 37 and built[0]["slots_active"] == 3
+    assert built[0]["decode_ahead_share"] == 0.0 < built[-1]["decode_ahead_share"]
 
 
 def test_one_prefill_span_per_admitted_request_joins_its_request_span(session):
@@ -389,6 +398,7 @@ def test_tokens_are_the_same_with_and_without_a_session(session):
     assert session["run"]["plain"]["tokens"] == session["quiet_tokens"]
     assert session["run"]["chunked"]["tokens"] == session["quiet_tokens"]
     assert session["run"]["ahead"]["tokens"] == session["quiet_tokens"]
+    assert session["run"]["one"]["tokens"] == session["quiet_tokens"]
 
 
 def test_annotate_is_inert_without_a_session():
