@@ -16,7 +16,7 @@ SUPPORTED_ROPE_TYPES = ("default", "llama3", "linear")
 # per-layer operators a ``layer_types`` entry may name (config.json names),
 # and the feed-forwards it may name where a layer is ONE sublayer
 # (``single_sublayer``: "mamba", "moe" and "mlp" stand in such a stack only)
-LAYER_TYPES = ("full_attention", "conv", "mamba")
+LAYER_TYPES = ("full_attention", "conv", "mamba", "linear_attention")
 FF_LAYER_TYPES = ("moe", "mlp")
 MLP_ACTIVATIONS = ("silu", "gelu_tanh", "relu2")
 # what a layer's attention computes: None is softmax over every earlier
@@ -197,11 +197,13 @@ class TransformerConfig:
     # choice restricted to the best groups of experts) is not written: 1, 1
     moe_n_group: int = 1
     moe_topk_group: int = 1
-    # a shared expert beside the routed ones (sigmoid router only): a plain
-    # feed-forward of this width that every token takes, ``moe/shared``; a
-    # layer that holds a share of the experts computes it whole, as every
-    # chip of the expert-parallel group does
+    # a shared expert beside the routed ones: a plain feed-forward of this
+    # width that every token takes, ``moe/shared``; a layer that holds a
+    # share of the experts computes it whole, as every chip of the
+    # expert-parallel group does. ``moe_shared_gate``: its output times
+    # sigmoid(w_s . x), one learned vector ``moe/shared_gate``
     moe_shared_intermediate_size: Optional[int] = None
+    moe_shared_gate: bool = False
     # the chip's share of an expert-parallel deployment: ``num_experts`` is
     # how many experts this layer HOLDS, the router keeps its published
     # width ``moe_router_width`` (None -> num_experts) and chooses among all
@@ -238,11 +240,30 @@ class TransformerConfig:
     mamba_state_size: int = 128
     mamba_conv_kernel: int = 4
     mamba_chunk_size: int = 128
+    # the Gated DeltaNet operator (``layer_types`` "linear_attention";
+    # models/transformer.GatedDeltaNet, ops/gated_delta.py): ``gdn_num_k_heads``
+    # query/key heads of ``gdn_head_k_dim`` and ``gdn_num_v_heads`` value heads
+    # of ``gdn_head_v_dim`` (key head j // (v heads / k heads) serves value
+    # head j), a causal depthwise convolution of ``gdn_conv_kernel`` taps, no
+    # bias, then silu over [q | k | v]; each value head keeps a float32 state
+    # of head_k_dim x head_v_dim, which a serving slot carries beside the KV
+    # pools of the attention layers
+    gdn_num_k_heads: int = 0
+    gdn_num_v_heads: int = 0
+    gdn_head_k_dim: int = 128
+    gdn_head_v_dim: int = 128
+    gdn_conv_kernel: int = 4
     # False: attention carries no position (q and k are not rotated): the
     # order comes from elsewhere in the stack (state-space layers)
     use_rope: bool = True
     # RMSNorm over head_dim on q and k (one weight vector each), before rope
     qk_norm: bool = False
+    # rope turns the first ``partial_rotary_factor`` x head_dim elements of a
+    # head (half-split pairing inside them); the rest pass unrotated
+    partial_rotary_factor: float = 1.0
+    # q_proj is twice as wide: each head's columns are [q | gate], and the
+    # attention output is multiplied by sigmoid(gate) before o_proj
+    attn_output_gate: bool = False
     # fp8 projections: e4m3 fwd / e5m2 bwd matmuls (ops/fp8.py) — the
     # TransformerEngine capability; pair with mixed_precision="fp8"
     fp8: bool = False
@@ -383,6 +404,7 @@ class TransformerConfig:
                     f"conv_L_cache must be >= 1, got {self.conv_L_cache}"
                 )
         self._validate_single_sublayer()
+        self._validate_gated_layers()
         if not self.use_rope:
             clash = [
                 name for name, on in (
@@ -445,11 +467,11 @@ class TransformerConfig:
                     "expert's activation is silu; relu2 experts are "
                     "mlp_gated=False"
                 )
-            if (self.moe_shared_intermediate_size is not None
-                    and self.moe_router != "sigmoid"):
+            if (self.moe_shared_gate
+                    and self.moe_shared_intermediate_size is None):
                 raise ValueError(
-                    "moe_shared_intermediate_size: a shared expert stands "
-                    "beside sigmoid-routed experts (moe_router 'sigmoid')"
+                    "moe_shared_gate gates a shared expert: set "
+                    "moe_shared_intermediate_size"
                 )
         if self.num_kv_heads is None:
             self.num_kv_heads = self.num_heads
@@ -490,6 +512,48 @@ class TransformerConfig:
                 raise ValueError(
                     f"mamba_conv_kernel {self.mamba_conv_kernel} and "
                     f"mamba_chunk_size {self.mamba_chunk_size} must be >= 1"
+                )
+
+    def _validate_gated_layers(self):
+        """The Gated DeltaNet sizes, partial rotary and the attention output
+        gate, each clash by name."""
+        if "linear_attention" in (self.layer_types or ()):
+            if (self.gdn_num_k_heads < 1 or self.gdn_num_v_heads < 1
+                    or self.gdn_num_v_heads % self.gdn_num_k_heads):
+                raise ValueError(
+                    f"gdn_num_v_heads {self.gdn_num_v_heads} must be a "
+                    f"positive multiple of gdn_num_k_heads {self.gdn_num_k_heads}"
+                )
+            if (self.gdn_head_k_dim < 1 or self.gdn_head_v_dim < 1
+                    or self.gdn_conv_kernel < 2):
+                raise ValueError(
+                    f"gdn_head_k_dim {self.gdn_head_k_dim} and gdn_head_v_dim "
+                    f"{self.gdn_head_v_dim} must be >= 1, gdn_conv_kernel "
+                    f"{self.gdn_conv_kernel} >= 2"
+                )
+            if self.single_sublayer:
+                raise ValueError(
+                    "layer_types 'linear_attention' names an operator that a "
+                    "feed-forward follows: not with single_sublayer"
+                )
+        if not 0.0 < self.partial_rotary_factor <= 1.0:
+            raise ValueError(
+                f"partial_rotary_factor {self.partial_rotary_factor} outside (0, 1]"
+            )
+        for name, on in (
+            ("partial_rotary_factor", self.partial_rotary_factor != 1.0),
+            ("attn_output_gate", self.attn_output_gate),
+        ):
+            clash = [
+                other for other, live in (
+                    ("fused_kernels", self.fused_kernels),
+                    ("attention_class", self.attention_class is not None),
+                ) if on and live
+            ]
+            if clash:
+                raise ValueError(
+                    f"{name} is written for plain softmax attention, unfused: "
+                    f"it cannot be combined with {clash}"
                 )
 
     # ------------------------------------------------------------------ #

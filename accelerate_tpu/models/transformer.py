@@ -119,8 +119,14 @@ def rope(
     positions: jax.Array,
     theta: float,
     scaling: Optional[dict] = None,
+    rotary_dim: Optional[int] = None,
 ) -> jax.Array:
-    """Rotary position embedding, x: (B, S, H, D), positions: (B, S)."""
+    """Rotary position embedding, x: (B, S, H, D), positions: (B, S).
+    ``rotary_dim``: only the first that many elements of a head turn (with
+    frequencies of a head that wide); the rest pass as they are."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        turned = rope(x[..., :rotary_dim], positions, theta, scaling)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     from ..parallel.sharding import live_mesh
 
     mesh = live_mesh()
@@ -288,10 +294,18 @@ class Attention(nn.Module):
         dtype = _dtype(cfg)
         q_dim = cfg.num_heads * cfg.head_dim
         kv_dim = cfg.num_kv_heads * cfg.head_dim
+        rotary_dim = (
+            None if cfg.partial_rotary_factor == 1.0
+            else int(cfg.head_dim * cfg.partial_rotary_factor)
+        )
+
+        def turn(a, at):
+            return rope(a, at, cfg.rope_theta, cfg.rope_scaling, rotary_dim)
 
         proj = _make_proj(cfg, dtype)
 
         b, s = x.shape[:2]
+        gate = None
         fused_qkv = False
         if pre_norm_scale is not None:
             # Block handed us the RAW residual stream + the norm scale:
@@ -343,9 +357,10 @@ class Attention(nn.Module):
                     norm_offset=cfg.norm_offset,
                 )
         if not fused_qkv:
+            # with the output gate each head's columns are [q | gate]
             q = proj(
-                "q_proj", q_dim, ("embed", "heads"),
-                use_bias=cfg.qkv_bias, bias_axis="heads",
+                "q_proj", q_dim * (2 if cfg.attn_output_gate else 1),
+                ("embed", "heads"), use_bias=cfg.qkv_bias, bias_axis="heads",
             )(x)
             k = proj(
                 "k_proj", kv_dim, ("embed", "kv"),
@@ -366,6 +381,10 @@ class Attention(nn.Module):
                 v = v + dv
             if qkv_in_place(self.decode, s):
                 q, k, v = jax.lax.optimization_barrier((q, k, v))
+            if cfg.attn_output_gate:
+                q, gate = jnp.split(
+                    q.reshape(b, s, cfg.num_heads, 2 * cfg.head_dim), 2, axis=-1)
+                gate = gate.reshape(b, s, q_dim)
             q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
             k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
             v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
@@ -418,19 +437,24 @@ class Attention(nn.Module):
             kv_int8 = getattr(paged, "kv_dtype", "native") == "int8"
             pool_dtype = jnp.int8 if kv_int8 else k.dtype
             is_initialized = self.has_variable("cache", "key_pool")
+            # a block's rows: one a position and KV head, or (the state
+            # says so: ops.attention.pool_heads_first) each head's together
+            a_block = (
+                (cfg.num_kv_heads, paged.block_size) if paged.heads_first
+                else (paged.block_size, cfg.num_kv_heads)
+            )
+            if paged.heads_first and eva:
+                raise NotImplementedError(
+                    "attention_class 'eva' over pools stored heads first")
             key_pool = self.variable(
                 "cache", "key_pool",
                 lambda: jnp.zeros(
-                    (paged.num_blocks, paged.block_size,
-                     cfg.num_kv_heads, cfg.head_dim), pool_dtype,
-                ),
+                    (paged.num_blocks, *a_block, cfg.head_dim), pool_dtype),
             )
             value_pool = self.variable(
                 "cache", "value_pool",
                 lambda: jnp.zeros(
-                    (paged.num_blocks, paged.block_size,
-                     cfg.num_kv_heads, cfg.head_dim), pool_dtype,
-                ),
+                    (paged.num_blocks, *a_block, cfg.head_dim), pool_dtype),
             )
             key_scale = value_scale = None
             if kv_int8:
@@ -491,8 +515,8 @@ class Attention(nn.Module):
             fresh = paged.positions is None
             start = 0 if fresh else paged.positions[:, None]
             positions = jnp.broadcast_to(start + jnp.arange(s)[None, :], (b, s))
-            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+            q = turn(q, positions)
+            k = turn(k, positions)
             if fresh:
                 # prefill of a padded bucket from position 0: attends what
                 # it projected, and leaves in the pool only the completed
@@ -528,8 +552,8 @@ class Attention(nn.Module):
             # path's single scalar index cannot express a decode batch
             # whose members are at different depths)
             positions = paged.cache_len[:, None] + jnp.arange(s)[None, :]
-            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+            q = turn(q, positions)
+            k = turn(k, positions)
             new_ks = new_vs = None
             if kv_int8:
                 new_k, new_v, new_ks, new_vs = paged_update(
@@ -546,16 +570,28 @@ class Attention(nn.Module):
                 )
             key_pool.value = new_k
             value_pool.value = new_v
-            out = paged_attention(
-                q, new_k, new_v, paged, scale=scale,
-                softcap=cfg.attn_softcap, window=window,
-                key_scale=new_ks, value_scale=new_vs, layer=layer,
-            )
+            if paged.fresh:
+                # a prefill from position 0 (a stack with recurrent layers
+                # never continues a cache by several tokens): what it
+                # projected is all there is to see, so the table is written
+                # and not gathered — flash over the bucket; the padded tail
+                # lies after every real row and is seen by none
+                out = dot_product_attention(
+                    q, k, v, causal=True, scale=scale,
+                    softcap=cfg.attn_softcap,
+                    implementation=cfg.attention_impl, window=window,
+                )
+            else:
+                out = paged_attention(
+                    q, new_k, new_v, paged, scale=scale,
+                    softcap=cfg.attn_softcap, window=window,
+                    key_scale=new_ks, value_scale=new_vs, layer=layer,
+                )
         elif decode:
             idx = cache_index.value
             positions = idx + jnp.arange(s)[None, :]  # (1, s) broadcasts over batch
-            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+            q = turn(q, positions)
+            k = turn(k, positions)
             key_cache = jax.lax.dynamic_update_slice(
                 cached_key.value, k, (0, idx, 0, 0)
             )
@@ -583,8 +619,8 @@ class Attention(nn.Module):
         elif eva:
             from ..ops.eva_attention import eva_attention
 
-            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+            q = turn(q, positions)
+            k = turn(k, positions)
             out, _, _ = eva_attention(
                 q, k, v, mu, phi, chunk=cfg.chunk_size,
                 window=cfg.window_size, scale=scale,
@@ -593,8 +629,8 @@ class Attention(nn.Module):
             # the fused prologue already applied rope; use_rope=False: this
             # attention carries no position
             if not fused_qkv and cfg.use_rope:
-                q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-                k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+                q = turn(q, positions)
+                k = turn(k, positions)
             out = dot_product_attention(
                 q, k, v, mask=mask, causal=cfg.causal,
                 kv_lengths=kv_lengths,
@@ -606,6 +642,9 @@ class Attention(nn.Module):
         # so backward never recomputes the attention kernel
         out = checkpoint_name(out, "attn_out")
         out = out.reshape(b, s, q_dim)
+        if gate is not None:
+            with jax.named_scope("gate"):
+                out = out * jax.nn.sigmoid(gate)
         y = proj("o_proj", cfg.hidden_size, ("heads", "embed"))(out)
         do = delta(out, "o_proj")
         if do is not None:
@@ -762,6 +801,166 @@ class Mamba2(nn.Module):
         return proj("out_proj", cfg.hidden_size, ("mlp", "embed"))(y)
 
 
+class GatedDeltaNet(nn.Module):
+    """The Gated DeltaNet operator (``layer_types`` "linear_attention"), Hk
+    query/key heads of Dk and Hv value heads of Dv (key head j // (Hv / Hk)
+    serves value head j):
+
+        [q | k | v | z] = in_proj_qkvz(x)       Hk Dk, Hk Dk, Hv Dv, Hv Dv
+        [b | a]         = in_proj_ba(x)         Hv, Hv
+        [q | k | v]    <- silu(conv1d([q | k | v]))   depthwise, causal, no bias
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)    float32
+        o_t = the gated delta rule over (q, k, v, g, beta)  ops/gated_delta.py
+        out_proj(o / rms(o) * norm * silu(z))   a head, plain weight
+
+    The column layout of the two fused projections is head-contiguous blocks
+    in the order written (a published checkpoint interleaves them by key
+    head: a permutation of columns). Without a cache the recurrence starts
+    from zero at the start of every row (training, evaluation). Under the
+    serving engine's paged cache (``paged``: ops/attention.PagedKVState) a
+    slot carries each value head's float32 state and the convolution's last
+    taps beside the KV pools, ``cache`` variables ``state`` (num_slots, Hv,
+    Dk, Dv) and ``taps`` (num_slots, taps - 1, width of [q | k | v]), written
+    in place: a ``fresh`` call (a prefill) starts from zero, runs the chunked
+    form and leaves in slot ``paged.slot`` the state and taps after the
+    row's last real position; a call of one position (a decode step) runs
+    the one-position form over every slot and leaves a row whose
+    ``paged.lengths`` is 0 exactly as it was. Several positions onto an
+    existing state (chunked prefill, speculative verification) are not
+    written."""
+
+    config: TransformerConfig
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, paged=None, layer=None):
+        from ..ops.gated_delta import gated_delta_chunked, gated_delta_step
+
+        cfg = self.config
+        dtype = _dtype(cfg)
+        proj = _make_proj(cfg, dtype)
+        hk, hv = cfg.gdn_num_k_heads, cfg.gdn_num_v_heads
+        dk, dv = cfg.gdn_head_k_dim, cfg.gdn_head_v_dim
+        key_dim, value_dim = hk * dk, hv * dv
+        conv_dim, taps = 2 * key_dim + value_dim, cfg.gdn_conv_kernel - 1
+        b, s = x.shape[:2]
+
+        def vector(name, init, size=hv):
+            v = self.param(
+                name, nn.with_partitioning(init, (None,)), (size,), jnp.float32)
+            return v.unbox() if hasattr(v, "unbox") else v
+
+        with jax.named_scope("proj"):
+            qkvz = proj("in_proj_qkvz", conv_dim + value_dim, ("embed", "mlp"))(x)
+            ba = proj("in_proj_ba", 2 * hv, ("embed", None))(x)
+        mixed, z = jnp.split(qkvz, [conv_dim], axis=-1)
+        kernel = self.param(
+            "conv1d",
+            nn.with_partitioning(
+                nn.initializers.variance_scaling(
+                    1.0, "fan_in", "normal", in_axis=0, out_axis=1),
+                (None, "mlp")),
+            (taps + 1, conv_dim), jnp.float32)
+        kernel = (kernel.unbox() if hasattr(kernel, "unbox") else kernel).astype(dtype)
+        a_log = vector("A_log", lambda key, shape, dt: jnp.log(
+            jax.random.uniform(key, shape, dt, 1e-3, 16.0)))
+        dt_bias = vector("dt_bias", nn.initializers.ones_init())
+        b_raw, a_raw = jnp.split(ba.astype(jnp.float32), 2, axis=-1)
+        beta = jax.nn.sigmoid(b_raw)
+        g = -jnp.exp(a_log) * jax.nn.softplus(a_raw + dt_bias)
+
+        cached = False
+        if self.decode:
+            if paged is None or not paged.num_slots:
+                raise NotImplementedError(
+                    "a 'linear_attention' layer carries its state a serving "
+                    "slot: the dense decode cache (models/generation.py) has "
+                    "no place for it; serve it through ServingEngine"
+                )
+            cached = self.has_variable("cache", "state")
+            state = self.variable(
+                "cache", "state",
+                lambda: jnp.zeros((paged.num_slots, hv, dk, dv), jnp.float32))
+            tail = self.variable(
+                "cache", "taps",
+                lambda: jnp.zeros((paged.num_slots, taps, conv_dim), dtype))
+
+        def rows(var):
+            """This layer's (num_slots, ...) rows of a cache leaf: the leaf
+            itself, or (``layer``) its row of a scanned segment's stack."""
+            if layer is None:
+                return var.value
+            return jax.lax.dynamic_index_in_dim(var.value, layer, keepdims=False)
+
+        def put(var, new, slot):
+            """``new`` (n, ...) written over the rows from ``slot`` on, in
+            place: the leaf, stacked or not, stays ONE buffer."""
+            start = (slot,) + (0,) * (new.ndim - 1)
+            if layer is not None:
+                new, start = new[None], (layer,) + start
+            var.value = jax.lax.dynamic_update_slice(
+                var.value, new.astype(var.value.dtype), start)
+
+        def conv(window):
+            """``window`` (B, taps + S, C) -> silu of the causal convolution
+            at its last S positions."""
+            n = window.shape[1] - taps
+            out = sum(kernel[j] * jax.lax.slice_in_dim(window, j, j + n, axis=1)
+                      for j in range(taps + 1))
+            return nn.silu(out)
+
+        def heads(u):
+            q, k, v = jnp.split(u, [key_dim, 2 * key_dim], axis=-1)
+            return (q.reshape(*u.shape[:-1], hk, dk), k.reshape(*u.shape[:-1], hk, dk),
+                    v.reshape(*u.shape[:-1], hv, dv))
+
+        if cached and not paged.fresh:
+            if s != 1:
+                raise NotImplementedError(
+                    "a 'linear_attention' layer: a call of several tokens onto "
+                    "an existing state (chunked or prefix-cached prefill, "
+                    "speculative verification) is not written"
+                )
+            old_tail, old_state = rows(tail), rows(state)
+            live = paged.lengths > 0
+            with jax.named_scope("conv"):
+                window = jnp.concatenate([old_tail, mixed], axis=1)
+                q, k, v = heads(conv(window)[:, 0])
+                put(tail, jnp.where(live[:, None, None], window[:, 1:], old_tail), 0)
+            with jax.named_scope("step"):
+                o, new_state = gated_delta_step(
+                    q, k, v, g[:, 0], beta[:, 0], old_state)
+                put(state, jnp.where(
+                    live[:, None, None, None], new_state, old_state), 0)
+            o = o[:, None]
+        else:
+            lengths = paged.lengths if cached else None
+            with jax.named_scope("conv"):
+                window = jnp.pad(mixed, ((0, 0), (taps, 0), (0, 0)))
+                q, k, v = heads(conv(window))
+            with jax.named_scope("scan"):
+                o, new_state = gated_delta_chunked(q, k, v, g, beta, lengths)
+            if cached:
+                if b != 1:
+                    raise NotImplementedError(
+                        "a prefill fills ONE slot's state a call")
+                with jax.named_scope("conv"):
+                    # the last taps inputs before ``lengths`` (zeros before
+                    # the row's start): the padded window from lengths on
+                    put(tail, jax.lax.dynamic_slice_in_dim(
+                        window[0], paged.lengths[0], taps, axis=0)[None],
+                        paged.slot[0])
+                with jax.named_scope("scan"):
+                    put(state, new_state, paged.slot[0])
+        with jax.named_scope("gate_norm"):
+            scale = vector("norm", nn.initializers.ones_init(), dv)
+            o = o * jax.lax.rsqrt(
+                jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.rms_norm_eps)
+            zf = z.reshape(b, s, hv, dv).astype(jnp.float32)
+            y = (o * scale * nn.silu(zf)).astype(dtype).reshape(b, s, value_dim)
+        return proj("out_proj", cfg.hidden_size, ("mlp", "embed"))(y)
+
+
 def _mlp_activation(cfg: TransformerConfig):
     return {
         "silu": nn.silu,
@@ -875,6 +1074,12 @@ class MoE(nn.Module):
     """
 
     config: TransformerConfig
+    # a serving call (the cache-carrying path, never differentiated): the
+    # ragged dispatch leaves the choices of absent experts out of every group
+    # instead of giving them a group of zero weights (``ops.moe.moe_ragged``
+    # ``forward_only``), and every dispatch sows ``moe_experts_touched``: how
+    # many of the experts held here at least one row of the call chose
+    decode: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -966,6 +1171,13 @@ class MoE(nn.Module):
                 "part of an expert-parallel deployment: ragged dispatch, no "
                 "live ep axis"
             )
+        if self.decode:  # whatever the dispatch: what the routing chose
+            local = sel.reshape(-1) - cfg.moe_expert_offset
+            chosen = jnp.zeros((E + 1,), bool).at[
+                jnp.where((local >= 0) & (local < E), local, E)
+            ].set(True)
+            self.sow("intermediates", "moe_experts_touched",
+                     jnp.sum(chosen[:E]).astype(jnp.int32))
         if dispatch == "ragged":
             from ..ops.moe import (
                 moe_ragged, moe_ragged_ep, padded_expert_shape,
@@ -1001,6 +1213,7 @@ class MoE(nn.Module):
                     expert_offset=cfg.moe_expert_offset,
                     router_width=R,
                     activation=act,
+                    forward_only=self.decode,
                 ).reshape(b, s, h)
                 for name, value in ragged_load_stats(
                     sel, E, cfg.moe_expert_offset, R
@@ -1050,8 +1263,20 @@ class MoE(nn.Module):
             "intermediates", "moe_aux_loss", load_balancing_loss(logits, sel, R)
         )
         if cfg.moe_shared_intermediate_size is not None:
-            out = out + MLP(
+            shared = MLP(
                 cfg, width=cfg.moe_shared_intermediate_size, name="shared")(xc)
+            if cfg.moe_shared_gate:
+                with jax.named_scope("shared_gate"):
+                    w_s = self.param(
+                        "shared_gate",
+                        nn.with_partitioning(
+                            nn.initializers.normal(h ** -0.5), ("embed",)),
+                        (h,), jnp.float32)
+                    w_s = w_s.unbox() if hasattr(w_s, "unbox") else w_s
+                    shared = shared * jax.nn.sigmoid(
+                        jnp.einsum("bsh,h->bs", xc, w_s.astype(dtype))
+                    )[..., None]
+            out = out + shared
         return out.astype(x.dtype)
 
 
@@ -1081,6 +1306,8 @@ class Block(nn.Module):
                 self, x, positions, mask, kv_lengths, paged, lora, scanned), None
         if self.mixer == "conv":
             return _conv_layer(self, x), None
+        if self.mixer == "linear_attention":
+            return _gdn_layer(self, x, paged, lora, scanned), None
         # ``scanned`` is this layer's slice of the per-layer traced data:
         # either the bare layer-window array (the pre-adapter form) or a
         # dict {"window": ..., "lora": {target: {lora_a, lora_b}}} — both
@@ -1142,7 +1369,8 @@ def _feed_forward(block: Block, h, lora=None, mlp_lora=None):
     if ff == "moe":
         # MoE blocks don't take adapters (the expert weights are the
         # specialization mechanism there); attention adapters still apply
-        return MoE(cfg, name="moe")(RMSNorm(cfg, name="mlp_norm")(h))
+        return MoE(cfg, decode=block.decode, name="moe")(
+            RMSNorm(cfg, name="mlp_norm")(h))
     return MLP(cfg, name="mlp")(
         RMSNorm(cfg, name="mlp_norm")(h), lora=lora, lora_stacks=mlp_lora,
     )
@@ -1161,6 +1389,24 @@ def _conv_layer(block: Block, x):
         )
     cfg = block.config
     h = x + ShortConv(cfg, name="conv")(RMSNorm(cfg, name="conv_norm")(x))
+    h = checkpoint_name(h, "attn_res")
+    return constrain_activations(h + _feed_forward(block, h))
+
+
+def _gdn_layer(block: Block, x, paged, lora, scanned):
+    """The layer whose operator is Gated DeltaNet, then its feed-forward. It
+    decodes from a state a slot (``GatedDeltaNet``); it takes no adapters and
+    no fused prologue."""
+    from ..parallel.sharding import constrain_activations
+
+    cfg = block.config
+    if lora is not None:
+        raise NotImplementedError(
+            "adapters ride a stack whose every layer is attention then "
+            "feed-forward: not a 'linear_attention' layer")
+    layer = scanned.get("layer") if isinstance(scanned, dict) else None
+    h = x + GatedDeltaNet(cfg, decode=block.decode, name="gdn")(
+        RMSNorm(cfg, name="gdn_norm")(x), paged, layer)
     h = checkpoint_name(h, "attn_res")
     return constrain_activations(h + _feed_forward(block, h))
 
@@ -1498,7 +1744,11 @@ class CausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, positions=None, mask=None, decode=False,
-                 paged=None, lora=None):
+                 paged=None, lora=None, logits_at=None):
+        # ``logits_at`` (B,): the head reads that row of each sequence alone
+        # and the logits come back (B, 1, V) — a prefill that samples one
+        # token of a padded bucket then never forms width x vocabulary
+        # logits (16,384 x 75,968 float32 are 5 GB). None: every position.
         cfg = self.config
         dtype = _dtype(cfg)
         if positions is None:
@@ -1541,6 +1791,8 @@ class CausalLM(nn.Module):
                 decode and paged is not None and not self.is_initializing()
             ),
         )
+        if logits_at is not None:
+            x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
         x = constrain_activations(RMSNorm(cfg, name="final_norm")(x))
         # logits matmul stays in the compute dtype (bf16 on the MXU — fp32
         # here costs ~4x on the biggest matmul); the loss upcasts to fp32

@@ -74,6 +74,21 @@ class PagedKVState:
     a cache it marks a call that starts a slot's cache from position 0 with
     nothing before it — a prefill, which writes other rows than it was
     given.
+
+    A model may keep, beside the pools, a state a SLOT that is overwritten in
+    place (a recurrent layer's: ``(num_slots, ...)`` leaves, named in
+    :data:`SLOT_STATE_LEAVES`). ``num_slots`` (static) sizes those leaves and
+    ``slot`` (B,) says which slot each row of the call writes; None is "row b
+    is slot b" — a decode batch over every seat. ``fresh`` (static) marks a
+    call that starts its rows' caches from position 0 with nothing before it:
+    a recurrent layer then starts from a zero state, reads none, and
+    attention attends what the call projected instead of gathering the table.
+
+    ``heads_first`` (static): a block of the K/V pools is stored ``(Hkv,
+    block_size, D)`` — each KV head's rows together — instead of
+    ``(block_size, Hkv, D)``; whoever creates the pools decides it by
+    :func:`pool_heads_first` and says so here, and every operation on them
+    reads it here (a pool's shape cannot tell the two apart).
     """
 
     block_table: jax.Array
@@ -84,6 +99,37 @@ class PagedKVState:
     kv_dtype: str = flax.struct.field(pytree_node=False, default="native")
     single_device: bool = flax.struct.field(pytree_node=False, default=False)
     positions: Optional[jax.Array] = None
+    slot: Optional[jax.Array] = None
+    num_slots: int = flax.struct.field(pytree_node=False, default=0)
+    fresh: bool = flax.struct.field(pytree_node=False, default=False)
+    heads_first: bool = flax.struct.field(pytree_node=False, default=False)
+
+
+# What a model's ``cache`` collection may hold, by the variable's name: the
+# engine asks these tables which leaf is what, never a leaf's shape. A paged
+# pool's value is how many trailing axes are one block's own (its block axis
+# stands before them); a slot-state leaf has its slot axis before the
+# trailing axes the value counts.
+PAGED_POOL_LEAVES = {"key_pool": 3, "value_pool": 3, "key_scale": 1,
+                     "value_scale": 1}
+SLOT_STATE_LEAVES = {"state": 3, "taps": 2}
+
+
+def pool_heads_first(kv_heads: int, head_dim: int) -> bool:
+    """Whether a model's K/V pools store a block ``(Hkv, block_size, D)``
+    instead of ``(block_size, Hkv, D)``: a rule of the shapes, for whoever
+    creates the pools (it then says ``PagedKVState.heads_first``).
+
+    Where the decode kernel can read the pools (``head_dim`` whole lanes)
+    and the KV heads do not fill a sublane tile (``Hkv % 8``), XLA:TPU tiles a
+    pool's minor pair ``(Hkv, D)`` below (8, 128) and has to RELAY OUT the
+    whole pool, every step, to hand the kernel its ``(block_size * Hkv, D)``
+    rows: at 2 KV heads of 256 and 64 slots x 16,384 positions, 2 x 1.07 GB
+    copied, 9.1 ms of a 35.8 ms decode step on the v5e (PERF.md, PR 38).
+    Heads first, the minor pair is ``(block_size, D)``, whole tiles, and the
+    same view is free. A block's rows are then ``h * block_size + t``, which
+    the kernel's mask reads (``paged_decode_attention(heads_first=)``)."""
+    return head_dim % 128 == 0 and kv_heads % 8 != 0
 
 
 # floor on the per-token amax scale: keeps all-zero rows (garbage block,
@@ -177,8 +223,18 @@ def paged_update(
     bf, of = blocks.reshape(-1), offsets.reshape(-1)
 
     def put(pool, rows, inner):
-        return _flat_pool(pool, inner).at[bf, of].set(rows).reshape(
-            pool.shape)
+        if inner == 3 and state.heads_first:
+            # (blocks, Hkv, block_size, D): position ``of`` of KV head ``h``
+            # of block ``bf`` is ROW (bf * Hkv + h) * block_size + of of the
+            # pool seen as rows of D — one row a write, so that the scatter
+            # keeps the pool's layout (indexed as [bf, :, of] XLA:TPU turns
+            # the whole pool token-major for the write and back: four
+            # passes over it a call)
+            hkv, d = rows.shape[-2:]
+            at = (bf[:, None] * hkv + jnp.arange(hkv)[None, :]) * bs + of[:, None]
+            return pool.reshape(-1, d).at[at.reshape(-1)].set(
+                rows.reshape(-1, d)).reshape(pool.shape)
+        return _flat_pool(pool, inner).at[bf, of].set(rows).reshape(pool.shape)
 
     if state.kv_dtype == "int8":
         if key_scale is None or value_scale is None:
@@ -212,6 +268,8 @@ def decode_kernel_eligible(state: PagedKVState, q_len: int, pool) -> bool:
     from .flash_attention import kernels_interpreted
 
     kv_heads, head_dim = pool.shape[-2:]
+    if state.heads_first:
+        kv_heads = pool.shape[-3]
     sublanes = 8 * max(1, 4 // jnp.dtype(pool.dtype).itemsize)
     return (
         q_len == 1
@@ -271,17 +329,20 @@ def paged_attention(
         return paged_decode_attention(
             q, key_pool, value_pool, state.block_table, state.cache_len,
             scale=scale, softcap=softcap, window=window, layer=layer,
+            heads_first=state.heads_first,
         )
     b, s = q.shape[:2]
     bs = state.block_size
     max_blocks = state.block_table.shape[1]
     table = state.block_table + _first_block(state, layer)
-    k = _flat_pool(key_pool, 3)[table].reshape(
-        b, max_blocks * bs, *key_pool.shape[-2:]
-    )
-    v = _flat_pool(value_pool, 3)[table].reshape(
-        b, max_blocks * bs, *value_pool.shape[-2:]
-    )
+
+    def gathered(pool):
+        rows = _flat_pool(pool, 3)[table]  # (B, max_blocks, <a block>)
+        if state.heads_first:
+            rows = jnp.swapaxes(rows, 2, 3)  # -> (.., block_size, Hkv, D)
+        return rows.reshape(b, max_blocks * bs, *rows.shape[-2:])
+
+    k, v = gathered(key_pool), gathered(value_pool)
     if state.kv_dtype == "int8":
         if key_scale is None or value_scale is None:
             raise ValueError(

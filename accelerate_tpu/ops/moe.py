@@ -146,14 +146,16 @@ def share_window_rows(num_choices: int, num_experts: int, router_width: int) -> 
 
 
 def _expert_rows(xs, tok, order, weights, group_sizes, w_gate, w_up, w_down,
-                 shape, activation=None):
+                 shape, activation=None, live=None):
     """A run of sorted rows ``xs`` (every one in a group) through the three
     grouped matmuls — two where the experts have no gate matrix (``w_gate``
     None: ``down(activation(up x))``) —, scattered back onto their tokens
     ``tok`` with the routing weights of the sorted choices ``order``:
     ``shape`` = (num_tokens, h). Rows and kernels come as
     :func:`_on_whole_tiles` made them: columns past ``h`` are zeros and are
-    dropped before the scatter."""
+    dropped before the scatter. ``live`` (forward only): the groups cover
+    the first ``live`` rows alone; what the grouped matmuls leave in the
+    others is undefined and is replaced by zeros before the scatter."""
     with jax.named_scope("experts"):
         if w_gate is None:
             hidden = activation(jax.lax.ragged_dot(xs, w_up, group_sizes))
@@ -166,6 +168,9 @@ def _expert_rows(xs, tok, order, weights, group_sizes, w_gate, w_up, w_down,
     with jax.named_scope("combine"):
         w_flat = weights.reshape(-1)[order].astype(out.dtype)
         out = out[:, :shape[1]]  # a slice of every column traces to nothing
+        if live is not None:
+            out = jnp.where(
+                jnp.arange(out.shape[0])[:, None] < live, out, 0)
         # weighted scatter-add back into token order (sums the K expert
         # contributions per token)
         return jnp.zeros(shape, out.dtype).at[tok].add(out * w_flat[:, None])
@@ -182,6 +187,7 @@ def moe_ragged(
     expert_offset: int = 0,
     router_width: Optional[int] = None,
     activation: Optional[Callable] = None,
+    forward_only: bool = False,
 ) -> jax.Array:
     """Exact sparse MoE via grouped matmuls (``jax.lax.ragged_dot``).
 
@@ -224,7 +230,14 @@ def moe_ragged(
     then has undefined rows that no ``jnp.where`` on a forward output
     reaches, and the gather's transpose adds them into ``dx``. A
     ``custom_vjp`` that also zeroes cotangents and backward outputs is
-    exact, and its time follows the routing, which drifts: PERF.md, PR 26.)
+    exact, and its time follows the routing, which drifts: PERF.md, PR 26.
+    ``forward_only`` — a serving call, which nothing differentiates — does
+    give it those group sizes: no zero group, so the held kernels are read
+    where they lie instead of being rebuilt with one more expert behind
+    them every call (at 256 held experts of 2048 x 512 that rebuild would
+    be 1.6 GB read and written a layer, more than a decode step needs in
+    all), and the rows of absent experts, which lie behind every group, are
+    zeroed by one ``where`` on the forward output.)
     On the v5e at LFM2-8B-A1B's widths (8 of 32 experts held, 4 x 4096
     tokens, top 4: 65,536 sorted rows a layer, C = 32,768) all rows through
     the grouped matmuls took 65.7 ms a layer forward + backward and the
@@ -297,7 +310,7 @@ def moe_ragged(
     with jax.named_scope("dispatch"):
         # one more group, of zero weights, for the choices of absent experts
         x, w_gate, w_up, w_down = _on_whole_tiles(
-            x, w_gate, w_up, w_down, zero_groups=1)
+            x, w_gate, w_up, w_down, zero_groups=0 if forward_only else 1)
         held = jnp.bincount(flat_sel, length=E + 1).astype(jnp.int32)[:E]
         ends = jnp.cumsum(held)  # where each held expert's sorted rows end
         starts = ends - held
@@ -308,10 +321,12 @@ def moe_ragged(
         rows = slice(lo, hi)
         with jax.named_scope("dispatch"):
             sizes = jnp.clip(ends, lo, hi) - jnp.clip(starts, lo, hi)
-            sizes = jnp.concatenate([sizes, (hi - lo - jnp.sum(sizes))[None]])
+            live = jnp.sum(sizes) if forward_only else None
+            if not forward_only:
+                sizes = jnp.concatenate([sizes, (hi - lo - jnp.sum(sizes))[None]])
             xs = jnp.take(x, tok[rows], axis=0)  # (hi - lo, h)
         return _expert_rows(xs, tok[rows], order[rows], weights, sizes,
-                            w_gate, w_up, w_down, (T, h), activation)
+                            w_gate, w_up, w_down, (T, h), activation, live)
 
     C = share_window_rows(TK, E, R)
     out = window(0, C)

@@ -13,7 +13,9 @@ repeat, 11 %, 2.9 ms; this kernel 2.6 %, 0.55 ms — PERF.md, PR 25.)
 Layout. The pools stay as they are, ``(num_blocks, block_size, Hkv, D)``,
 in HBM. One block is ``block_size * Hkv`` rows of ``D`` lanes; a chunk of
 blocks copied to VMEM is a ``(tokens * Hkv, D)`` matrix whose row
-``t * Hkv + h`` is token ``t`` of KV head ``h``. All ``H`` query heads are
+``t * Hkv + h`` is token ``t`` of KV head ``h`` (pools stored heads first,
+``(num_blocks, Hkv, block_size, D)``: row ``h * block_size + t`` of its
+block; only the mask's two index computations differ). All ``H`` query heads are
 scored against all those rows in ONE matmul, and the columns of another
 KV head are masked out (``row % Hkv == head // G``) together with the
 dead positions: the masked softmax over ``tokens * Hkv`` columns IS the
@@ -52,7 +54,7 @@ N_BUFFERS = 2
 
 def _decode_kernel(*refs, scale: float, softcap: Optional[float],
                    windowed: bool, block_size: int, chunk: int,
-                   kv_heads: int):
+                   kv_heads: int, heads_first: bool):
     if windowed:
         (table_ref, len_ref, win_ref, q_ref, k_hbm, v_hbm, o_ref,
          k_buf, v_buf, sems) = refs
@@ -126,10 +128,15 @@ def _decode_kernel(*refs, scale: float, softcap: Optional[float],
         cursor = issue(cursor)
 
     col = jax.lax.broadcasted_iota(jnp.int32, (n_heads, rows), 1)
-    own_head = (col % kv_heads) == (
+    if heads_first:  # row h * block_size + t of a block
+        col_head = (col % blk_rows) // block_size
+        col_token = (col // blk_rows) * block_size + col % block_size
+    else:  # row t * Hkv + h
+        col_head = col % kv_heads
+        col_token = col // kv_heads
+    own_head = col_head == (
         jax.lax.broadcasted_iota(jnp.int32, (n_heads, rows), 0) // group
     )
-    col_token = col // kv_heads
 
     def slot_body(b, carry):
         c_lo, c_hi = chunk_range(b)
@@ -189,9 +196,12 @@ def paged_decode_attention(
     softcap: Optional[float] = None,
     window=None,
     layer=None,
+    heads_first: bool = False,
 ) -> jax.Array:
     """``q`` (B, 1, H, D) against the blocks ``block_table`` (B,
-    max_blocks) names in the pools (num_blocks, block_size, Hkv, D);
+    max_blocks) names in the pools (num_blocks, block_size, Hkv, D) — with
+    ``heads_first`` (num_blocks, Hkv, block_size, D): the same rows of a
+    block, each KV head's together (``ops.attention.pool_heads_first``);
     slot ``b``'s query sits at global position ``cache_len[b]`` (its own
     K/V already written there). Returns (B, 1, H, D) at ``q``'s dtype:
     fp32 scores and softmax statistics, the pools' dtype into the MXU.
@@ -204,6 +214,8 @@ def paged_decode_attention(
     if s != 1:
         raise ValueError(f"paged_decode_attention is the S == 1 shape, got {s}")
     block_size, kv_heads = key_pool.shape[-3:-1]
+    if heads_first:
+        kv_heads, block_size = block_size, kv_heads
     if layer is not None:
         block_table = block_table + layer * key_pool.shape[-4]
     scale = scale if scale is not None else d ** -0.5
@@ -217,6 +229,7 @@ def paged_decode_attention(
     kernel = functools.partial(
         _decode_kernel, scale=scale, softcap=softcap, windowed=windowed,
         block_size=block_size, chunk=chunk, kv_heads=kv_heads,
+        heads_first=heads_first,
     )
     whole = pl.BlockSpec((b, h, d), lambda i, *refs: (0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)

@@ -42,8 +42,14 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..logging import get_logger
-from ..models.transformer import qkv_in_place
-from ..ops.attention import PagedKVState, decode_kernel_eligible
+from ..models.transformer import layer_kinds, qkv_in_place
+from ..ops.attention import (
+    PAGED_POOL_LEAVES,
+    SLOT_STATE_LEAVES,
+    PagedKVState,
+    decode_kernel_eligible,
+    pool_heads_first,
+)
 from ..utils.profiling import annotate
 from .block_pool import BlockPool, PrefixCache, prefix_keys
 from .sampling import SlotSampling, sample_tokens
@@ -297,6 +303,12 @@ class ServingEngine:
             from ..ops.eva_attention import EvaLayout
 
             self._eva = EvaLayout(cfg.window_size, cfg.chunk_size, block_size)
+        # a stack with recurrent layers (``layer_types`` "linear_attention"):
+        # beside the pools of its attention layers a slot carries a state
+        # that every position overwrites in place. There is no block of it
+        # to share, copy, swap or hand off, and no several positions onto it
+        self._recurrent = "linear_attention" in (
+            getattr(cfg, "layer_types", None) or ())
         features = [name for name, on in (
             ("prefix_cache", prefix_cache),
             ("spec_decode", spec_decode is not None),
@@ -320,10 +332,10 @@ class ServingEngine:
         self._fetched = 0
         self._fetched_ahead = 0
         for feature in features:
-            self._refuse_for_eva(feature)
+            self._refuse_block_list_feature(feature)
             self._land_or_refuse(feature)
         if kv_dtype == "int8":
-            self._refuse_for_eva("kv_dtype 'int8'")
+            self._refuse_block_list_feature("kv_dtype 'int8'")
         self._max_table = (
             self._eva.peak_blocks(cfg.max_seq_len) if self._eva is not None
             else -(-cfg.max_seq_len // block_size)
@@ -400,10 +412,12 @@ class ServingEngine:
         # qkv_in_place: of the traced decode programs, how many read each
         # layer's q/k/v kernels where they lie in the stacked parameters
         # (models/transformer.py::qkv_in_place: one position a slot)
+        # recurrent_state: of the traced prefill and decode programs, how
+        # many carry a per-slot state beside the pools (all or none)
         self._traces = {
             "prefill": 0, "decode": 0, "decode_attn_kernel": 0, "cow": 0,
             "verify": 0, "swap_out": 0, "swap_in": 0, "kv_in_place": 0,
-            "eva": 0, "qkv_in_place": 0,
+            "eva": 0, "qkv_in_place": 0, "recurrent_state": 0,
         }
         self._rollovers_total = 0
         # every bucket width a prefill ever ran at — the set
@@ -425,6 +439,14 @@ class ServingEngine:
 
         from ..models.generation import init_cache
 
+        # what every paged state of this engine says beside its tables: how
+        # a block of the pools is laid out (a rule of the model's shapes; the
+        # eva regime's own writes are written for one row a position and
+        # head) and, for a recurrent stack, how many seats carry a state
+        seat_and_layout = {"heads_first": self._eva is None and pool_heads_first(
+            cfg.num_kv_heads, cfg.head_dim)}
+        if self._recurrent:
+            seat_and_layout["num_slots"] = max_slots
         init_state = PagedKVState(
             block_table=jnp.zeros((1, self._max_table), jnp.int32),
             cache_len=jnp.zeros((1,), jnp.int32),
@@ -432,40 +454,60 @@ class ServingEngine:
             num_blocks=num_blocks,
             block_size=block_size,
             kv_dtype=kv_state_dtype,
+            **seat_and_layout,
         )
         self.cache = init_cache(
             model.init, jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
             decode=True, paged=init_state, device=self._device,
         )
-        # paged cache leaves by position: (flat leaf index, block axis)
-        # for every K/V pool ((..., num_blocks, block_size, Hkv, D)) and
-        # every int8 scale array ((..., num_blocks, block_size)) — the
-        # shared shape contract the COW copy and the preemption swap
-        # gather/scatter address blocks through
+        # the cache leaves, by what the model declares each to be (its
+        # variable's name: ops/attention.py's two tables), never by shape:
+        # (flat leaf index, block axis) for every K/V pool ((..., num_blocks,
+        # block_size, Hkv, D)) and every int8 scale array ((..., num_blocks,
+        # block_size)) — what the COW copy and the preemption swap address
+        # blocks through —, and the per-slot state leaves ((..., num_slots,
+        # ...)), which no block list reaches
         self._kv_leaf_info: list[tuple[int, int]] = []
-        kv_bytes = 0
-        for i, leaf in enumerate(jax.tree.leaves(self.cache)):
-            if (
-                leaf.ndim >= 4
-                and leaf.shape[-4] == num_blocks
-                and leaf.shape[-3] == block_size
-            ):
-                self._kv_leaf_info.append((i, leaf.ndim - 4))
+        kv_bytes = state_bytes = 0
+        flat, _ = jax.tree_util.tree_flatten_with_path(self.cache)
+        for i, (path, leaf) in enumerate(flat):
+            name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+            if name in PAGED_POOL_LEAVES:
+                axis = leaf.ndim - 1 - PAGED_POOL_LEAVES[name]
+                assert leaf.shape[axis] == num_blocks and block_size in (
+                    leaf.shape[axis + 1:axis + 3]), (name, leaf.shape)
+                self._kv_leaf_info.append((i, axis))
                 kv_bytes += leaf.nbytes
-            elif (
-                leaf.ndim >= 2
-                and leaf.shape[-2] == num_blocks
-                and leaf.shape[-1] == block_size
-            ):
-                self._kv_leaf_info.append((i, leaf.ndim - 2))
-                kv_bytes += leaf.nbytes
+            elif name in SLOT_STATE_LEAVES:
+                axis = leaf.ndim - 1 - SLOT_STATE_LEAVES[name]
+                assert leaf.shape[axis] == max_slots, (name, leaf.shape)
+                state_bytes += leaf.nbytes
+            else:
+                raise NotImplementedError(
+                    f"cache leaf {name!r} {leaf.shape} is neither a paged "
+                    "pool nor a per-slot state (ops/attention.py: "
+                    "PAGED_POOL_LEAVES, SLOT_STATE_LEAVES)"
+                )
         # the sizing headline int8 halves: HBM bytes per cached token
-        # across every layer's pools (+ scale overhead when quantized)
+        # across every layer's pools (+ scale overhead when quantized);
+        # what a seat holds beside them whatever its length: the state
         self.kv_bytes_per_token = kv_bytes / (num_blocks * block_size)
         self.kv_pool_bytes = kv_bytes
+        self.state_bytes_per_slot = state_bytes / max_slots
 
         traces = self._traces
+        kv_leaf_info = self._kv_leaf_info
         eva = self._eva is not None
+        recurrent = self._recurrent
+        # a decode step of a stack with experts also says how many distinct
+        # experts held here its rows chose (``experts_touched`` on the fetch
+        # span): the experts are most of what such a step reads
+        counts = int(cfg.num_experts > 0)
+        self._counts_experts = bool(counts)
+        self._experts_held = cfg.num_experts * sum(
+            ff == "moe" for _, ff in layer_kinds(cfg)) if counts else 0
+        # of the decode steps in flight, by dispatch count: (rows, seated)
+        self._step_stats: dict[int, tuple] = {}
         # what the engine knows about its pools and attention cannot see
         # from inside a trace: they sit whole on the weights' one device
         single_device = self._device is not None
@@ -494,10 +536,11 @@ class ServingEngine:
             }
 
         def _prefill(params, cache, ids, table, length, cached_len, key,
-                     temp, *lora_args):
+                     temp, slot=None, *lora_args):
             traces["prefill"] += 1  # trace-time counter (not per call)
             traces["kv_in_place"] += 1
             traces["eva"] += eva
+            traces["recurrent_state"] += recurrent
             # cached_len > 0 is the warm-hit path: ``ids`` holds only the
             # UNCACHED tail and the paged cache already contains KV for
             # the first cached_len positions (shared prefix blocks in
@@ -513,15 +556,19 @@ class ServingEngine:
                 block_size=block_size,
                 kv_dtype=kv_state_dtype,
                 single_device=single_device,
+                # a recurrent stack: the seat whose state this prompt fills,
+                # from zero (``slot`` (1,); no other model is told one)
+                slot=slot, **seat_and_layout,
+                fresh=recurrent,
             )
+            # the head reads the last VALID row of the padded bucket alone,
+            # not the padded tail: width x vocabulary logits are never formed
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, ids, decode=True,
                 paged=state, mutable=["cache"], **_lora_kwargs(lora_args),
+                logits_at=length - 1,
             )
-            # last VALID row of the padded bucket, not the padded tail
-            last = jnp.take_along_axis(
-                logits, (length - 1)[:, None, None], axis=1
-            )[:, 0]
+            last = logits[:, 0]
             with jax.named_scope("sample"):
                 token = sample_tokens(last, key, temp, top_k=top_k, top_p=top_p)
             return mutated["cache"], token
@@ -531,6 +578,7 @@ class ServingEngine:
             traces["decode"] += 1  # zero-retrace contract rides on this
             traces["kv_in_place"] += 1
             traces["eva"] += eva
+            traces["recurrent_state"] += recurrent
             # eva: ``cache_lens`` are the slots' ROWS and ``positions`` what
             # they stand for (None for every other model: one and the same)
             state = PagedKVState(
@@ -542,6 +590,7 @@ class ServingEngine:
                 kv_dtype=kv_state_dtype,
                 single_device=single_device,
                 positions=positions,
+                **seat_and_layout,
             )
             traces["decode_attn_kernel"] += decode_kernel_eligible(
                 state, tokens.shape[1], jax.tree.leaves(cache)[pool_leaf]
@@ -549,12 +598,24 @@ class ServingEngine:
             traces["qkv_in_place"] += qkv_in_place(True, tokens.shape[1])
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, tokens, decode=True,
-                paged=state, mutable=["cache"], **_lora_kwargs(lora_args),
+                paged=state, mutable=["cache"] + ["intermediates"] * counts,
+                **_lora_kwargs(lora_args),
             )
             with jax.named_scope("sample"):
                 token = sample_tokens(
                     logits[:, -1], key, temps, top_k=top_k, top_p=top_p
                 )
+            if counts:
+                # the step's distinct held experts, summed over the expert
+                # layers, behind the tokens: one array, one copy to the host
+                touched = sum(
+                    jnp.sum(leaf) for path, leaf in
+                    jax.tree_util.tree_flatten_with_path(
+                        mutated["intermediates"])[0]
+                    if any(getattr(k, "key", None) == "moe_experts_touched"
+                           for k in path))
+                token = jnp.concatenate(
+                    [token, touched.astype(token.dtype)[None]])
             return mutated["cache"], token
 
         def _rollover(params, cache, src, dst):
@@ -576,29 +637,16 @@ class ServingEngine:
 
         def _cow(cache, src, dst):
             traces["cow"] += 1  # one compiled program, reused per copy
-            # Copy one block row in every per-layer K/V pool. Pools are
-            # nn.scan-stacked: leaves shaped (L, num_blocks, block_size,
-            # kv_heads, head_dim) — match on the (num_blocks, block_size)
-            # axes rather than names so non-pool cache leaves pass through.
-            def copy(leaf):
-                if (
-                    leaf.ndim >= 4
-                    and leaf.shape[-4] == num_blocks
-                    and leaf.shape[-3] == block_size
-                ):
-                    lead = (slice(None),) * (leaf.ndim - 4)
-                    return leaf.at[lead + (dst,)].set(leaf[lead + (src,)])
-                if (
-                    leaf.ndim >= 2
-                    and leaf.shape[-2] == num_blocks
-                    and leaf.shape[-1] == block_size
-                ):
-                    # int8 KV: the per-token scale rows travel with
-                    # their block's quantized contents
-                    lead = (slice(None),) * (leaf.ndim - 2)
-                    return leaf.at[lead + (dst,)].set(leaf[lead + (src,)])
-                return leaf
-            return jax.tree.map(copy, cache)
+            # Copy one block row in every paged leaf — each layer's K/V
+            # pools and, under int8 KV, the per-token scale rows that
+            # travel with their block's quantized contents —, found as
+            # the swap finds them: ``_kv_leaf_info``'s (leaf, block axis)
+            leaves = list(jax.tree.leaves(cache))
+            for i, axis in kv_leaf_info:
+                lead = (slice(None),) * axis
+                leaves[i] = leaves[i].at[lead + (dst,)].set(
+                    leaves[i][lead + (src,)])
+            return jax.tree.unflatten(jax.tree.structure(cache), leaves)
 
         def _make_verify(width: int):
             # Speculative verification: ONE target pass at the fixed
@@ -622,6 +670,7 @@ class ServingEngine:
                     block_size=block_size,
                     kv_dtype=kv_state_dtype,
                     single_device=single_device,
+                    **seat_and_layout,
                 )
                 logits, mutated = model.apply(
                     {"params": params, "cache": cache}, tokens, decode=True,
@@ -654,6 +703,8 @@ class ServingEngine:
             # from it, after it has landed too, so the decode program sees
             # ONE kind of argument
             def _feed(prev, tokens, from_prev):
+                if counts:  # the step before's count rides behind its tokens
+                    prev = prev[:max_slots]
                 return jnp.where(from_prev[:, None], prev[:, None], tokens)
 
             # the fed tokens lie where a step's own do: on the weights' one
@@ -666,7 +717,8 @@ class ServingEngine:
             self._feed_fn = jax.jit(_feed, out_shardings=on_mesh)
             none = np.zeros(max_slots, np.int32)
             self._no_tokens = jax.device_put(
-                none, on_mesh if self._device is None else self._device
+                np.zeros(max_slots + counts, np.int32),
+                on_mesh if self._device is None else self._device,
             )
             with self._placed():
                 self._feed_fn(self._no_tokens, none[:, None],
@@ -793,20 +845,32 @@ class ServingEngine:
                 f"got {role!r}"
             )
         if role != "colocated":
-            self._refuse_for_eva(f"role {role!r}")
+            self._refuse_block_list_feature(f"role {role!r}")
             self._land_or_refuse(f"role {role!r}")
         self._role = role
 
-    def _refuse_for_eva(self, feature: str) -> None:
+    def _refuse_block_list_feature(self, feature: str) -> None:
         """Engine features that take a request's state for a list of
-        blocks holding one row a position each are refused, by name, for a
-        model whose cache is not that."""
+        blocks holding one row a position each (prefix cache, copy on
+        write, preemption swap, hand-off, speculation, chunked prefill, int8
+        pools, adapters) are refused, by name, for a model whose cache is
+        not that. The ONE predicate."""
         if self._eva is not None:
-            raise NotImplementedError(
-                f"{feature} is not written for attention_class 'eva': a "
-                "request's cache is chunk summaries beside a window of "
-                "rows, not one row a position (ROADMAP Reach A4)"
+            why = (
+                "attention_class 'eva': a request's cache is chunk summaries "
+                "beside a window of rows, not one row a position"
             )
+        elif self._recurrent:
+            why = (
+                "a stack with 'linear_attention' layers: a request's cache "
+                "is a recurrent state a slot, overwritten in place, beside "
+                "the blocks of its attention layers"
+            )
+        else:
+            return
+        raise NotImplementedError(
+            f"{feature} is not written for {why} (ROADMAP Reach A4)"
+        )
 
     def _land_or_refuse(self, feature: str) -> None:
         """Engine features that change a slot's blocks, its place in the
@@ -979,6 +1043,7 @@ class ServingEngine:
         else:
             # whoever it decoded for ended meanwhile (an eos)
             self._ahead = None
+            self._step_stats.clear()
         with annotate("atpu:serve.emit") as phase:
             if emit is not None:
                 emit(events)
@@ -1088,6 +1153,11 @@ class ServingEngine:
             jnp.asarray(slot_ids, jnp.int32),
         )
 
+    def _slot_arg(self, slot: Slot):
+        """What a prefill is told of the seat it fills: its index, where a
+        state lives there (a recurrent stack), else nothing."""
+        return np.asarray([slot.index], np.int32) if self._recurrent else None
+
     def _cow_block(self, slot: Slot, tindex: int) -> None:
         """Copy-on-write table position ``tindex`` of ``slot``: allocate
         a private block (the admission-reserved spare first), one
@@ -1132,7 +1202,8 @@ class ServingEngine:
         tail_len = prompt_len - cached
         bucket = _next_pow2(tail_len)
         with annotate("atpu:serve.prefill", request_id=req.request_id,
-                      bucket=bucket, cached=cached):
+                      bucket=bucket, cached=cached, tokens=tail_len,
+                      width=bucket):
             self.span_log.on_prefill(
                 req.request_id, self._now(), cached_prefix_tokens=cached
             )
@@ -1160,6 +1231,7 @@ class ServingEngine:
                 jnp.asarray([tail_len], jnp.int32),
                 jnp.asarray([cached], jnp.int32), self._split_key(),
                 jnp.asarray([req.temperature], jnp.float32),
+                self._slot_arg(slot),
                 *self._lora_call_args([self._slot_adapter[slot.index]]),
             )
             token = int(np.asarray(token)[0])
@@ -1295,6 +1367,7 @@ class ServingEngine:
                 jnp.asarray([chunk_len], jnp.int32),
                 jnp.asarray([start], jnp.int32), key,
                 jnp.asarray([req.temperature], jnp.float32),
+                None,  # no seat's state: a recurrent stack refuses chunks
                 *self._lora_call_args([self._slot_adapter[slot.index]]),
             )
             slot.cache_len = start + chunk_len
@@ -1677,7 +1750,7 @@ class ServingEngine:
         seated, the dedup split (``reused_blocks`` found warm in the
         local CACHED index vs ``moved_blocks`` scatter-restored from the
         manifest's host images and their ``moved_bytes``)."""
-        self._refuse_for_eva("hand-off (acquire)")
+        self._refuse_block_list_feature("hand-off (acquire)")
         self._land_or_refuse("hand-off (acquire)")
         res = self._try_seat_manifest(manifest)
         if res is None:
@@ -1880,8 +1953,18 @@ class ServingEngine:
         with annotate("atpu:serve.decode.wait", program=program, n=n) as span:
             if span.is_enabled():
                 out.block_until_ready()
-        with annotate("atpu:serve.decode.fetch"):
-            return np.asarray(out)
+        with annotate("atpu:serve.decode.fetch") as span:
+            host = np.asarray(out)
+            held = self._step_stats.pop(n, None) if program == "jit__decode" else None
+            if held is not None:
+                # the step that was fetched, in one place: what it held
+                # (from its dispatch) and what its routing touched
+                rows, seated = held
+                span.set_metadata(
+                    rows=rows, seated=seated,
+                    experts_touched=int(host[self.max_slots]),
+                    experts_held=self._experts_held)
+            return host
 
     def _fills_window(self, slot: Slot, ahead: int = 0) -> bool:
         """eva: ``slot`` (``ahead`` positions from now) stands past a
@@ -1926,10 +2009,11 @@ class ServingEngine:
                     positions[slot.index] = slot.cache_len + ahead
             # what this step's attention reads: the rows the seated slots
             # hold, their new one included, and the positions they stand for
-            phase.set_metadata(
-                rows=int(cache_lens.sum()) + len(slots),
-                positions=at + len(slots),
-            )
+            rows = int(cache_lens.sum()) + len(slots)
+            phase.set_metadata(rows=rows, positions=at + len(slots))
+            if self._counts_experts:
+                self._step_stats[self._dispatched["jit__decode"]] = (
+                    rows, len(slots))
             if self._feed_fn is None:
                 fed = jnp.asarray(tokens)
             else:
@@ -2261,6 +2345,7 @@ class ServingEngine:
             "resumes_total": self._resumes_total,
             "prefill_chunks_total": self._prefill_chunks_total,
             "kv_bytes_per_token": self.kv_bytes_per_token,
+            "state_bytes_per_slot": self.state_bytes_per_slot,
             "pool_alias_bytes": self.pool_alias_bytes,
             "decode_ahead_share": self.decode_ahead_share,
         }
@@ -2426,6 +2511,8 @@ class ServingEngine:
                     jax.ShapeDtypeStruct((1,), i32),
                     key_s,
                     jax.ShapeDtypeStruct((1,), jnp.float32),
+                    (jax.ShapeDtypeStruct((1,), i32)
+                     if self._recurrent else None),
                     *lora1,
                     bucket=bucket,
                 )
@@ -2583,7 +2670,7 @@ class ServingEngine:
         in-flight shared blocks keep their refcounts and drain
         normally)."""
         if enabled:
-            self._refuse_for_eva("prefix_cache")
+            self._refuse_block_list_feature("prefix_cache")
             self._land_or_refuse("prefix_cache")
             if model_fingerprint is not None:
                 self._model_fingerprint = model_fingerprint
@@ -2611,7 +2698,7 @@ class ServingEngine:
             self._proposer = None
             self.scheduler.lookahead_tokens = 0
             return
-        self._refuse_for_eva("spec_decode")
+        self._refuse_block_list_feature("spec_decode")
         self._land_or_refuse("spec_decode")
         proposer = self._proposers.get(id(spec))
         if proposer is None:

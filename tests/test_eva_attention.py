@@ -497,13 +497,17 @@ def test_a_request_longer_than_max_seq_len_is_refused_at_add_request(
 # at ISSUE 31 (parent b456505, where it read e0b6a19d9582e3dd): a decode call
 # of one position pins its q/k/v projections two-dimensional
 # (``transformer.qkv_in_place``), one ``optimization_barrier`` a layer more;
-# "forward" and "prefill" are the ones of 276d936 still. The two train
-# cells' own configurations (the gradient of ``CausalLM.loss_fn`` at the
-# cells' rows and widths, abstract weights) were pinned at b456505
+# "forward" is the one of 276d936 still. "prefill" was re-pinned at ISSUE 38
+# (parent 6dd8b99, where it read 0e358c278cc3492e): every prefill hands the
+# model the row it samples from (``CausalLM(logits_at=)``), so the final norm
+# and the head run over that one row and not over the padded bucket; nothing
+# else of the text moved. The two train cells' own configurations (the
+# gradient of ``CausalLM.loss_fn`` at the cells' rows and widths, abstract
+# weights) were pinned at b456505
 PARENT = {
     "forward": "185f96c4ca8bf1ef",
     "decode": "afba1e62f208a5b5",
-    "prefill": "0e358c278cc3492e",
+    "prefill": "fe2eb56c78af16d5",
     "train-dense-1chip": "9ce6fdb3752bf4cd",
     "train-moe-conv-1chip": "53210cc0beedf81c",
 }

@@ -545,8 +545,7 @@ _SSM = dict(single_sublayer=True, layer_types=("mamba", "moe"), mamba_num_heads=
     (dict(_SSM, mamba_n_groups=3), "multiple of mamba_n_groups"),
     (dict(_SSM, mlp_gated=False, moe_dispatch="capacity"), "without a gate matrix"),
     (dict(_SSM, mlp_activation="relu2"), "relu2 experts are"),
-    (dict(_SSM, moe_router="softmax", moe_shared_intermediate_size=8),
-     "beside sigmoid-routed experts"),
+    (dict(_SSM, moe_shared_gate=True), "moe_shared_gate gates a shared expert"),
     (dict(use_rope=False, attention_class="eva"), "carries no position"),
     (dict(use_rope=False, rope_scaling={"rope_type": "linear", "factor": 2.0}),
      "carries no position"),
