@@ -28,8 +28,9 @@ utils/megatron_lm.py:1641-); this is the native equivalent.
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -145,17 +146,94 @@ def share_window_rows(num_choices: int, num_experts: int, router_width: int) -> 
     return min(_WINDOW_MULTIPLE * math.ceil(window / _WINDOW_MULTIPLE), num_choices)
 
 
-def _expert_rows(xs, tok, order, weights, group_sizes, w_gate, w_up, w_down,
-                 shape, activation=None, live=None):
+class _Way(NamedTuple):
+    """One window of the sorted choices as a permutation, both directions:
+    ``tok`` (C,) the token and ``chosen`` (C,) the choice ``j * T + t`` of
+    each of the window's rows; ``place`` (K, T) the window's row of each
+    choice, and ``ok`` (K, T; None: all) False where a choice has no row
+    there that counts and ``place`` names any row at all."""
+    tok: jax.Array
+    chosen: jax.Array
+    place: jax.Array
+    ok: Optional[jax.Array]
+
+
+def _sum_back(y, weights, way: _Way, h: int):
+    """Token ``t`` is the sum over ``j`` of row ``place[j, t]`` of ``y``,
+    its first ``h`` columns, by ``weights[j, t]`` (None: 1), zero where
+    ``ok`` is False — a select, never a product with 0; float32, cast once.
+    (K, T, h) sums over whole slabs: no row is relaid."""
+    picked = y.at[way.place].get(mode="promise_in_bounds")[..., :h]
+    if way.ok is not None:
+        picked = jnp.where(way.ok[..., None], picked, 0)
+    picked = picked.astype(jnp.float32)
+    if weights is not None:
+        picked = picked * weights[..., None].astype(jnp.float32)
+    return jnp.sum(picked, axis=0).astype(y.dtype)
+
+
+# Rows from token order to expert-sorted order and back, for a caller that
+# knows what JAX cannot: ``tok`` and ``place`` are the two directions of ONE
+# permutation of the choices, so each way is a gather and each is the
+# other's transpose — autodiff alone would transpose either gather to a
+# scatter-add of T*K rows.
+@jax.custom_vjp
+def _to_experts(x, way: _Way):
+    """``x[tok]``: (T, h) -> (C, h). Transposed: the sum of a token's rows."""
+    return x.at[way.tok].get(mode="promise_in_bounds")
+
+
+def _to_experts_fwd(x, way):
+    return _to_experts(x, way), way
+
+
+def _to_experts_bwd(way, g):
+    return _sum_back(g, None, way, g.shape[-1]), None
+
+
+_to_experts.defvjp(_to_experts_fwd, _to_experts_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_tokens(y, weights, way: _Way, h: int):
+    """:func:`_sum_back`: (C, h'), (K, T) -> (T, h). Transposed: ``g[tok]``
+    by each row's weight — the rows and no more, nothing ``T*K`` long —,
+    and a weight's cotangent is its row's product with ``g[tok]``."""
+    return _sum_back(y, weights, way, h)
+
+
+def _to_tokens_fwd(y, weights, way, h):
+    return _sum_back(y, weights, way, h), (y, weights, way)
+
+
+def _to_tokens_bwd(h, res, g):
+    y, weights, way = res
+    g_rows = g.at[way.tok].get(mode="promise_in_bounds").astype(jnp.float32)
+    w_rows = weights.reshape(-1).at[way.chosen].get(mode="promise_in_bounds")
+    dy = jnp.pad((g_rows * w_rows[:, None].astype(jnp.float32)).astype(y.dtype),
+                 ((0, 0), (0, y.shape[-1] - h)))
+    d_row = jnp.sum(y[:, :h].astype(jnp.float32) * g_rows, axis=-1)
+    dw = d_row.at[way.place].get(mode="promise_in_bounds")
+    if way.ok is not None:
+        dw = jnp.where(way.ok, dw, 0)
+    return dy, dw.astype(weights.dtype), None
+
+
+_to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
+
+
+def _expert_rows(xs, way: _Way, weights, group_sizes, w_gate, w_up, w_down,
+                 h, activation=None):
     """A run of sorted rows ``xs`` (every one in a group) through the three
     grouped matmuls — two where the experts have no gate matrix (``w_gate``
-    None: ``down(activation(up x))``) —, scattered back onto their tokens
-    ``tok`` with the routing weights of the sorted choices ``order``:
-    ``shape`` = (num_tokens, h). Rows and kernels come as
-    :func:`_on_whole_tiles` made them: columns past ``h`` are zeros and are
-    dropped before the scatter. ``live`` (forward only): the groups cover
-    the first ``live`` rows alone; what the grouped matmuls leave in the
-    others is undefined and is replaced by zeros before the scatter."""
+    None: ``down(activation(up x))``) —, then back in token order by a
+    gather (:func:`_to_tokens`): token ``t``'s ``j``-th choice takes row
+    ``way.place[j, t]`` of the run — zeros where ``way.ok[j, t]`` is False:
+    a choice that lies in another window, or, forward only, past the live
+    rows, where what the grouped matmuls leave is undefined — and a token
+    is the float32 sum of its ``K`` rows by the routing ``weights`` (K, T),
+    cast once. Rows and kernels come as :func:`_on_whole_tiles` made them:
+    columns past ``h`` are zeros and are dropped before the sum."""
     with jax.named_scope("experts"):
         if w_gate is None:
             hidden = activation(jax.lax.ragged_dot(xs, w_up, group_sizes))
@@ -166,14 +244,7 @@ def _expert_rows(xs, tok, order, weights, group_sizes, w_gate, w_up, w_down,
         out = jax.lax.ragged_dot(hidden, w_down, group_sizes)  # (rows, h)
 
     with jax.named_scope("combine"):
-        w_flat = weights.reshape(-1)[order].astype(out.dtype)
-        out = out[:, :shape[1]]  # a slice of every column traces to nothing
-        if live is not None:
-            out = jnp.where(
-                jnp.arange(out.shape[0])[:, None] < live, out, 0)
-        # weighted scatter-add back into token order (sums the K expert
-        # contributions per token)
-        return jnp.zeros(shape, out.dtype).at[tok].add(out * w_flat[:, None])
+        return _to_tokens(out, weights, way, h)
 
 
 def moe_ragged(
@@ -207,10 +278,10 @@ def moe_ragged(
     from shapes alone; all of them where that would be under a quarter of
     them):
 
-    * the **first window**, rows ``[0, C)``, always runs: the gather, the
-      three grouped matmuls and the weighted scatter-add over ``C`` rows,
-      the held experts' groups clipped into the window and one more group,
-      of ZERO weights, for whatever else lies in it;
+    * the **first window**, rows ``[0, C)``, always runs: the gather of
+      ``C`` rows, the three grouped matmuls over them and the gather back
+      (below), the held experts' groups clipped into the window and one
+      more group, of ZERO weights, for whatever else lies in it;
     * the **rest window**, rows ``[C, T*K)``, is the same under a
       ``lax.cond`` that is true only when a held row lies past ``C``; its
       transpose is a ``cond`` on the same predicate, so the backward skips
@@ -228,7 +299,7 @@ def moe_ragged(
     live rows alone: XLA:TPU's kernel then SKIPS the other rows but leaves
     stale memory in them, in the backward kernel too: autodiff's ``d xs``
     then has undefined rows that no ``jnp.where`` on a forward output
-    reaches, and the gather's transpose adds them into ``dx``. A
+    reaches, and the gather's transpose sums them into ``dx``. A
     ``custom_vjp`` that also zeroes cotangents and backward outputs is
     exact, and its time follows the routing, which drifts: PERF.md, PR 26.
     ``forward_only`` — a serving call, which nothing differentiates — does
@@ -237,7 +308,7 @@ def moe_ragged(
     them every call (at 256 held experts of 2048 x 512 that rebuild would
     be 1.6 GB read and written a layer, more than a decode step needs in
     all), and the rows of absent experts, which lie behind every group, are
-    zeroed by one ``where`` on the forward output.)
+    left out by one ``where`` on the rows gathered back.)
     On the v5e at LFM2-8B-A1B's widths (8 of 32 experts held, 4 x 4096
     tokens, top 4: 65,536 sorted rows a layer, C = 32,768) all rows through
     the grouped matmuls took 65.7 ms a layer forward + backward and the
@@ -253,8 +324,8 @@ def moe_ragged(
     to :func:`padded_expert_shape` — ``f`` to the next multiple of 512 and
     then ``h`` too, each where that is at most a seventh wider; one
     ``jnp.pad`` a kernel in the rebuild that adds the zero group, one of
-    ``x`` before the gather, the padded columns dropped before the
-    scatter-add. The parameters, their gradients and the result keep the
+    ``x`` before the gather, the padded columns dropped before the sum over
+    a token's choices. The parameters, their gradients and the result keep the
     published shapes and values (zeros meet zeros; the sums may be taken in
     another order); a width of whole lanes lowers to the text it had. On the
     v5e at Nemotron-3-Nano's widths (h 2688, f 1856 = 14.5 lanes; 8 of 128
@@ -277,8 +348,34 @@ def moe_ragged(
     (and to :func:`moe_ragged_ep` at ep>1 — its docstring carries the
     drop-rate/collective-bytes evidence).
 
-    Fully differentiable (ragged_dot has grad rules; sort / gather /
-    scatter-add are linear; ``cond`` differentiates branch by branch).
+    **Rows move by gathers alone, both ways and in both passes.** Every
+    token has exactly ``K`` choices, so the stable sort of the ``T*K``
+    choices (choice-major: token ``t``'s ``j``-th is choice ``j * T + t``)
+    is a permutation ``order`` with an inverse ``inv``, which a second
+    ``argsort`` gives. Dispatch gathers ``x[order % T]``; combine gathers
+    the grouped matmuls' rows back a row a choice, ``out[inv]`` seen as
+    ``(K, T, h)`` (whole slabs: no row is relaid), and a token is the
+    float32 sum of its ``K`` rows by the routing weights, cast once. JAX
+    would transpose each gather to a scatter-add of ``T*K`` rows because it
+    cannot know the indices are a permutation; the code does, and says so
+    once (:func:`_to_experts` and :func:`_to_tokens`, each a ``custom_vjp``
+    whose backward pass is the other): the transpose of "rows by ``order``"
+    is "rows by ``inv``" summed over ``K``, and the other way round; a
+    routing weight's cotangent is its row's product with the gathered
+    cotangent, read where the row lies. No ``scatter`` of a row is left in
+    the layer, forward or backward (``bincount``'s is scalar), and nothing
+    ``T*K`` rows long is kept for the backward pass that the scatter-add
+    did not keep. A window gathers back from its own rows at ``inv - lo``
+    and a select leaves out the choices that lie in the other window — and,
+    ``forward_only``, past the live rows, where the grouped matmuls wrote
+    nothing. On the v5e one layer forward + backward under its cell's remat
+    read 125.5 -> 108.1 ms at ``train-ssm-moe-1chip``'s shapes (98,304 rows
+    of 3,072) and 43.3 -> 40.8 at ``train-moe-conv-1chip``'s (two windows of
+    32,768 of 2,048), forward alone 17.9 -> 12.0 ms at 8,192 x 10 rows of
+    2,048 (``moe_rows_on_chip.py``, my chip runs, PR 39; PERF.md section 6).
+
+    Fully differentiable (ragged_dot has grad rules; sort and the gathers
+    are linear; ``cond`` differentiates branch by branch).
 
     Use on single-chip / data-parallel meshes. With ``ep_size > 1``
     the per-expert group sizes are data-dependent, which GSPMD cannot
@@ -295,17 +392,23 @@ def moe_ragged(
     TK = T * K
     R = router_width or E
     with jax.named_scope("dispatch"):
-        local = sel.reshape(TK) - expert_offset
+        # choice-major: token t's j-th choice is choice j * T + t, so what is
+        # gathered back, a row a choice, is (K, T, h) and sums over whole slabs
+        local = sel.T.reshape(TK) - expert_offset
+        weights = weights.T
         flat_sel = jnp.where((local >= 0) & (local < E), local, E)
-        order = jnp.argsort(flat_sel)  # stable: ties keep token order
-        tok = jnp.repeat(jnp.arange(T), K)[order]  # source token per sorted row
+        order = jnp.argsort(flat_sel)  # the choice of each sorted row; stable
+        # the sorted row of each choice: the same permutation the other way
+        inv = jnp.argsort(order).reshape(K, T)
+        tok = order % T  # source token per sorted row
     if R == E:  # every choice is of a held expert: one run of T*K rows
+        way = _Way(tok, order, inv, None)
         with jax.named_scope("dispatch"):
             x, w_gate, w_up, w_down = _on_whole_tiles(x, w_gate, w_up, w_down)
-            xs = jnp.take(x, tok, axis=0)  # (TK, h) rows grouped by expert
+            xs = _to_experts(x, way)  # (TK, h) rows grouped by expert
             group_sizes = jnp.bincount(flat_sel, length=E + 1).astype(jnp.int32)
-        return _expert_rows(xs, tok, order, weights, group_sizes[:E],
-                            w_gate, w_up, w_down, (T, h), activation)
+        return _expert_rows(xs, way, weights, group_sizes[:E],
+                            w_gate, w_up, w_down, h, activation)
 
     with jax.named_scope("dispatch"):
         # one more group, of zero weights, for the choices of absent experts
@@ -321,12 +424,20 @@ def moe_ragged(
         rows = slice(lo, hi)
         with jax.named_scope("dispatch"):
             sizes = jnp.clip(ends, lo, hi) - jnp.clip(starts, lo, hi)
-            live = jnp.sum(sizes) if forward_only else None
-            if not forward_only:
+            # where each choice lies in this window, and whether it does
+            place, ok = inv, None
+            if hi - lo < TK:
+                place = jnp.clip(inv - lo, 0, hi - lo - 1)
+                ok = (inv >= lo) & (inv < hi)
+            if forward_only:  # and whether a grouped matmul writes its row
+                written = inv < lo + jnp.sum(sizes)
+                ok = written if ok is None else ok & written
+            else:
                 sizes = jnp.concatenate([sizes, (hi - lo - jnp.sum(sizes))[None]])
-            xs = jnp.take(x, tok[rows], axis=0)  # (hi - lo, h)
-        return _expert_rows(xs, tok[rows], order[rows], weights, sizes,
-                            w_gate, w_up, w_down, (T, h), activation, live)
+            way = _Way(tok[rows], order[rows], place, ok)
+            xs = _to_experts(x, way)  # (hi - lo, h)
+        return _expert_rows(xs, way, weights, sizes,
+                            w_gate, w_up, w_down, h, activation)
 
     C = share_window_rows(TK, E, R)
     out = window(0, C)

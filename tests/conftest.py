@@ -50,3 +50,25 @@ def reset_singletons():
     GradientState._reset_state()
     PartialState._reset_state()
     reset_program_registry()
+
+
+@pytest.fixture
+def row_scatters():
+    """``(text, width) -> [(operand, update)]``: the types of every
+    ``stablehlo.scatter`` of a lowered program whose update is a block of
+    rows at least ``width`` wide (``bincount``'s and ``take_along_axis``'s
+    scalar updates are not among them)."""
+    import re
+
+    def find(text, width):
+        found = re.findall(
+            r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<([^>]*)>, tensor<[^>]*>, '
+            r'tensor<([^>]*)>\)', text, flags=re.DOTALL)
+        wide = []
+        for operand, update in found:
+            dims = [int(d) for d in update.split("x")[:-1]]
+            if len(dims) >= 2 and dims[-1] >= width:
+                wide.append((operand, update))
+        return wide
+
+    return find

@@ -380,6 +380,81 @@ def test_the_window_form_equals_the_dense_oracle(where, remat):
             assert not np.any(np.asarray(g)), name
 
 
+# (t, k, h, f, held, offset, router width, gated, forward only): the callers
+# of ``moe_ragged`` beside the two windows above
+_GATHER_FORMS = {
+    # a serving call: one window, no zero group, rows past the live ones undefined
+    "forward_only": (64, 4, 16, 8, 4, 4, 16, True, True),
+    "forward_only_nothing_held": (64, 4, 16, 8, 4, 12, 16, True, True),
+    # 4 of 64 held: under an eighth, so all 1024 rows in ONE window with the
+    # zero group; 448 x 464 reach the grouped matmuls as 512 x 512
+    "one_window_padded": (256, 4, 448, 464, 4, 0, 64, False, False),
+    # Mixtral-style: every expert held, no zero group
+    "every_expert": (128, 2, 16, 8, 4, 0, 4, True, False),
+}
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "dots_ragged"])
+@pytest.mark.parametrize("case", sorted(_GATHER_FORMS))
+def test_the_gather_form_equals_the_dense_oracle(case, remat, monkeypatch):
+    """Value, dx, dweights, dw_gate, dw_up, dw_down of ``moe_ragged``
+    (float32) against the dense oracle where rows move by gathers alone:
+    a serving call, one window with a zero group and padded sides, every
+    expert held — plain and under ``jax.checkpoint``. A serving call is
+    never differentiated: its value is held, with every row the grouped
+    matmuls did NOT write (past the live rows) poisoned with NaN, so a
+    product with zero in place of the select would show."""
+    from accelerate_tpu.models.transformer import _REMAT_POLICIES
+    from accelerate_tpu.ops import moe
+
+    t, k, h, f, held, offset, width, gated, forward_only = _GATHER_FORMS[case]
+    ks = jax.random.split(jax.random.PRNGKey(17), 7)
+    sel = jax.random.randint(ks[0], (t, k), 0, width)
+    if case == "forward_only_nothing_held":
+        sel = sel % offset  # every choice on an expert below the held ones
+    operands = (jax.random.normal(ks[1], (t, h)), jax.random.uniform(ks[2], (t, k)),
+                jax.random.normal(ks[3], (held, h, f)) * h ** -0.5 if gated else None,
+                jax.random.normal(ks[4], (held, h, f)) * h ** -0.5,
+                jax.random.normal(ks[5], (held, f, h)) * f ** -0.5)
+    cot = jax.random.normal(ks[6], (t, h))
+    act = None if gated else _relu2
+    argnums = tuple(i for i, a in enumerate(operands) if a is not None)
+    live = int(jnp.sum((sel >= offset) & (sel < offset + held)))
+    assert (live == 0) == (case == "forward_only_nothing_held")
+    if forward_only:
+        real = jax.lax.ragged_dot
+
+        def poisoned(lhs, rhs, group_sizes, **kw):
+            written = jnp.arange(lhs.shape[0])[:, None] < jnp.sum(group_sizes)
+            return jnp.where(written, real(lhs, rhs, group_sizes, **kw), jnp.nan)
+
+        monkeypatch.setattr(moe.jax.lax, "ragged_dot", poisoned)
+
+    def layer(*a):
+        return moe_ragged(a[0], sel, *a[1:], expert_offset=offset, router_width=width,
+                          activation=act, forward_only=forward_only)
+
+    def oracle(*a):
+        return _plain_experts(a[0], sel, *a[1:], offset, act or _relu2)
+
+    if remat:
+        layer = jax.checkpoint(layer, policy=_REMAT_POLICIES["dots_ragged"]())
+    with jax.default_matmul_precision("highest"):
+        if forward_only:
+            got, want = (jax.jit(layer)(*operands),), (oracle(*operands),)
+        else:
+            got, want = (jax.tree.leaves(jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(fn(*a) * cot), argnums=argnums))(*operands))
+                for fn in (layer, oracle))
+    names = ["value"] if forward_only else (
+        ["value", "dx", "dweights"] + ["dw_gate"] * gated + ["dw_up", "dw_down"])
+    assert len(got) == len(want) == len(names)
+    for name, g, w in zip(names, got, want):
+        scale = float(jnp.max(jnp.abs(w))) + 1e-6
+        assert np.all(np.isfinite(np.asarray(g))), name
+        np.testing.assert_allclose(g, w, atol=2e-5 * scale, rtol=2e-4, err_msg=name)
+
+
 def _eqns(jaxpr, name):
     return [e for e in jaxpr.eqns if e.primitive.name == name]
 
@@ -411,15 +486,19 @@ def test_a_share_holding_layer_is_one_window_and_one_cond(held, width, want):
     assert [e.invars[0].aval.shape[0] for e in taken] == [t * k - want] * 3
 
 
-# sha256 of jit(moe_ragged).lower(...).as_text() at bf6ebce, the parent of the
-# PR that brought the window (T 64, k 2, 4 experts, h 16, f 8, float32)
-_ALL_HELD_LOWERED = "6c366365cc27260e9e310b8ff1fd0d693c8ecd3310060350c848df7ac3bdba61"
+# sha256 of jit(moe_ragged).lower(...).as_text() as PR 39 left it, when the rows
+# came to move by gathers alone (T 64, k 2, 4 experts, h 16, f 8, float32);
+# until then the text of bf6ebce, the parent of the PR that brought the window
+_ALL_HELD_LOWERED = "5588d3e948ae8bfb42a097ac93f1d4bbe7a5571fe88a4fa756f04b9b60de28f8"
 
 
 @pytest.mark.parametrize("router_width", [None, 4])
-def test_with_every_expert_held_the_lowered_text_is_the_parents(router_width):
-    """No window, no zero group, no ``cond``: a Mixtral-style layer lowers
-    byte for byte as it did before a share could be held in part."""
+def test_with_every_expert_held_the_lowered_text_is_one_run_of_gathers(
+        router_width, row_scatters):
+    """No window, no zero group, no ``cond``, no row scattered: a
+    Mixtral-style layer lowers to one run of all its rows — the same text
+    whether the router's width is given or not — and a change of that text
+    is a change of every Mixtral-style program, to be made knowingly."""
     t, k, e, h, f = 64, 2, 4, 16, 8
     sds = jax.ShapeDtypeStruct
     text = jax.jit(lambda *a: moe_ragged(*a, router_width=router_width)).lower(
@@ -427,6 +506,7 @@ def test_with_every_expert_held_the_lowered_text_is_the_parents(router_width):
         sds((e, h, f), jnp.float32), sds((e, h, f), jnp.float32),
         sds((e, f, h), jnp.float32)).as_text()
     assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    assert not row_scatters(text, 1) and f"tensor<{k}x{t}x{h}xf32>" in text
     assert hashlib.sha256(text.encode()).hexdigest() == _ALL_HELD_LOWERED
 
 
@@ -502,18 +582,22 @@ def test_a_convolution_layer_refuses_to_decode():
 # --------------------------------------------------------------------------- #
 # the scope paths the benchmark's per-layer metrics read
 # --------------------------------------------------------------------------- #
-def _scope_paths(model, ids):
-    """Every operation's path in the loss's gradient, cleaned as the
-    benchmark's ``scope_share`` cleans the profiler's."""
+def _op_names(model, ids):
+    """Every operation's raw path in the compiled gradient of the loss."""
     import re
-
-    from harness import program_trace
 
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"]
     text = jax.jit(jax.grad(CausalLM.loss_fn(model))).lower(
         params, {"input_ids": ids}).compile().as_text()
-    return {program_trace.scope_of(raw, "CausalLM")
-            for raw in re.findall(r'op_name="([^"]+)"', text)}
+    return set(re.findall(r'op_name="([^"]+)"', text))
+
+
+def _scope_paths(model, ids):
+    """Every operation's path in the loss's gradient, cleaned as the
+    benchmark's ``scope_share`` cleans the profiler's."""
+    from harness import program_trace
+
+    return {program_trace.scope_of(raw, "CausalLM") for raw in _op_names(model, ids)}
 
 
 def _metric_scope(name):
@@ -551,6 +635,34 @@ def test_the_per_layer_metrics_find_their_scope_paths(stack):
         rx = re.compile(_metric_scope(name))
         hit = [p for p in paths if rx.search(p)]
         assert any(sample in p for p in hit), (name, sample, sorted(hit)[:8])
+
+
+def test_the_backward_gathers_of_an_expert_layer_lie_in_its_own_scopes():
+    """The backward passes of ``_to_tokens`` and ``_to_experts`` are traced
+    when the gradient is: their gathers (the combine's way back, ``g[tok]``,
+    and the dispatch's, the sum over a token's k rows) must still read
+    ``moe/combine/`` and ``moe/dispatch/``,
+    or ``moe_route_device_share.*`` and the two shares that split it would
+    lose them to UNSCOPED and read a gain that is none. Every scatter the
+    gradient keeps in an expert layer is scalar (``bincount``; the router's
+    ``take_along_axis``)."""
+    import re
+
+    from harness import program_trace
+
+    cfg = tiny_hybrid.config()
+    names = _op_names(_model(cfg, remat="dots_ragged"), _ids(cfg))
+    backward = {program_trace.scope_of(raw, "CausalLM") for raw in names
+                if "transpose(" in raw and "rematted_computation" not in raw}
+    for metric in ("moe_combine_device_share.train", "moe_dispatch_device_share.train"):
+        rx = re.compile(_metric_scope(metric))
+        hit = sorted(p for p in backward if rx.search(p))
+        assert any(p.endswith("/gather") for p in hit), (metric, hit)
+        assert re.compile(_metric_scope("moe_route_device_share.train")).search(hit[0])
+    moved = {p.rsplit("/", 1)[-1] for p in backward if "/moe/" in p}
+    assert "reduce_sum" in moved and "scatter-add" in moved  # the sum over k; the router's
+    assert not [p for p in backward
+                if re.search(r"moe/(dispatch|combine)/.*scatter", p)], backward
 
 
 def test_the_grouped_matmul_that_skips_rows_equals_the_one_that_does_not():
@@ -720,25 +832,27 @@ def test_the_grouped_matmuls_are_given_whole_tiles_and_the_same_rows(
     assert not kernel_concats
 
 
-# sha256[:16] of jit(moe_ragged).lower(...).as_text() at bdfc916, the parent of
-# the PR that brought the pad (T 64, k 2, 4 experts, h 16, float32), by
-# (router width, gated, expert width); ungated with activation=jax.nn.relu
+# sha256[:16] of jit(moe_ragged).lower(...).as_text() (T 64, k 2, 4 experts, h
+# 16, float32), by (router width, gated, expert width); ungated with
+# activation=jax.nn.relu. Pinned at bdfc916, the parent of the PR that brought
+# the pad, and again at PR 39, which moved every ``moe_ragged`` text (rows by
+# gathers alone) and no pad
 _UNPADDED_LOWERED = {
-    (None, True, 8): "6c366365cc27260e", (None, True, 130): "4ea19eb26dfe0c1d",
-    (None, True, 256): "74802344c815b38d", (None, False, 8): "876c302639b0eb45",
-    (None, False, 130): "6b295dc839f6d3dd", (None, False, 256): "10fc94b392bd4e51",
-    (16, True, 8): "a3e56a86948700dd", (16, True, 130): "fd9c69cab2e5c970",
-    (16, True, 256): "3fce1014377733be", (16, False, 8): "808089419c781cff",
-    (16, False, 130): "5f1c8499b402b2bc", (16, False, 256): "107c8077ee1acc6e",
+    (None, True, 8): "5588d3e948ae8bfb", (None, True, 130): "f76432b61f77068d",
+    (None, True, 256): "7a7c877faee6b69b", (None, False, 8): "6a5520e25d010214",
+    (None, False, 130): "66091cafb51b6ae2", (None, False, 256): "a90d5a6c6422c8a8",
+    (16, True, 8): "1b17e1e9e1c14b96", (16, True, 130): "5bfaa52903197b4a",
+    (16, True, 256): "13b851b3f210366b", (16, False, 8): "30e5f6cd2651dc0a",
+    (16, False, 130): "be38a12acc96e7e9", (16, False, 256): "844d9aa7a86e1f96",
 }
 
 
 @pytest.mark.parametrize("case", sorted(_UNPADDED_LOWERED, key=str), ids=str)
-def test_a_width_the_rule_leaves_lowers_to_the_parents_text(case):
+def test_a_width_the_rule_leaves_lowers_to_the_pinned_text(case):
     """Whole lanes (256), and widths too far from a tile (8, 130): with a
     share held (the zero group is still a ``concatenate``) and with every
-    expert held, gated and not, the lowered text is byte for byte the
-    parent's."""
+    expert held, gated and not, the lowered text holds no pad and is byte
+    for byte the pinned one."""
     router_width, gated, f = case
     t, k, e, h = 64, 2, 4, 16
     sds = jax.ShapeDtypeStruct

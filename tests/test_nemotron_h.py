@@ -718,3 +718,24 @@ def test_the_cells_grouped_matmuls_are_given_whole_tiles_at_its_real_shapes():
     stacks = set(re.findall(r"tensor<9x(\d{4})x(\d{4})xbf16>", text))
     assert stacks == {("3072", "2048"), ("2048", "3072")}, stacks
     assert "tensor<8x2688x1856xf32>" in text and "tensor<8x1856x2688xf32>" in text
+
+
+def test_the_cells_expert_layers_scatter_no_rows_at_its_real_shapes(row_scatters):
+    """``train-ssm-moe-1chip``'s own gradient, lowered at 2 x 8192 tokens
+    over abstract weights: the one ``stablehlo.scatter`` left that adds
+    whole rows is the embedding's gradient (16,384 rows of the table from 2
+    x 8192 tokens); none moves the 98,304 sorted rows of an expert layer,
+    forward (the combine) or backward (the dispatch's transpose) — both are
+    gathers by the sort's permutation and its inverse (``ops.moe._to_experts``, ``_to_tokens``)."""
+    cell = cells.load_cell(tiny.TWIN)
+    spec, cfg = cell["spec"], cell["config"]
+    seq = spec["traffic"]["seq_len"]
+    model = CausalLM(common.program_config(
+        cfg, max_seq_len=seq, remat=spec["remat"], dtype=spec["compute_dtype"]))
+    ids = jax.ShapeDtypeStruct((spec["rows_per_chip"], seq), jnp.int32)
+    text = jax.jit(jax.grad(CausalLM.loss_fn(model))).lower(
+        W.abstract_tree(cfg, jnp.float32), {"input_ids": ids}).as_text()
+    assert row_scatters(text, 128) == [
+        ("16384x2688xbf16", "2x8192x2688xbf16")], row_scatters(text, 128)
+    # the gathers are there: 98,304 rows at the padded 3072 and the published 2688
+    assert "tensor<98304x3072xbf16>" in text and "tensor<6x16384x3072xbf16>" in text

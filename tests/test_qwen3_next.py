@@ -535,6 +535,35 @@ def test_any_stack_with_experts_says_how_many_a_decode_step_touched(dispatch):
     assert float(np.max(want[at].max(-1) - want[at, np.asarray(tokens)])) < 1e-4
 
 
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_the_engines_programs_scatter_no_rows_of_an_expert_layer(
+        params, program, row_scatters):
+    """The tiny twin's own ``jit__prefill`` (32 positions wide) and
+    ``jit__decode``, lowered: the expert layers bring their rows back into
+    token order by a gather (``ops.moe._to_tokens``) and a sum over the choices,
+    so the only ``stablehlo.scatter``s of whole rows are the K/V write's two
+    (``attn/kv_write``: rows of ``head_dim`` into the paged pools);
+    ``bincount``'s and ``moe_experts_touched``'s are scalar."""
+    eng = ServingEngine(_model(), params, max_slots=2, block_size=4)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    n, table = eng.max_slots, eng._max_table
+    if program == "prefill":
+        lowered = eng._prefill_fn.lower(
+            params, eng.cache, i32(1, 32), i32(1, table), i32(1), i32(1), eng._key,
+            f32(1), i32(1))
+    else:
+        lowered = eng._decode_fn.lower(
+            params, eng.cache, i32(n, 1), i32(n, table), i32(n), i32(n), f32(n),
+            eng._key)
+    text = lowered.as_text()
+    hidden, rows = CFG["hidden_size"], 32 if program == "prefill" else n
+    picked = f"tensor<{CFG['num_experts_per_tok']}x{rows}x{hidden}xf32>"
+    assert picked in text  # the gather: a row a choice, (k, T, hidden)
+    kv_row = f"{rows}x{CFG['num_key_value_heads']}x{CFG['head_dim']}xf32"
+    assert [update for _, update in row_scatters(text, CFG["head_dim"])] == [kv_row] * 2
+
+
 @pytest.mark.parametrize("hybrid", [False, True], ids=["dense", "hybrid"])
 def test_the_head_read_at_one_row_is_that_row_of_every_rows_logits(params, hybrid):
     """``CausalLM(logits_at=)``: what every prefill of the engine samples
