@@ -12,7 +12,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 
-SUPPORTED_ROPE_TYPES = ("default", "llama3", "linear")
+SUPPORTED_ROPE_TYPES = ("default", "llama3", "linear", "yarn")
 # per-layer operators a ``layer_types`` entry may name (config.json names),
 # and the feed-forwards it may name where a layer is ONE sublayer
 # (``single_sublayer``: "mamba", "moe" and "mlp" stand in such a stack only)
@@ -33,6 +33,8 @@ _ROPE_REQUIRED_KEYS = {
         "high_freq_factor",
         "original_max_position_embeddings",
     ),
+    # beta_fast / beta_slow (32 / 1) and mscale / mscale_all_dim may follow
+    "yarn": ("factor", "original_max_position_embeddings"),
 }
 
 
@@ -193,8 +195,10 @@ class TransformerConfig:
     moe_norm_topk_prob: bool = True
     moe_norm_topk_eps: float = 1e-6
     moe_routed_scaling_factor: float = 1.0
-    # group-limited routing (config.json ``n_group`` / ``topk_group``: the
-    # choice restricted to the best groups of experts) is not written: 1, 1
+    # group-limited routing (config.json ``n_group`` / ``topk_group``): the
+    # router's outputs in ``moe_n_group`` equal groups, a group's score the
+    # sum of its two largest choice scores, the choice made inside the
+    # ``moe_topk_group`` best groups alone. (1, 1): among all outputs
     moe_n_group: int = 1
     moe_topk_group: int = 1
     # a shared expert beside the routed ones: a plain feed-forward of this
@@ -264,6 +268,20 @@ class TransformerConfig:
     # q_proj is twice as wide: each head's columns are [q | gate], and the
     # attention output is multiplied by sigmoid(gate) before o_proj
     attn_output_gate: bool = False
+    # multi-head LATENT attention (config.json names; ``kv_lora_rank`` set
+    # turns it on, in every layer: models/transformer.LatentAttention).
+    # Queries come through a ``q_lora_rank`` bottleneck as ``num_heads`` heads
+    # of [``qk_nope_head_dim`` | ``qk_rope_head_dim``]; a position's keys and
+    # values are ONE latent row [c_kv (``kv_lora_rank``) | k_rope
+    # (``qk_rope_head_dim``)] shared by all heads — what the cache holds —,
+    # from which ``kv_b_proj`` gives each head ``qk_nope_head_dim`` of key and
+    # ``v_head_dim`` of value. ``head_dim`` is then the score's width,
+    # ``qk_nope_head_dim + qk_rope_head_dim``; ``num_kv_heads`` says nothing
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
     # fp8 projections: e4m3 fwd / e5m2 bwd matmuls (ops/fp8.py) — the
     # TransformerEngine capability; pair with mixed_precision="fp8"
     fp8: bool = False
@@ -294,6 +312,10 @@ class TransformerConfig:
         # crashing only at trace time) would pass every weight check and
         # still diverge from the source model
         validate_rope_scaling(self.rope_scaling)
+        if rope_type(self.rope_scaling) == "yarn" and self.fused_kernels:
+            raise ValueError(
+                "rope_scaling type 'yarn' scales cos and sin, which the fused "
+                "norm -> qkv -> rope prologue (fused_kernels) does not")
         if self.sliding_window is not None:
             if self.sliding_window <= 0:
                 raise ValueError(
@@ -405,6 +427,7 @@ class TransformerConfig:
                 )
         self._validate_single_sublayer()
         self._validate_gated_layers()
+        self._validate_latent_attention()
         if not self.use_rope:
             clash = [
                 name for name, on in (
@@ -450,11 +473,17 @@ class TransformerConfig:
                     "through the ragged dispatch only (moe_dispatch 'auto' "
                     "or 'ragged')"
                 )
-            if self.moe_n_group != 1 or self.moe_topk_group != 1:
+            groups, kept = self.moe_n_group, self.moe_topk_group
+            if (groups < 1 or width % groups or not 1 <= kept <= groups
+                    or self.num_experts_per_tok > kept * (width // groups)
+                    or (groups > 1 and (width // groups < 2
+                                        or self.moe_router != "sigmoid"))):
                 raise ValueError(
-                    f"moe_n_group {self.moe_n_group} / moe_topk_group "
-                    f"{self.moe_topk_group}: group-limited routing is not "
-                    "written; the router chooses among all its outputs (1, 1)"
+                    f"moe_n_group {groups} / moe_topk_group {kept}: the "
+                    f"router's {width} outputs in equal groups of at least "
+                    "two, 1 <= moe_topk_group <= moe_n_group, the kept groups "
+                    f"hold the {self.num_experts_per_tok} choices, and the "
+                    "group limit is written for moe_router 'sigmoid'"
                 )
             if not self.mlp_gated and self.moe_dispatch == "capacity":
                 raise ValueError(
@@ -555,6 +584,53 @@ class TransformerConfig:
                     f"{name} is written for plain softmax attention, unfused: "
                     f"it cannot be combined with {clash}"
                 )
+
+    def _validate_latent_attention(self):
+        """Latent attention's sizes, and each thing written for per-head K
+        and V that it cannot be combined with, by name."""
+        if self.kv_lora_rank is None:
+            if self.q_lora_rank is not None:
+                raise ValueError(
+                    "q_lora_rank is latent attention's query bottleneck: set "
+                    "kv_lora_rank too")
+            return
+        sizes = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+                 self.qk_rope_head_dim, self.v_head_dim)
+        if None in sizes or min(sizes) < 1 or self.qk_rope_head_dim % 2:
+            raise ValueError(
+                f"latent attention: q_lora_rank, kv_lora_rank, qk_nope_head_dim,"
+                f" qk_rope_head_dim (even) and v_head_dim must be set and "
+                f"positive, got {sizes}")
+        width = self.qk_nope_head_dim + self.qk_rope_head_dim
+        clash = [
+            name for name, on in (
+                ("num_kv_heads", self.num_kv_heads not in (None, self.num_heads)),
+                ("head_dim", self.head_dim not in (None, width)),
+                ("qkv_bias", self.qkv_bias),
+                ("qk_norm", self.qk_norm),
+                ("attn_output_gate", self.attn_output_gate),
+                ("partial_rotary_factor", self.partial_rotary_factor != 1.0),
+                ("use_rope=False", not self.use_rope),
+                ("causal=False", not self.causal),
+                ("sliding_window", self.sliding_window is not None),
+                ("layer_windows", self.layer_windows is not None),
+                ("attn_softcap", self.attn_softcap is not None),
+                ("query_pre_attn_scalar", self.query_pre_attn_scalar is not None),
+                ("attention_class", self.attention_class is not None),
+                ("attention_impl='ring'", self.attention_impl == "ring"),
+                ("fused_kernels", self.fused_kernels),
+                ("fp8", self.fp8),
+                ("single_sublayer", self.single_sublayer),
+                ("layer_types", any(
+                    t != "full_attention" for t in self.layer_types or ())),
+            ) if on
+        ]
+        if clash:
+            raise ValueError(
+                "latent attention (kv_lora_rank) shares one latent row a "
+                "position among all heads and rotates a part of the key of "
+                f"its own; it cannot be combined with {clash}")
+        self.head_dim = width
 
     # ------------------------------------------------------------------ #
     # presets (BASELINE.md model families)

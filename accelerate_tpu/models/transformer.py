@@ -20,6 +20,7 @@ TPU-native design decisions:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -28,8 +29,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops.attention import dot_product_attention, paged_attention, paged_update
+from ..ops.attention import (
+    dot_product_attention, forward_parts, latent_attention, latent_row_width,
+    latent_update, paged_attention, paged_update,
+)
 from .config import FF_LAYER_TYPES, TransformerConfig
+from .config import rope_type as _rope_type
 
 Dtype = Any
 
@@ -81,7 +86,25 @@ class RMSNorm(nn.Module):
             _dtype(cfg) if cfg.fp32_residual else x.dtype)
 
 
-def _scale_rope_freqs(freqs: jax.Array, scaling: Optional[dict]) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1`` (1 at
+    ``factor <= 1``): cos and sin are multiplied by
+    ``m(mscale) / m(mscale_all_dim)`` (``m(1)`` where the dict names neither),
+    and latent attention multiplies its softmax scale by
+    ``m(mscale_all_dim) ** 2``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_cos_sin_factor(scaling: dict) -> float:
+    factor = float(scaling["factor"])
+    if "mscale" in scaling and "mscale_all_dim" in scaling:
+        return (yarn_mscale(factor, float(scaling["mscale"]))
+                / yarn_mscale(factor, float(scaling["mscale_all_dim"])))
+    return float(scaling.get("attention_factor") or yarn_mscale(factor))
+
+
+def _scale_rope_freqs(freqs: jax.Array, scaling: Optional[dict],
+                      theta: Optional[float] = None) -> jax.Array:
     """Apply HF-style rope frequency scaling to inverse frequencies.
 
     ``llama3`` mirrors transformers' ``_compute_llama3_parameters``
@@ -89,11 +112,15 @@ def _scale_rope_freqs(freqs: jax.Array, scaling: Optional[dict]) -> jax.Array:
     original context keep full resolution divided by ``factor``, short
     wavelengths are untouched, and a smooth ramp interpolates between the
     two bands. ``linear`` is plain position-interpolation (freq/factor).
+    ``yarn`` (transformers' ``_compute_yarn_parameters``; needs ``theta``):
+    over the d/2 frequencies of a d-wide head, ``low`` / ``high`` the indices
+    whose wave turns ``beta_fast`` / ``beta_slow`` times over the original
+    context, frequency i is divided by ``factor`` by the share ``ramp_i =
+    clip((i - low) / (high - low), 0, 1)``: the fast ones pass, the slow ones
+    are interpolated.
     The parity anchor is reference utils/modeling.py:1608 — its loader is
     architecture-faithful to whatever rope the checkpoint declares.
     """
-    from .config import rope_type as _rope_type
-
     rt = _rope_type(scaling)
     if rt == "default":
         return freqs
@@ -111,6 +138,20 @@ def _scale_rope_freqs(freqs: jax.Array, scaling: Optional[dict]) -> jax.Array:
         scaled = jnp.where(wavelen > old_len / low, freqs / factor, freqs)
         is_medium = (wavelen <= old_len / low) & (wavelen >= old_len / high)
         return jnp.where(is_medium, smoothed, scaled)
+    if rt == "yarn":
+        d = 2 * freqs.shape[0]
+        old_len = float(scaling["original_max_position_embeddings"])
+
+        def index_of(turns):  # where a wave turns that often over old_len
+            return d * math.log(old_len / (2 * math.pi * turns)) / (
+                2 * math.log(theta))
+
+        low = max(math.floor(index_of(float(scaling.get("beta_fast", 32)))), 0)
+        high = min(math.ceil(index_of(float(scaling.get("beta_slow", 1)))), d - 1)
+        ramp = jnp.clip(
+            (jnp.arange(d // 2, dtype=jnp.float32) - low)
+            / (high - low if high != low else 0.001), 0.0, 1.0)
+        return freqs / factor * ramp + freqs * (1.0 - ramp)
     raise ValueError(f"unsupported rope_scaling type {rt!r}")
 
 
@@ -147,9 +188,13 @@ def rope(
         x = jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
     d = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    freqs = _scale_rope_freqs(freqs, scaling)
+    freqs = _scale_rope_freqs(freqs, scaling, theta)
     angles = positions[:, :, None, None].astype(jnp.float32) * freqs  # (B,S,1,D/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if _rope_type(scaling) == "yarn":
+        stretch = _yarn_cos_sin_factor(scaling)
+        if stretch != 1.0:
+            cos, sin = cos * stretch, sin * stretch
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -652,6 +697,144 @@ class Attention(nn.Module):
         return y
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (``config.kv_lora_rank``; DeepSeek-V2/V3).
+
+    ``c_q = norm(x W_DQ)``; ``q = c_q W_UQ`` as ``num_heads`` heads of
+    ``[q_nope | q_rope]``, ``q_rope`` rotated. ``[c_kv | k_r] = x W_DKV``;
+    ``c_kv = norm(c_kv)``, ``k_rope = rope(k_r)``, ONE for all heads: the
+    latent row ``[c_kv | k_rope]`` is all a position keeps. ``kv_b_proj``
+    (``W_UKV``, ONE leaf) gives head h its ``[k_nope | v]`` from ``c_kv``:
+
+        score_h(t, s) = (q_nope_h(t) . k_nope_h(s) + q_rope_h(t) . k_rope(s)) * scale
+
+    causal softmax in float32, ``o_h = sum_s P v_h(s)``, ``o_proj`` over the
+    heads' outputs. ``scale`` is ``head_dim ** -0.5`` times YaRN's
+    ``m(mscale_all_dim) ** 2`` where the rope scaling names one.
+
+    Two forms of the one layer, on the same parameters:
+
+    * **expanded** — a call that sees only what it projects: training and
+      evaluation, and a serving PREFILL (``paged.fresh``). Every position's
+      latent goes through ``kv_b_proj`` and attention runs over per-head keys
+      ``head_dim`` wide and values ``v_head_dim`` wide (flash where the
+      dispatch takes it). A serving call whose per-head q, k, v would pass
+      ``ops.attention.FORWARD_PART_BYTES`` walks the heads in groups.
+    * **absorbed** — a call against the paged latent cache (a decode step;
+      the gather form takes any length): ``W_UK`` goes into the query,
+      ``(q_nope_h W_UK^h^T) . c_kv(s)``, and ``W_UV`` behind the softmax,
+      ``(sum_s P c_kv(s)) W_UV^h`` — equal to the above by associativity —,
+      so a cached position is read once, as its latent row, for all heads
+      (``ops.attention.latent_attention``; one position a slot: the
+      ``latent_decode`` kernel). ``W_UK`` / ``W_UV`` are ``kv_b_proj``'s
+      halves, read out of the one leaf.
+
+    Both write the cache the same way: the latent normed BEFORE it is cached,
+    ``k_rope`` rotated BEFORE it is cached (``ops.attention.latent_update``:
+    variable ``latent_pool``, one row a position shared by all heads, its
+    width on whole lanes; nothing a head is kept). The dense decode cache of
+    ``models/generation.py`` is not written for it."""
+
+    config: TransformerConfig
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, positions, mask=None, kv_lengths=None, paged=None,
+                 layer=None):
+        cfg = self.config
+        dtype = _dtype(cfg)
+        heads, rank = cfg.num_heads, cfg.kv_lora_rank
+        nope, rot, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        b, s = x.shape[:2]
+        scale = (nope + rot) ** -0.5
+        scaling = cfg.rope_scaling
+        if _rope_type(scaling) == "yarn" and scaling.get("mscale_all_dim"):
+            scale *= yarn_mscale(
+                float(scaling["factor"]), float(scaling["mscale_all_dim"])) ** 2
+        if self.decode and paged is None:
+            raise NotImplementedError(
+                "latent attention keeps one latent row a position: the dense "
+                "decode cache (models/generation.py) holds per-head keys and "
+                "values; serve it through ServingEngine's paged cache")
+        use_paged = self.decode and self.has_variable("cache", "latent_pool")
+        if self.decode:
+            width = latent_row_width(rank, rot)
+            pool = self.variable(
+                "cache", "latent_pool", lambda: jnp.zeros(
+                    (paged.num_blocks, paged.block_size, width), dtype))
+        if use_paged:
+            positions = paged.cache_len[:, None] + jnp.arange(s)[None, :]
+
+        def turn(a):
+            return rope(a, positions, cfg.rope_theta, scaling)
+
+        proj = _make_proj(cfg, dtype)
+        c_q = RMSNorm(cfg, name="q_a_norm")(
+            proj("q_a_proj", cfg.q_lora_rank, ("embed", None))(x))
+        down = proj("kv_a_proj", rank + rot, ("embed", None))(x)
+        c_kv = RMSNorm(cfg, name="kv_a_norm")(down[..., :rank])
+        with jax.named_scope("k_rope"):
+            k_rope = turn(down[..., None, rank:])  # (b, s, 1, rot)
+        w_uq, _ = _ProjParams(
+            heads * (nope + rot), (None, "heads"), name="q_b_proj")(
+                cfg.q_lora_rank)
+        w_ukv, _ = _ProjParams(
+            heads * (nope + dv), (None, "heads"), name="kv_b_proj")(rank)
+        w_uq, w_ukv = w_uq.astype(dtype), w_ukv.astype(dtype)
+
+        def queries(w):  # (q_lora_rank, n * (nope + rot)) -> the n heads' q
+            with jax.named_scope("q_up"):
+                q = jnp.dot(c_q, w)
+                if qkv_in_place(self.decode, s):
+                    q = jax.lax.optimization_barrier(q)
+                q = q.reshape(b, s, -1, nope + rot)
+                return q[..., :nope], turn(q[..., nope:])
+
+        if use_paged:
+            pool.value = latent_update(
+                pool.value,
+                jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1), paged,
+                layer=layer)
+        if use_paged and not paged.fresh:
+            w_ukv = w_ukv.reshape(rank, heads, nope + dv)
+            q_nope, q_rope = queries(w_uq)
+            with jax.named_scope("absorb_k"):
+                q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_ukv[..., :nope])
+            o_lat = latent_attention(
+                jnp.concatenate([q_lat, q_rope], axis=-1), pool.value, paged,
+                value_width=rank, scale=scale, layer=layer)
+            with jax.named_scope("absorb_v"):
+                out = jnp.einsum("bshc,chd->bshd", o_lat, w_ukv[..., nope:])
+        else:
+            def expanded(w_q, w_kv):
+                q_nope, q_rope = queries(w_q)
+                with jax.named_scope("kv_up"):
+                    kv = jnp.dot(c_kv, w_kv).reshape(b, s, -1, nope + dv)
+                    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                        k_rope, q_rope.shape)], axis=-1)
+                return dot_product_attention(
+                    jnp.concatenate([q_nope, q_rope], axis=-1), k,
+                    kv[..., nope:], mask=mask, causal=True,
+                    kv_lengths=kv_lengths, scale=scale,
+                    implementation=cfg.attention_impl)
+
+            # per-head q, k, the up-projection's output, v and the result
+            groups = forward_parts(
+                b * s * heads * (2 * (nope + rot) + (nope + dv) + 2 * dv)
+                * dtype.itemsize, heads) if self.decode else 1
+            if groups == 1:
+                out = expanded(w_uq, w_ukv)
+            else:
+                def of_groups(w):  # (rank, heads * d) -> (groups, rank, .)
+                    return jnp.moveaxis(w.reshape(w.shape[0], groups, -1), 1, 0)
+
+                out = jax.lax.map(
+                    lambda w: expanded(*w), (of_groups(w_uq), of_groups(w_ukv)))
+                out = jnp.moveaxis(out, 0, 2)  # (b, s, groups, heads a group, dv)
+        out = checkpoint_name(out.reshape(b, s, heads * dv), "attn_out")
+        return proj("o_proj", cfg.hidden_size, ("heads", "embed"))(out)
+
+
 class _CausalDepthwiseConv(nn.Module):
     """c_t = sum_j kernel[j] * z_{t-(L-1)+j}, z_{<0} = 0: one filter of
     ``length`` taps per channel, no activation; ``use_bias`` adds one bias a
@@ -1123,6 +1306,19 @@ class MoE(nn.Module):
                     # moves the choice and not the weight: top_k's indices
                     # carry no gradient, so the bias's is exactly zero
                     choice = scores + bias
+                if cfg.moe_n_group > 1:
+                    # group-limited: a group's score is the sum of its two
+                    # largest choice scores, and the choice is made inside
+                    # the ``moe_topk_group`` best groups alone
+                    groups = cfg.moe_n_group
+                    grouped = choice.reshape(b, s, groups, R // groups)
+                    _, best = jax.lax.top_k(
+                        jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1),
+                        cfg.moe_topk_group)
+                    kept = jnp.any(
+                        best[..., None] == jnp.arange(groups), axis=-2)
+                    choice = jnp.where(
+                        kept[..., None], grouped, -jnp.inf).reshape(b, s, R)
                 _, sel = jax.lax.top_k(choice, K)  # (B,S,K)
                 weights = jnp.take_along_axis(scores, sel, axis=-1)
                 if cfg.moe_norm_topk_prob:
@@ -1329,7 +1525,15 @@ class Block(nn.Module):
                 t: p for t, p in lora_scan.items()
                 if t in ("gate_proj", "up_proj", "down_proj")
             } or None
-        if cfg.fused_kernels:
+        if cfg.kv_lora_rank is not None:
+            if lora is not None:
+                raise NotImplementedError(
+                    "adapters name per-head q/k/v projections: not written "
+                    "for latent attention")
+            attn_out = LatentAttention(cfg, decode=self.decode, name="attn")(
+                RMSNorm(cfg, name="attn_norm")(x), positions, mask,
+                kv_lengths, paged, layer=layer)
+        elif cfg.fused_kernels:
             # fused prologue: hand Attention the raw residual stream plus
             # the norm scale so ops/fused.py can run norm -> qkv -> rope
             # as one kernel (it falls back to the exact unfused math for

@@ -23,6 +23,30 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 
+# What a forward-only (serving) call may hold at once of temporaries that grow
+# with its rows times something wide — a prefill's per-head q, k and v of
+# latent attention (``models/transformer.py::LatentAttention``), the rows an
+# expert layer gathers to its experts and back, one a choice
+# (``ops/moe.py::moe_ragged``): beyond it the call walks them in equal parts,
+# one after the other (:func:`forward_parts`). ONE budget, each user counting
+# its own rows; a constant of the program, not read off the device: 1.5 GiB is
+# what lets a 16,384-wide prefill at DeepSeek-V3's widths compile beside
+# 12.5 GB of arguments on a v5e (heads whole, or the experts' rows in halves:
+# refused by 0.2 GB) and cuts no program that ran before it (PERF.md, PR 40).
+FORWARD_PART_BYTES = 3 << 29
+
+
+def forward_parts(nbytes: int, of: int, budget: Optional[int] = None) -> int:
+    """Into how many equal parts (a power of two that divides ``of``) a
+    forward-only call cuts ``of`` things that together need ``nbytes`` of
+    temporaries, so that a part needs at most ``budget``
+    (:data:`FORWARD_PART_BYTES`); 1 where they fit whole."""
+    budget = FORWARD_PART_BYTES if budget is None else budget
+    parts = 1
+    while nbytes > parts * budget and of % (2 * parts) == 0:
+        parts *= 2
+    return parts
+
 
 @flax.struct.dataclass
 class PagedKVState:
@@ -110,8 +134,13 @@ class PagedKVState:
 # pool's value is how many trailing axes are one block's own (its block axis
 # stands before them); a slot-state leaf has its slot axis before the
 # trailing axes the value counts.
+# Three kinds of pool: per-head keys and values (``key_pool`` / ``value_pool``,
+# (.., block_size, Hkv, D) or heads first; with int8 rows their ``key_scale`` /
+# ``value_scale``, one float32 a position), and ``latent_pool``, (..,
+# block_size, W): ONE row a position shared by all heads (latent attention),
+# written by :func:`latent_update` and read by :func:`latent_attention`.
 PAGED_POOL_LEAVES = {"key_pool": 3, "value_pool": 3, "key_scale": 1,
-                     "value_scale": 1}
+                     "value_scale": 1, "latent_pool": 2}
 SLOT_STATE_LEAVES = {"state": 3, "taps": 2}
 
 
@@ -173,6 +202,22 @@ def _first_block(state: "PagedKVState", layer):
     return 0 if layer is None else layer * state.num_blocks
 
 
+def _write_rows(state: "PagedKVState", s: int, layer):
+    """(block, offset in it) of each of a call's ``B * s`` positions, flat:
+    token i of slot b belongs at global position ``cache_len[b] + i``, table
+    slot ``pos // block_size``, offset ``pos % block_size``; at or beyond
+    ``lengths[b]`` it goes to the reserved block 0."""
+    bs = state.block_size
+    max_blocks = state.block_table.shape[1]
+    pos = state.cache_len[:, None] + jnp.arange(s)[None, :]  # (B, S) global
+    valid = jnp.arange(s)[None, :] < state.lengths[:, None]
+    tbl = jnp.clip(pos // bs, 0, max_blocks - 1)
+    blocks = jnp.take_along_axis(state.block_table, tbl, axis=1)
+    blocks = jnp.where(valid, blocks, 0) + _first_block(state, layer)
+    offsets = pos % bs
+    return blocks.reshape(-1), offsets.reshape(-1)
+
+
 @jax.named_scope("kv_write")
 def paged_update(
     key_pool: jax.Array,
@@ -213,14 +258,7 @@ def paged_update(
     """
     b, s = k.shape[:2]
     bs = state.block_size
-    max_blocks = state.block_table.shape[1]
-    pos = state.cache_len[:, None] + jnp.arange(s)[None, :]  # (B, S) global
-    valid = jnp.arange(s)[None, :] < state.lengths[:, None]
-    tbl = jnp.clip(pos // bs, 0, max_blocks - 1)
-    blocks = jnp.take_along_axis(state.block_table, tbl, axis=1)
-    blocks = jnp.where(valid, blocks, 0) + _first_block(state, layer)
-    offsets = pos % bs
-    bf, of = blocks.reshape(-1), offsets.reshape(-1)
+    bf, of = _write_rows(state, s, layer)
 
     def put(pool, rows, inner):
         if inner == 3 and state.heads_first:
@@ -265,18 +303,27 @@ def decode_kernel_eligible(state: PagedKVState, q_len: int, pool) -> bool:
     ``decode_attn_kernel`` trace count. The last clause is the kernel's
     tiling rule: a block's ``block_size * Hkv`` rows fill whole sublane
     tiles of the pool's dtype."""
-    from .flash_attention import kernels_interpreted
-
     kv_heads, head_dim = pool.shape[-2:]
     if state.heads_first:
         kv_heads = pool.shape[-3]
-    sublanes = 8 * max(1, 4 // jnp.dtype(pool.dtype).itemsize)
+    return _kernel_takes(
+        state, q_len, pool.dtype, head_dim, state.block_size * kv_heads)
+
+
+def _kernel_takes(state: PagedKVState, q_len: int, dtype, lanes: int,
+                  rows_a_block: int) -> bool:
+    """What both decode kernels ask of a call: one position a slot, the pool
+    native and whole on one device, its rows on whole lanes, a block's rows on
+    whole sublane tiles of ``dtype``, a TPU or ``kernel_interpret_mode()``."""
+    from .flash_attention import kernels_interpreted
+
+    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
     return (
         q_len == 1
         and state.kv_dtype == "native"
         and state.single_device
-        and head_dim % 128 == 0
-        and (state.block_size * kv_heads) % sublanes == 0
+        and lanes % 128 == 0
+        and rows_a_block % sublanes == 0
         and (jax.default_backend() == "tpu" or kernels_interpreted())
     )
 
@@ -360,6 +407,78 @@ def paged_attention(
     return xla_attention(
         q, k, v, mask=keep, causal=False, scale=scale, softcap=softcap
     )
+
+
+def latent_row_width(kv_rank: int, rope_dim: int) -> int:
+    """Lanes of a latent pool's row: ``[c_kv (kv_rank) | k_rope (rope_dim)]``
+    and zeros up to whole 128-lane tiles (576 -> 640: Mosaic refuses a DMA of
+    rows that are no whole number of lanes, and a pool whose minor pair is
+    whole tiles is read where it lies: PERF.md, PR 38 and PR 40). A rule of
+    the shapes, for whoever creates the pool."""
+    return -(-(kv_rank + rope_dim) // 128) * 128
+
+
+@jax.named_scope("kv_write")
+def latent_update(pool: jax.Array, rows: jax.Array, state: PagedKVState,
+                  layer=None) -> jax.Array:
+    """:func:`paged_update` for a latent pool, (num_blocks, block_size, W) or
+    with ``layer`` the stack of every layer's: ``rows`` (B, S, <= W), ONE row
+    a position whatever the heads, zeros behind it up to the pool's width,
+    land where :func:`paged_update` puts a position's K and V."""
+    b, s, w = rows.shape
+    width = pool.shape[-1]
+    if w < width:
+        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, width - w)))
+    bf, of = _write_rows(state, s, layer)
+    return _flat_pool(pool, 2).at[bf, of].set(
+        rows.reshape(b * s, width).astype(pool.dtype)).reshape(pool.shape)
+
+
+def latent_kernel_eligible(state: PagedKVState, q_len: int, pool) -> bool:
+    """:func:`decode_kernel_eligible` for a latent pool ((.., block_size, W);
+    only its shape and dtype are read): one position a slot, the pool native
+    and whole on one device, its rows on whole lanes and a block on whole
+    sublane tiles, a TPU or ``kernel_interpret_mode()``. The ONE predicate:
+    the serving engine asks it for its ``mla_decode_kernel`` trace count."""
+    return _kernel_takes(
+        state, q_len, pool.dtype, pool.shape[-1], state.block_size)
+
+
+@jax.named_scope("latent_attention")
+def latent_attention(q: jax.Array, pool: jax.Array, state: PagedKVState,
+                     value_width: int, scale: float, layer=None) -> jax.Array:
+    """Absorbed latent attention through the block table. ``q`` (B, S, H, w):
+    a head's query in the LATENT's own coordinates, ``[q_nope W_UK^T |
+    q_rope]``, scored against each cached row's first ``w`` lanes — all heads
+    against the same rows —, and the result (B, S, H, value_width) is the
+    softmax-weighted sum of the rows' first ``value_width`` lanes (``c_kv``),
+    for the caller to take through ``W_UV``. The mask rule is
+    :func:`paged_attention`'s: the query at global row r sees column c iff
+    ``c <= r``. Float32 scores and softmax statistics.
+
+    Two forms, as there: the ``latent_decode`` kernel
+    (:func:`latent_kernel_eligible`) walks each slot's live blocks and copies
+    every live latent byte from HBM ONCE for both matmuls; the gather form
+    (any ``S``, the CPU, a sharded pool — and the kernel's oracle) gathers
+    ``max_blocks * block_size`` rows a slot."""
+    b, s, h, w = q.shape
+    if latent_kernel_eligible(state, s, pool):
+        from .paged_attention import latent_decode_attention
+
+        return latent_decode_attention(
+            q, pool, state.block_table, state.cache_len,
+            value_width=value_width, scale=scale, layer=layer)
+    table = state.block_table + _first_block(state, layer)
+    rows = _flat_pool(pool, 2)[table]  # (B, max_blocks, block_size, W)
+    rows = rows.reshape(b, -1, rows.shape[-1])
+    scores = jnp.einsum(
+        "bshw,bkw->bhsk", q, rows[..., :w],
+        preferred_element_type=jnp.float32) * scale
+    at = (state.cache_len[:, None] + jnp.arange(s)[None, :])[:, None, :, None]
+    keep = jnp.arange(rows.shape[1])[None, None, None, :] <= at
+    scores = jnp.where(keep, scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhsk,bkc->bshc", probs, rows[..., :value_width])
 
 
 def make_causal_mask(

@@ -230,6 +230,10 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
 def _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window):
     B, H, S, D = q.shape
     Hkv, Skv = k.shape[1], k.shape[2]
+    # the value's width, and the output's: the score's (q and k) except where
+    # a layer's keys carry more than its values do (latent attention: 192 /
+    # 128); the forward pass alone is written for that
+    Dv = v.shape[3]
     g = H // Hkv
     bq, bk = min(block_q, S), min(block_k, Skv)
     nq, nk = pl.cdiv(S, bq), pl.cdiv(Skv, bk)
@@ -240,20 +244,20 @@ def _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window):
     in_specs = [
         pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik, *refs: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik, *refs, g=g: (b, h // g, ik, 0)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik, *refs, g=g: (b, h // g, ik, 0)),
+        pl.BlockSpec((1, 1, bk, Dv), lambda b, h, iq, ik, *refs, g=g: (b, h // g, ik, 0)),
     ]
     out_specs = [
-        pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik, *refs: (b, h, iq, 0)),
+        pl.BlockSpec((1, 1, bq, Dv), lambda b, h, iq, ik, *refs: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, bq, 128), lambda b, h, iq, ik, *refs: (b, h, iq, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         jax.ShapeDtypeStruct((B, H, S, 128), jnp.float32),
     ]
     scratch_shapes = [
         pltpu.VMEM((bq, 128), jnp.float32),
         pltpu.VMEM((bq, 128), jnp.float32),
-        pltpu.VMEM((bq, D), jnp.float32),
+        pltpu.VMEM((bq, Dv), jnp.float32),
     ]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
@@ -485,6 +489,11 @@ def _flash(q, k, v, lengths, scale, causal, block_q, block_k, window):
     return out
 
 def _flash_fwd(q, k, v, lengths, scale, causal, block_q, block_k, window):
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            f"flash attention at a score width {q.shape[-1]} and a value width "
+            f"{v.shape[-1]} that differ is the forward pass alone: the "
+            "backward kernels take one head_dim")
     out, lse = _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window)
     return out, (q, k, v, lengths, out, lse)
 
@@ -506,6 +515,8 @@ def flash_attention(
     window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention, (batch, seq, heads, head_dim) layout, GQA-aware.
+    ``v`` may be narrower or wider a head than ``q`` and ``k`` (forward only):
+    the result has ``v``'s width.
 
     ``window`` (requires ``causal``): the Mistral/Qwen2 sliding-window
     band — query row r sees keys (r - window, r], HF semantics. kv blocks

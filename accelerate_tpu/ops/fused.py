@@ -75,7 +75,7 @@ def rope_inv_freqs(head_dim: int, theta: float, scaling: Optional[dict]) -> jax.
     )
     from ..models.transformer import _scale_rope_freqs
 
-    return _scale_rope_freqs(freqs, scaling)
+    return _scale_rope_freqs(freqs, scaling, theta)
 
 
 def rms_norm_reference(x, scale, *, eps: float, norm_offset: bool):

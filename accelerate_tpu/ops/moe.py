@@ -35,6 +35,8 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from .attention import forward_parts
+
 
 def expert_capacity(
     num_tokens: int,
@@ -308,7 +310,11 @@ def moe_ragged(
     them every call (at 256 held experts of 2048 x 512 that rebuild would
     be 1.6 GB read and written a layer, more than a decode step needs in
     all), and the rows of absent experts, which lie behind every group, are
-    left out by one ``where`` on the rows gathered back.)
+    left out by one ``where`` on the rows gathered back. It also walks its
+    tokens in equal parts, one after the other, where the rows it gathers —
+    one a choice, to the experts and back — would pass
+    ``ops.attention.FORWARD_PART_BYTES``: a 16,384-wide prefill at 8 choices
+    of 7,168 gathers 1.9 GB each way.)
     On the v5e at LFM2-8B-A1B's widths (8 of 32 experts held, 4 x 4096
     tokens, top 4: 65,536 sorted rows a layer, C = 32,768) all rows through
     the grouped matmuls took 65.7 ms a layer forward + backward and the
@@ -391,6 +397,18 @@ def moe_ragged(
     E = w_up.shape[0]
     TK = T * K
     R = router_width or E
+    if forward_only:
+        # every choice's row, gathered to the experts and gathered back
+        parts = forward_parts(2 * TK * h * x.dtype.itemsize, T)
+        if parts > 1:
+            def part(rows):
+                return moe_ragged(
+                    *rows, w_gate, w_up, w_down, expert_offset=expert_offset,
+                    router_width=R, activation=activation, forward_only=True)
+
+            return jax.lax.map(part, tuple(
+                r.reshape(parts, -1, r.shape[-1]) for r in (x, sel, weights))
+            ).reshape(T, h)
     with jax.named_scope("dispatch"):
         # choice-major: token t's j-th choice is choice j * T + t, so what is
         # gathered back, a row a choice, is (K, T, h) and sums over whole slabs

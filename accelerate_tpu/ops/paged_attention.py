@@ -255,3 +255,134 @@ def paged_decode_attention(
         key_pool.reshape(pool_shape), value_pool.reshape(pool_shape),
     )
     return out.reshape(b, 1, h, d)
+
+
+def _latent_kernel(table_ref, len_ref, q_ref, pool_hbm, o_ref, buf, sems, *,
+                   scale: float, block_size: int, chunk: int, value_width: int):
+    """One slot a grid step: its live blocks in chunks of ``chunk`` blocks,
+    each copied to VMEM once (the next chunk's copies fly while this one is
+    scored) and used for BOTH matmuls — all heads' scores against the rows'
+    lanes, then the probabilities against the rows' first ``value_width``
+    lanes."""
+    b = pl.program_id(0)
+    max_blocks = table_ref.shape[1]
+    rows = chunk * block_size
+    last = len_ref[b]
+    live = jnp.minimum(last // block_size + 1, max_blocks)  # never 0
+    n_chunks = (live + chunk - 1) // chunk
+
+    def for_live_copies(c, slot, act):
+        def one(i, _):
+            j = c * chunk + i
+            blk = table_ref[b, jnp.minimum(j, max_blocks - 1)]
+
+            @pl.when(j < live)
+            def _():
+                act(pltpu.make_async_copy(
+                    pool_hbm.at[blk],
+                    buf.at[slot, pl.ds(pl.multiple_of(i * block_size, block_size),
+                                       block_size)],
+                    sems.at[slot]))
+            return 0
+
+        jax.lax.fori_loop(0, chunk, one, 0)
+
+    # a block the walk skips inside a live chunk leaves its rows as they
+    # were: masked to probability 0, so they must be finite, not garbage
+    @pl.when(b == 0)
+    def _():
+        buf[...] = jnp.zeros_like(buf)
+
+    for_live_copies(0, 0, lambda cp: cp.start())
+    q = q_ref[0]  # (H, w)
+    w = q.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], rows), 1)
+
+    def chunk_body(c, carry):
+        m_prev, l_prev, acc = carry
+        slot = c % N_BUFFERS
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            for_live_copies(c + 1, (c + 1) % N_BUFFERS, lambda cp: cp.start())
+
+        for_live_copies(c, slot, lambda cp: cp.wait())
+        latent = buf[slot]  # (rows, W)
+        s = jax.lax.dot_general(
+            q, latent[:, :w], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (H, rows) f32
+        s = jnp.where(c * rows + col <= last, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            p.astype(latent.dtype), latent[:, :value_width],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    heads = q.shape[0]
+    _, l, acc = jax.lax.fori_loop(0, n_chunks, chunk_body, (
+        jnp.full((heads, 1), NEG_INF, jnp.float32),
+        jnp.zeros((heads, 1), jnp.float32),
+        jnp.zeros((heads, value_width), jnp.float32)))
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def latent_decode_attention(
+    q: jax.Array,
+    pool: jax.Array,
+    block_table: jax.Array,
+    cache_len: jax.Array,
+    value_width: int,
+    scale: float,
+    layer=None,
+) -> jax.Array:
+    """Absorbed latent attention for one position a slot. ``q`` (B, 1, H, w):
+    each head's query in the latent's coordinates (``ops.attention.
+    latent_attention``), against the rows ``block_table`` (B, max_blocks)
+    names in the latent pool (num_blocks, block_size, W >= w), ONE row a
+    position for all heads; slot ``b``'s query sits at global position
+    ``cache_len[b]``, its own row already written. Returns (B, 1, H,
+    value_width): the softmax-weighted sums of the rows' first
+    ``value_width`` lanes. Every live row is copied from HBM once and serves
+    the scores and the values both; with all heads against the same rows
+    nothing is masked but the dead positions. ``layer``: as
+    :func:`paged_decode_attention`."""
+    b, s, h, w = q.shape
+    if s != 1:
+        raise ValueError(f"latent_decode_attention is the S == 1 shape, got {s}")
+    block_size, width = pool.shape[-2:]
+    if layer is not None:
+        block_table = block_table + layer * pool.shape[-3]
+    # the query on whole lanes like the rows, zeros behind it
+    lanes = min(-(-w // 128) * 128, width)
+    if lanes > w:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, lanes - w)))
+    chunk = max(1, min(CHUNK_ROWS // block_size, block_table.shape[1]))
+    kernel = functools.partial(
+        _latent_kernel, scale=scale, block_size=block_size, chunk=chunk,
+        value_width=value_width)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, h, lanes), lambda i, *refs: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, h, value_width), lambda i, *refs: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((N_BUFFERS, chunk * block_size, width), pool.dtype),
+                pltpu.SemaphoreType.DMA((N_BUFFERS,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, value_width), q.dtype),
+        interpret=kernels_interpreted(),
+        name="latent_decode",
+    )(
+        block_table.astype(jnp.int32), cache_len.astype(jnp.int32),
+        q.reshape(b, h, lanes), pool.reshape(-1, block_size, width),
+    )
+    return out.reshape(b, 1, h, value_width)

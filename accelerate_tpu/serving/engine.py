@@ -48,6 +48,7 @@ from ..ops.attention import (
     SLOT_STATE_LEAVES,
     PagedKVState,
     decode_kernel_eligible,
+    latent_kernel_eligible,
     pool_heads_first,
 )
 from ..utils.profiling import annotate
@@ -309,6 +310,12 @@ class ServingEngine:
         # to share, copy, swap or hand off, and no several positions onto it
         self._recurrent = "linear_attention" in (
             getattr(cfg, "layer_types", None) or ())
+        # latent attention (``kv_lora_rank``): the pool holds ONE latent row a
+        # position for all heads. A prefill EXPANDS what it projects and sees
+        # no cache; a decode step reads the rows ABSORBED, one position a slot
+        # (models/transformer.LatentAttention): several positions onto cached
+        # latent rows are a program no feature here may ask for
+        self._latent = getattr(cfg, "kv_lora_rank", None) is not None
         features = [name for name, on in (
             ("prefix_cache", prefix_cache),
             ("spec_decode", spec_decode is not None),
@@ -418,6 +425,11 @@ class ServingEngine:
             "prefill": 0, "decode": 0, "decode_attn_kernel": 0, "cow": 0,
             "verify": 0, "swap_out": 0, "swap_in": 0, "kv_in_place": 0,
             "eva": 0, "qkv_in_place": 0, "recurrent_state": 0,
+            # latent attention: of the traced decode programs, how many read
+            # the latent rows absorbed through the ``latent_decode`` kernel;
+            # of the traced prefill programs, how many expanded the prompt's
+            # latent and attended what they projected
+            "mla_decode_kernel": 0, "mla_prefill_expanded": 0,
         }
         self._rollovers_total = 0
         # every bucket width a prefill ever ran at — the set
@@ -443,8 +455,9 @@ class ServingEngine:
         # a block of the pools is laid out (a rule of the model's shapes; the
         # eva regime's own writes are written for one row a position and
         # head) and, for a recurrent stack, how many seats carry a state
-        seat_and_layout = {"heads_first": self._eva is None and pool_heads_first(
-            cfg.num_kv_heads, cfg.head_dim)}
+        seat_and_layout = {"heads_first": (
+            self._eva is None and not self._latent
+            and pool_heads_first(cfg.num_kv_heads, cfg.head_dim))}
         if self._recurrent:
             seat_and_layout["num_slots"] = max_slots
         init_state = PagedKVState(
@@ -499,6 +512,7 @@ class ServingEngine:
         kv_leaf_info = self._kv_leaf_info
         eva = self._eva is not None
         recurrent = self._recurrent
+        latent = self._latent
         # a decode step of a stack with experts also says how many distinct
         # experts held here its rows chose (``experts_touched`` on the fetch
         # span): the experts are most of what such a step reads
@@ -541,6 +555,7 @@ class ServingEngine:
             traces["kv_in_place"] += 1
             traces["eva"] += eva
             traces["recurrent_state"] += recurrent
+            traces["mla_prefill_expanded"] += latent
             # cached_len > 0 is the warm-hit path: ``ids`` holds only the
             # UNCACHED tail and the paged cache already contains KV for
             # the first cached_len positions (shared prefix blocks in
@@ -559,7 +574,9 @@ class ServingEngine:
                 # a recurrent stack: the seat whose state this prompt fills,
                 # from zero (``slot`` (1,); no other model is told one)
                 slot=slot, **seat_and_layout,
-                fresh=recurrent,
+                # nothing before it to see: a recurrent layer starts from
+                # zero, attention attends what the call projected
+                fresh=recurrent or latent,
             )
             # the head reads the last VALID row of the padded bucket alone,
             # not the padded tail: width x vocabulary logits are never formed
@@ -592,9 +609,12 @@ class ServingEngine:
                 positions=positions,
                 **seat_and_layout,
             )
-            traces["decode_attn_kernel"] += decode_kernel_eligible(
+            kernel = (latent_kernel_eligible if latent
+                      else decode_kernel_eligible)(
                 state, tokens.shape[1], jax.tree.leaves(cache)[pool_leaf]
             )
+            traces["decode_attn_kernel"] += kernel
+            traces["mla_decode_kernel"] += latent and kernel
             traces["qkv_in_place"] += qkv_in_place(True, tokens.shape[1])
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, tokens, decode=True,
@@ -855,6 +875,7 @@ class ServingEngine:
         write, preemption swap, hand-off, speculation, chunked prefill, int8
         pools, adapters) are refused, by name, for a model whose cache is
         not that. The ONE predicate."""
+        item = "A4"
         if self._eva is not None:
             why = (
                 "attention_class 'eva': a request's cache is chunk summaries "
@@ -866,10 +887,16 @@ class ServingEngine:
                 "is a recurrent state a slot, overwritten in place, beside "
                 "the blocks of its attention layers"
             )
+        elif self._latent:
+            item, why = "A3", (
+                "latent attention: a request's cache is one latent row a "
+                "position, which a prefill expands and never reads back and "
+                "a decode step reads absorbed, one position a slot"
+            )
         else:
             return
         raise NotImplementedError(
-            f"{feature} is not written for {why} (ROADMAP Reach A4)"
+            f"{feature} is not written for {why} (ROADMAP Reach {item})"
         )
 
     def _land_or_refuse(self, feature: str) -> None:
@@ -2010,7 +2037,10 @@ class ServingEngine:
             # what this step's attention reads: the rows the seated slots
             # hold, their new one included, and the positions they stand for
             rows = int(cache_lens.sum()) + len(slots)
-            phase.set_metadata(rows=rows, positions=at + len(slots))
+            # and what those rows occupy in the pools as they are allocated
+            phase.set_metadata(
+                rows=rows, positions=at + len(slots),
+                cache_bytes=int(rows * self.kv_bytes_per_token))
             if self._counts_experts:
                 self._step_stats[self._dispatched["jit__decode"]] = (
                     rows, len(slots))
@@ -2346,6 +2376,10 @@ class ServingEngine:
             "prefill_chunks_total": self._prefill_chunks_total,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "state_bytes_per_slot": self.state_bytes_per_slot,
+            # what ONE position holds over the layers as the latent pool is
+            # allocated, lanes of padding included (0: per-head K and V)
+            "latent_row_bytes": (
+                self.kv_bytes_per_token if self._latent else 0),
             "pool_alias_bytes": self.pool_alias_bytes,
             "decode_ahead_share": self.decode_ahead_share,
         }

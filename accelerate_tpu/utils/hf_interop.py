@@ -194,7 +194,7 @@ def infer_config_from_hf(checkpoint: str, **overrides) -> "Any":
         )
         kw.update(overrides)
         return TransformerConfig(**kw)
-    # rope_scaling (llama3 / linear applied natively; yarn etc. rejected)
+    # rope_scaling (llama3 / linear / yarn applied natively; others rejected)
     # is validated by TransformerConfig.__post_init__ — the construction
     # below fails loudly, including on parameter keys missing for the
     # declared type, so nothing can only blow up at trace time.

@@ -15,6 +15,11 @@ is, and the cut is printed), with random weights made from a seed:
   a conv layer, an attention layer (head_dim 64) with 8 of 32 experts held;
 * serve   — ``ServingEngine`` answering more requests than it has slots (one
   engine per chip behind ``FleetRouter`` when the host has several);
+* mla     — the same engine over DeepSeek-V3's layers at its widths (latent
+            attention: an expanded prefill, an absorbed decode through the
+            ``latent_decode`` kernel over ONE latent row a position; a dense
+            layer and a layer of 16 of 256 group-limited experts): the pool
+            donated and written in place, decode dispatched ahead.
 * eva     — the same engine over EvaByte's layer (chunk summaries beside a
   2,048-byte window in the one pool), with windows that fill.
 
@@ -48,7 +53,7 @@ import sys
 import time
 
 SEED = 20260926
-PHASES = ("kernels", "train", "hybrid", "serve", "eva")
+PHASES = ("kernels", "train", "hybrid", "serve", "eva", "mla")
 
 
 # --------------------------------------------------------------------------- #
@@ -782,6 +787,9 @@ def random_bf16_params(model, device):
             if "scale" in name:
                 leaves.append(jnp.ones(leaf.shape, jnp.bfloat16))
                 continue
+            if leaf.ndim < 2:  # a bias a router output: none
+                leaves.append(jnp.zeros(leaf.shape, leaf.dtype))
+                continue
             std = 0.02 if "embed" in name else leaf.shape[-2] ** -0.5
             leaves.append(
                 std * jax.random.normal(key, leaf.shape, jnp.bfloat16)
@@ -1123,6 +1131,96 @@ def eva_phase(say, dry: bool) -> None:
     say("eva phase PASSED")
 
 
+def mla_phase(say, dry: bool) -> None:
+    """``ServingEngine`` over two layers at DeepSeek-V3's published widths
+    (hidden 7168; 128 heads of 128 + 64 over a latent of 512 beside 64
+    rotated, queries through 1536, values 128; YaRN at factor 40; one dense
+    layer of 18,432 and one of 16 of 256 group-limited sigmoid experts of
+    2,048 with a shared expert; depth cut 61 -> 2) on ONE chip, 4 slots x
+    4,096 positions: a prefill EXPANDS (flash at 192 / 128, the heads in
+    groups at the widest) and writes latent rows alone, every decode step
+    reads them ABSORBED through the ``latent_decode`` kernel, donated and
+    dispatched ahead. The served tokens are held against the model's OWN
+    plain forward pass of prompt + answer (the expanded form, no cache)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from accelerate_tpu import ServingEngine
+    from accelerate_tpu.models import CausalLM, TransformerConfig, count_params
+
+    if dry:
+        w = dict(vocab_size=96, hidden_size=64, intermediate_size=96,
+                 moe_intermediate_size=24, moe_shared_intermediate_size=24,
+                 num_heads=4, q_lora_rank=32, kv_lora_rank=24,
+                 qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                 num_experts=4, moe_router_width=16, num_experts_per_tok=3,
+                 moe_n_group=4, moe_topk_group=2)
+        block, max_seq, new, prompts, old = 8, 256, 12, (40, 9, 100, 64), 32
+    else:
+        w = dict(vocab_size=16160, hidden_size=7168, intermediate_size=18432,
+                 moe_intermediate_size=2048, moe_shared_intermediate_size=2048,
+                 num_heads=128, q_lora_rank=1536, kv_lora_rank=512,
+                 qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                 num_experts=16, moe_router_width=256, num_experts_per_tok=8,
+                 moe_n_group=8, moe_topk_group=4)
+        block, max_seq, new, prompts, old = 16, 4096, 64, (3000, 130, 2048, 700), 4096
+    cfg = TransformerConfig(
+        **w, num_layers=2, num_dense_layers=1, moe_router="sigmoid",
+        moe_expert_bias=True, moe_norm_topk_eps=1e-20,
+        moe_routed_scaling_factor=2.5, rope_theta=10000.0, rms_norm_eps=1e-6,
+        rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": old},
+        scan_layers=False, fp32_logits=True, max_seq_len=max_seq,
+        dtype="bfloat16")
+    model = CausalLM(cfg)
+    device = jax.devices()[0]
+    params = random_bf16_params(model, device)
+    eng = ServingEngine(model, params, max_slots=4, block_size=block,
+                        decode_ahead=True)
+    gauges = eng._gauge_fields()
+    say(f"mla: depth cut 61 -> {cfg.num_layers} layers, "
+        f"{count_params(params) / 1e6:.0f}M bf16 parameters, 4 slots x "
+        f"{max_seq} positions: pool {eng.num_blocks} blocks, "
+        f"latent_row_bytes {gauges['latent_row_bytes']:.0f} a position")
+    rng = np.random.default_rng(SEED + 6)
+    asked = [rng.integers(0, cfg.vocab_size, n).astype(np.int32) for n in prompts]
+    rids = [eng.add_request(p, max_new_tokens=new) for p in asked]
+    while eng.has_work:
+        eng.step()
+    counts, stats = eng.trace_counts(), eng.pool.stats()
+    say(f"mla: trace_counts {counts} pool allocated={stats['allocated']} "
+        f"decode_ahead_share {eng.decode_ahead_share:.3f}")
+    assert counts["decode"] == 1 and stats["allocated"] == 0, (counts, stats)
+    assert counts["mla_prefill_expanded"] == counts["prefill"] >= 1, counts
+    assert {k.split("'")[-2] for k in map(
+        jax.tree_util.keystr, dict(jax.tree_util.tree_flatten_with_path(
+            eng.cache)[0]))} == {"latent_pool"}
+    assert eng.state_bytes_per_slot == 0
+    if not dry:
+        assert counts["mla_decode_kernel"] >= 1, counts
+    assert eng.decode_ahead_share >= 0.9, eng.decode_ahead_share
+    assert_pool_in_place(say, eng)
+    apply = jax.jit(lambda p, ids: model.apply({"params": p}, ids))
+    gaps = []
+    for rid, prompt in zip(rids, asked):
+        out = np.asarray(eng.result(rid))
+        assert len(out) == new and ((0 <= out) & (out < cfg.vocab_size)).all()
+        seq = np.concatenate([prompt, out])
+        logits = np.asarray(apply(params, jnp.asarray(seq)[None])[0].astype(
+            jnp.float32))[len(prompt) - 1:len(seq) - 1]
+        gaps.append(logits.max(-1) - logits[np.arange(new), out])
+    gaps = np.concatenate(gaps)
+    say(f"mla: served tokens against the plain forward pass: mean logit gap "
+        f"{gaps.mean():.4g}, widest {gaps.max():.4g}, "
+        f"{int((gaps > 0).sum())} of {gaps.size} off its best")
+    # two bf16 programs of one arithmetic in two forms: a near-tie may fall
+    # otherwise
+    assert gaps.mean() < 0.1 and gaps.max() < 2.0, (gaps.mean(), gaps.max())
+    say("mla phase PASSED")
+
+
 # --------------------------------------------------------------------------- #
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -1195,7 +1293,8 @@ def main(argv=None) -> int:
            "train": lambda: train_phase(say, sz, dry),
            "hybrid": lambda: hybrid_phase(say, dry),
            "serve": lambda: serve_phase(say, sz, dry),
-           "eva": lambda: eva_phase(say, dry)}
+           "eva": lambda: eva_phase(say, dry),
+           "mla": lambda: mla_phase(say, dry)}
     wall = []
     for phase in phases:
         t0 = time.perf_counter()

@@ -533,8 +533,8 @@ _SSM = dict(single_sublayer=True, layer_types=("mamba", "moe"), mamba_num_heads=
 
 
 @pytest.mark.parametrize("kw,match", [
-    (dict(_SSM, moe_n_group=2), "group-limited routing is not written"),
-    (dict(_SSM, moe_topk_group=4), "group-limited routing is not written"),
+    (dict(_SSM, moe_n_group=3), "moe_n_group 3 / moe_topk_group 1"),
+    (dict(_SSM, moe_topk_group=4), "moe_n_group 1 / moe_topk_group 4"),
     (dict(_SSM, single_sublayer=False), "set single_sublayer"),
     (dict(_SSM, layer_types=None), "layer_types=None"),
     (dict(_SSM, num_dense_layers=1), "num_dense_layers"),
