@@ -619,10 +619,12 @@ class Attention(nn.Module):
                 # a prefill from position 0 (a stack with recurrent layers
                 # never continues a cache by several tokens): what it
                 # projected is all there is to see, so the table is written
-                # and not gathered — flash over the bucket; the padded tail
-                # lies after every real row and is seen by none
+                # and not gathered — flash over the prompt's real rows: the
+                # bucket's padded tail lies after them, is seen by none, and
+                # comes out as zeros that cost no work
                 out = dot_product_attention(
                     q, k, v, causal=True, scale=scale,
+                    kv_lengths=paged.lengths, q_lengths=paged.lengths,
                     softcap=cfg.attn_softcap,
                     implementation=cfg.attention_impl, window=window,
                 )
@@ -812,10 +814,14 @@ class LatentAttention(nn.Module):
                     kv = jnp.dot(c_kv, w_kv).reshape(b, s, -1, nope + dv)
                     k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
                         k_rope, q_rope.shape)], axis=-1)
+                # a prefill says how many rows of its bucket are real: the
+                # rest come out as zeros that cost no work
+                real = paged.lengths if use_paged else None
                 return dot_product_attention(
                     jnp.concatenate([q_nope, q_rope], axis=-1), k,
                     kv[..., nope:], mask=mask, causal=True,
-                    kv_lengths=kv_lengths, scale=scale,
+                    kv_lengths=kv_lengths if real is None else real,
+                    q_lengths=real, scale=scale,
                     implementation=cfg.attention_impl)
 
             # per-head q, k, the up-projection's output, v and the result

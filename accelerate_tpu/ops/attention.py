@@ -516,6 +516,7 @@ def xla_attention(
     kv_lengths: Optional[jax.Array] = None,
     window: Optional[int] = None,
     softcap: Optional[float] = None,
+    q_lengths: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Reference-path attention, shapes (B, S, H, D) / kv (B, Skv, Hkv, D).
 
@@ -541,7 +542,8 @@ def xla_attention(
     band — each query sees at most the last ``window`` keys; a TRACED
     window (the per-layer Gemma-2 pattern riding the layer scan) is fine
     here — only this path, not flash/ring, accepts one. ``softcap``:
-    Gemma-2 tanh soft-capping of the raw scores.
+    Gemma-2 tanh soft-capping of the raw scores. ``q_lengths`` (B,): the
+    rows at or past it come out as zeros, as the flash kernel leaves them.
     """
     if window is not None and not causal:
         raise ValueError("sliding window requires causal attention")
@@ -590,11 +592,15 @@ def xla_attention(
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(orig_dtype)
     if g == 1:
-        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    out = jnp.einsum(
-        "bhgqk,bkhd->bqhgd", probs.reshape(b, h_kv, g, s_q, s_kv), v
-    )
-    return out.reshape(b, s_q, h, d)
+        out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    else:
+        out = jnp.einsum(
+            "bhgqk,bkhd->bqhgd", probs.reshape(b, h_kv, g, s_q, s_kv), v
+        ).reshape(b, s_q, h, d)
+    if q_lengths is not None:
+        real = lengths_to_mask(q_lengths, s_q)[:, 0, 0]  # (B, S)
+        out = jnp.where(real[:, :, None, None], out, 0)
+    return out
 
 
 def flash_self_attention_eligible(seq_len: int) -> bool:
@@ -628,12 +634,19 @@ def dot_product_attention(
     implementation: Optional[str] = None,
     window: Optional[int] = None,
     softcap: Optional[float] = None,
+    q_lengths: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Attention entry point, shapes (batch, seq, heads, head_dim).
 
     ``kv_lengths``: (B,) valid-prefix key lengths — the structured form of
     a right-padding key mask (HF tokenizer convention). Flash and xla both
     honor it; arbitrary (non-prefix) masks take the xla path.
+
+    ``q_lengths``: (B,) how many of each row's queries are real, beside
+    ``kv_lengths`` in causal self-attention (a prompt in a padded bucket).
+    The rows past it are zeros out of either path, and the flash kernel does
+    no work for the q blocks that hold none but them; ring attention takes
+    no lengths.
 
     ``window``: causal sliding-window band (Mistral / sliding Qwen2).
     Supported by the xla and flash paths (the flash kernel additionally
@@ -653,6 +666,10 @@ def dot_product_attention(
     that's the padded-batch fast path), else xla.
     """
     window_static = window is None or isinstance(window, int)
+    if q_lengths is not None and not (
+            causal and kv_lengths is not None and q.shape[1] == k.shape[1]):
+        raise ValueError(
+            "q_lengths needs causal self-attention and kv_lengths beside it")
     if implementation is None:
         # trace-time decision: tracers have no .devices(), so the
         # eligibility helper keys off the default backend (correct under
@@ -669,6 +686,7 @@ def dot_product_attention(
         return xla_attention(
             q, k, v, mask=mask, bias=bias, scale=scale, causal=causal,
             kv_lengths=kv_lengths, window=window, softcap=softcap,
+            q_lengths=q_lengths,
         )
     if implementation == "flash":
         from .flash_attention import flash_attention
@@ -686,7 +704,7 @@ def dot_product_attention(
             )
         return flash_attention(
             q, k, v, scale=scale, causal=causal, kv_lengths=kv_lengths,
-            window=window,
+            window=window, q_lengths=q_lengths,
         )
     if implementation == "ring":
         from .ring_attention import ring_attention

@@ -133,6 +133,45 @@ def _apply_kv_padding(s, ik, bq, bk, kvlen):
     return jnp.where(cols < kvlen, s, NEG_INF)
 
 
+def _apply_masks_under_lengths(s, iq, ik, bq, bk, causal, offset, window,
+                               kvlen):
+    """The forward kernel's masks in the mode that carries lengths, under ONE
+    ``cond``: the causal mask of :func:`_apply_causal` and the key-length
+    mask of :func:`_apply_kv_padding` only on a block that straddles the
+    diagonal (or the band's lower edge) or the length — a second ``cond``
+    would hand the whole score tile through VMEM once more on every block
+    (8.6-9.8 % of a call at 192 / 128, PERF.md section 6, PR 41). Blocks
+    wholly past either never run (``_block_visible``)."""
+    clear = (ik + 1) * bk <= kvlen
+    if causal:
+        clear = jnp.logical_and(clear, (ik + 1) * bk - 1 <= iq * bq + offset)
+        if window is not None:
+            clear = jnp.logical_and(
+                clear, ik * bk > iq * bq + (bq - 1) + offset - window)
+
+    def masked(s):
+        cols = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        keep = cols < kvlen
+        if causal:
+            keep = jnp.logical_and(
+                keep, _causal_mask_block(iq, ik, bq, bk, offset, window))
+        return jnp.where(keep, s, NEG_INF)
+
+    return jax.lax.cond(clear, lambda s: s, masked, s)
+
+
+def _last_block(length, block):
+    """The block that holds row ``length - 1`` (block 0 for no row)."""
+    return jax.lax.div(jnp.maximum(length - 1, 0), block)
+
+
+def _real_rows(lens_ref, qlens_ref, b):
+    """How many of batch row ``b``'s queries the forward kernel computes: its
+    query length, and none where it has no key (every query then sees
+    nothing, and is skipped to the same zeros)."""
+    return jnp.where(lens_ref[b] > 0, qlens_ref[b], 0)
+
+
 def _apply_causal(s, iq, ik, bq, bk, offset, window=None):
     """Mask only when the block straddles the diagonal (or the band's
     lower edge); interior blocks skip the iota/compare/where entirely
@@ -158,17 +197,23 @@ def _apply_causal(s, iq, ik, bq, bk, offset, window=None):
 # forward
 # ---------------------------------------------------------------------- #
 def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
-                block_k: int, offset: int, padded: bool, window):
-    if padded:
-        lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-        kvlen = lens_ref[pl.program_id(0)]
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-        kvlen = None
+                block_k: int, offset: int, padded: bool, window,
+                rows: bool):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
+    # the prefetched lengths come first: the keys', then the queries'
+    lens = refs[:padded + rows]
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[len(lens):]
+    kvlen = lens[0][pl.program_id(0)] if padded else None
+    qlen = live = None
+    if rows:  # a q block wholly past the query length runs nothing
+        qlen = _real_rows(*lens, pl.program_id(0))
+        live = iq * block_q < qlen
 
-    @pl.when(ik == 0)
+    def _and_live(when):
+        return when if live is None else jnp.logical_and(when, live)
+
+    @pl.when(_and_live(ik == 0))
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -179,7 +224,7 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
     run = _block_visible(iq, ik, block_q, block_k, causal, offset, kvlen,
                          window)
 
-    @pl.when(run)
+    @pl.when(_and_live(run))
     def _body():
         # matmul inputs stay in the native (bf16) dtype — the MXU multiplies
         # bf16 at full rate with fp32 accumulation; upcasting inputs to f32
@@ -190,20 +235,23 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk) f32
-        if causal:
-            s = _apply_causal(s, iq, ik, block_q, block_k, offset, window)
         if padded:
-            s = _apply_kv_padding(s, ik, block_q, block_k, kvlen)
+            s = _apply_masks_under_lengths(
+                s, iq, ik, block_q, block_k, causal, offset, window, kvlen)
+        elif causal:
+            s = _apply_causal(s, iq, ik, block_q, block_k, offset, window)
         m_prev = m_scr[:, 0:1]  # (bq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        if padded or (causal and offset < 0):
+        if (padded and not (rows and window is None)) or (causal and offset < 0):
             # Rows fully masked within a *visible* block keep m_new ==
             # NEG_INF and exp(s - m_new) would be 1 everywhere — force p
             # (and hence l, acc) to 0 so _finish emits zero output, not
             # mean-of-v. Happens when the causal diagonal crosses
             # mid-block with q_len > kv_len, or (padding mode) when
             # kvlen == 0. Without either, every row sees >= 1 column and
-            # the guard is compiled out of the hot path.
+            # the guard is compiled out of the hot path — as it is under a
+            # query length (causal from row 0, and a q block runs only
+            # where there is a key): every row that is walked sees column 0.
             p = jnp.where(m_new <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))
         else:
             p = jnp.exp(s - m_new)  # (bq, bk) f32
@@ -216,18 +264,74 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
         m_scr[:, 0:1] = m_new
         l_scr[:, 0:1] = l_new
 
-    @pl.when(ik == nk - 1)
+    @pl.when(_and_live(ik == nk - 1))
     def _finish():
         l = l_scr[:, 0:1]
         l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0 output
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        out = acc_scr[:] / l_safe
+        if rows:
+            # the rows past the length in the block that straddles it: they
+            # were walked beside the real ones, and read like the skipped
+            real = (iq * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, 1), 0)) < qlen
+            out = jnp.where(real, out, 0.0)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
+        lse = m_scr[:, 0:1] + jnp.log(l_safe)
+        if rows:
+            lse = jnp.where(real, lse, NEG_INF)
         # lse broadcast into the 128-lane dim (TPU min tile; see out_shape)
-        lse_ref[0, 0] = jnp.broadcast_to(
-            m_scr[:, 0:1] + jnp.log(l_safe), lse_ref.shape[2:]
-        )
+        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+
+    if rows:
+        @pl.when(jnp.logical_and(ik == nk - 1, jnp.logical_not(live)))
+        def _no_rows():
+            # a q block wholly past the length: written once, never as
+            # uninitialised memory (o_proj, a router, kv_write read the rows)
+            o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
+            lse_ref[0, 0] = jnp.full_like(lse_ref[0, 0], NEG_INF)
 
 
-def _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window):
+def _fwd_index_maps(bq, bk, g, offset, causal, padded, rows):
+    """The q and the k / v ``index_map`` of the forward grid ``(b, h, iq,
+    ik)``. ``*refs`` absorbs the scalar-prefetch refs PrefetchScalarGridSpec
+    appends to every index_map call in padding mode: the key lengths, then
+    the query lengths where there are any. Without lengths every step names
+    its own block."""
+
+    def q_index(b, h, iq, ik, *refs):
+        if rows:  # a skipped q block names the last real one: no copy
+            iq = jnp.minimum(iq, _last_block(_real_rows(*refs, b), bq))
+        return (b, h, iq, 0)
+
+    def kv_index(b, h, iq, ik, *refs):
+        if padded:
+            # a step that computes nothing names the last block its q block
+            # sees — the lower of the causal diagonal and the length —, which
+            # is resident already: consecutive skipped steps copy nothing
+            last = _last_block(refs[0][b], bk)
+            if rows:
+                qlen = _real_rows(*refs, b)
+                skipped = iq * bq >= qlen
+                iq = jnp.minimum(iq, _last_block(qlen, bq))
+            if causal:
+                last = jnp.minimum(last, jax.lax.div(
+                    jnp.maximum(iq * bq + (bq - 1) + offset, 0), bk))
+            ik = jnp.minimum(ik, last)
+            if rows:
+                ik = jnp.where(skipped, last, ik)
+        return (b, h // g, ik, 0)
+
+    return q_index, kv_index
+
+
+def _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window,
+         q_lengths=None):
+    """``lengths`` (B,) int32 or None: keys ``[0, len)`` are valid, and a grid
+    step whose kv block lies wholly past them (or past the causal diagonal)
+    computes nothing and copies nothing — its k / v block is the one already
+    resident. ``q_lengths`` (B,) beside it (causal, ``S == Skv``): queries
+    ``[0, len)`` are real; the rest come out as zeros with a log-sum-exp of
+    ``NEG_INF``, and a q block wholly past the length costs one write."""
     B, H, S, D = q.shape
     Hkv, Skv = k.shape[1], k.shape[2]
     # the value's width, and the output's: the score's (q and k) except where
@@ -238,13 +342,14 @@ def _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window):
     bq, bk = min(block_q, S), min(block_k, Skv)
     nq, nk = pl.cdiv(S, bq), pl.cdiv(Skv, bk)
     padded = lengths is not None
+    rows = q_lengths is not None
+    offset = Skv - S
 
-    # *refs absorbs the scalar-prefetch ref PrefetchScalarGridSpec appends
-    # to every index_map call in padding mode
+    q_index, kv_index = _fwd_index_maps(bq, bk, g, offset, causal, padded, rows)
     in_specs = [
-        pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik, *refs: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, bk, D), lambda b, h, iq, ik, *refs, g=g: (b, h // g, ik, 0)),
-        pl.BlockSpec((1, 1, bk, Dv), lambda b, h, iq, ik, *refs, g=g: (b, h // g, ik, 0)),
+        pl.BlockSpec((1, 1, bq, D), q_index),
+        pl.BlockSpec((1, 1, bk, D), kv_index),
+        pl.BlockSpec((1, 1, bk, Dv), kv_index),
     ]
     out_specs = [
         pl.BlockSpec((1, 1, bq, Dv), lambda b, h, iq, ik, *refs: (b, h, iq, 0)),
@@ -261,12 +366,13 @@ def _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window):
     ]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        offset=Skv - S, padded=padded, window=window,
+        offset=offset, padded=padded, window=window, rows=rows,
     )
+    prefix = ((lengths,) if padded else ()) + ((q_lengths,) if rows else ())
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1 if padded else 0,
+            num_scalar_prefetch=len(prefix),
             grid=(B, H, nq, nk),
             in_specs=in_specs,
             out_specs=out_specs,
@@ -275,7 +381,7 @@ def _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window):
         out_shape=out_shape,
         interpret=kernels_interpreted(),
         name="flash_fwd",
-    )(*(((lengths,) if padded else ()) + (q, k, v)))
+    )(*(prefix + (q, k, v)))
     return out, lse
 
 
@@ -483,22 +589,28 @@ def _bwd(scale, causal, block_q, block_k, window, res, dout):
 # ---------------------------------------------------------------------- #
 # public wrapper with custom VJP
 # ---------------------------------------------------------------------- #
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, lengths, scale, causal, block_q, block_k, window):
-    out, _ = _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, lengths, q_lengths, scale, causal, block_q, block_k,
+           window):
+    out, _ = _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window,
+                  q_lengths)
     return out
 
-def _flash_fwd(q, k, v, lengths, scale, causal, block_q, block_k, window):
+def _flash_fwd(q, k, v, lengths, q_lengths, scale, causal, block_q, block_k,
+               window):
     if v.shape[-1] != q.shape[-1]:
         raise NotImplementedError(
             f"flash attention at a score width {q.shape[-1]} and a value width "
             f"{v.shape[-1]} that differ is the forward pass alone: the "
             "backward kernels take one head_dim")
-    out, lse = _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window)
+    out, lse = _fwd(q, k, v, lengths, scale, causal, block_q, block_k, window,
+                    q_lengths)
+    # the backward kernels take no query length: a row past it left the
+    # forward pass with lse == NEG_INF, which zeroes its p in both of them
     return out, (q, k, v, lengths, out, lse)
 
 def _flash_bwd(scale, causal, block_q, block_k, window, res, dout):
-    return _bwd(scale, causal, block_q, block_k, window, res, dout)
+    return _bwd(scale, causal, block_q, block_k, window, res, dout) + (None,)
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
@@ -513,6 +625,7 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     window: Optional[int] = None,
+    q_lengths: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Flash attention, (batch, seq, heads, head_dim) layout, GQA-aware.
     ``v`` may be narrower or wider a head than ``q`` and ``k`` (forward only):
@@ -533,6 +646,12 @@ def flash_attention(
     still compute (their outputs are garbage); mask them downstream in
     pooling/loss exactly as with a dense attention mask over keys.
 
+    ``q_lengths`` (B,) int32 beside ``kv_lengths`` (causal self-attention
+    only) marks queries ``[0, len)`` real — a prompt in a padded bucket. The
+    rows past it are ZEROS (their log-sum-exp ``NEG_INF``, their gradients
+    zero), and the forward kernel computes and copies nothing for a q block
+    that lies wholly past the length: the work is the real rows' half-square.
+
     Blocks adapt downward to divide the sequence (1024 -> 512 -> 256 -> 128
     steps), so any multiple of 128 works; non-contiguous key masks need the
     xla path.
@@ -551,24 +670,31 @@ def flash_attention(
             f"flash_attention needs seq divisible by a block size >= "
             f"{MIN_BLOCK}: q seq {q.shape[1]}, kv seq {k.shape[1]}"
         )
-    if kv_lengths is not None:
-        if kv_lengths.shape != (q.shape[0],):
+    if q_lengths is not None and not (
+            causal and kv_lengths is not None and q.shape[1] == k.shape[1]):
+        raise ValueError(
+            "q_lengths needs causal self-attention and kv_lengths beside it")
+    lengths = []
+    for name, given in (("kv_lengths", kv_lengths), ("q_lengths", q_lengths)):
+        if given is None:
+            continue
+        if given.shape != (q.shape[0],):
             raise ValueError(
-                f"kv_lengths must be shape ({q.shape[0]},), got "
-                f"{kv_lengths.shape}"
+                f"{name} must be shape ({q.shape[0]},), got {given.shape}"
             )
-        kv_lengths = kv_lengths.astype(jnp.int32)
+        lengths.append(given.astype(jnp.int32))
 
-    def local(q, k, v, lengths):
+    def local(q, k, v, kv_lengths=None, q_lengths=None):
         # (B,S,H,D) -> (B,H,S,D)
         qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-        out = _flash(qt, kt, vt, lengths, scale, causal, bq, bk, window)
+        out = _flash(qt, kt, vt, kv_lengths, q_lengths, scale, causal, bq, bk,
+                     window)
         return jnp.swapaxes(out, 1, 2)
 
-    return _over_mesh(local, q, k, v, kv_lengths)
+    return _over_mesh(local, q, k, v, *lengths)
 
 
-def _over_mesh(local, q, k, v, kv_lengths):
+def _over_mesh(local, q, k, v, *lengths):
     """Run the per-device kernel body over the live mesh. A Mosaic kernel
     cannot be partitioned by GSPMD ("Mosaic kernels cannot be automatically
     partitioned. Please wrap the call in a shard_map"): on any mesh of more
@@ -583,7 +709,7 @@ def _over_mesh(local, q, k, v, kv_lengths):
 
     mesh = live_mesh()
     if mesh is None:
-        return local(q, k, v, kv_lengths)
+        return local(q, k, v, *lengths)
     from ..utils.operations import nested_manual_mesh
 
     # inside a pipeline stage (pp already Manual) the nested shard_map is
@@ -606,13 +732,10 @@ def _over_mesh(local, q, k, v, kv_lengths):
         else None
     )
     spec = P(batch_axes or None, None, heads, None)
-    args, in_specs = (q, k, v), (spec, spec, spec)
-    body = lambda q, k, v: local(q, k, v, None)
-    if kv_lengths is not None:
-        args, in_specs = args + (kv_lengths,), in_specs + (P(batch_axes or None),)
-        body = local
+    # the lengths ((B,) each: the keys', then the queries') split with the batch
+    in_specs = (spec, spec, spec) + (P(batch_axes or None),) * len(lengths)
     return shard_map(
-        body, mesh=sm_mesh, in_specs=in_specs, out_specs=spec,
+        local, mesh=sm_mesh, in_specs=in_specs, out_specs=spec,
         axis_names=set(mesh.axis_names) - manual,
         check_vma=ctx is not None,
-    )(*args)
+    )(q, k, v, *lengths)

@@ -430,6 +430,10 @@ class ServingEngine:
             # of the traced prefill programs, how many expanded the prompt's
             # latent and attended what they projected
             "mla_decode_kernel": 0, "mla_prefill_expanded": 0,
+            # of the traced prefill programs, how many attend what they
+            # projected (``fresh``) and so hand attention the prompt's real
+            # length: flash then walks the real rows, not the bucket
+            "flash_real_rows": 0,
         }
         self._rollovers_total = 0
         # every bucket width a prefill ever ran at — the set
@@ -513,6 +517,9 @@ class ServingEngine:
         eva = self._eva is not None
         recurrent = self._recurrent
         latent = self._latent
+        # a prefill that has nothing before it to see attends what it
+        # projected (``PagedKVState.fresh``), by the prompt's real length
+        fresh = recurrent or latent
         # a decode step of a stack with experts also says how many distinct
         # experts held here its rows chose (``experts_touched`` on the fetch
         # span): the experts are most of what such a step reads
@@ -556,6 +563,7 @@ class ServingEngine:
             traces["eva"] += eva
             traces["recurrent_state"] += recurrent
             traces["mla_prefill_expanded"] += latent
+            traces["flash_real_rows"] += fresh
             # cached_len > 0 is the warm-hit path: ``ids`` holds only the
             # UNCACHED tail and the paged cache already contains KV for
             # the first cached_len positions (shared prefix blocks in
@@ -576,7 +584,7 @@ class ServingEngine:
                 slot=slot, **seat_and_layout,
                 # nothing before it to see: a recurrent layer starts from
                 # zero, attention attends what the call projected
-                fresh=recurrent or latent,
+                fresh=fresh,
             )
             # the head reads the last VALID row of the padded bucket alone,
             # not the padded tail: width x vocabulary logits are never formed
@@ -781,8 +789,9 @@ class ServingEngine:
         # padded prefill compute issued so far, in bucket tokens — the
         # pow2 bucket width of every prefill/chunk call, cumulative. A
         # per-step delta of this IS the step's prefill compute cost
-        # (padding included)
+        # (padding included); beside it the tokens that were real
         self.prefill_bucket_tokens_total = 0
+        self.prefill_real_tokens_total = 0
         if spec_decode is not None:
             self.set_speculation(spec_decode)
         self._register_census_owners()
@@ -1247,6 +1256,7 @@ class ServingEngine:
             tail = req.prompt[cached:]
             self._prefill_buckets.add(bucket)
             self.prefill_bucket_tokens_total += bucket
+            self.prefill_real_tokens_total += tail_len
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :tail_len] = tail
             table = np.zeros((1, self._max_table), np.int32)
@@ -1378,6 +1388,7 @@ class ServingEngine:
                     self._cow_block(slot, t)
             self._prefill_buckets.add(bucket)
             self.prefill_bucket_tokens_total += bucket
+            self.prefill_real_tokens_total += chunk_len
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :chunk_len] = req.prompt[start:start + chunk_len]
             table = np.zeros((1, self._max_table), np.int32)
@@ -2374,6 +2385,13 @@ class ServingEngine:
             "preempts_growth_total": self._preempt_counts["growth"],
             "resumes_total": self._resumes_total,
             "prefill_chunks_total": self._prefill_chunks_total,
+            # real tokens over bucket tokens of every prefill so far: what of
+            # the prefill programs' width held a token
+            "prefill_real_token_share": (
+                self.prefill_real_tokens_total
+                / self.prefill_bucket_tokens_total
+                if self.prefill_bucket_tokens_total else 0.0
+            ),
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "state_bytes_per_slot": self.state_bytes_per_slot,
             # what ONE position holds over the layers as the latent pool is

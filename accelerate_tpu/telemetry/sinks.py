@@ -161,6 +161,9 @@ shed; emitted by the ServingEngine's span log)::
     preempts_{priority,pool,growth}_total int   per-reason splits)
     resumes_total                        int    preempted requests resumed
     prefill_chunks_total                 int    chunked-prefill calls run
+    prefill_real_token_share             float  real tokens / bucket tokens of
+                                                every prefill so far (0.0
+                                                before the first)
     kv_bytes_per_token                   float  KV+scale bytes per cached
                                                 token (int8 shrinks this)
     pool_alias_bytes                     int    bytes the captured prefill/

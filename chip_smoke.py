@@ -87,7 +87,9 @@ class Sizes:
     # kernels
     flash_short: tuple  # (batch, seq)
     flash_long: tuple
-    flash_variants: tuple  # (window?, kv_lengths?) pairs run per shape
+    # (window?, kv_lengths?) pairs run per shape; "rows" for the second: the
+    # query lengths beside the key lengths (a prompt in a padded bucket)
+    flash_variants: tuple
     adamw_leaf: tuple
 
 
@@ -98,7 +100,8 @@ REAL = Sizes(
     serve_layers=24, serve_slots=8, serve_block=16, serve_max_seq=512,
     serve_requests=16, serve_new_tokens=32, serve_prompt_range=(17, 250),
     flash_short=(8, 1024), flash_long=(1, 8192),
-    flash_variants=((False, False), (False, True), (True, False), (True, True)),
+    flash_variants=((False, False), (False, True), (True, False), (True, True),
+                    (False, "rows")),
     adamw_leaf=(4096, 14336),
 )
 TINY = Sizes(
@@ -108,7 +111,7 @@ TINY = Sizes(
     serve_layers=2, serve_slots=2, serve_block=8, serve_max_seq=128,
     serve_requests=5, serve_new_tokens=4, serve_prompt_range=(5, 60),
     flash_short=(2, 128), flash_long=(1, 256),
-    flash_variants=((False, False), (True, True)),  # halves the rehearsal
+    flash_variants=((False, False), (True, True), (False, "rows")),
     adamw_leaf=(128, 352),
 )
 
@@ -265,21 +268,26 @@ def flash_cases(say, sz: Sizes, dry: bool, shapes=None) -> None:
         for use_window, use_lengths in sz.flash_variants:
             window = sz.window if use_window else None
             kv_lengths = lens if use_lengths else None
+            q_lengths = lens if use_lengths == "rows" else None
             name = (f"flash B{batch} S{seq} H{sz.heads}/{sz.kv_heads} "
                     f"D{sz.head_dim} window={window} "
-                    f"kv_lengths={'yes' if kv_lengths is not None else 'no'}")
+                    f"kv_lengths={'yes' if kv_lengths is not None else 'no'}"
+                    + (" q_lengths=yes" if q_lengths is not None else ""))
             lengths = full if kv_lengths is None else kv_lengths
             valid = (jnp.arange(seq)[None, :]
                      < lengths[:, None])[:, :, None, None]
 
-            def kernel(q, k, v, kv_lengths=kv_lengths, window=window):
+            def kernel(q, k, v, kv_lengths=kv_lengths, window=window,
+                       q_lengths=q_lengths):
                 return flash_attention(
                     q, k, v, causal=True, window=window,
-                    kv_lengths=kv_lengths,
+                    kv_lengths=kv_lengths, q_lengths=q_lengths,
                 )
 
             out = run_compiled(say, name + " fwd", kernel, (q, k, v),
                                expect_mosaic=1, dry=dry)
+            if q_lengths is not None:  # the rows past it: zeros, by contract
+                assert not bool(jnp.any(jnp.where(valid, 0, out) != 0)), name
             grads = run_compiled(
                 say, name + " fwd+dq+dkv",
                 jax.grad(lambda q, k, v, kernel=kernel, lengths=lengths:
@@ -1191,9 +1199,13 @@ def mla_phase(say, dry: bool) -> None:
         eng.step()
     counts, stats = eng.trace_counts(), eng.pool.stats()
     say(f"mla: trace_counts {counts} pool allocated={stats['allocated']} "
-        f"decode_ahead_share {eng.decode_ahead_share:.3f}")
+        f"decode_ahead_share {eng.decode_ahead_share:.3f} "
+        "prefill_real_token_share "
+        f"{eng._gauge_fields()['prefill_real_token_share']:.3f}")
     assert counts["decode"] == 1 and stats["allocated"] == 0, (counts, stats)
     assert counts["mla_prefill_expanded"] == counts["prefill"] >= 1, counts
+    # every prefill told flash how many rows of its bucket are real
+    assert counts["flash_real_rows"] == counts["prefill"], counts
     assert {k.split("'")[-2] for k in map(
         jax.tree_util.keystr, dict(jax.tree_util.tree_flatten_with_path(
             eng.cache)[0]))} == {"latent_pool"}

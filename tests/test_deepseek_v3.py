@@ -390,19 +390,39 @@ def _hold_against_one_forward_pass(params, served, cfg=CFG):
     return worst
 
 
-@pytest.mark.parametrize("kernel,budget", [(False, None), (True, None), (False, 1 << 12)],
-                         ids=["gather", "latent_decode", "in_parts"])
+@pytest.mark.parametrize("kernel,budget,flash", [
+    (False, None, False), (True, None, False), (False, 1 << 12, False),
+    (True, None, True),
+], ids=["gather", "latent_decode", "in_parts", "flash_real_rows"])
 def test_prefill_then_decode_through_the_latent_cache_is_one_forward_pass(
-        params, monkeypatch, kernel, budget):
+        params, monkeypatch, kernel, budget, flash):
     """Three requests of unequal length in three slots, the later ones
     prefilled while a decode step of the earlier is in flight: the prefill
     EXPANDS and writes latent rows alone, every decode step reads them
     ABSORBED — through the gather form, and through the ``latent_decode``
     kernel (interpreted) — and both are the reference's one full pass; so
     they are under a budget so small (``FORWARD_PART_BYTES``) that every
-    prefill walks its heads in groups and its experts' rows in parts."""
-    schedule = [(0, _ids(23, 1), 12), (4, _ids(81, 2), 9), (2, _ids(8, 3), 14)]
+    prefill walks its heads in groups and its experts' rows in parts. Every
+    prefill hands attention its prompt's real length, and the second prompt
+    fills half its bucket and one token: the rows past it come out of
+    attention as zeros (the xla path; ``flash_real_rows``: the interpreted
+    ``flash_fwd`` kernel told both lengths) and nothing real moves."""
+    schedule = [(0, _ids(23, 1), 12), (4, _ids(65, 2), 9), (2, _ids(8, 3), 14)]
     taken = {}
+    lengths = []
+    if flash:
+        from accelerate_tpu.ops import flash_attention as flash_ops
+
+        fwd = flash_ops._fwd
+
+        def told(q, k, v, kv_lengths, *a):
+            lengths.append((q.shape[2], kv_lengths is not None, a[-1] is not None))
+            return fwd(q, k, v, kv_lengths, *a)
+
+        monkeypatch.setattr(flash_ops, "_fwd", told)
+        # the dispatch of a TPU at this test's widths: whole sublanes
+        monkeypatch.setattr(
+            attn_ops, "flash_self_attention_eligible", lambda s: s % 8 == 0)
     if budget:
         def parts(nbytes, of, _=None):
             n = attn_ops.forward_parts(nbytes, of, budget)
@@ -423,6 +443,12 @@ def test_prefill_then_decode_through_the_latent_cache_is_one_forward_pass(
     counts = eng.trace_counts()
     assert counts["decode"] == 1 and counts["mla_decode_kernel"] == int(kernel)
     assert counts["mla_prefill_expanded"] == counts["prefill"] >= 2
+    assert counts["flash_real_rows"] == counts["prefill"]
+    # 23 + 65 + 8 real tokens in buckets of 32 + 128 + 8
+    assert eng._gauge_fields()["prefill_real_token_share"] == 96 / 168
+    if flash:  # a kernel call a layer a bucket, each told both lengths
+        assert sorted(set(lengths)) == [
+            (8, True, True), (32, True, True), (128, True, True)]
     assert counts["kv_in_place"] == counts["prefill"] + 1
     assert eng.pool.stats()["allocated"] == 0 and eng.decode_ahead_share > 0.5
     if budget:  # the heads of a prefill, the tokens of its 32 / 128 / 8 rows

@@ -382,6 +382,7 @@ def test_engine_holds_rows_and_blocks_by_the_layout_and_gives_all_back(params):
     assert shrunk  # slots that gave blocks back before they ended
     assert counts["decode"] == 1 and counts["prefill"] == 4  # 8 .. 64 wide
     assert counts["eva"] == counts["prefill"] + counts["decode"] + 1
+    assert counts["flash_real_rows"] == 0  # its windows are whole: no length
     # 16->56: 2 (at 32, 48); 7->37: 2; 32->37: 0; 21->71: 3; 50->120: 4; 20->29: 0
     assert eng._gauge_fields()["window_rollovers_total"] == 11
     for rid, prompt, new in sent:

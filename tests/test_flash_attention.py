@@ -6,6 +6,8 @@ covers it without a chip; on a real TPU the same tests exercise the
 compiled kernel.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -350,3 +352,251 @@ def test_flash_runs_per_device_over_a_live_mesh():
     )
     # each device really got its own rows and heads
     assert out.sharding.is_equivalent_to(qs.sharding, out.ndim)
+
+
+# --------------------------------------------------------------------------- #
+# a query length beside the key length: a prompt in a padded bucket
+# --------------------------------------------------------------------------- #
+# S = 512 in blocks of 128: a length that straddles a block, fills one
+# exactly, leaves whole q blocks empty (two, three and all four of them),
+# fills the bucket, and 0
+_REAL = [133, 128, 256, 40, 512, 0]
+
+
+def _both_lengths(length, B=1):
+    lens = jnp.full((B,), length, jnp.int32)
+    return {"kv_lengths": lens, "q_lengths": lens}
+
+
+def _fwd_with_lse(q, k, v, length=None, block=128):
+    """The forward kernel itself, for its log-sum-exp: (out (B, S, H, Dv),
+    lse (B, S, H))."""
+    from accelerate_tpu.ops.flash_attention import _fwd
+
+    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+    lens = None if length is None else jnp.full((q.shape[0],), length, jnp.int32)
+    with _kernel_mode():
+        out, lse = _fwd(qt, kt, vt, lens, q.shape[-1] ** -0.5, True, block,
+                        block, None, lens)
+    return np.asarray(jnp.swapaxes(out, 1, 2)), np.asarray(
+        jnp.swapaxes(lse[..., 0], 1, 2))
+
+
+@pytest.mark.parametrize("widths", [(64, 64), (192, 128)], ids=["64", "192-128"])
+@pytest.mark.parametrize("length", _REAL)
+def test_q_lengths_real_rows_are_the_plain_calls_and_the_rest_zeros(
+        length, widths):
+    """Causal self-attention told how many rows are real: the real rows are
+    those of the call without lengths (no real row sees a key past the
+    length), the rows past it exactly zero with a log-sum-exp of NEG_INF,
+    and nothing is left uninitialised — at one head_dim and at a 192-wide
+    score over a 128-wide value."""
+    from accelerate_tpu.ops.flash_attention import NEG_INF
+
+    d, dv = widths
+    hkv = 2 if d == dv else 4  # keys wider than values: one KV head a head
+    q, k, _ = _qkv(S=512, H=4, Hkv=hkv, D=d, seed=11)
+    v = _qkv(S=512, H=4, Hkv=hkv, D=dv, seed=12)[2]
+    plain, plain_lse = _fwd_with_lse(q, k, v)
+    out, lse = _fwd_with_lse(q, k, v, length)
+    assert out.shape == (1, 512, 4, dv)
+    assert np.all(np.isfinite(out)) and np.all(np.isfinite(lse))
+    np.testing.assert_allclose(out[:, :length], plain[:, :length],
+                               atol=5e-3, rtol=1e-2)
+    np.testing.assert_allclose(lse[:, :length], plain_lse[:, :length],
+                               atol=5e-3, rtol=1e-2)
+    np.testing.assert_array_equal(out[:, length:], 0.0)
+    np.testing.assert_array_equal(lse[:, length:], np.float32(NEG_INF))
+    # the public call, and the xla path beside it: the same rows, the same zeros
+    with _kernel_mode():
+        public = flash_attention(q, k, v, causal=True, block_q=128,
+                                 block_k=128, **_both_lengths(length))
+    np.testing.assert_array_equal(np.asarray(public), out)
+    ref = np.asarray(xla_attention(q, k, v, causal=True,
+                                   **_both_lengths(length)))
+    np.testing.assert_array_equal(ref[:, length:], 0.0)
+    np.testing.assert_allclose(out, ref, atol=5e-3, rtol=1e-2)
+
+
+def test_q_lengths_differ_a_row_of_the_batch():
+    """Each row of a batch has its own length: every one of ``_REAL`` beside
+    the others in one call."""
+    q, k, v = _qkv(B=len(_REAL), S=512, seed=13)
+    lens = jnp.asarray(_REAL, jnp.int32)
+    with _kernel_mode():
+        out = np.asarray(flash_attention(
+            q, k, v, causal=True, kv_lengths=lens, q_lengths=lens,
+            block_q=128, block_k=128))
+        plain = np.asarray(flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128))
+    for b, n in enumerate(_REAL):
+        np.testing.assert_allclose(out[b, :n], plain[b, :n], atol=5e-3, rtol=1e-2)
+        np.testing.assert_array_equal(out[b, n:], 0.0)
+
+
+@pytest.mark.parametrize("length", [133, 256, 40, 0])
+def test_q_lengths_backward_matches_xla_with_the_same_rows_zeroed(length):
+    """Gradients of a call with both lengths are the oracle's with the same
+    rows zeroed: the rows past the length give none, and get none."""
+    q, k, v = _qkv(S=512, seed=14)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v, causal=True, **_both_lengths(length)) ** 2)
+
+    flash = functools.partial(flash_attention, block_q=128, block_k=128)
+    with _kernel_mode():
+        g1 = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss(xla_attention), argnums=(0, 1, 2))(q, k, v)
+    for g in g1:
+        assert np.all(np.isfinite(np.asarray(g)))
+        np.testing.assert_array_equal(np.asarray(g[:, length:]), 0.0)
+    if length:
+        _assert_grads_close(g1, g2)
+
+
+def test_q_lengths_under_a_window_keep_the_fully_masked_row_guard():
+    """A key length shorter than the query length under a sliding window
+    leaves real rows that see no key: zeros, not the mean of v."""
+    q, k, v = _qkv(S=512, seed=15)
+    kv, ql = jnp.asarray([100], jnp.int32), jnp.asarray([400], jnp.int32)
+    with _kernel_mode():
+        out = np.asarray(flash_attention(
+            q, k, v, causal=True, window=64, kv_lengths=kv, q_lengths=ql,
+            block_q=128, block_k=128))
+    ref = np.asarray(xla_attention(
+        q, k, v, causal=True, window=64, kv_lengths=kv, q_lengths=ql))
+    assert np.all(np.isfinite(out))
+    np.testing.assert_allclose(out[:, :163], ref[:, :163], atol=5e-3, rtol=1e-2)
+    np.testing.assert_array_equal(out[:, 163:], 0.0)  # row 163 sees (99, 163]
+
+
+@pytest.mark.parametrize("case", ["alone", "bidirectional", "cross", "shape"])
+def test_q_lengths_are_refused_where_they_mean_nothing(case):
+    from accelerate_tpu.ops.attention import dot_product_attention
+
+    q, k, v = _qkv(S=128)
+    lens = jnp.asarray([64], jnp.int32)
+    kwargs = {
+        "alone": dict(causal=True, q_lengths=lens),
+        "bidirectional": dict(causal=False, kv_lengths=lens, q_lengths=lens),
+        "cross": dict(causal=True, kv_lengths=lens, q_lengths=lens),
+        "shape": dict(causal=True, kv_lengths=lens,
+                      q_lengths=jnp.asarray([64, 64], jnp.int32)),
+    }[case]
+    if case == "cross":
+        q = q[:, :64]
+    for call in (flash_attention, dot_product_attention):
+        if case == "shape" and call is dot_product_attention:
+            continue  # the kernel's own check
+        with pytest.raises(ValueError, match="q_lengths"):
+            call(q, k, v, **kwargs)
+
+
+def _head_walk(length, rows, S=512, block=128):
+    """One head's grid as the kernel's own predicate and index maps see it
+    (concrete values): how many steps compute, how many name another k / v
+    block than the step before (a copy), how many another q block."""
+    from accelerate_tpu.ops.flash_attention import (
+        _block_visible, _fwd_index_maps,
+    )
+
+    n = S // block
+    padded = length is not None
+    q_index, kv_index = _fwd_index_maps(block, block, 1, 0, True, padded, rows)
+    refs = (np.asarray([length or 0], np.int32),) * (padded + rows)
+    run = kv = qs = 0
+    at_kv = at_q = None
+    for iq in range(n):
+        for ik in range(n):
+            live = (not rows) or iq * block < length
+            vis = bool(_block_visible(
+                iq, ik, block, block, True, 0, length if padded else None))
+            here_kv = int(kv_index(0, 0, iq, ik, *refs)[2])
+            here_q = int(q_index(0, 0, iq, ik, *refs)[2])
+            if live and vis:  # a step that runs reads its own blocks
+                run += 1
+                assert (here_q, here_kv) == (iq, ik)
+            kv += here_kv != at_kv
+            qs += here_q != at_q
+            at_kv, at_q = here_kv, here_q
+    return run, kv, qs
+
+
+@pytest.mark.parametrize("length,rows,want", [
+    # no lengths: the causal half-square, and every step copies a block
+    (None, False, (10, 16, 4)),
+    # the key length alone: every q block walks the real keys
+    # (9 copies for 10 steps: a q block ends on the block the next begins on)
+    (512, False, (10, 9, 4)), (133, False, (7, 6, 4)), (128, False, (4, 1, 4)),
+    # both: the real rows' half-square, and past it nothing moves
+    (512, True, (10, 9, 4)), (384, True, (6, 5, 3)), (133, True, (3, 2, 2)),
+    (128, True, (1, 1, 1)), (0, True, (0, 1, 1)),
+])
+def test_a_step_past_the_lengths_names_the_block_already_resident(
+        length, rows, want):
+    """What interpret mode cannot see: a step that computes nothing copies
+    nothing. With both lengths a head walks the real rows' half-square; a
+    q block past the length names the blocks already there."""
+    assert _head_walk(length, rows) == want
+
+
+# --------------------------------------------------------------------------- #
+# a call without lengths: the kernels it had
+# --------------------------------------------------------------------------- #
+def _mosaic_kernels(lowered_text):
+    """The Mosaic kernels in a text lowered for the TPU, as MLIR WITHOUT
+    locations: the serialized body also holds the source lines of
+    ``ops/flash_attention.py``, which move with any edit to the file."""
+    import base64
+    import re
+
+    from jax._src.lib.mlir import ir
+
+    kernels = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered_text):
+        with ir.Context() as ctx:
+            # serialized under a versioned dialect name (``stable_mosaic``):
+            # printed in the generic form, which is all a comparison needs
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(body))
+            kernels.append(module.operation.get_asm(enable_debug_info=False))
+    return kernels
+
+
+# sha256 of the kernels at commit 772de96 (the parent of ISSUE 41), as this
+# test lowers them: what the three train cells and eva's windows run
+_NO_LENGTHS = {
+    "causal_grad": ("f78e0cc03dce4976", 3),
+    "window_grad": ("7f1a15335f7a76bc", 3),
+    "bidirectional": ("317d4304959a2ded", 1),
+    "score_192_value_128": ("780f5851efd4c14f", 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_NO_LENGTHS))
+def test_a_call_without_lengths_lowers_to_the_kernels_it_had(case):
+    """The mode that carries lengths changed (ISSUE 41); a call without them
+    — ``padded=False`` — keeps its forward and backward kernels operation
+    for operation."""
+    import hashlib
+
+    bf = jnp.bfloat16
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, bf)  # noqa: E731
+    q, k = spec(2, 2048, 32, 128), spec(2, 2048, 8, 128)
+    total = lambda out: out.astype(jnp.float32).sum()  # noqa: E731
+    fn, args = {
+        "causal_grad": (jax.grad(lambda q, k, v: total(
+            flash_attention(q, k, v)), argnums=(0, 1, 2)), (q, k, k)),
+        "window_grad": (jax.grad(lambda q, k, v: total(
+            flash_attention(q, k, v, window=512)), argnums=(0, 1, 2)), (q, k, k)),
+        "bidirectional": (
+            lambda q, k, v: flash_attention(q, k, v, causal=False), (q, k, k)),
+        "score_192_value_128": (
+            lambda q, k, v: flash_attention(q, k, v),
+            (spec(1, 8192, 32, 192), spec(1, 8192, 32, 192), spec(1, 8192, 32, 128))),
+    }[case]
+    text = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    kernels = _mosaic_kernels(text)
+    sha = hashlib.sha256("\n".join(kernels).encode()).hexdigest()[:16]
+    assert (sha, len(kernels)) == _NO_LENGTHS[case], (sha, len(kernels))

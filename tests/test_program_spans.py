@@ -360,8 +360,10 @@ def test_an_engine_with_no_collector_builds_no_gauge_record(session):
     assert [g["engine_steps"] for g in built] == list(range(1, steps + 1))
     # as before, the share of its steps that were decoded ahead, and what a
     # seat holds beside its K/V rows (0 for a stack without recurrent layers),
-    # and what a position's latent row holds (0: per-head K and V)
-    assert len(built[0]) == 39 and built[0]["slots_active"] == 3
+    # and what a position's latent row holds (0: per-head K and V), and what
+    # of its prefill buckets held a token
+    assert len(built[0]) == 40 and built[0]["slots_active"] == 3
+    assert 0.5 < built[0]["prefill_real_token_share"] <= 1.0
     assert built[0]["state_bytes_per_slot"] == 0 == built[0]["latent_row_bytes"]
     assert built[0]["decode_ahead_share"] == 0.0 < built[-1]["decode_ahead_share"]
 

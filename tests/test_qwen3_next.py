@@ -359,18 +359,23 @@ def test_prefill_then_decode_through_the_engine_is_one_forward_pass(
     prefilled while the first's decode step is in flight (decode-ahead), a
     prompt that fills no whole chunk and one that fills one and a part; with
     every layer a module of its own (the configuration's) and with the three
-    DeltaNet layers one scan whose state leaves are stacked."""
+    DeltaNet layers one scan whose state leaves are stacked. The second
+    prompt leaves nearly half its bucket empty: attention zeroes those rows."""
     cfg = tiny.config(scan_layers=scan)
     if scan:
         params = W.make_tree(cfg, SEED, jnp.float32)
     eng, served, flying = _serve(params, monkeypatch, [
-        (0, _ids(23, 1), 12), (4, _ids(81, 2), 9)], cfg=cfg)
+        (0, _ids(23, 1), 12), (4, _ids(65, 2), 9)], cfg=cfg)
     assert eng.decode_ahead and flying == [False, True]
     worst = _hold_against_one_forward_pass(params, served, cfg)
     print("engine vs one forward pass, widest logit error:", worst)
     assert worst < 5 * TOL
     counts = eng.trace_counts()
     assert counts["decode"] == 1 and counts["recurrent_state"] == counts["prefill"] + 1
+    # the attention layer is handed each prompt's real length (the second
+    # fills half its bucket and one token: 23 + 65 tokens in 32 + 128)
+    assert counts["flash_real_rows"] == counts["prefill"] == 2
+    assert eng._gauge_fields()["prefill_real_token_share"] == 88 / 160
     assert eng.pool.stats()["allocated"] == 0
     assert len(jax.tree.leaves(eng.cache)) == (4 if scan else 8)
 
@@ -517,7 +522,6 @@ def test_any_stack_with_experts_says_how_many_a_decode_step_touched(dispatch):
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     eng = ServingEngine(model, params, max_slots=2, block_size=8)
     assert eng._counts_experts and eng._experts_held == 2 * 4
-    assert eng.trace_counts()["recurrent_state"] == 0
     fetched, fetch = [], eng._fetch
     eng._fetch = lambda *a: fetched.append(fetch(*a)) or fetched[-1]
     prompt = _ids(11, 2, {"vocab_size": cfg.vocab_size})
@@ -533,6 +537,11 @@ def test_any_stack_with_experts_says_how_many_a_decode_step_touched(dispatch):
         np.concatenate([prompt, tokens])[None])))[0]
     at = np.arange(len(prompt) - 1, len(prompt) + 5)
     assert float(np.max(want[at].max(-1) - want[at, np.asarray(tokens)])) < 1e-4
+    # a dense prefill gathers its table: no state a slot, no length to flash
+    counts = eng.trace_counts()
+    assert counts["prefill"] == 1
+    assert counts["recurrent_state"] == counts["flash_real_rows"] == 0
+    assert eng._gauge_fields()["prefill_real_token_share"] == 11 / 16
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])
