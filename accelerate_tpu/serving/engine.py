@@ -51,6 +51,7 @@ from ..ops.attention import (
     latent_kernel_eligible,
     pool_heads_first,
 )
+from ..ops.gated_delta import chunked_kernel_eligible
 from ..utils.profiling import annotate
 from .block_pool import BlockPool, PrefixCache, prefix_keys
 from .sampling import SlotSampling, sample_tokens
@@ -434,6 +435,9 @@ class ServingEngine:
             # projected (``fresh``) and so hand attention the prompt's real
             # length: flash then walks the real rows, not the bucket
             "flash_real_rows": 0,
+            # of the traced prefill programs, how many run their DeltaNet
+            # layers' chunked rule as the ``gdn_chunked`` kernel
+            "gdn_kernel": 0,
         }
         self._rollovers_total = 0
         # every bucket width a prefill ever ran at — the set
@@ -564,6 +568,8 @@ class ServingEngine:
             traces["recurrent_state"] += recurrent
             traces["mla_prefill_expanded"] += latent
             traces["flash_real_rows"] += fresh
+            traces["gdn_kernel"] += recurrent and chunked_kernel_eligible(
+                cfg.gdn_head_k_dim, cfg.gdn_head_v_dim)
             # cached_len > 0 is the warm-hit path: ``ids`` holds only the
             # UNCACHED tail and the paged cache already contains KV for
             # the first cached_len positions (shared prefix blocks in

@@ -128,6 +128,11 @@ TOL_KERNEL_BF16 = 2e-2
 # largest logit bounds that without admitting a wrong cache row (which
 # moves logits by O(100 %)).
 TOL_LOGITS_BF16 = 5e-2
+# The gated delta rule is float32 on both sides at full-precision products;
+# the kernel and the jax.numpy form sum 2,048 positions in another order
+# (the state is O(1)): an absolute 1e-3 is ~100 x what that leaves, and far
+# under what one bfloat16 pass would (1e-2).
+TOL_GDN = 1e-3
 
 
 def mistral_config(sz: Sizes, **kw):
@@ -462,6 +467,39 @@ def paged_decode_case(say, sz: Sizes, dry: bool) -> None:
     check_errors(say, name, {"out": nerr(got, want)}, TOL_KERNEL_BF16)
 
 
+def gdn_case(say, dry: bool) -> None:
+    """The chunked gated delta rule as the ``gdn_chunked`` kernel against the
+    ``jax.numpy`` form of the same rule, at Qwen3-Next's heads (16 key and 32
+    value heads of 128, float32 state), one row of a 2,048 bucket: a prompt
+    that fills it and one that ends inside its eleventh chunk. Real rows and
+    the state agree; the kernel's rows past the length are zeros."""
+    import jax
+    import jax.numpy as jnp
+    from gdn_scan_on_chip import inputs
+
+    from accelerate_tpu.ops import gated_delta
+
+    hk, hv, d, width = (2, 4, 8, 256) if dry else (16, 32, 128, 2048)
+    q, k, v, g, beta = inputs(width, hk, hv, d, d, SEED + 6)
+    assert gated_delta.chunked_kernel_eligible(d, d)
+    want_fn = jax.jit(gated_delta._chunked_reference)
+    for length in (width * 700 // 2048, width):
+        lengths = jnp.asarray([length], jnp.int32)
+        name = (f"gdn f32 1 x {width} x {hk}/{hv} heads of {d}, "
+                f"length {length}")
+        o, state = run_compiled(
+            say, name, gated_delta.gated_delta_chunked,
+            (q, k, v, g, beta, lengths), expect_mosaic=1, dry=dry)
+        want_o, want_state = want_fn(q, k, v, g, beta, lengths)
+        gaps = {"o": float(jnp.max(jnp.abs(o[:, :length] - want_o[:, :length]))),
+                "state": float(jnp.max(jnp.abs(state - want_state)))}
+        say(f"kernel {name}: max abs difference "
+            + " ".join(f"{n}={e:.2e}" for n, e in gaps.items())
+            + f" (bound {TOL_GDN:.0e})")
+        assert all(e <= TOL_GDN for e in gaps.values()), gaps
+        assert not bool(jnp.any(o[:, length:] != 0)), "rows past the length"
+
+
 def kernel_phase(say, sz: Sizes, dry: bool) -> None:
     import contextlib
 
@@ -483,6 +521,7 @@ def kernel_phase(say, sz: Sizes, dry: bool) -> None:
         prologue_case(say, sz, dry)
         adamw_case(say, sz, dry)
         paged_decode_case(say, sz, dry)
+        gdn_case(say, dry)
     say("kernel phase PASSED")
 
 

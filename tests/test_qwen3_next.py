@@ -7,6 +7,7 @@ the benchmark's plain float32 reference
 token-by-token recurrence itself) on seeded weights
 (``qwen3_next_weights.py``), at widths the CPU can hold."""
 
+import contextlib
 import os
 import sys
 
@@ -29,6 +30,8 @@ from harness import qwen3_next_work as work  # noqa: E402
 from accelerate_tpu.models import CausalLM, TransformerConfig  # noqa: E402
 from accelerate_tpu.models.transformer import (  # noqa: E402
     Attention, MoE, layer_kinds, plan_layers)
+from accelerate_tpu.ops import gated_delta  # noqa: E402
+from accelerate_tpu.ops.flash_attention import kernel_interpret_mode  # noqa: E402
 from accelerate_tpu.ops.gated_delta import (  # noqa: E402
     gated_delta_chunked, gated_delta_step)
 from accelerate_tpu.serving import ServingEngine, SpecConfig  # noqa: E402
@@ -112,13 +115,14 @@ def test_params_held_is_the_seeded_trees_count_at_the_published_widths():
 # --------------------------------------------------------------------------- #
 # the gated delta rule: chunked and one position against the recurrence
 # --------------------------------------------------------------------------- #
-def _rule_inputs(b, s, hk=2, hv=4, dk=8, dv=8, seed=0):
+def _rule_inputs(b, s, hk=2, hv=4, dk=8, dv=8, seed=0, log_decay=(-6.0, 2.0)):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q = jax.random.normal(ks[0], (b, s, hk, dk))
     k = jax.random.normal(ks[1], (b, s, hk, dk))
     v = jax.random.normal(ks[2], (b, s, hv, dv))
     # log-decays from fast (exp(g) ~ 1e-3) to none, writes from weak to whole
-    g = -jnp.exp(jax.random.uniform(ks[3], (b, s, hv), minval=-6.0, maxval=2.0))
+    g = -jnp.exp(jax.random.uniform(
+        ks[3], (b, s, hv), minval=log_decay[0], maxval=log_decay[1]))
     beta = jax.nn.sigmoid(3.0 * jax.random.normal(ks[4], (b, s, hv)))
     return q, k, v, g, beta
 
@@ -162,6 +166,117 @@ def test_chunked_prefill_then_steps_is_the_recurrence_over_both(prompt, steps):
         o, state = gated_delta_step(q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t], state)
         assert float(jnp.max(jnp.abs(o - want_o[:, t]))) < TOL
     assert float(jnp.max(jnp.abs(state - want_s))) < TOL
+
+
+# --------------------------------------------------------------------------- #
+# the chunked form as one kernel (interpreted): against the recurrence and
+# against the jax.numpy form it replaces on a chip
+# --------------------------------------------------------------------------- #
+_DECAYS = {"mixed": (-6.0, 2.0), "fast": (0.0, 2.0), "slow": (-9.0, -5.0)}
+
+
+def _gap(a, b):
+    return float(jnp.max(jnp.abs(a - b))) if a.size else 0.0
+
+
+def test_off_the_chip_the_chunked_form_is_the_jax_numpy_one():
+    """No TPU and no interpreter: the present tests above run the kept form,
+    bit for bit; inside ``kernel_interpret_mode`` the kernel takes heads of
+    whole sublanes alone."""
+    assert not gated_delta.chunked_kernel_eligible(8, 8)
+    args = _rule_inputs(2, 100, seed=3)
+    for got, want in zip(gated_delta_chunked(*args),
+                         gated_delta._chunked_reference(*args)):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    with kernel_interpret_mode():
+        assert gated_delta.chunked_kernel_eligible(8, 16)
+        assert not gated_delta.chunked_kernel_eligible(12, 8)
+        assert not gated_delta.chunked_kernel_eligible(8, 4)
+
+
+@pytest.mark.parametrize("hk", [2, 4], ids=["two_value_heads_a_key", "one"])
+@pytest.mark.parametrize("length", [1, 37, 64, 100, 128, 200])
+def test_the_kernel_is_the_recurrence_and_the_jax_numpy_form(length, hk):
+    args = _rule_inputs(2, length, hk=hk, seed=length)
+    with kernel_interpret_mode():
+        got_o, got_s = gated_delta_chunked(*args)
+    for want_o, want_s in (_recurrence(*args),
+                           gated_delta._chunked_reference(*args)):
+        assert _gap(got_o, want_o) < TOL
+        assert _gap(got_s, want_s) < TOL
+
+
+@pytest.mark.parametrize("decay", list(_DECAYS))
+@pytest.mark.parametrize("lengths", [(100, 128), (1, 77), (64, 5), (0, 192)])
+def test_the_kernel_walks_no_chunk_past_a_length(lengths, decay):
+    """Rows of a bucket of 192 that end before it, inside a chunk, on a
+    chunk's end, before the first position: the real rows are the
+    recurrence's (and the kept form's), every row at or past the length is
+    zeros, and the state is the one after the row's last real position."""
+    args = _rule_inputs(2, 192, seed=sum(lengths), log_decay=_DECAYS[decay])
+    lens = jnp.asarray(lengths)
+    with kernel_interpret_mode():
+        got_o, got_s = gated_delta_chunked(*args, lengths=lens)
+    kept_o, kept_s = gated_delta._chunked_reference(*args, lengths=lens)
+    assert _gap(got_s, kept_s) < TOL
+    for row, n in enumerate(lengths):
+        want_o, want_s = _recurrence(*(a[row:row + 1, :n] for a in args))
+        assert _gap(got_o[row, :n], want_o[0]) < TOL
+        assert _gap(got_o[row, :n], kept_o[row, :n]) < TOL
+        assert _gap(got_s[row], want_s[0]) < TOL
+        assert not np.any(np.asarray(got_o[row, n:]))
+
+
+@pytest.mark.parametrize("prompt,steps", [(37, 5), (64, 9), (130, 3)])
+def test_the_kernels_prefill_then_steps_is_the_recurrence_over_both(prompt, steps):
+    q, k, v, g, beta = _rule_inputs(2, prompt + steps, seed=prompt)
+    want_o, want_s = _recurrence(q, k, v, g, beta)
+    with kernel_interpret_mode():
+        _, state = gated_delta_chunked(*(a[:, :prompt] for a in (q, k, v, g, beta)))
+    for t in range(prompt, prompt + steps):
+        o, state = gated_delta_step(q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t], state)
+        assert _gap(o, want_o[:, t]) < TOL
+    assert _gap(state, want_s) < TOL
+
+
+@pytest.mark.parametrize("lengths", [None, (70, 128)], ids=["whole", "padded"])
+def test_the_kernels_gradient_is_the_jax_numpy_forms(lengths):
+    """The kernel's forward pass under ``custom_vjp``, the kept form's
+    backward pass: every input's gradient is what it was, and a row past a
+    length (zeros out of the kernel whatever the inputs) pulls nothing back."""
+    args = _rule_inputs(2, 128, seed=9)
+    lens = None if lengths is None else jnp.asarray(lengths)
+    w_o = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 4, 8))
+    w_s = jax.random.normal(jax.random.PRNGKey(2), (2, 4, 8, 8))
+    real = (jnp.arange(128)[None] < (
+        jnp.full((2,), 128) if lens is None else lens)[:, None])[..., None, None]
+
+    def loss(fn, *a):
+        o, s = fn(*a, lengths=lens)
+        return jnp.sum(jnp.where(real, o, 0.0) * w_o) + jnp.sum(s * w_s)
+
+    want = jax.grad(lambda *a: loss(gated_delta._chunked_reference, *a),
+                    argnums=range(5))(*args)
+    with kernel_interpret_mode():
+        got = jax.grad(lambda *a: loss(gated_delta_chunked, *a),
+                       argnums=range(5))(*args)
+        value = loss(gated_delta_chunked, *args)
+    assert abs(float(value - loss(gated_delta._chunked_reference, *args))) < 1e-3
+    for name, a, b in zip("qkvgb", got, want):
+        assert _gap(a, b) < 1e-5, name
+
+
+def test_heads_the_kernel_does_not_tile_take_the_jax_numpy_form():
+    """A key head of 12 is no whole number of sublanes (nor of lanes on a
+    chip): the call falls back inside the interpreter too, and still holds."""
+    args = _rule_inputs(2, 100, dk=12, seed=5)
+    with kernel_interpret_mode():
+        got = gated_delta_chunked(*args, lengths=jnp.asarray([100, 41]))
+    want = gated_delta._chunked_reference(*args, lengths=jnp.asarray([100, 41]))
+    for a, b in zip(got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    want_o, want_s = _recurrence(*(a[1:, :41] for a in args))
+    assert _gap(got[0][1, :41], want_o[0]) < TOL and _gap(got[1][1], want_s[0]) < TOL
 
 
 # --------------------------------------------------------------------------- #
@@ -352,20 +467,24 @@ def _hold_against_one_forward_pass(params, served, cfg=CFG):
     return worst
 
 
-@pytest.mark.parametrize("scan", [False, True], ids=["layers_alone", "scanned"])
+@pytest.mark.parametrize("scan,kernel", [(False, False), (True, False), (False, True)],
+                         ids=["layers_alone", "scanned", "gdn_kernel"])
 def test_prefill_then_decode_through_the_engine_is_one_forward_pass(
-        params, monkeypatch, scan):
+        params, monkeypatch, scan, kernel):
     """Two requests of different length in different slots, the second
     prefilled while the first's decode step is in flight (decode-ahead), a
     prompt that fills no whole chunk and one that fills one and a part; with
     every layer a module of its own (the configuration's) and with the three
     DeltaNet layers one scan whose state leaves are stacked. The second
-    prompt leaves nearly half its bucket empty: attention zeroes those rows."""
+    prompt leaves nearly half its bucket empty: attention zeroes those rows,
+    and (``gdn_kernel``: the interpreted kernel, as on a chip) the DeltaNet
+    layers walk its two real chunks alone."""
     cfg = tiny.config(scan_layers=scan)
     if scan:
         params = W.make_tree(cfg, SEED, jnp.float32)
-    eng, served, flying = _serve(params, monkeypatch, [
-        (0, _ids(23, 1), 12), (4, _ids(65, 2), 9)], cfg=cfg)
+    with kernel_interpret_mode() if kernel else contextlib.nullcontext():
+        eng, served, flying = _serve(params, monkeypatch, [
+            (0, _ids(23, 1), 12), (4, _ids(65, 2), 9)], cfg=cfg)
     assert eng.decode_ahead and flying == [False, True]
     worst = _hold_against_one_forward_pass(params, served, cfg)
     print("engine vs one forward pass, widest logit error:", worst)
@@ -375,6 +494,7 @@ def test_prefill_then_decode_through_the_engine_is_one_forward_pass(
     # the attention layer is handed each prompt's real length (the second
     # fills half its bucket and one token: 23 + 65 tokens in 32 + 128)
     assert counts["flash_real_rows"] == counts["prefill"] == 2
+    assert counts["gdn_kernel"] == (2 if kernel else 0)  # 0 off the chip
     assert eng._gauge_fields()["prefill_real_token_share"] == 88 / 160
     assert eng.pool.stats()["allocated"] == 0
     assert len(jax.tree.leaves(eng.cache)) == (4 if scan else 8)
@@ -541,6 +661,7 @@ def test_any_stack_with_experts_says_how_many_a_decode_step_touched(dispatch):
     counts = eng.trace_counts()
     assert counts["prefill"] == 1
     assert counts["recurrent_state"] == counts["flash_real_rows"] == 0
+    assert counts["gdn_kernel"] == 0
     assert eng._gauge_fields()["prefill_real_token_share"] == 11 / 16
 
 
