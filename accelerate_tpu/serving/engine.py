@@ -42,18 +42,10 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..logging import get_logger
-from ..models.transformer import layer_kinds, qkv_in_place
-from ..ops.attention import (
-    PAGED_POOL_LEAVES,
-    SLOT_STATE_LEAVES,
-    PagedKVState,
-    decode_kernel_eligible,
-    latent_kernel_eligible,
-    pool_heads_first,
-)
-from ..ops.gated_delta import chunked_kernel_eligible
+from ..models.transformer import layer_kinds
 from ..utils.profiling import annotate
 from .block_pool import BlockPool, PrefixCache, prefix_keys
+from .cache_regime import TRACE_COUNTS, CacheRegime
 from .sampling import SlotSampling, sample_tokens
 from .scheduler import ContinuousScheduler, Request, Slot
 from .slo import SLOConfig, SloTracker
@@ -264,8 +256,6 @@ class ServingEngine:
         # scales ((num_blocks, block_size) fp32 beside each pool);
         # "bf16" keeps the pools at the model's native compute dtype.
         self.kv_dtype = kv_dtype
-        kv_state_dtype = "int8" if kv_dtype == "int8" else "native"
-        self._kv_state_dtype = kv_state_dtype
         # prefill/decode disaggregation (PR 19, default OFF): a
         # "prefill" engine runs prompt ingestion only and publishes each
         # finished chain as a TransferManifest (chain keys + per-block
@@ -294,29 +284,15 @@ class ServingEngine:
         # change, so the zero-retrace contract holds across tenant churn.
         self.adapters = adapters
         cfg = model.config
-        # a cache that is not one row a position (attention_class "eva":
-        # chunk summaries beside a window of rows, ops/eva_attention.py):
-        # a slot's position (``Slot.cache_len``, what rope turns by), its
-        # rows (``_rows``: the write offset and what a query sees) and its
-        # blocks part ways, the table and the pool are sized by the rows,
-        # and a slot gives blocks back before it ends (``_roll_over``)
-        self._eva = None
-        if getattr(cfg, "attention_class", None) == "eva":
-            from ..ops.eva_attention import EvaLayout
-
-            self._eva = EvaLayout(cfg.window_size, cfg.chunk_size, block_size)
-        # a stack with recurrent layers (``layer_types`` "linear_attention"):
-        # beside the pools of its attention layers a slot carries a state
-        # that every position overwrites in place. There is no block of it
-        # to share, copy, swap or hand off, and no several positions onto it
-        self._recurrent = "linear_attention" in (
-            getattr(cfg, "layer_types", None) or ())
-        # latent attention (``kv_lora_rank``): the pool holds ONE latent row a
-        # position for all heads. A prefill EXPANDS what it projects and sees
-        # no cache; a decode step reads the rows ABSORBED, one position a slot
-        # (models/transformer.LatentAttention): several positions onto cached
-        # latent rows are a program no feature here may ask for
-        self._latent = getattr(cfg, "kv_lora_rank", None) is not None
+        # what a request's cache is, and all that follows from it
+        regime = self._regime = CacheRegime(
+            cfg, block_size, max_slots, kv_dtype, num_blocks)
+        # eva's layout of rows (None for every other model), the width of a
+        # slot's table and the pool's size: the regime's, under the names
+        # the tests and the benchmark's rehearsals read
+        self._eva = regime.layout
+        self._max_table = regime.max_table
+        self.num_blocks = num_blocks = regime.num_blocks
         features = [name for name, on in (
             ("prefix_cache", prefix_cache),
             ("spec_decode", spec_decode is not None),
@@ -340,17 +316,10 @@ class ServingEngine:
         self._fetched = 0
         self._fetched_ahead = 0
         for feature in features:
-            self._refuse_block_list_feature(feature)
+            regime.refuse(feature)
             self._land_or_refuse(feature)
         if kv_dtype == "int8":
-            self._refuse_block_list_feature("kv_dtype 'int8'")
-        self._max_table = (
-            self._eva.peak_blocks(cfg.max_seq_len) if self._eva is not None
-            else -(-cfg.max_seq_len // block_size)
-        )
-        if num_blocks is None:
-            num_blocks = max_slots * self._max_table + 1
-        self.num_blocks = num_blocks
+            regime.refuse("kv_dtype 'int8'")
         self.pool = BlockPool(num_blocks, block_size)
         # prefix caching (vLLM-style shared KV): pure host-side policy —
         # the SAME compiled programs serve cold and warm requests, warm
@@ -373,15 +342,13 @@ class ServingEngine:
             ),
             prefix_cache=self.prefix_cache,
             max_table_blocks=self._max_table,
-        )
-        # chunk-aware admission (the over-reservation fix) is only safe
-        # when preemption provides the can't-grow escape hatch: without
-        # it admission keeps the full-footprint reservation that makes
-        # mid-flight OOM impossible by construction.
-        self.scheduler.layout = self._eva
-        self.scheduler.chunk_tokens = prefill_chunk_tokens
-        self.scheduler.chunked_reserve = (
-            prefill_chunk_tokens is not None and preemption
+            regime=regime,
+            chunk_tokens=prefill_chunk_tokens,
+            # chunk-aware admission (the over-reservation fix) is only safe
+            # when preemption provides the can't-grow escape hatch: without
+            # it admission keeps the full-footprint reservation that makes
+            # mid-flight OOM impossible by construction.
+            chunked_reserve=prefill_chunk_tokens is not None and preemption,
         )
         self.sampling = SlotSampling(max_slots)
         self.stats = ServeStats()
@@ -410,35 +377,8 @@ class ServingEngine:
         self._shed_order: collections.deque = collections.deque()
         self._steps = 0
         self._http: Any = None
-        # decode_attn_kernel: of the traced decode programs, how many
-        # took the Pallas paged-attention kernel (the rest gather).
-        # kv_in_place: of the traced prefill, decode and verify programs,
-        # how many hold each pool as ONE buffer from their (donated)
-        # input through the layer loop to their output
-        # eva: of the traced prefill, decode and roll-over programs, how
-        # many ran the cache of summaries beside a window (all or none)
-        # qkv_in_place: of the traced decode programs, how many read each
-        # layer's q/k/v kernels where they lie in the stacked parameters
-        # (models/transformer.py::qkv_in_place: one position a slot)
-        # recurrent_state: of the traced prefill and decode programs, how
-        # many carry a per-slot state beside the pools (all or none)
-        self._traces = {
-            "prefill": 0, "decode": 0, "decode_attn_kernel": 0, "cow": 0,
-            "verify": 0, "swap_out": 0, "swap_in": 0, "kv_in_place": 0,
-            "eva": 0, "qkv_in_place": 0, "recurrent_state": 0,
-            # latent attention: of the traced decode programs, how many read
-            # the latent rows absorbed through the ``latent_decode`` kernel;
-            # of the traced prefill programs, how many expanded the prompt's
-            # latent and attended what they projected
-            "mla_decode_kernel": 0, "mla_prefill_expanded": 0,
-            # of the traced prefill programs, how many attend what they
-            # projected (``fresh``) and so hand attention the prompt's real
-            # length: flash then walks the real rows, not the bucket
-            "flash_real_rows": 0,
-            # of the traced prefill programs, how many run their DeltaNet
-            # layers' chunked rule as the ``gdn_chunked`` kernel
-            "gdn_kernel": 0,
-        }
+        # trace-time counters: ``regime.mark`` says which a program's trace bumps
+        self._traces = dict(TRACE_COUNTS)
         self._rollovers_total = 0
         # every bucket width a prefill ever ran at — the set
         # capture_programs() reconstructs abstract specs from
@@ -459,71 +399,18 @@ class ServingEngine:
 
         from ..models.generation import init_cache
 
-        # what every paged state of this engine says beside its tables: how
-        # a block of the pools is laid out (a rule of the model's shapes; the
-        # eva regime's own writes are written for one row a position and
-        # head) and, for a recurrent stack, how many seats carry a state
-        seat_and_layout = {"heads_first": (
-            self._eva is None and not self._latent
-            and pool_heads_first(cfg.num_kv_heads, cfg.head_dim))}
-        if self._recurrent:
-            seat_and_layout["num_slots"] = max_slots
-        init_state = PagedKVState(
-            block_table=jnp.zeros((1, self._max_table), jnp.int32),
-            cache_len=jnp.zeros((1,), jnp.int32),
-            lengths=jnp.ones((1,), jnp.int32),
-            num_blocks=num_blocks,
-            block_size=block_size,
-            kv_dtype=kv_state_dtype,
-            **seat_and_layout,
-        )
         self.cache = init_cache(
             model.init, jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-            decode=True, paged=init_state, device=self._device,
+            decode=True, device=self._device, paged=regime.state(
+                jnp.zeros((1, self._max_table), jnp.int32),
+                jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32)),
         )
-        # the cache leaves, by what the model declares each to be (its
-        # variable's name: ops/attention.py's two tables), never by shape:
-        # (flat leaf index, block axis) for every K/V pool ((..., num_blocks,
-        # block_size, Hkv, D)) and every int8 scale array ((..., num_blocks,
-        # block_size)) — what the COW copy and the preemption swap address
-        # blocks through —, and the per-slot state leaves ((..., num_slots,
-        # ...)), which no block list reaches
-        self._kv_leaf_info: list[tuple[int, int]] = []
-        kv_bytes = state_bytes = 0
-        flat, _ = jax.tree_util.tree_flatten_with_path(self.cache)
-        for i, (path, leaf) in enumerate(flat):
-            name = next(k.key for k in reversed(path) if hasattr(k, "key"))
-            if name in PAGED_POOL_LEAVES:
-                axis = leaf.ndim - 1 - PAGED_POOL_LEAVES[name]
-                assert leaf.shape[axis] == num_blocks and block_size in (
-                    leaf.shape[axis + 1:axis + 3]), (name, leaf.shape)
-                self._kv_leaf_info.append((i, axis))
-                kv_bytes += leaf.nbytes
-            elif name in SLOT_STATE_LEAVES:
-                axis = leaf.ndim - 1 - SLOT_STATE_LEAVES[name]
-                assert leaf.shape[axis] == max_slots, (name, leaf.shape)
-                state_bytes += leaf.nbytes
-            else:
-                raise NotImplementedError(
-                    f"cache leaf {name!r} {leaf.shape} is neither a paged "
-                    "pool nor a per-slot state (ops/attention.py: "
-                    "PAGED_POOL_LEAVES, SLOT_STATE_LEAVES)"
-                )
-        # the sizing headline int8 halves: HBM bytes per cached token
-        # across every layer's pools (+ scale overhead when quantized);
-        # what a seat holds beside them whatever its length: the state
-        self.kv_bytes_per_token = kv_bytes / (num_blocks * block_size)
-        self.kv_pool_bytes = kv_bytes
-        self.state_bytes_per_slot = state_bytes / max_slots
+        # where the COW copy, the preemption swap and the hand-off find a
+        # block, and the sizing headlines: bytes a cached token, a seat's state
+        (self._kv_leaf_info, self.kv_pool_bytes, self.kv_bytes_per_token,
+         self.state_bytes_per_slot) = regime.leaves(self.cache)
 
         traces = self._traces
-        kv_leaf_info = self._kv_leaf_info
-        eva = self._eva is not None
-        recurrent = self._recurrent
-        latent = self._latent
-        # a prefill that has nothing before it to see attends what it
-        # projected (``PagedKVState.fresh``), by the prompt's real length
-        fresh = recurrent or latent
         # a decode step of a stack with experts also says how many distinct
         # experts held here its rows chose (``experts_touched`` on the fetch
         # span): the experts are most of what such a step reads
@@ -562,14 +449,7 @@ class ServingEngine:
 
         def _prefill(params, cache, ids, table, length, cached_len, key,
                      temp, slot=None, *lora_args):
-            traces["prefill"] += 1  # trace-time counter (not per call)
-            traces["kv_in_place"] += 1
-            traces["eva"] += eva
-            traces["recurrent_state"] += recurrent
-            traces["mla_prefill_expanded"] += latent
-            traces["flash_real_rows"] += fresh
-            traces["gdn_kernel"] += recurrent and chunked_kernel_eligible(
-                cfg.gdn_head_k_dim, cfg.gdn_head_v_dim)
+            regime.mark(traces, "prefill")  # at trace time, not per call
             # cached_len > 0 is the warm-hit path: ``ids`` holds only the
             # UNCACHED tail and the paged cache already contains KV for
             # the first cached_len positions (shared prefix blocks in
@@ -577,21 +457,9 @@ class ServingEngine:
             # sees cols <= cached_len + i, exactly a mid-sequence
             # continuation. cached_len == 0 is the cold path, and both
             # run the SAME compiled program (cached_len is traced data).
-            state = PagedKVState(
-                block_table=table,
-                cache_len=cached_len,
-                lengths=length,
-                num_blocks=num_blocks,
-                block_size=block_size,
-                kv_dtype=kv_state_dtype,
-                single_device=single_device,
-                # a recurrent stack: the seat whose state this prompt fills,
-                # from zero (``slot`` (1,); no other model is told one)
-                slot=slot, **seat_and_layout,
-                # nothing before it to see: a recurrent layer starts from
-                # zero, attention attends what the call projected
-                fresh=fresh,
-            )
+            state = regime.state(
+                table, cached_len, length, slot=slot,
+                single_device=single_device, prefill=True)
             # the head reads the last VALID row of the padded bucket alone,
             # not the padded tail: width x vocabulary logits are never formed
             logits, mutated = model.apply(
@@ -606,30 +474,13 @@ class ServingEngine:
 
         def _decode(params, cache, tokens, tables, cache_lens, lengths,
                     temps, key, positions=None, *lora_args):
-            traces["decode"] += 1  # zero-retrace contract rides on this
-            traces["kv_in_place"] += 1
-            traces["eva"] += eva
-            traces["recurrent_state"] += recurrent
             # eva: ``cache_lens`` are the slots' ROWS and ``positions`` what
             # they stand for (None for every other model: one and the same)
-            state = PagedKVState(
-                block_table=tables,
-                cache_len=cache_lens,
-                lengths=lengths,
-                num_blocks=num_blocks,
-                block_size=block_size,
-                kv_dtype=kv_state_dtype,
-                single_device=single_device,
-                positions=positions,
-                **seat_and_layout,
-            )
-            kernel = (latent_kernel_eligible if latent
-                      else decode_kernel_eligible)(
-                state, tokens.shape[1], jax.tree.leaves(cache)[pool_leaf]
-            )
-            traces["decode_attn_kernel"] += kernel
-            traces["mla_decode_kernel"] += latent and kernel
-            traces["qkv_in_place"] += qkv_in_place(True, tokens.shape[1])
+            state = regime.state(
+                tables, cache_lens, lengths, positions=positions,
+                single_device=single_device)
+            regime.mark(traces, "decode", state, tokens.shape[1],
+                        jax.tree.leaves(cache)[pool_leaf])
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, tokens, decode=True,
                 paged=state, mutable=["cache"] + ["intermediates"] * counts,
@@ -653,7 +504,7 @@ class ServingEngine:
             return mutated["cache"], token
 
         def _rollover(params, cache, src, dst):
-            traces["eva"] += 1
+            regime.mark(traces, "rollover")
             from ..models.transformer import eva_roll_over_cache
 
             return eva_roll_over_cache(cfg, params, cache, src, dst)
@@ -676,7 +527,7 @@ class ServingEngine:
             # travel with their block's quantized contents —, found as
             # the swap finds them: ``_kv_leaf_info``'s (leaf, block axis)
             leaves = list(jax.tree.leaves(cache))
-            for i, axis in kv_leaf_info:
+            for i, axis in self._kv_leaf_info:
                 lead = (slice(None),) * axis
                 leaves[i] = leaves[i].at[lead + (dst,)].set(
                     leaves[i][lead + (src,)])
@@ -694,18 +545,9 @@ class ServingEngine:
             # ONCE per width, the zero-retrace contract's new leg.
             def _verify(params, cache, tokens, tables, cache_lens, lengths,
                         temps, keys, *lora_args):
-                traces["verify"] += 1
-                traces["kv_in_place"] += 1
-                state = PagedKVState(
-                    block_table=tables,
-                    cache_len=cache_lens,
-                    lengths=lengths,
-                    num_blocks=num_blocks,
-                    block_size=block_size,
-                    kv_dtype=kv_state_dtype,
-                    single_device=single_device,
-                    **seat_and_layout,
-                )
+                regime.mark(traces, "verify")
+                state = regime.state(
+                    tables, cache_lens, lengths, single_device=single_device)
                 logits, mutated = model.apply(
                     {"params": params, "cache": cache}, tokens, decode=True,
                     paged=state, mutable=["cache"],
@@ -758,17 +600,14 @@ class ServingEngine:
                 self._feed_fn(self._no_tokens, none[:, None],
                               np.zeros(max_slots, bool))
         self._rollover_fn = None
-        if eva:
+        if self._eva is not None:
             # what a filling window runs is compiled now, not at the first
             # window that fills mid-traffic: one call over the garbage
             # block (reads it, writes its summaries back into it)
             self._rollover_fn = jax.jit(_rollover, donate_argnums=1)
             with self._placed():
                 self.cache = self._rollover_fn(
-                    self.params, self.cache,
-                    np.zeros(self._eva.window_blocks, np.int32),
-                    np.zeros(self._eva.summary_blocks, np.int32),
-                )
+                    self.params, self.cache, *regime.rollover_args())
         # speculative decoding: verify programs cached by width (k + 1)
         # and warm proposers cached by config identity, so set_speculation
         # toggles on a warm engine never retrace
@@ -880,39 +719,9 @@ class ServingEngine:
                 f"got {role!r}"
             )
         if role != "colocated":
-            self._refuse_block_list_feature(f"role {role!r}")
+            self._regime.refuse(f"role {role!r}")
             self._land_or_refuse(f"role {role!r}")
         self._role = role
-
-    def _refuse_block_list_feature(self, feature: str) -> None:
-        """Engine features that take a request's state for a list of
-        blocks holding one row a position each (prefix cache, copy on
-        write, preemption swap, hand-off, speculation, chunked prefill, int8
-        pools, adapters) are refused, by name, for a model whose cache is
-        not that. The ONE predicate."""
-        item = "A4"
-        if self._eva is not None:
-            why = (
-                "attention_class 'eva': a request's cache is chunk summaries "
-                "beside a window of rows, not one row a position"
-            )
-        elif self._recurrent:
-            why = (
-                "a stack with 'linear_attention' layers: a request's cache "
-                "is a recurrent state a slot, overwritten in place, beside "
-                "the blocks of its attention layers"
-            )
-        elif self._latent:
-            item, why = "A3", (
-                "latent attention: a request's cache is one latent row a "
-                "position, which a prefill expands and never reads back and "
-                "a decode step reads absorbed, one position a slot"
-            )
-        else:
-            return
-        raise NotImplementedError(
-            f"{feature} is not written for {why} (ROADMAP Reach {item})"
-        )
 
     def _land_or_refuse(self, feature: str) -> None:
         """Engine features that change a slot's blocks, its place in the
@@ -944,14 +753,6 @@ class ServingEngine:
         ahead (only a step behind an idle one is dispatched and awaited in
         one ``step()``), 0.0 for one that takes a step at a time."""
         return self._fetched_ahead / self._fetched if self._fetched else 0.0
-
-    def _rows(self, slot: Slot, ahead: int = 0) -> int:
-        """Cache rows ``slot`` holds (``ahead`` positions from now): its
-        write offset, and the last row its next query sees. One a
-        position, or the layout's count."""
-        if self._eva is None:
-            return slot.cache_len + ahead
-        return int(self._eva.rows(slot.cache_len + ahead))
 
     def trace_counts(self) -> dict:
         """Compiled-program counts, bumped at trace time. After warmup,
@@ -1195,11 +996,6 @@ class ServingEngine:
             jnp.asarray(slot_ids, jnp.int32),
         )
 
-    def _slot_arg(self, slot: Slot):
-        """What a prefill is told of the seat it fills: its index, where a
-        state lives there (a recurrent stack), else nothing."""
-        return np.asarray([slot.index], np.int32) if self._recurrent else None
-
     def _cow_block(self, slot: Slot, tindex: int) -> None:
         """Copy-on-write table position ``tindex`` of ``slot``: allocate
         a private block (the admission-reserved spare first), one
@@ -1270,17 +1066,14 @@ class ServingEngine:
             if self.adapters is not None:
                 self._slot_adapter[slot.index] = self.adapters.slot_of(req.adapter)
             self.cache, token = self._prefill_fn(
-                self.params, self.cache, jnp.asarray(ids), jnp.asarray(table),
-                jnp.asarray([tail_len], jnp.int32),
-                jnp.asarray([cached], jnp.int32), self._split_key(),
-                jnp.asarray([req.temperature], jnp.float32),
-                self._slot_arg(slot),
-                *self._lora_call_args([self._slot_adapter[slot.index]]),
+                self.params, self.cache, *self._regime.prefill_args(
+                    ids, table, tail_len, cached, self._split_key(),
+                    req.temperature, slot.index,
+                    self._lora_call_args([self._slot_adapter[slot.index]])),
             )
             token = int(np.asarray(token)[0])
             slot.cache_len = prompt_len
-            if self._eva is not None:
-                slot.windows_done = prompt_len // self._eva.window
+            slot.windows_done = self._regime.windows(prompt_len)
             slot.pending = token
             slot.generated = [token]
             # index every FULL prompt block we freshly prefilled so the next
@@ -1407,12 +1200,10 @@ class ServingEngine:
             # decode keys differently, so cross-run parity is greedy-exact.
             key = self._split_key() if final else self._key
             self.cache, token = self._prefill_fn(
-                self.params, self.cache, jnp.asarray(ids), jnp.asarray(table),
-                jnp.asarray([chunk_len], jnp.int32),
-                jnp.asarray([start], jnp.int32), key,
-                jnp.asarray([req.temperature], jnp.float32),
-                None,  # no seat's state: a recurrent stack refuses chunks
-                *self._lora_call_args([self._slot_adapter[slot.index]]),
+                self.params, self.cache, *self._regime.prefill_args(
+                    ids, table, chunk_len, start, key, req.temperature,
+                    None,  # no seat's state: a recurrent stack refuses chunks
+                    self._lora_call_args([self._slot_adapter[slot.index]])),
             )
             slot.cache_len = start + chunk_len
             slot.chunks += 1
@@ -1794,7 +1585,7 @@ class ServingEngine:
         seated, the dedup split (``reused_blocks`` found warm in the
         local CACHED index vs ``moved_blocks`` scatter-restored from the
         manifest's host images and their ``moved_bytes``)."""
-        self._refuse_block_list_feature("hand-off (acquire)")
+        self._regime.refuse("hand-off (acquire)")
         self._land_or_refuse("hand-off (acquire)")
         res = self._try_seat_manifest(manifest)
         if res is None:
@@ -1934,6 +1725,7 @@ class ServingEngine:
         step boundary: an engine that has landed since fetches the step in
         flight and dispatches none behind it."""
         ahead, self._ahead = self._ahead, None
+        windows = self._regime.windows
         self._fetched += 1
         self._fetched_ahead += ahead is not None
         if ahead is None:
@@ -1952,7 +1744,7 @@ class ServingEngine:
                 s for s in active
                 if s.index not in flying or (
                     len(s.generated) + 1 < s.request.max_new_tokens
-                    and not self._fills_window(s, ahead=1)
+                    and windows(s.cache_len + 1) <= s.windows_done
                 )
             ]
             if nxt:
@@ -1969,7 +1761,7 @@ class ServingEngine:
                 slot.pending = token
                 slot.generated.append(token)
                 self._note_token(slot, token, events)
-                if not slot.done and self._fills_window(slot):
+                if not slot.done and windows(slot.cache_len) > slot.windows_done:
                     self._roll_over(slot)
 
         return emit
@@ -2010,13 +1802,6 @@ class ServingEngine:
                     experts_held=self._experts_held)
             return host
 
-    def _fills_window(self, slot: Slot, ahead: int = 0) -> bool:
-        """eva: ``slot`` (``ahead`` positions from now) stands past a
-        window whose summaries are not in its table yet."""
-        return self._eva is not None and (
-            (slot.cache_len + ahead) // self._eva.window > slot.windows_done
-        )
-
     def _dispatch_decode(self, slots: list[Slot], prev=None) -> tuple:
         """Put one decode step over ``slots`` on the device; returns its
         sampled tokens, not fetched, and the dispatch's count
@@ -2031,9 +1816,8 @@ class ServingEngine:
             cache_lens = np.zeros(self.max_slots, np.int32)
             lengths = np.zeros(self.max_slots, np.int32)
             from_prev = np.zeros(self.max_slots, bool)
-            positions = None
-            if self._eva is not None:
-                positions = np.zeros(self.max_slots, np.int32)
+            positions = self._regime.host_positions()
+            rows_at = self._regime.rows
             at = 0
             for slot in slots:
                 ahead = int(slot.index in flying)
@@ -2046,7 +1830,7 @@ class ServingEngine:
                     self._cow_block(slot, t)
                 tokens[slot.index, 0] = slot.pending
                 from_prev[slot.index] = ahead
-                cache_lens[slot.index] = self._rows(slot, ahead)
+                cache_lens[slot.index] = rows_at(slot.cache_len + ahead)
                 lengths[slot.index] = 1
                 at += slot.cache_len + ahead
                 if positions is not None:
@@ -2068,12 +1852,10 @@ class ServingEngine:
                     self._no_tokens if prev_out is None else prev_out,
                     tokens, from_prev,
                 )
-            args = (
-                fed, self._tables_device(), jnp.asarray(cache_lens),
-                jnp.asarray(lengths), self.sampling.temperatures(),
-                self._split_key(),
-                None if positions is None else jnp.asarray(positions),
-                *self._lora_call_args(self._slot_adapter),
+            args = self._regime.decode_args(
+                fed, self._tables_device(), cache_lens, lengths,
+                self.sampling.temperatures(), self._split_key(), positions,
+                self._lora_call_args(self._slot_adapter),
             )
         return self._dispatch(
             "jit__decode", self._decode_fn, *args,
@@ -2093,15 +1875,12 @@ class ServingEngine:
         assert len(src) == lay.window_blocks, (len(src), slot.cache_len)
         with annotate("atpu:serve.roll_over", slot=slot.index,
                       window=slot.windows_done):
-            # numpy rows, as every other call's host inputs: jnp.asarray of
-            # a Python list compiles a conversion, once a shape
-            src = np.asarray(src, np.int32)
             self.cache = self._rollover_fn(
-                self.params, self.cache, src, src[:lay.summary_blocks])
+                self.params, self.cache, *self._regime.rollover_args(src))
         slot.windows_done += 1
         self._rollovers_total += 1
         req = slot.request
-        keep = lay.peak_blocks(
+        keep = self._regime.footprint(
             len(req.prompt) + req.max_new_tokens, start=slot.cache_len)
         if keep < len(slot.blocks):
             self.pool.free(slot.blocks[keep:])
@@ -2161,11 +1940,10 @@ class ServingEngine:
                 # would cost width+1 dispatches on the hottest loop in
                 # serving)
                 keys = np.stack(self._peek_keys(width))
-                args = (
-                    jnp.asarray(tokens), self._tables_device(),
-                    jnp.asarray(cache_lens), jnp.asarray(lengths),
-                    self.sampling.temperatures(), jnp.asarray(keys),
-                    *self._lora_call_args(self._slot_adapter),
+                args = self._regime.verify_args(
+                    tokens, self._tables_device(), cache_lens, lengths,
+                    self.sampling.temperatures(), keys,
+                    self._lora_call_args(self._slot_adapter),
                 )
         if not drafted_any:
             # nothing proposed this round (n-gram miss everywhere): the
@@ -2330,6 +2108,7 @@ class ServingEngine:
             queue_age_p95 = 0.0
         pool = self.pool.stats()
         active = [s for s in sched.slots if s.busy]
+        tokens_in_flight = sum(s.cache_len for s in active)
         fields = {
             "engine_steps": self._steps,
             "queue_depth": n_queued,
@@ -2353,13 +2132,14 @@ class ServingEngine:
                 self.prefix_cache.tokens_saved_total
                 if self.prefix_cache is not None else 0
             ),
-            "tokens_in_flight": sum(s.cache_len for s in active),
+            "tokens_in_flight": tokens_in_flight,
             # table entries that hold a position the next decode step
             # reads (cache_len included: its token is written first),
             # over the max_slots x max_blocks a gather reads: the share
             # of that read the decode kernel's live-block walk still makes
             "live_block_share": sum(
-                self._rows(s) // self.block_size + 1 for s in active
+                self._regime.rows(s.cache_len) // self.block_size + 1
+                for s in active
             ) / (self.max_slots * self._max_table),
             "admission_blocked_no_free_slot_total":
                 sched.blocked_reasons["no_free_slot"],
@@ -2400,28 +2180,13 @@ class ServingEngine:
             ),
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "state_bytes_per_slot": self.state_bytes_per_slot,
-            # what ONE position holds over the layers as the latent pool is
-            # allocated, lanes of padding included (0: per-head K and V)
-            "latent_row_bytes": (
-                self.kv_bytes_per_token if self._latent else 0),
+            # what the cache's kind adds (other kinds keep their schema)
+            **self._regime.gauges(
+                active, tokens_in_flight, self.kv_bytes_per_token,
+                self._rollovers_total),
             "pool_alias_bytes": self.pool_alias_bytes,
             "decode_ahead_share": self.decode_ahead_share,
         }
-        if self._eva is not None:
-            # a cache that is not one row a position: what it holds beside
-            # what it stands for (other engines' records keep their schema)
-            rows = sum(self._rows(s) for s in active)
-            summary = sum(
-                s.windows_done * self._eva.summary_blocks for s in active)
-            fields.update(
-                cache_rows_live=rows,
-                cache_rows_per_token=(
-                    rows / max(1, fields["tokens_in_flight"])),
-                window_rollovers_total=self._rollovers_total,
-                summary_blocks=summary,
-                window_blocks=sum(
-                    self._eva.blocks(s.cache_len) for s in active) - summary,
-            )
         if self._role != "colocated":
             # PR 19 disaggregation plane: hand-off accounting only for
             # pool members — a colocated engine's gauge records stay
@@ -2508,8 +2273,9 @@ class ServingEngine:
         separate, so holding a ``Compiled`` in hand costs ONE explicit
         AOT compile per program — this is an explicit, once-per-topology
         call (after warmup), not something the hot path pays. Abstract
-        specs are reconstructed analytically from the engine's shape
-        contract (the fixed decode/verify batch shapes, every prefill
+        specs are the shapes of what the live call sites' own argument
+        builders (the regime's) return for empty inputs at the engine's
+        shape contract (the fixed decode/verify batch shapes, every prefill
         bucket seen so far); the ``.lower()`` re-traces each closure, so
         the trace counters are snapshotted and restored — the
         zero-retrace contract's counters stay at their steady-state
@@ -2521,19 +2287,10 @@ class ServingEngine:
         # NOT `registry or ...`: an empty ProgramRegistry is falsy (len 0)
         registry = get_program_registry() if registry is None else registry
 
-        def _abs(tree):
-            return jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(
-                    jnp.shape(x), jnp.result_type(x)
-                ),
-                tree,
-            )
-
-        params_s = _abs(self.params)
-        cache_s = _abs(self.cache)
-        key_s = _abs(self._key)
-        temps_s = _abs(self.sampling.temperatures())
-        i32 = jnp.int32
+        regime, n = self._regime, self.max_slots
+        temps = self.sampling.temperatures()
+        tables = np.zeros((n, self._max_table), np.int32)
+        none = np.zeros(n, np.int32)
         labels: list[str] = []
         snapshot = dict(self._traces)
 
@@ -2542,7 +2299,8 @@ class ServingEngine:
             t0 = _time.perf_counter()
             if compiled is None:
                 try:
-                    compiled = fn.lower(*specs).compile()
+                    compiled = fn.lower(
+                        *jax.eval_shape(lambda *a: a, *specs)).compile()
                 except Exception as exc:  # noqa: BLE001 — partial > none
                     logger.debug(f"capture_programs({label}) failed: {exc}")
                     return
@@ -2555,68 +2313,35 @@ class ServingEngine:
             labels.append(label)
 
         try:
-            lora1 = tuple(_abs(a) for a in self._lora_call_args([0]))
-            lora_n = tuple(
-                _abs(a) for a in self._lora_call_args(self._slot_adapter)
-            )
+            lora_n = self._lora_call_args(self._slot_adapter)
             for bucket in sorted(self._prefill_buckets):
                 _one(
                     f"serve_prefill_b{bucket}", self._prefill_fn,
-                    params_s, cache_s,
-                    jax.ShapeDtypeStruct((1, bucket), i32),
-                    jax.ShapeDtypeStruct((1, self._max_table), i32),
-                    jax.ShapeDtypeStruct((1,), i32),
-                    jax.ShapeDtypeStruct((1,), i32),
-                    key_s,
-                    jax.ShapeDtypeStruct((1,), jnp.float32),
-                    (jax.ShapeDtypeStruct((1,), i32)
-                     if self._recurrent else None),
-                    *lora1,
+                    self.params, self.cache, *regime.prefill_args(
+                        np.zeros((1, bucket), np.int32), tables[:1], 0, 0,
+                        self._key, 0.0, 0, self._lora_call_args([0])),
                     bucket=bucket,
                 )
             _one(
-                "serve_decode", self._decode_fn,
-                params_s, cache_s,
-                jax.ShapeDtypeStruct((self.max_slots, 1), i32),
-                jax.ShapeDtypeStruct((self.max_slots, self._max_table), i32),
-                jax.ShapeDtypeStruct((self.max_slots,), i32),
-                jax.ShapeDtypeStruct((self.max_slots,), i32),
-                temps_s, key_s,
-                # eva: the slots' positions beside their rows
-                (jax.ShapeDtypeStruct((self.max_slots,), i32)
-                 if self._eva is not None else None),
-                *lora_n,
+                "serve_decode", self._decode_fn, self.params, self.cache,
+                *regime.decode_args(
+                    none[:, None], tables, none, none, temps, self._key,
+                    regime.host_positions(), lora_n),
             )
-            if self._eva is not None:
-                _one(
-                    "serve_rollover", self._rollover_fn, params_s, cache_s,
-                    jax.ShapeDtypeStruct((self._eva.window_blocks,), i32),
-                    jax.ShapeDtypeStruct((self._eva.summary_blocks,), i32),
-                )
+            if self._rollover_fn is not None:
+                _one("serve_rollover", self._rollover_fn, self.params, self.cache,
+                     *regime.rollover_args())
             for width, vfn in sorted(self._verify_fns.items()):
-                keys_s = jax.ShapeDtypeStruct(
-                    (width,) + tuple(jnp.shape(self._key)),
-                    jnp.result_type(self._key),
-                )
                 _one(
-                    f"serve_verify_w{width}", vfn,
-                    params_s, cache_s,
-                    jax.ShapeDtypeStruct((self.max_slots, width), i32),
-                    jax.ShapeDtypeStruct(
-                        (self.max_slots, self._max_table), i32
-                    ),
-                    jax.ShapeDtypeStruct((self.max_slots,), i32),
-                    jax.ShapeDtypeStruct((self.max_slots,), i32),
-                    temps_s, keys_s, *lora_n,
+                    f"serve_verify_w{width}", vfn, self.params, self.cache,
+                    *regime.verify_args(
+                        np.zeros((n, width), np.int32), tables, none, none,
+                        temps, np.stack([self._key] * width), lora_n),
                     width=width,
                 )
-            _one(
-                "serve_cow", self._cow_fn,
-                cache_s,
-                jax.ShapeDtypeStruct((), i32),
-                jax.ShapeDtypeStruct((), i32),
-            )
-            _one("serve_key_chain", self._key_chain_fn, key_s)
+            zero = np.zeros((), np.int32)
+            _one("serve_cow", self._cow_fn, self.cache, zero, zero)
+            _one("serve_key_chain", self._key_chain_fn, self._key)
         finally:
             # .lower() above re-traced the closures; restore the
             # steady-state counters the zero-retrace assertions read
@@ -2728,7 +2453,7 @@ class ServingEngine:
         in-flight shared blocks keep their refcounts and drain
         normally)."""
         if enabled:
-            self._refuse_block_list_feature("prefix_cache")
+            self._regime.refuse("prefix_cache")
             self._land_or_refuse("prefix_cache")
             if model_fingerprint is not None:
                 self._model_fingerprint = model_fingerprint
@@ -2756,7 +2481,7 @@ class ServingEngine:
             self._proposer = None
             self.scheduler.lookahead_tokens = 0
             return
-        self._refuse_block_list_feature("spec_decode")
+        self._regime.refuse("spec_decode")
         self._land_or_refuse("spec_decode")
         proposer = self._proposers.get(id(spec))
         if proposer is None:

@@ -172,6 +172,9 @@ class ContinuousScheduler:
         adapter_ready: Optional[Callable[[Optional[str]], bool]] = None,
         prefix_cache=None,
         max_table_blocks: Optional[int] = None,
+        regime=None,
+        chunk_tokens: Optional[int] = None,
+        chunked_reserve: bool = False,
     ):
         if max_slots < 1:
             raise ValueError("max_slots must be >= 1")
@@ -211,8 +214,8 @@ class ContinuousScheduler:
         # mid-flight growth path then has preempt-and-swap as its
         # can't-allocate escape, preserving the no-mid-flight-OOM
         # guarantee the full reservation used to provide).
-        self.chunk_tokens: Optional[int] = None
-        self.chunked_reserve = False
+        self.chunk_tokens = chunk_tokens
+        self.chunked_reserve = chunked_reserve
         # sticky: set once any nonzero-priority request is submitted —
         # the queue then stops being submit-ordered and shed_expired
         # must scan past the head
@@ -221,11 +224,11 @@ class ContinuousScheduler:
         # new submits are refused with shed_reason="draining", seated
         # work finishes — the router's graceful-rotation state
         self.draining = False
-        # set by the engine for a model whose cache is not one row a
-        # position (ops/eva_attention.EvaLayout): a request's footprint is
-        # then ``layout.peak_blocks``, allocated at admission like any
-        # other; the engine gives back what the last filled window frees
-        self.layout = None
+        # what a request's cache is (the engine's ``CacheRegime``; None: one
+        # row a position): a request's footprint is its word, allocated at
+        # admission whatever the kind; where a slot frees blocks before it
+        # ends (eva), the engine gives back what the last filled window frees
+        self.regime = regime
         self.shed_counts = {"queue_full": 0, "queue_deadline": 0}
         self.blocked_reasons = {
             "no_free_slot": 0,
@@ -238,8 +241,8 @@ class ContinuousScheduler:
     def footprint(self, tokens: int, start: int = 0) -> int:
         """The most blocks a request holds at once on its way from
         ``start`` positions to ``tokens``."""
-        if self.layout is not None:
-            return self.layout.peak_blocks(tokens, start)
+        if self.regime is not None:
+            return self.regime.footprint(tokens, start)
         return self.pool.blocks_for_tokens(tokens)
 
     def submit(self, request: Request) -> str:

@@ -194,7 +194,7 @@ class DraftModelProposer:
         import jax.numpy as jnp
 
         from ..models.generation import init_cache
-        from ..ops.attention import PagedKVState
+        from .cache_regime import CacheRegime
 
         self.cfg = cfg
         self.model = cfg.draft_model
@@ -210,8 +210,6 @@ class DraftModelProposer:
                 f"draft max_seq_len ({dcfg.max_seq_len}) must cover the "
                 f"target's ({target_config.max_seq_len})"
             )
-        self.num_blocks = num_blocks
-        self.block_size = block_size
         self.max_table = max_table
         self.max_slots = max_slots
         # tokens of draft KV written per slot; engine updates via
@@ -224,39 +222,30 @@ class DraftModelProposer:
         traces = self._traces
         model = self.model
 
-        init_state = PagedKVState(
-            block_table=jnp.zeros((1, max_table), jnp.int32),
-            cache_len=jnp.zeros((1,), jnp.int32),
-            lengths=jnp.ones((1,), jnp.int32),
-            num_blocks=num_blocks,
-            block_size=block_size,
-        )
+        # the draft's own pool: native rows in the engine's block id space
+        regime = CacheRegime(dcfg, block_size, max_slots, num_blocks=num_blocks)
         self.cache = init_cache(
             model.init, jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-            decode=True, paged=init_state,
+            decode=True, paged=regime.state(
+                jnp.zeros((1, max_table), jnp.int32),
+                jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32)),
         )
 
         def _prefill(params, cache, ids, table, length, cached_len):
             traces["draft_prefill"] += 1  # trace-time counter
-            state = PagedKVState(
-                block_table=table, cache_len=cached_len, lengths=length,
-                num_blocks=num_blocks, block_size=block_size,
-            )
             _, mutated = model.apply(
                 {"params": params, "cache": cache}, ids, decode=True,
-                paged=state, mutable=["cache"],
+                paged=regime.state(table, cached_len, length),
+                mutable=["cache"],
             )
             return mutated["cache"]
 
         def _step(params, cache, tokens, tables, cache_lens, lengths):
             traces["draft_step"] += 1  # two shapes ever: (B, 2) and (B, 1)
-            state = PagedKVState(
-                block_table=tables, cache_len=cache_lens, lengths=lengths,
-                num_blocks=num_blocks, block_size=block_size,
-            )
             logits, mutated = model.apply(
                 {"params": params, "cache": cache}, tokens, decode=True,
-                paged=state, mutable=["cache"],
+                paged=regime.state(tables, cache_lens, lengths),
+                mutable=["cache"],
             )
             # greedy proposals from the last VALID position per slot;
             # rows with lengths == 0 are inert (writes routed to the
@@ -280,39 +269,26 @@ class DraftModelProposer:
         prefix blocks are re-written on purpose: their draft rows may
         predate this proposer (chain published with speculation off),
         and identical-content writes cannot corrupt any other holder."""
+        self._ingest(slot, slot.request.prompt, 0)
+
+    def _ingest(self, slot, tokens: list[int], start: int) -> None:
+        """One bucketed prefill of ``tokens`` at the slot's position
+        ``start``: the whole prompt at admission, or the tokens the target
+        wrote while this proposer wasn't running (``full[dl : cache_len]``,
+        so the draft's lag returns to 1)."""
         import jax.numpy as jnp
 
-        prompt = slot.request.prompt
-        n = len(prompt)
+        n = len(tokens)
         bucket = 1 << max(n - 1, 0).bit_length() if n > 1 else 1
         ids = np.zeros((1, bucket), np.int32)
-        ids[0, :n] = prompt
+        ids[0, :n] = tokens
         table = np.zeros((1, self.max_table), np.int32)
         table[0, :len(slot.blocks)] = slot.blocks
         self.cache = self._prefill_fn(
             self.params, self.cache, jnp.asarray(ids), jnp.asarray(table),
-            jnp.asarray([n], jnp.int32), jnp.asarray([0], jnp.int32),
+            jnp.asarray([n], jnp.int32), jnp.asarray([start], jnp.int32),
         )
-        self._draft_len[slot.index] = n
-
-    def _catch_up(self, slot, full: list[int], dl: int) -> None:
-        """Ingest ``full[dl : cache_len]`` (the tokens the target wrote
-        while this proposer wasn't running) so the draft's lag returns
-        to 1. Same bucketed-prefill program family as admission."""
-        import jax.numpy as jnp
-
-        gap = full[dl:slot.cache_len]
-        n = len(gap)
-        bucket = 1 << max(n - 1, 0).bit_length() if n > 1 else 1
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :n] = gap
-        table = np.zeros((1, self.max_table), np.int32)
-        table[0, :len(slot.blocks)] = slot.blocks
-        self.cache = self._prefill_fn(
-            self.params, self.cache, jnp.asarray(ids), jnp.asarray(table),
-            jnp.asarray([n], jnp.int32), jnp.asarray([dl], jnp.int32),
-        )
-        self._draft_len[slot.index] = slot.cache_len
+        self._draft_len[slot.index] = start + n
 
     def propose(self, slots, tables) -> dict[int, list[int]]:
         import jax.numpy as jnp
@@ -334,7 +310,7 @@ class DraftModelProposer:
                 # off mid-flight, or this proposer was attached late) —
                 # catch the draft cache up with one bucketed prefill of
                 # the gap, then proceed at lag 1
-                self._catch_up(slot, full, dl)
+                self._ingest(slot, full[dl:slot.cache_len], dl)
                 dl = int(self._draft_len[slot.index])
                 lag = slot.cache_len + 1 - dl
             assert 1 <= lag <= 2, (slot.index, lag)

@@ -357,7 +357,7 @@ def test_engine_holds_rows_and_blocks_by_the_layout_and_gives_all_back(params):
         for slot in eng.scheduler.slots:
             if slot.busy and not slot.done:
                 n = slot.cache_len
-                assert eng._rows(slot) == lay.rows(n) == (
+                assert eng._regime.rows(n) == lay.rows(n) == (
                     lay.per_window * (n // WINDOW) + n % WINDOW)
                 assert slot.windows_done == n // WINDOW
                 # it holds what is still to come needs at most: the whole
@@ -442,25 +442,8 @@ def test_gauges_and_the_decode_span_count_rows_not_positions(params):
     assert plain.trace_counts()["eva"] == 0
 
 
-@pytest.mark.parametrize("feature,kwargs", [
-    ("prefix_cache", {"prefix_cache": True}),
-    ("spec_decode", {"spec_decode": SpecConfig(k=2)}),
-    ("prefill_chunk_tokens", {"prefill_chunk_tokens": 16}),
-    ("preemption", {"preemption": True}),
-    ("kv_dtype 'int8'", {"kv_dtype": "int8"}),
-    ("role 'prefill'", {"role": "prefill"}),
-    ("role 'decode'", {"role": "decode"}),
-])
-def test_features_that_take_the_cache_for_one_row_a_position_are_refused(
-        params, feature, kwargs):
-    """Prefix cache, chunked prefill, speculation, preemption swap and the
-    hand-off all take a request's state for blocks of one row a position:
-    each is refused when the engine is built with this class, by name."""
-    with pytest.raises(NotImplementedError) as err:
-        _engine(params, **kwargs)
-    assert feature in str(err.value) and "'eva'" in str(err.value)
-
-
+# (each feature refused when an engine is BUILT: tests/test_cache_regime.py,
+# one table over the regimes)
 def test_the_same_features_are_refused_on_a_warm_engine(params):
     eng = _engine(params)
     for name, call in (

@@ -720,17 +720,8 @@ def test_the_head_read_at_one_row_is_that_row_of_every_rows_logits(params, hybri
 WHY = "not written for a stack with 'linear_attention' layers"
 
 
-@pytest.mark.parametrize("kw", [
-    dict(prefix_cache=True), dict(spec_decode=SpecConfig(k=2)),
-    dict(prefill_chunk_tokens=16), dict(preemption=True),
-    dict(role="prefill"), dict(role="decode"), dict(kv_dtype="int8"),
-], ids=lambda kw: next(iter(kw)) + "-" + str(next(iter(kw.values())))[:8])
-def test_features_that_take_the_cache_for_a_list_of_blocks_are_refused(params, kw):
-    with pytest.raises(NotImplementedError, match=WHY) as err:
-        ServingEngine(_model(), params, max_slots=2, block_size=4, **kw)
-    assert next(iter(kw)).split("_")[0] in str(err.value)
-
-
+# (each feature refused when an engine is BUILT: tests/test_cache_regime.py,
+# one table over the regimes)
 def test_the_same_features_are_refused_on_a_warm_engine(params):
     eng = ServingEngine(_model(), params, max_slots=2, block_size=4)
     with pytest.raises(NotImplementedError, match="prefix_cache.*" + WHY):
