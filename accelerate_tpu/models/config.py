@@ -16,9 +16,10 @@ SUPPORTED_ROPE_TYPES = ("default", "llama3", "linear", "yarn")
 # per-layer operators a ``layer_types`` entry may name (config.json names),
 # and the feed-forwards it may name where a layer is ONE sublayer
 # (``single_sublayer``: "mamba", "moe" and "mlp" stand in such a stack only)
-LAYER_TYPES = ("full_attention", "conv", "mamba", "linear_attention")
+LAYER_TYPES = ("full_attention", "sliding_attention", "conv", "mamba",
+               "linear_attention")
 FF_LAYER_TYPES = ("moe", "mlp")
-MLP_ACTIVATIONS = ("silu", "gelu_tanh", "relu2")
+MLP_ACTIVATIONS = ("silu", "gelu_tanh", "relu2", "relu")
 # what a layer's attention computes: None is softmax over every earlier
 # position (under ``sliding_window`` if set); "eva" is chunk summaries beside
 # a window of exact keys and values (models/transformer.Attention)
@@ -101,7 +102,8 @@ class TransformerConfig:
     # RMSNorm multiplies by (1 + scale) with zero-init scales,
     norm_offset: bool = False
     # the MLP gate activation ("silu" = Llama/Mixtral, "gelu_tanh" =
-    # Gemma's gelu_pytorch_tanh, "relu2" = relu(.)^2, Nemotron-H),
+    # Gemma's gelu_pytorch_tanh, "relu2" = relu(.)^2, Nemotron-H, "relu" =
+    # the ReGLU gate, SmallThinker),
     mlp_activation: str = "silu"
     # False: no gate matrix, down(act(up x)) — the dense MLP, the shared
     # expert and (through the ragged and dense dispatches) the experts; their
@@ -115,10 +117,14 @@ class TransformerConfig:
     # sliding-window attention band (Mistral / sliding Qwen2): each query
     # sees at most the last `sliding_window` keys, self included — HF
     # semantics (kv_idx > q_idx - sliding_window AND causal). Applies to
-    # EVERY layer (per-layer mixes are rejected by utils/hf_interop.py —
-    # the nn.scan layout compiles one homogeneous layer body). xla and
-    # flash attention honor it (flash skips below-band kv blocks: work
-    # scales with S*window); ring attention rejects it.
+    # EVERY layer, unless ``layer_types`` names "sliding_attention" layers:
+    # the band then belongs to those alone, a Python int where each is
+    # traced, and the "full_attention" layers beside them see every earlier
+    # position (a scanned period of both is one body of several blocks;
+    # ServingEngine then holds a ring of ``sliding_window`` rows a slot for
+    # the sliding layers, serving/cache_regime.py "ring"). xla and flash
+    # attention honor it (flash skips below-band kv blocks: work scales
+    # with S*window); ring attention rejects it.
     sliding_window: Optional[int] = None
     # Gemma-2 family switches (utils/hf_interop.py maps model_type
     # "gemma2" onto these, on top of the Gemma-1 trio above):
@@ -260,6 +266,13 @@ class TransformerConfig:
     # False: attention carries no position (q and k are not rotated): the
     # order comes from elsewhere in the stack (state-space layers)
     use_rope: bool = True
+    # rope by layer (config.json ``rope_layout``, one 0 / 1 a layer): layer l
+    # rotates q and k iff ``rope_layout[l]``; None: ``use_rope`` for all
+    rope_layout: Optional[tuple] = None
+    # the router reads the normed input of the ATTENTION sublayer (the
+    # SmallThinker family's "router placed before attention") while the
+    # experts read the feed-forward's
+    moe_router_pre_attention: bool = False
     # RMSNorm over head_dim on q and k (one weight vector each), before rope
     qk_norm: bool = False
     # rope turns the first ``partial_rotary_factor`` x head_dim elements of a
@@ -419,7 +432,17 @@ class TransformerConfig:
             if self.layer_windows is not None:
                 raise ValueError(
                     "layer_windows and layer_types cannot be combined: the "
-                    "per-layer window rides ONE homogeneous scan"
+                    "per-layer window rides ONE homogeneous scan as a traced "
+                    "value; layer_types 'sliding_attention' gives the "
+                    "sliding layers sliding_window as a static band"
+                )
+            if ("sliding_attention" in self.layer_types) != (
+                    self.sliding_window is not None):
+                raise ValueError(
+                    "layer_types 'sliding_attention' layers take their band "
+                    "from sliding_window, and beside layer_types "
+                    "sliding_window belongs to them alone: set both or "
+                    "neither"
                 )
             if self.conv_L_cache < 1:
                 raise ValueError(
@@ -428,6 +451,32 @@ class TransformerConfig:
         self._validate_single_sublayer()
         self._validate_gated_layers()
         self._validate_latent_attention()
+        if self.rope_layout is not None:
+            self.rope_layout = tuple(bool(r) for r in self.rope_layout)
+            clash = [
+                name for name, on in (
+                    ("use_rope=False", not self.use_rope),
+                    ("attention_class", self.attention_class is not None),
+                    ("fused_kernels", self.fused_kernels),
+                    ("kv_lora_rank", self.kv_lora_rank is not None),
+                    ("single_sublayer", self.single_sublayer),
+                ) if on
+            ]
+            if len(self.rope_layout) != self.num_layers or clash:
+                raise ValueError(
+                    f"rope_layout: one entry a layer ({self.num_layers}), got "
+                    f"{len(self.rope_layout)}; it cannot be combined with "
+                    f"{clash}")
+        if self.moe_router_pre_attention and (
+                self.num_experts == 0 or self.single_sublayer
+                or self.kv_lora_rank is not None or self.fused_kernels
+                or set(self.layer_types or ()) - {
+                    "full_attention", "sliding_attention"}):
+            raise ValueError(
+                "moe_router_pre_attention: the router of an expert layer "
+                "reads its attention sublayer's input; it needs experts, "
+                "layers of per-head attention then feed-forward and no "
+                "fused_kernels")
         if not self.use_rope:
             clash = [
                 name for name, on in (

@@ -19,6 +19,7 @@ TPU-native design decisions:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Any, Optional
@@ -315,6 +316,11 @@ def qkv_in_place(decode: bool, q_len: int) -> bool:
 class Attention(nn.Module):
     config: TransformerConfig
     decode: bool = False
+    # a layer kind's own, where the stack has kinds that differ in them
+    # (``layer_types`` "sliding_attention" beside "full_attention";
+    # ``rope_layout``). None: the configuration's one value for the stack
+    sliding: Optional[bool] = None
+    rope: Optional[bool] = None
 
     @nn.compact
     def __call__(self, x, positions, mask=None, kv_lengths=None,
@@ -326,11 +332,17 @@ class Attention(nn.Module):
             raise NotImplementedError(
                 "use_rope=False: every cache regime rotates q and k by the "
                 "slot's position; attention without rope runs the training "
-                "and evaluation path only"
+                "and evaluation path only (rope_layout says which layers of "
+                "a served stack carry none)"
             )
+        use_rope = cfg.use_rope if self.rope is None else self.rope
         delta = _lora_delta_fn(self, lora, lora_stacks)
-        # static homogeneous band, or the per-layer traced one (Gemma-2)
-        window = cfg.sliding_window if layer_window is None else layer_window
+        if self.sliding is None:
+            # static homogeneous band, or the per-layer traced one (Gemma-2)
+            window = cfg.sliding_window if layer_window is None else layer_window
+        else:
+            # the band of this layer's kind: a Python int, or none at all
+            window = cfg.sliding_window if self.sliding else None
         # Gemma-2 decouples the attention scale from head_dim
         scale = (
             cfg.query_pre_attn_scalar ** -0.5
@@ -345,6 +357,8 @@ class Attention(nn.Module):
         )
 
         def turn(a, at):
+            if not use_rope:  # this attention carries no position
+                return a
             return rope(a, at, cfg.rope_theta, cfg.rope_scaling, rotary_dim)
 
         proj = _make_proj(cfg, dtype)
@@ -481,7 +495,13 @@ class Attention(nn.Module):
             # branch resolves at trace time: one engine, one lattice.
             kv_int8 = getattr(paged, "kv_dtype", "native") == "int8"
             pool_dtype = jnp.int8 if kv_int8 else k.dtype
-            is_initialized = self.has_variable("cache", "key_pool")
+            # a sliding layer beside full ones holds a RING a slot, in pools
+            # of its own name and size (ops.attention.PagedKVState.ring)
+            ring = bool(self.sliding) and paged.ring > 0
+            key_name, value_name, pool_blocks = (
+                ("key_ring", "value_ring", paged.ring_blocks) if ring
+                else ("key_pool", "value_pool", paged.num_blocks))
+            is_initialized = self.has_variable("cache", key_name)
             # a block's rows: one a position and KV head, or (the state
             # says so: ops.attention.pool_heads_first) each head's together
             a_block = (
@@ -492,14 +512,14 @@ class Attention(nn.Module):
                 raise NotImplementedError(
                     "attention_class 'eva' over pools stored heads first")
             key_pool = self.variable(
-                "cache", "key_pool",
+                "cache", key_name,
                 lambda: jnp.zeros(
-                    (paged.num_blocks, *a_block, cfg.head_dim), pool_dtype),
+                    (pool_blocks, *a_block, cfg.head_dim), pool_dtype),
             )
             value_pool = self.variable(
-                "cache", "value_pool",
+                "cache", value_name,
                 lambda: jnp.zeros(
-                    (paged.num_blocks, *a_block, cfg.head_dim), pool_dtype),
+                    (pool_blocks, *a_block, cfg.head_dim), pool_dtype),
             )
             key_scale = value_scale = None
             if kv_int8:
@@ -600,40 +620,48 @@ class Attention(nn.Module):
             q = turn(q, positions)
             k = turn(k, positions)
             new_ks = new_vs = None
-            if kv_int8:
-                new_k, new_v, new_ks, new_vs = paged_update(
-                    key_pool.value, value_pool.value, k, v, paged,
-                    key_scale=key_scale.value,
-                    value_scale=value_scale.value, layer=layer,
-                )
-                key_scale.value = new_ks
-                value_scale.value = new_vs
-            else:
-                new_k, new_v = paged_update(
-                    key_pool.value, value_pool.value, k, v, paged,
-                    layer=layer,
-                )
-            key_pool.value = new_k
-            value_pool.value = new_v
-            if paged.fresh:
-                # a prefill from position 0 (a stack with recurrent layers
-                # never continues a cache by several tokens): what it
-                # projected is all there is to see, so the table is written
-                # and not gathered — flash over the prompt's real rows: the
-                # bucket's padded tail lies after them, is seen by none, and
-                # comes out as zeros that cost no work
-                out = dot_product_attention(
-                    q, k, v, causal=True, scale=scale,
-                    kv_lengths=paged.lengths, q_lengths=paged.lengths,
-                    softcap=cfg.attn_softcap,
-                    implementation=cfg.attention_impl, window=window,
-                )
-            else:
-                out = paged_attention(
-                    q, new_k, new_v, paged, scale=scale,
-                    softcap=cfg.attn_softcap, window=window,
-                    key_scale=new_ks, value_scale=new_vs, layer=layer,
-                )
+            # where layer kinds hold caches of two sizes, each kind's cache
+            # write and read stand under its own scope in a device trace
+            with (contextlib.nullcontext() if self.sliding is None
+                  else jax.named_scope("window" if self.sliding else "full")):
+                if kv_int8:
+                    new_k, new_v, new_ks, new_vs = paged_update(
+                        key_pool.value, value_pool.value, k, v, paged,
+                        key_scale=key_scale.value,
+                        value_scale=value_scale.value, layer=layer,
+                    )
+                    key_scale.value = new_ks
+                    value_scale.value = new_vs
+                else:
+                    new_k, new_v = paged_update(
+                        key_pool.value, value_pool.value, k, v, paged,
+                        layer=layer, ring=ring,
+                    )
+                key_pool.value = new_k
+                value_pool.value = new_v
+                if paged.fresh:
+                    # a prefill from position 0 (a stack with recurrent
+                    # layers, or rings, never continues a cache by several
+                    # tokens): what it projected is all there is to see, so
+                    # the table is written and not gathered — flash over the
+                    # prompt's real rows: the bucket's padded tail lies after
+                    # them, is seen by none, and comes out as zeros that cost
+                    # no work
+                    out = dot_product_attention(
+                        q, k, v, causal=True, scale=scale,
+                        kv_lengths=paged.lengths, q_lengths=paged.lengths,
+                        softcap=cfg.attn_softcap,
+                        implementation=cfg.attention_impl, window=window,
+                    )
+                else:
+                    # a ring holds what the band allows and no more: no band
+                    out = paged_attention(
+                        q, new_k, new_v, paged, scale=scale,
+                        softcap=cfg.attn_softcap,
+                        window=None if ring else window,
+                        key_scale=new_ks, value_scale=new_vs, layer=layer,
+                        ring=ring,
+                    )
         elif decode:
             idx = cache_index.value
             positions = idx + jnp.arange(s)[None, :]  # (1, s) broadcasts over batch
@@ -673,9 +701,8 @@ class Attention(nn.Module):
                 window=cfg.window_size, scale=scale,
             )
         else:
-            # the fused prologue already applied rope; use_rope=False: this
-            # attention carries no position
-            if not fused_qkv and cfg.use_rope:
+            # the fused prologue already applied rope
+            if not fused_qkv:
                 q = turn(q, positions)
                 k = turn(k, positions)
             out = dot_product_attention(
@@ -1155,6 +1182,7 @@ def _mlp_activation(cfg: TransformerConfig):
         "silu": nn.silu,
         "gelu_tanh": lambda z: nn.gelu(z, approximate=True),  # Gemma
         "relu2": lambda z: jnp.square(nn.relu(z)),  # Nemotron-H
+        "relu": nn.relu,  # the ReGLU gate (SmallThinker)
     }[cfg.mlp_activation]
 
 
@@ -1216,7 +1244,8 @@ class MoE(nn.Module):
     ``moe_norm_topk_prob``, times ``moe_routed_scaling_factor``. The router
     works in float32.
 
-    Experts are gated (``down(silu(gate x) * up x)``) or, with ``mlp_gated``
+    Experts are gated (``down(act(gate x) * up x)``, ``mlp_activation``
+    "silu" or "relu") or, with ``mlp_gated``
     off, ``down(act(up x))`` under ``mlp_activation`` with no ``gate_proj``
     in the tree (the ragged and dense dispatches). With
     ``moe_shared_intermediate_size`` a shared expert of that width — a
@@ -1271,7 +1300,10 @@ class MoE(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_x=None):
+        # ``router_x``: what the router reads where that is not the experts'
+        # input (``moe_router_pre_attention``: the attention sublayer's
+        # normed input); None: ``x``, every other configuration
         from ..ops.moe import (
             load_balancing_loss, moe_dispatch_combine, ragged_load_stats,
         )
@@ -1294,7 +1326,7 @@ class MoE(nn.Module):
                     nn.initializers.lecun_normal(), ("embed", None)
                 ),
                 name="router",
-            )(x.astype(jnp.float32))  # (B,S,R)
+            )((x if router_x is None else router_x).astype(jnp.float32))  # (B,S,R)
             if cfg.moe_router == "sigmoid":
                 scores = jax.nn.sigmoid(logits)
                 choice = scores
@@ -1350,7 +1382,7 @@ class MoE(nn.Module):
                   if gated else None)
         w_up = epar("up_proj", (E, h, f), ("expert", "embed", "mlp"))
         w_down = epar("down_proj", (E, f, h), ("expert", "mlp", "embed"))
-        act = None if gated else _mlp_activation(cfg)
+        act = _mlp_activation(cfg)  # the gate's, or the non-gated expert's
 
         xc = x.astype(dtype)
         from ..parallel.sharding import live_mesh
@@ -1385,10 +1417,11 @@ class MoE(nn.Module):
                 moe_ragged, moe_ragged_ep, padded_expert_shape,
             )
 
-            if ep_live and not gated:
+            if ep_live and not (gated and cfg.mlp_activation == "silu"):
                 raise NotImplementedError(
-                    "mlp_gated=False under a live ep axis: moe_ragged_ep "
-                    "writes the gated expert's three grouped matmuls in"
+                    "mlp_gated=False or a gate that is not silu under a live "
+                    "ep axis: moe_ragged_ep writes the silu-gated expert's "
+                    "three grouped matmuls in"
                 )
             if ep_live:
                 # expert-parallel ragged: shard-capacity schedule — the
@@ -1427,7 +1460,7 @@ class MoE(nn.Module):
         elif dispatch == "capacity":
             def experts_fn(buf):  # (E, C, h) -> (E, C, h)
                 hidden = jnp.einsum("ech,ehf->ecf", buf, w_gate.astype(dtype))
-                hidden = nn.silu(hidden) * jnp.einsum(
+                hidden = act(hidden) * jnp.einsum(
                     "ech,ehf->ecf", buf, w_up.astype(dtype)
                 )
                 return jnp.einsum("ecf,efh->ech", hidden, w_down.astype(dtype))
@@ -1449,7 +1482,7 @@ class MoE(nn.Module):
             ].add(weights)
             if gated:
                 hidden = jnp.einsum("bsh,ehf->ebsf", xc, w_gate.astype(dtype))
-                hidden = nn.silu(hidden) * jnp.einsum(
+                hidden = act(hidden) * jnp.einsum(
                     "bsh,ehf->ebsf", xc, w_up.astype(dtype)
                 )
             else:
@@ -1496,6 +1529,9 @@ class Block(nn.Module):
     decode: bool = False
     mixer: Optional[str] = "full_attention"
     ff: Optional[str] = None
+    # whether this layer's attention rotates q and k (``rope_layout``);
+    # None: the configuration's ``use_rope``
+    rope: Optional[bool] = None
 
     @nn.compact
     def __call__(self, x, positions, mask=None, kv_lengths=None,
@@ -1551,9 +1587,15 @@ class Block(nn.Module):
                 layer=layer,
             )
         else:
-            attn_out = Attention(cfg, decode=self.decode, name="attn")(
-                RMSNorm(cfg, name="attn_norm")(x), positions, mask,
-                kv_lengths, paged, layer_window,
+            # a stack with "sliding_attention" layers: the band is theirs
+            # alone, static (None elsewhere: ``sliding_window`` for all)
+            sliding = (self.mixer == "sliding_attention"
+                       if "sliding_attention" in (cfg.layer_types or ())
+                       else None)
+            attn_in = RMSNorm(cfg, name="attn_norm")(x)
+            attn_out = Attention(cfg, decode=self.decode, sliding=sliding,
+                                 rope=self.rope, name="attn")(
+                attn_in, positions, mask, kv_lengths, paged, layer_window,
                 lora=lora, lora_stacks=attn_lora, layer=layer,
             )
         if cfg.post_norms:
@@ -1561,7 +1603,9 @@ class Block(nn.Module):
             # 4 per block — transformers Gemma2DecoderLayer)
             attn_out = RMSNorm(cfg, name="post_attn_norm")(attn_out)
         h = checkpoint_name(x + attn_out, "attn_res")
-        ff_out = _feed_forward(self, h, lora, mlp_lora)
+        ff_out = _feed_forward(
+            self, h, lora, mlp_lora,
+            router_x=attn_in if cfg.moe_router_pre_attention else None)
         if cfg.post_norms:
             ff_out = RMSNorm(cfg, name="post_mlp_norm")(ff_out)
         # pin the residual stream's layout once per layer so GSPMD cannot
@@ -1569,18 +1613,19 @@ class Block(nn.Module):
         # (each flip is a full resharding per layer)
         return constrain_activations(h + ff_out), None
 
-def _feed_forward(block: Block, h, lora=None, mlp_lora=None):
+def _feed_forward(block: Block, h, lora=None, mlp_lora=None, router_x=None):
     """``block``'s feed-forward on the residual stream ``h``. A function,
     not a method: a method of a module would put its own name into every
     operation's scope path, and ``layers/mlp/...`` is what the benchmark's
-    metrics read."""
+    metrics read. ``router_x``: what an expert layer's router reads instead
+    of the experts' input (``moe_router_pre_attention``)."""
     cfg = block.config
     ff = block.ff or ("moe" if cfg.num_experts > 0 else "mlp")
     if ff == "moe":
         # MoE blocks don't take adapters (the expert weights are the
         # specialization mechanism there); attention adapters still apply
         return MoE(cfg, decode=block.decode, name="moe")(
-            RMSNorm(cfg, name="mlp_norm")(h))
+            RMSNorm(cfg, name="mlp_norm")(h), router_x)
     return MLP(cfg, name="mlp")(
         RMSNorm(cfg, name="mlp_norm")(h), lora=lora, lora_stacks=mlp_lora,
     )
@@ -1729,11 +1774,20 @@ def layer_kinds(cfg: TransformerConfig, num_layers=None) -> list:
         # ONE sublayer a layer: (operator, None) or (None, feed-forward)
         return [(None, t) if t in FF_LAYER_TYPES else (t, None)
                 for t in types[:n]]
-    return [
+    kinds = [
         (types[l],
          "moe" if cfg.num_experts > 0 and l >= cfg.num_dense_layers else "mlp")
         for l in range(n)
     ]
+    if cfg.rope_layout is not None:
+        # rope by layer is part of a layer's kind: (mixer, ff, rope)
+        kinds = [kind + (cfg.rope_layout[l],) for l, kind in enumerate(kinds)]
+    return kinds
+
+
+def _kind_kwargs(kind) -> dict:
+    """A :func:`layer_kinds` entry as :class:`Block`'s fields."""
+    return {} if kind is None else dict(zip(("mixer", "ff", "rope"), kind))
 
 
 def plan_layers(kinds: list) -> list:
@@ -1768,9 +1822,9 @@ class _Period(nn.Module):
 
     @nn.compact
     def __call__(self, x, *args):
-        for j, (mixer, ff) in enumerate(self.kinds):
+        for j, kind in enumerate(self.kinds):
             x, _ = self.block_cls(
-                self.config, decode=self.decode, mixer=mixer, ff=ff,
+                self.config, decode=self.decode, **_kind_kwargs(kind),
                 name=f"b{j}",
             )(x, *args)
         return x, None
@@ -1834,9 +1888,6 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
             "of one kind; this stack has layers of several kinds"
         )
 
-    def kind_kwargs(kind):
-        return {} if kind is None else {"mixer": kind[0], "ff": kind[1]}
-
     def scan(body, length, in_axes):
         axes = {"params": 0, "intermediates": 0}
         if not carry_cache:
@@ -1863,7 +1914,7 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
                 # slice EVERY leaf's layer axis (per_layer may be a dict
                 # of adapter stacks, not just the bare window array)
                 args = extra + (jax.tree.map(lambda l: l[i], per_layer),)
-            x, _ = cls(cfg, decode=decode, **kind_kwargs(kinds[i]),
+            x, _ = cls(cfg, decode=decode, **_kind_kwargs(kinds[i]),
                        name=f"layer_{i}")(x, *args)
         return x
 
@@ -1885,14 +1936,14 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
         # not a Flax scope: the parameter tree is unchanged
         with jax.named_scope("layers"):
             x, _ = scan(remat(base_cls, scanned=True), n, in_axes)(
-                cfg, decode=decode, **kind_kwargs(kinds[0]), name="layers"
+                cfg, decode=decode, **_kind_kwargs(kinds[0]), name="layers"
             )(x, *args)
         return x
 
     for start, period, repeats in segments:
         if repeats == 1:
             x, _ = remat(base_cls, scanned=False)(
-                cfg, decode=decode, **kind_kwargs(period[0]),
+                cfg, decode=decode, **_kind_kwargs(period[0]),
                 name=f"layer_{start}",
             )(x, *extra)
             continue
@@ -1900,7 +1951,7 @@ def _apply_layer_stack(cfg: TransformerConfig, x, *extra, decode=False,
         in_axes, args = scan_args(None, repeats)
         if len(period) == 1:
             body = scan(remat(base_cls, scanned=True), repeats, in_axes)(
-                cfg, decode=decode, **kind_kwargs(period[0]), name=name)
+                cfg, decode=decode, **_kind_kwargs(period[0]), name=name)
         else:
             body = scan(_Period, repeats, in_axes)(
                 cfg, remat(base_cls, scanned=True), period, decode, name=name)

@@ -113,6 +113,20 @@ class PagedKVState:
     ``(block_size, Hkv, D)``; whoever creates the pools decides it by
     :func:`pool_heads_first` and says so here, and every operation on them
     reads it here (a pool's shape cannot tell the two apart).
+
+    ``ring`` (static, whole blocks; 0: none): a stack whose layers are full
+    and SLIDING-WINDOW attention side by side keeps, for the window layers,
+    pools of their own (:data:`RING_POOL_LEAVES`) in which a SLOT holds a
+    ring of ``ring`` rows whatever its length — position ``p`` at row ``p %
+    ring`` of the slot's ``ring // block_size`` blocks, which no table hands
+    out: slot ``s`` owns blocks ``[1 + s * ring // block_size, 1 + (s + 1) *
+    ring // block_size)`` of ``ring_blocks`` (block 0 is the garbage block
+    there too), ``slot`` / ``num_slots`` as for a slot's state. Keys are
+    stored rotated and a softmax does not ask in which order its rows lie,
+    so a query at position ``p`` reads the ring's first ``min(p + 1, ring)``
+    rows under no band at all: :func:`paged_update` and
+    :func:`paged_attention` with ``ring=True``. ``block_table`` and
+    ``num_blocks`` stay the full layers'.
     """
 
     block_table: jax.Array
@@ -127,6 +141,12 @@ class PagedKVState:
     num_slots: int = flax.struct.field(pytree_node=False, default=0)
     fresh: bool = flax.struct.field(pytree_node=False, default=False)
     heads_first: bool = flax.struct.field(pytree_node=False, default=False)
+    ring: int = flax.struct.field(pytree_node=False, default=0)
+
+    @property
+    def ring_blocks(self) -> int:
+        """Blocks of a window layer's pool: every slot's ring and block 0."""
+        return self.num_slots * (self.ring // self.block_size) + 1
 
 
 # What a model's ``cache`` collection may hold, by the variable's name: the
@@ -142,6 +162,9 @@ class PagedKVState:
 PAGED_POOL_LEAVES = {"key_pool": 3, "value_pool": 3, "key_scale": 1,
                      "value_scale": 1, "latent_pool": 2}
 SLOT_STATE_LEAVES = {"state": 3, "taps": 2}
+# a window layer's K/V rings (``PagedKVState.ring``): laid out as a K/V pool,
+# ``ring_blocks`` blocks that no block list reaches
+RING_POOL_LEAVES = {"key_ring": 3, "value_ring": 3}
 
 
 def pool_heads_first(kv_heads: int, head_dim: int) -> bool:
@@ -202,15 +225,31 @@ def _first_block(state: "PagedKVState", layer):
     return 0 if layer is None else layer * state.num_blocks
 
 
-def _write_rows(state: "PagedKVState", s: int, layer):
+def _ring_view(state: "PagedKVState") -> "PagedKVState":
+    """``state`` as a window layer's rings see it: the table is each row's
+    slot's own blocks, in ring order, and the pool is ``ring_blocks`` long."""
+    per_slot = state.ring // state.block_size
+    slot = (jnp.arange(state.cache_len.shape[0], dtype=jnp.int32)
+            if state.slot is None else state.slot)
+    table = 1 + slot[:, None] * per_slot + jnp.arange(
+        per_slot, dtype=jnp.int32)[None, :]
+    return state.replace(block_table=table, num_blocks=state.ring_blocks)
+
+
+def _write_rows(state: "PagedKVState", s: int, layer, ring: bool = False):
     """(block, offset in it) of each of a call's ``B * s`` positions, flat:
     token i of slot b belongs at global position ``cache_len[b] + i``, table
     slot ``pos // block_size``, offset ``pos % block_size``; at or beyond
-    ``lengths[b]`` it goes to the reserved block 0."""
+    ``lengths[b]`` it goes to the reserved block 0. ``ring`` (``state`` a
+    :func:`_ring_view`): the position's row is ``pos % state.ring``, and of a
+    call longer than the ring only the last ``ring`` valid rows are kept."""
     bs = state.block_size
     max_blocks = state.block_table.shape[1]
     pos = state.cache_len[:, None] + jnp.arange(s)[None, :]  # (B, S) global
     valid = jnp.arange(s)[None, :] < state.lengths[:, None]
+    if ring:
+        valid &= jnp.arange(s)[None, :] >= state.lengths[:, None] - state.ring
+        pos = pos % state.ring
     tbl = jnp.clip(pos // bs, 0, max_blocks - 1)
     blocks = jnp.take_along_axis(state.block_table, tbl, axis=1)
     blocks = jnp.where(valid, blocks, 0) + _first_block(state, layer)
@@ -228,8 +267,13 @@ def paged_update(
     key_scale: Optional[jax.Array] = None,
     value_scale: Optional[jax.Array] = None,
     layer=None,
+    ring: bool = False,
 ) -> tuple[jax.Array, ...]:
     """Scatter one call's K/V into the block pools.
+
+    ``ring``: the pools are a window layer's rings (``state.ring`` rows a
+    slot, :class:`PagedKVState`): the call's last ``ring`` valid rows land at
+    row ``position % ring`` of their slot's own blocks.
 
     The pools are one layer's own, (num_blocks, block_size, Hkv, D), or
     with ``layer`` (a traced index) the stack of every layer's, (L,
@@ -258,7 +302,9 @@ def paged_update(
     """
     b, s = k.shape[:2]
     bs = state.block_size
-    bf, of = _write_rows(state, s, layer)
+    if ring:
+        state = _ring_view(state)
+    bf, of = _write_rows(state, s, layer, ring)
 
     def put(pool, rows, inner):
         if inner == 3 and state.heads_first:
@@ -340,6 +386,7 @@ def paged_attention(
     key_scale: Optional[jax.Array] = None,
     value_scale: Optional[jax.Array] = None,
     layer=None,
+    ring: bool = False,
 ) -> jax.Array:
     """Attention read through the block table, in one of two forms of
     one algorithm (grouped softmax over a slot's blocks, under one mask
@@ -369,7 +416,22 @@ def paged_attention(
     Under ``kv_dtype="int8"`` the gathered int8 rows are dequantized
     (row * its per-token scale) at the query's dtype before the math —
     the pools stay int8 in HBM, only the gathered working set widens.
+
+    ``ring``: the pools are a window layer's rings (:class:`PagedKVState`).
+    ONE position a slot, just written at row ``cache_len % ring``: it sees
+    the first ``min(cache_len + 1, ring)`` rows of its slot's ring — every
+    position the band allows and no other — in whatever order they lie,
+    through either form, the table the slot's own blocks and no band.
     """
+    if ring:
+        if q.shape[1] != 1 or window is not None or state.kv_dtype == "int8":
+            raise NotImplementedError(
+                "a window layer's ring is read by one position a slot, "
+                "native rows, under no band: a call of several tokens onto "
+                "an existing ring (chunked or prefix-cached prefill, "
+                "speculative verification) is not written")
+        state = _ring_view(state).replace(
+            cache_len=jnp.minimum(state.cache_len, state.ring - 1))
     if decode_kernel_eligible(state, q.shape[1], key_pool):
         from .paged_attention import paged_decode_attention
 

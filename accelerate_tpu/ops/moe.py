@@ -227,7 +227,8 @@ _to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
 def _expert_rows(xs, way: _Way, weights, group_sizes, w_gate, w_up, w_down,
                  h, activation=None):
     """A run of sorted rows ``xs`` (every one in a group) through the three
-    grouped matmuls — two where the experts have no gate matrix (``w_gate``
+    grouped matmuls, ``down(activation(gate x) * up x)`` (``activation``
+    None: silu) — two where the experts have no gate matrix (``w_gate``
     None: ``down(activation(up x))``) —, then back in token order by a
     gather (:func:`_to_tokens`): token ``t``'s ``j``-th choice takes row
     ``way.place[j, t]`` of the run — zeros where ``way.ok[j, t]`` is False:
@@ -240,7 +241,7 @@ def _expert_rows(xs, way: _Way, weights, group_sizes, w_gate, w_up, w_down,
         if w_gate is None:
             hidden = activation(jax.lax.ragged_dot(xs, w_up, group_sizes))
         else:
-            hidden = jax.nn.silu(
+            hidden = (activation or jax.nn.silu)(
                 jax.lax.ragged_dot(xs, w_gate, group_sizes)
             ) * jax.lax.ragged_dot(xs, w_up, group_sizes)  # (rows, f)
         out = jax.lax.ragged_dot(hidden, w_down, group_sizes)  # (rows, h)
@@ -265,7 +266,8 @@ def moe_ragged(
     """Exact sparse MoE via grouped matmuls (``jax.lax.ragged_dot``).
 
     ``w_gate`` None: experts without a gate matrix, ``down(activation(up
-    x))`` — two grouped matmuls a window where the gated expert has three.
+    x))`` — two grouped matmuls a window where the gated expert has three,
+    ``down(activation(gate x) * up x)`` (``activation`` None: silu).
 
     Tokens sort by their selected expert; each expert's contiguous group
     multiplies against its weights with NO capacity padding and NO drops —

@@ -1,4 +1,4 @@
-"""What a request's cache IS, in one place: four regimes, told apart by the
+"""What a request's cache IS, in one place: five regimes, told apart by the
 model's configuration alone, and nothing else under ``serving/`` reads the
 fields that tell them apart.
 
@@ -10,7 +10,14 @@ fields that tell them apart.
   the rows, and a slot gives blocks back before it ends (the roll-over);
 * ``recurrent`` (``layer_types`` "linear_attention") and ``latent``
   (``kv_lora_rank``, models/transformer.LatentAttention): as the refusals
-  below say.
+  below say;
+* ``ring`` (``layer_types`` "sliding_attention" beside "full_attention"):
+  layers of ONE stack hold caches of two sizes. A full layer keeps ``rows``'
+  pools and block table; a window layer holds, a SLOT, a ring of
+  ``sliding_window`` rows in pools of its own that no table hands out
+  (ops/attention.py: ``PagedKVState.ring``), so the pool, the table and the
+  scheduler count the full layers' blocks alone and a seat's rings are a
+  state it holds whatever its length.
 """
 
 from __future__ import annotations
@@ -24,6 +31,7 @@ import numpy as np
 from ..models.transformer import qkv_in_place
 from ..ops.attention import (
     PAGED_POOL_LEAVES,
+    RING_POOL_LEAVES,
     SLOT_STATE_LEAVES,
     PagedKVState,
     decode_kernel_eligible,
@@ -45,6 +53,10 @@ _NOT_A_BLOCK_LIST = {
     "latent": ("A3", "latent attention: a request's cache is one latent row a "
                "position, which a prefill expands and never reads back and a "
                "decode step reads absorbed, one position a slot"),
+    "ring": ("A2", "a stack with 'sliding_attention' layers: a request's "
+             "cache is, beside the blocks of its full-attention layers, a "
+             "ring of sliding_window rows a slot in each window layer, which "
+             "no block list reaches and a later position overwrites"),
 }
 
 # An engine's trace-time counters, at zero, and what each counts
@@ -79,26 +91,45 @@ TRACE_COUNTS = {
     # of the traced prefill programs, how many run their DeltaNet layers'
     # chunked rule as the ``gdn_chunked`` kernel
     "gdn_kernel": 0,
+    # of the traced prefill and decode programs, how many ran their window
+    # layers through a ring a slot (all or none); of the traced decode
+    # programs, how many read the rings through the ``paged_decode`` kernel
+    "window_ring": 0, "window_decode_kernel": 0,
 }
 
 
 class CacheRegime:
     """The kind of one model's cache and what follows from it, for the engine,
     the scheduler, the draft proposer (its own pool's) and
-    ``capture_programs`` to ask. Built once, never changed."""
+    ``capture_programs`` to ask. Built once, never changed (but for a ring's
+    count of its wraps)."""
 
     def __init__(self, config: Any, block_size: int, max_slots: int,
                  kv_dtype: str = "bf16", num_blocks: Optional[int] = None):
         # eva: where a request's rows lie by its position (an ``EvaLayout``)
         self.layout = None
+        # ring: the rows a window layer holds a slot (0: no such layers), and
+        # how often a seat's rings have wrapped so far (the one thing here
+        # that moves: a counter for the gauge)
+        self.ring = 0
+        self.ring_wraps_total = 0
+        types = getattr(config, "layer_types", None) or ()
         if getattr(config, "attention_class", None) == "eva":
             from ..ops.eva_attention import EvaLayout
 
             self.kind = "eva"
             self.layout = EvaLayout(
                 config.window_size, config.chunk_size, block_size)
-        elif "linear_attention" in (getattr(config, "layer_types", None) or ()):
+        elif "linear_attention" in types:
             self.kind = "recurrent"
+        elif "sliding_attention" in types:
+            self.kind = "ring"
+            self.ring = config.sliding_window
+            if self.ring % block_size:
+                raise ValueError(
+                    f"sliding_window {self.ring} must be whole blocks of "
+                    f"block_size {block_size}: a window layer's ring holds "
+                    "exactly the rows its band allows")
         elif getattr(config, "kv_lora_rank", None) is not None:
             self.kind = "latent"
         else:
@@ -115,11 +146,16 @@ class CacheRegime:
         # how a block of the pools is laid out: a rule of the model's shapes
         # (the eva regime's own writes are written for one row a position and
         # head; a latent row has no heads)
-        self.heads_first = self.kind in ("rows", "recurrent") and (
+        self.heads_first = self.kind in ("rows", "recurrent", "ring") and (
             pool_heads_first(config.num_kv_heads, config.head_dim))
         # a prefill that has nothing before it to see attends what it
         # projected (``PagedKVState.fresh``), by the prompt's real length
-        self.fresh = self.kind in ("recurrent", "latent")
+        self.fresh = self.kind in ("recurrent", "latent", "ring")
+        # a state lives in the seat itself: a prefill is told which
+        self._seated_state = self.kind in ("recurrent", "ring")
+        # layers that hold a ring a slot, and those that hold every row
+        self._window_layers = types.count("sliding_attention")
+        self._full_layers = types.count("full_attention")
         self._config = config
 
     def state(self, block_table, cache_len, lengths, *, slot=None,
@@ -134,8 +170,9 @@ class CacheRegime:
             num_blocks=self.num_blocks, block_size=self.block_size,
             kv_dtype=self.kv_dtype, single_device=single_device,
             positions=positions, slot=slot,
-            num_slots=self.max_slots if self.kind == "recurrent" else 0,
+            num_slots=self.max_slots if self._seated_state else 0,
             fresh=prefill and self.fresh, heads_first=self.heads_first,
+            ring=self.ring,
         )
 
     def rows(self, cache_len: int) -> int:
@@ -164,11 +201,13 @@ class CacheRegime:
         by shape. Returns (flat leaf index, block axis) of every pool and
         int8 scale array ((..., num_blocks, block_size, ...): what the COW
         copy, the swap and the hand-off address blocks through; no block list
-        reaches the state leaves, (..., num_slots, ...)), the pools' bytes,
-        those of one cached token over every layer (the headline int8
-        halves), and those of the state a seat holds whatever its length."""
+        reaches the state leaves, (..., num_slots, ...), nor a window
+        layer's rings), the pools' bytes, rings included, those of one cached
+        token over every layer that holds a row a position (the headline int8
+        halves), and those of what a seat holds whatever its length: its
+        state, its rings."""
         info: list[tuple[int, int]] = []
-        kv_bytes = state_bytes = 0
+        kv_bytes = state_bytes = ring_bytes = 0
         flat, _ = jax.tree_util.tree_flatten_with_path(cache)
         for i, (path, leaf) in enumerate(flat):
             name = next(k.key for k in reversed(path) if hasattr(k, "key"))
@@ -183,13 +222,21 @@ class CacheRegime:
                 axis = leaf.ndim - 1 - SLOT_STATE_LEAVES[name]
                 assert leaf.shape[axis] == self.max_slots, (name, leaf.shape)
                 state_bytes += leaf.nbytes
+            elif name in RING_POOL_LEAVES:
+                blocks = leaf.shape[leaf.ndim - 1 - RING_POOL_LEAVES[name]]
+                assert (blocks - 1) % self.max_slots == 0, (name, leaf.shape)
+                ring_bytes += leaf.nbytes
+                # the slots' own blocks: all but the garbage block
+                state_bytes += leaf.nbytes // blocks * (blocks - 1)
             else:
                 raise NotImplementedError(
                     f"cache leaf {name!r} {leaf.shape} is neither a paged "
-                    "pool nor a per-slot state (ops/attention.py: "
-                    "PAGED_POOL_LEAVES, SLOT_STATE_LEAVES)"
+                    "pool, a window layer's ring nor a per-slot state "
+                    "(ops/attention.py: PAGED_POOL_LEAVES, RING_POOL_LEAVES, "
+                    "SLOT_STATE_LEAVES)"
                 )
-        return (info, kv_bytes, kv_bytes / (self.num_blocks * self.block_size),
+        return (info, kv_bytes + ring_bytes,
+                kv_bytes / (self.num_blocks * self.block_size),
                 state_bytes / self.max_slots)
 
     def refuse(self, feature: str) -> None:
@@ -209,7 +256,7 @@ class CacheRegime:
             jnp.asarray([tokens], jnp.int32), jnp.asarray([cached], jnp.int32),
             key, jnp.asarray([temperature], jnp.float32),
             np.asarray([slot], np.int32)
-            if self.kind == "recurrent" and slot is not None else None,
+            if self._seated_state and slot is not None else None,
             *lora,
         )
 
@@ -258,6 +305,7 @@ class CacheRegime:
             return
         traces["eva"] += eva
         traces["recurrent_state"] += recurrent
+        traces["window_ring"] += self.kind == "ring"
         if program == "prefill":
             traces["mla_prefill_expanded"] += latent
             traces["flash_real_rows"] += self.fresh
@@ -268,12 +316,48 @@ class CacheRegime:
                       else decode_kernel_eligible)(state, q_len, pool)
             traces["decode_attn_kernel"] += kernel
             traces["mla_decode_kernel"] += latent and kernel
+            # a ring's blocks are a pool's: the one predicate holds for both
+            traces["window_decode_kernel"] += self.kind == "ring" and kernel
             traces["qkv_in_place"] += qkv_in_place(True, q_len)
+
+    def count_wraps(self, start: int, end: int) -> None:
+        """ring: a prefill took a seat from ``start`` positions to ``end``;
+        count how often its rings wrapped (a position written over an earlier
+        one's row) on the way. A decode step's are counted by
+        :meth:`step_stats`."""
+        if self.ring:
+            self.ring_wraps_total += (
+                max(0, (end - 1) // self.ring)
+                - max(0, (start - 1) // self.ring))
+
+    def step_stats(self, cache_lens: np.ndarray, lengths: np.ndarray) -> dict:
+        """What a decode step's spans say beside ``rows``, the sum of the
+        rows (positions, their new one included) its seated slots (``lengths``
+        1) stand at — ring: ``window_rows``, what a window layer reads of
+        them, and over every layer the rows read (``layer_rows``) beside the
+        rows a cache of one row a position and layer would read
+        (``layer_positions``)."""
+        if not self.ring:
+            return {}
+        rows = (cache_lens + 1)[lengths > 0]
+        # the step writes position ``rows - 1``: over ring row 0 again
+        self.ring_wraps_total += int(
+            ((rows > self.ring) & ((rows - 1) % self.ring == 0)).sum())
+        total = int(rows.sum())
+        window = int(np.minimum(rows, self.ring).sum())
+        return {
+            "window_rows": window,
+            "layer_rows": (window * self._window_layers
+                           + total * self._full_layers),
+            "layer_positions": total * (
+                self._window_layers + self._full_layers),
+        }
 
     def gauges(self, active: list, tokens_in_flight: int,
                bytes_per_token: float, rollovers_total: int) -> dict:
         """The regime's own fields of a ``serve_gauge`` record, over the
-        seated slots ``active`` and the positions they stand at."""
+        seated slots ``active`` and the positions they stand at
+        (``rollovers_total``: eva's roll-overs)."""
         fields = {
             # what ONE position holds over the layers as the latent pool is
             # allocated, lanes of padding included (0: per-head K and V)
@@ -292,5 +376,19 @@ class CacheRegime:
                 summary_blocks=summary,
                 window_blocks=sum(
                     self.layout.blocks(s.cache_len) for s in active) - summary,
+            )
+        if self.ring:
+            full = sum(s.cache_len for s in active)
+            window = sum(min(s.cache_len, self.ring) for s in active)
+            layers = self._window_layers + self._full_layers
+            fields.update(
+                # rows a window layer and a full layer hold for the seats
+                window_rows_live=window, full_rows_live=full,
+                # over every layer, rows held a position stood at: 1.0 where
+                # every layer holds every row
+                cache_rows_per_token=(
+                    window * self._window_layers + full * self._full_layers
+                ) / max(1, full * layers),
+                ring_wraps_total=self.ring_wraps_total,
             )
         return fields

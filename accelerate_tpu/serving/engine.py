@@ -417,7 +417,7 @@ class ServingEngine:
         counts = int(cfg.num_experts > 0)
         self._counts_experts = bool(counts)
         self._experts_held = cfg.num_experts * sum(
-            ff == "moe" for _, ff in layer_kinds(cfg)) if counts else 0
+            kind[1] == "moe" for kind in layer_kinds(cfg)) if counts else 0
         # of the decode steps in flight, by dispatch count: (rows, seated)
         self._step_stats: dict[int, tuple] = {}
         # what the engine knows about its pools and attention cannot see
@@ -1074,6 +1074,7 @@ class ServingEngine:
             token = int(np.asarray(token)[0])
             slot.cache_len = prompt_len
             slot.windows_done = self._regime.windows(prompt_len)
+            self._regime.count_wraps(0, prompt_len)
             slot.pending = token
             slot.generated = [token]
             # index every FULL prompt block we freshly prefilled so the next
@@ -1795,11 +1796,11 @@ class ServingEngine:
             if held is not None:
                 # the step that was fetched, in one place: what it held
                 # (from its dispatch) and what its routing touched
-                rows, seated = held
+                rows, seated, more = held
                 span.set_metadata(
                     rows=rows, seated=seated,
                     experts_touched=int(host[self.max_slots]),
-                    experts_held=self._experts_held)
+                    experts_held=self._experts_held, **more)
             return host
 
     def _dispatch_decode(self, slots: list[Slot], prev=None) -> tuple:
@@ -1838,13 +1839,15 @@ class ServingEngine:
             # what this step's attention reads: the rows the seated slots
             # hold, their new one included, and the positions they stand for
             rows = int(cache_lens.sum()) + len(slots)
+            # what the cache's kind says of them beside that
+            more = self._regime.step_stats(cache_lens, lengths)
             # and what those rows occupy in the pools as they are allocated
             phase.set_metadata(
                 rows=rows, positions=at + len(slots),
-                cache_bytes=int(rows * self.kv_bytes_per_token))
+                cache_bytes=int(rows * self.kv_bytes_per_token), **more)
             if self._counts_experts:
                 self._step_stats[self._dispatched["jit__decode"]] = (
-                    rows, len(slots))
+                    rows, len(slots), more)
             if self._feed_fn is None:
                 fed = jnp.asarray(tokens)
             else:

@@ -673,6 +673,24 @@ def _export_arch(config) -> tuple[str, str]:
             "'gelu_tanh'/embed_scale must all be on or all off) matches "
             "no HF model_type; save a native checkpoint instead"
         )
+    kinds = [
+        name for name, on in (
+            ("layer_types", getattr(config, "layer_types", None) is not None),
+            ("rope_layout", getattr(config, "rope_layout", None) is not None),
+            ("moe_router_pre_attention",
+             getattr(config, "moe_router_pre_attention", False)),
+        ) if on
+    ]
+    if kinds:
+        # the mappings here write ONE kind of layer: a stack whose layers
+        # differ (``layer_types`` operators, sliding beside full attention
+        # with rope by layer) would come back as that one kind, every tensor
+        # loaded and the logits silently another model's
+        raise ValueError(
+            f"no HF model_type this module maps carries {kinds} (layer_types "
+            "names per-layer operators and windows the parameter mappings "
+            "do not write); save a native checkpoint instead"
+        )
     qkv = getattr(config, "qkv_bias", False)
     moe = bool(config.num_experts)
     post = getattr(config, "post_norms", False)

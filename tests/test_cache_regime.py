@@ -2,8 +2,8 @@
 (``serving/cache_regime.py``): the features x regimes refusals as one table,
 the source rules that keep the cache's kind known in one module, and
 ``capture_programs`` through the regime's argument builders, on the tiny
-configurations of ``test_eva_attention.py``, ``test_qwen3_next.py`` and
-``test_deepseek_v3.py``."""
+configurations of ``test_eva_attention.py``, ``test_qwen3_next.py``,
+``test_deepseek_v3.py`` and ``test_smallthinker.py``."""
 
 import ast
 import os
@@ -22,8 +22,10 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark", "tests"))
 import tiny_deepseek_v3  # noqa: E402
 import tiny_eva  # noqa: E402
 import tiny_qwen3_next  # noqa: E402
+import tiny_smallthinker  # noqa: E402
 from harness import common  # noqa: E402
-from harness import deepseek_v3_weights, evabyte_weights, qwen3_next_weights  # noqa: E402
+from harness import (  # noqa: E402
+    deepseek_v3_weights, evabyte_weights, qwen3_next_weights, smallthinker_weights)
 
 from accelerate_tpu.models import CausalLM, TransformerConfig  # noqa: E402
 from accelerate_tpu.profiling.registry import ProgramRegistry  # noqa: E402
@@ -37,6 +39,7 @@ TINY = {
     "eva": (tiny_eva, evabyte_weights, 2**31 + 5, 4),
     "recurrent": (tiny_qwen3_next, qwen3_next_weights, 2**31 + 38, 4),
     "latent": (tiny_deepseek_v3, deepseek_v3_weights, 2**31 + 40, 8),
+    "ring": (tiny_smallthinker, smallthinker_weights, 2**31 + 45, 4),
 }
 
 
@@ -60,6 +63,10 @@ WHY = {
     "latent": ("A3", "latent attention: a request's cache is one latent row a "
                "position, which a prefill expands and never reads back and a "
                "decode step reads absorbed, one position a slot"),
+    "ring": ("A2", "a stack with 'sliding_attention' layers: a request's "
+             "cache is, beside the blocks of its full-attention layers, a "
+             "ring of sliding_window rows a slot in each window layer, which "
+             "no block list reaches and a later position overwrites"),
 }
 FEATURES = {
     "prefix_cache": dict(prefix_cache=True),
@@ -170,8 +177,8 @@ def test_the_engine_asks_the_regime_and_branches_on_no_kind_itself():
 # --------------------------------------------------------------------------- #
 @pytest.mark.parametrize("regime,kw", [
     ("rows", {}), ("rows", {"kv_dtype": "int8"}), ("eva", {}),
-    ("recurrent", {}), ("latent", {}),
-], ids=["rows-bf16", "rows-int8", "eva", "recurrent", "latent"])
+    ("recurrent", {}), ("latent", {}), ("ring", {}),
+], ids=["rows-bf16", "rows-int8", "eva", "recurrent", "latent", "ring"])
 def test_capture_programs_registers_each_regimes_programs(regime, kw):
     model = _model(regime)
     if regime == "rows":
@@ -193,7 +200,8 @@ def test_capture_programs_registers_each_regimes_programs(regime, kw):
     assert traced["eva"] == (3 if regime == "eva" else 0)  # + the roll-over
     assert traced["recurrent_state"] == (2 if regime == "recurrent" else 0)
     assert traced["mla_prefill_expanded"] == (regime == "latent")
-    assert traced["flash_real_rows"] == (regime in ("recurrent", "latent"))
+    assert traced["flash_real_rows"] == (regime in ("recurrent", "latent", "ring"))
+    assert traced["window_ring"] == (2 if regime == "ring" else 0)
     labels = eng.capture_programs(ProgramRegistry())
     assert labels == ["serve_prefill_b16", "serve_decode"] + (
         ["serve_rollover"] if regime == "eva" else []) + [
