@@ -257,6 +257,87 @@ def _write_rows(state: "PagedKVState", s: int, layer, ring: bool = False):
     return blocks.reshape(-1), offsets.reshape(-1)
 
 
+def block_write_eligible(state: "PagedKVState", s: int) -> bool:
+    """Whether a call of ``s`` positions writes its rows by the BLOCK they
+    fill instead of by the row — decided at trace time from what the call can
+    observe, never from a model's name or an option. ONE predicate: the
+    serving engine asks it too, for its ``kv_block_write`` trace count.
+
+    A ``fresh`` call starts every row's cache at position 0, so a call of
+    whole blocks fills table entries ``0 .. s / block_size - 1`` in order and
+    the unit of "put position p's row where the table says" can be the block
+    (a slot's ring: one run of blocks). Everything else — a decode step, a
+    prefill onto a ``cache_len`` that need not be block-aligned, a bucket
+    narrower than a block, int8 pools with their scale arrays — keeps the row
+    scatter of :func:`_write_rows`."""
+    return (state.fresh and s % state.block_size == 0
+            and state.kv_dtype == "native")
+
+
+def _as_blocks(rows: jax.Array, state: "PagedKVState") -> jax.Array:
+    """``rows`` (B, n, <a row>), ``n`` whole blocks -> (B, n / block_size, <a
+    block>) as a pool stores one: rows of (Hkv, D) turned (Hkv, block_size,
+    D) once where the pool is heads first (a latent row has no heads)."""
+    b, n = rows.shape[:2]
+    blocks = rows.reshape(b, n // state.block_size, state.block_size,
+                          *rows.shape[2:])
+    if rows.ndim == 4 and state.heads_first:
+        blocks = jnp.swapaxes(blocks, 2, 3)
+    return blocks
+
+
+def _fill_blocks(pool: jax.Array, rows: jax.Array, state: "PagedKVState",
+                 layer) -> jax.Array:
+    """A fresh call's ``rows`` (B, S, <a row>) into ``pool``, each block WHOLE
+    at its table entry: one update of a block's values a block, indexed on the
+    pool's major axis alone — nothing asks XLA to relay the pool out. A block
+    that starts at or past ``lengths[b]`` is dropped (an index past the
+    pool, each its own: the indices are unique); the prompt's last, partial
+    block is written whole, so its rows past the length hold the padded
+    positions' values inside the slot's OWN block, where no read reaches
+    (every read is bounded by ``cache_len``; decode overwrites them in
+    order)."""
+    blocks = _as_blocks(rows, state)
+    b, n = blocks.shape[:2]
+    flat = _flat_pool(pool, blocks.ndim - 2)
+    j = jnp.arange(n, dtype=jnp.int32)
+    table = state.block_table[
+        :, jnp.minimum(j, state.block_table.shape[1] - 1)]
+    live = j[None, :] * state.block_size < state.lengths[:, None]
+    at = jnp.where(
+        live, table + _first_block(state, layer),
+        flat.shape[0] + jnp.arange(b * n, dtype=jnp.int32).reshape(b, n))
+    return flat.at[at.reshape(-1)].set(
+        blocks.reshape(b * n, *flat.shape[1:]).astype(pool.dtype),
+        mode="drop", unique_indices=True).reshape(pool.shape)
+
+
+def _fill_ring(pool: jax.Array, rows: jax.Array, state: "PagedKVState",
+               layer) -> jax.Array:
+    """A fresh call's ``rows`` (B, S, Hkv, D) into a window layer's rings
+    (``state`` a :func:`_ring_view`): ring row ``r`` of a prompt of ``L``
+    positions holds position ``r + ring * ((L - 1 - r) // ring)`` — the
+    prompt's last ``ring`` rows, rotated — so ``min(S, ring)`` rows are
+    gathered in ring order (a bucket no longer than the ring lies in ring
+    order as it is), laid out as blocks and written with ONE slice at the
+    slot's first block. Rows ``r >= L`` of a ring not yet wrapped are garbage
+    inside the slot's own ring: a read takes ``min(position + 1, ring)``
+    rows."""
+    s, ring = rows.shape[1], state.ring
+    if s > ring:
+        r = jnp.arange(ring, dtype=jnp.int32)[None, :]
+        at = r + ring * ((state.lengths[:, None] - 1 - r) // ring)
+        rows = jnp.take_along_axis(
+            rows, jnp.clip(at, 0, s - 1)[:, :, None, None], axis=1)
+    blocks = _as_blocks(rows, state).astype(pool.dtype)
+    flat = _flat_pool(pool, 3)
+    first = state.block_table[:, 0] + _first_block(state, layer)
+    for i in range(blocks.shape[0]):
+        flat = jax.lax.dynamic_update_slice(
+            flat, blocks[i], (first[i], 0, 0, 0))
+    return flat.reshape(pool.shape)
+
+
 @jax.named_scope("kv_write")
 def paged_update(
     key_pool: jax.Array,
@@ -289,6 +370,13 @@ def paged_update(
     so garbage can never collide with live data. Static shapes: one
     compiled scatter regardless of how full any sequence is.
 
+    One algorithm — "put position p's row where the table says" — in two
+    units: where :func:`block_write_eligible` holds (a ``fresh`` call of
+    whole blocks, native rows) the unit is the BLOCK (:func:`_fill_blocks`;
+    a ring: one slice a slot, :func:`_fill_ring`), everywhere else the row
+    (:func:`_write_rows`). Every row a read can reach holds the same bits
+    either way.
+
     Because every write lands at ``cache_len + i``, a nonzero
     ``cache_len`` makes the SAME program a tail prefill: prefix caching
     passes the cached-token count as ``cache_len`` and only the uncached
@@ -304,6 +392,10 @@ def paged_update(
     bs = state.block_size
     if ring:
         state = _ring_view(state)
+    if block_write_eligible(state, s):
+        fill = _fill_ring if ring else _fill_blocks
+        return (fill(key_pool, k, state, layer),
+                fill(value_pool, v, state, layer))
     bf, of = _write_rows(state, s, layer, ring)
 
     def put(pool, rows, inner):
@@ -486,11 +578,14 @@ def latent_update(pool: jax.Array, rows: jax.Array, state: PagedKVState,
     """:func:`paged_update` for a latent pool, (num_blocks, block_size, W) or
     with ``layer`` the stack of every layer's: ``rows`` (B, S, <= W), ONE row
     a position whatever the heads, zeros behind it up to the pool's width,
-    land where :func:`paged_update` puts a position's K and V."""
+    land where :func:`paged_update` puts a position's K and V — by the block
+    where :func:`block_write_eligible` holds, as there."""
     b, s, w = rows.shape
     width = pool.shape[-1]
     if w < width:
         rows = jnp.pad(rows, ((0, 0), (0, 0), (0, width - w)))
+    if block_write_eligible(state, s):
+        return _fill_blocks(pool, rows, state, layer)
     bf, of = _write_rows(state, s, layer)
     return _flat_pool(pool, 2).at[bf, of].set(
         rows.reshape(b * s, width).astype(pool.dtype)).reshape(pool.shape)

@@ -34,6 +34,7 @@ from ..ops.attention import (
     RING_POOL_LEAVES,
     SLOT_STATE_LEAVES,
     PagedKVState,
+    block_write_eligible,
     decode_kernel_eligible,
     latent_kernel_eligible,
     pool_heads_first,
@@ -88,6 +89,9 @@ TRACE_COUNTS = {
     # (``fresh``) and so hand attention the prompt's real length: flash then
     # walks the real rows, not the bucket
     "flash_real_rows": 0,
+    # of the traced prefill programs, how many write their rows into the
+    # pools by the block they fill (a ring: one slice), not by the row
+    "kv_block_write": 0,
     # of the traced prefill programs, how many run their DeltaNet layers'
     # chunked rule as the ``gdn_chunked`` kernel
     "gdn_kernel": 0,
@@ -309,6 +313,7 @@ class CacheRegime:
         if program == "prefill":
             traces["mla_prefill_expanded"] += latent
             traces["flash_real_rows"] += self.fresh
+            traces["kv_block_write"] += block_write_eligible(state, q_len)
             traces["gdn_kernel"] += recurrent and chunked_kernel_eligible(
                 self._config.gdn_head_k_dim, self._config.gdn_head_v_dim)
         else:

@@ -449,7 +449,6 @@ class ServingEngine:
 
         def _prefill(params, cache, ids, table, length, cached_len, key,
                      temp, slot=None, *lora_args):
-            regime.mark(traces, "prefill")  # at trace time, not per call
             # cached_len > 0 is the warm-hit path: ``ids`` holds only the
             # UNCACHED tail and the paged cache already contains KV for
             # the first cached_len positions (shared prefix blocks in
@@ -460,6 +459,8 @@ class ServingEngine:
             state = regime.state(
                 table, cached_len, length, slot=slot,
                 single_device=single_device, prefill=True)
+            # at trace time, not per call
+            regime.mark(traces, "prefill", state, ids.shape[1])
             # the head reads the last VALID row of the padded bucket alone,
             # not the padded tail: width x vocabulary logits are never formed
             logits, mutated = model.apply(

@@ -1245,6 +1245,9 @@ def mla_phase(say, dry: bool) -> None:
     assert counts["mla_prefill_expanded"] == counts["prefill"] >= 1, counts
     # every prefill told flash how many rows of its bucket are real
     assert counts["flash_real_rows"] == counts["prefill"], counts
+    # and wrote its latent rows by the block (prompts of 130 and up: every
+    # bucket is whole blocks)
+    assert counts["kv_block_write"] == counts["prefill"], counts
     assert {k.split("'")[-2] for k in map(
         jax.tree_util.keystr, dict(jax.tree_util.tree_flatten_with_path(
             eng.cache)[0]))} == {"latent_pool"}

@@ -201,6 +201,8 @@ def test_capture_programs_registers_each_regimes_programs(regime, kw):
     assert traced["recurrent_state"] == (2 if regime == "recurrent" else 0)
     assert traced["mla_prefill_expanded"] == (regime == "latent")
     assert traced["flash_real_rows"] == (regime in ("recurrent", "latent", "ring"))
+    # the bucket of 16 is whole blocks, and only those three start from nothing
+    assert traced["kv_block_write"] == (regime in ("recurrent", "latent", "ring"))
     assert traced["window_ring"] == (2 if regime == "ring" else 0)
     labels = eng.capture_programs(ProgramRegistry())
     assert labels == ["serve_prefill_b16", "serve_decode"] + (
@@ -210,3 +212,75 @@ def test_capture_programs_registers_each_regimes_programs(regime, kw):
     compiled = eng.capture_compile_count
     assert eng.capture_programs(ProgramRegistry()) == labels
     assert eng.capture_compile_count == compiled == len(labels)
+
+
+# --------------------------------------------------------------------------- #
+# a fresh prefill writes its rows by the block (PR 46)
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("fresh,width,kv_dtype,by_block", [
+    (True, 16, "native", True), (True, 64, "native", True),
+    (True, 2, "native", False),    # a bucket narrower than a block
+    (True, 12, "native", False),   # no whole number of blocks
+    (False, 16, "native", False),  # onto a cache_len that need not be aligned
+    (True, 16, "int8", False),     # the scale arrays keep the row scatter
+    (False, 1, "native", False),   # a decode step
+], ids=["whole-blocks", "wide", "under-a-block", "ragged", "not-fresh", "int8",
+        "decode"])
+def test_the_block_write_asks_what_the_call_can_observe(
+        fresh, width, kv_dtype, by_block):
+    from accelerate_tpu.ops.attention import PagedKVState, block_write_eligible
+
+    state = PagedKVState(
+        block_table=jnp.zeros((1, 8), jnp.int32),
+        cache_len=jnp.zeros(1, jnp.int32), lengths=jnp.ones(1, jnp.int32),
+        num_blocks=9, block_size=8, kv_dtype=kv_dtype, fresh=fresh)
+    assert block_write_eligible(state, width) == by_block
+
+
+def _served_logits(regime, monkeypatch, by_block):
+    """The logits an engine's own prefill and decode programs sampled from
+    over a prompt that ends inside a block, and its trace counts — with the
+    block write as the predicate says, or with every call on the row
+    scatter."""
+    from accelerate_tpu.ops import attention
+    from accelerate_tpu.serving import cache_regime, engine as engine_module
+
+    if not by_block:
+        for module in (attention, cache_regime):
+            monkeypatch.setattr(
+                module, "block_write_eligible", lambda state, s: False)
+    seen, real = [], engine_module.sample_tokens
+
+    def sample(logits, *a, **kw):
+        jax.debug.callback(lambda x: seen.append(np.asarray(x)), logits,
+                           ordered=True)
+        return real(logits, *a, **kw)
+
+    monkeypatch.setattr(engine_module, "sample_tokens", sample)
+    tiny, weights, seed, block = TINY[regime]
+    params = weights.make_tree(tiny.config(), seed, jnp.float32)
+    eng = ServingEngine(_model(regime), params, max_slots=2, block_size=block)
+    prompt = np.random.default_rng(5).integers(
+        0, eng.model.config.vocab_size, 2 * block + 3).astype(np.int32)
+    rid = eng.add_request(prompt, max_new_tokens=block + 2)
+    while eng.has_work:
+        eng.step()
+    jax.effects_barrier()
+    assert len(eng.result(rid)) == block + 2
+    return [x[0] for x in seen], eng.trace_counts()
+
+
+@pytest.mark.parametrize("regime", ["recurrent", "latent", "ring"])
+def test_decode_after_a_block_written_prompt_reads_no_padded_row(
+        regime, monkeypatch):
+    """A prompt of ``2 blocks + 3`` in a bucket of 4: the block write leaves
+    the bucket's padded positions in the rest of the slot's third block. The
+    decode steps that follow, across that block's end, sample from the logits
+    of the tree in which every call keeps the row scatter."""
+    got, counts = _served_logits(regime, monkeypatch, True)
+    assert counts["kv_block_write"] == counts["prefill"] == 1
+    want, rows = _served_logits(regime, monkeypatch, False)
+    assert rows["kv_block_write"] == 0 and rows["prefill"] == 1
+    assert len(got) == len(want) > TINY[regime][3]
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)

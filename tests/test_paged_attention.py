@@ -351,6 +351,59 @@ def test_decode_kernel_compiles_for_v5e_with_no_copy_of_the_pools(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+# a fresh prefill's write at the shapes of the three cells that make one:
+# (KV heads, row width, slots, ring) over 16,384 positions a slot in blocks
+# of 16 — SmallThinker's full and window layers, Qwen3-Next's attention
+# layer, DeepSeek-V3's latent row
+_FRESH_WRITES = {"full-4x128": (4, 128, 32, 0), "ring-4x128": (4, 128, 32, 4096),
+                 "full-2x256": (2, 256, 64, 0), "latent-640": (0, 640, 32, 0)}
+
+
+@pytest.mark.parametrize("case", sorted(_FRESH_WRITES))
+def test_the_block_write_compiles_for_v5e_in_place_with_no_copy_of_a_pool(
+        one_chip, case):
+    """An 8,192-wide fresh call into DONATED pools of the cell's size: the
+    compiled program aliases every pool to its output and holds under a
+    hundredth of one pool in temporaries — no relayout of a pool for the
+    write, which an update indexed past the major axis draws (``put``'s
+    comment in ``paged_update``)."""
+    from accelerate_tpu.ops.attention import (
+        latent_update, paged_update, pool_heads_first)
+
+    hkv, d, slots, ring = _FRESH_WRITES[case]
+    bs, ctx, width = 16, 16384, 8192
+    heads_first = bool(hkv) and pool_heads_first(hkv, d)
+    blocks = slots * ((ring or ctx) // bs) + 1
+    row = (hkv, d) if hkv else (d,)
+    block = (hkv, bs, d) if heads_first else (bs, *row)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = [sds((blocks, *block), jnp.bfloat16)] * (2 if hkv else 1)
+    rows = [sds((1, width, *row), jnp.bfloat16)] * len(pools)
+
+    def write(*args):
+        *arrays, table, lengths, slot = args
+        state = PagedKVState(
+            block_table=table, cache_len=jnp.zeros_like(lengths),
+            lengths=lengths, num_blocks=slots * (ctx // bs) + 1, block_size=bs,
+            fresh=True, heads_first=heads_first, ring=ring, num_slots=slots,
+            slot=slot)
+        if not hkv:
+            return latent_update(*arrays, state)
+        return paged_update(*arrays, state, ring=bool(ring))
+
+    compiled = _compiled_for_the_chip(jax.jit(
+        write, donate_argnums=tuple(range(len(pools)))).lower(
+            *pools, *rows, sds((1, ctx // bs), jnp.int32), sds((1,), jnp.int32),
+            sds((1,), jnp.int32)))
+    memory = compiled.memory_analysis()
+    pool_bytes = 2 * blocks * bs * (hkv or 1) * d
+    assert memory.alias_size_in_bytes == len(pools) * pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 100
+
+
 # ---------------------------------------------------------------------- #
 # (c) the engine: same tokens, and the counts say which form ran
 # ---------------------------------------------------------------------- #
@@ -579,6 +632,149 @@ def test_the_paged_operations_take_a_stack_and_a_layer_like_a_pool(what, layer):
                 direct(q, *got_pools, st.block_table, st.cache_len,
                        layer=traced)[:2],
                 direct(q, *want_pools, st.block_table, st.cache_len)[:2])
+
+
+# a fresh call's rows by the BLOCK (PR 46): three seats of eight blocks (a
+# ring: two), the call fills the third and the first, the second is another
+# request's. name -> (bucket, length of the first row of the call)
+_WRITE_SLOTS, _WRITE_RING = 3, 2 * BS
+_BUCKET = 8 * BS
+_BLOCK_WRITES = {
+    "full": {f"L={n}": (_BUCKET, n) for n in (
+        1, BS - 1, BS, BS + 1, _BUCKET - BS + 1, _BUCKET)},
+    "ring": {
+        "L<ring": (_BUCKET, 5), "L==ring": (_BUCKET, _WRITE_RING),
+        # the block of position L - 1 and that of position L - ring are ONE
+        # ring block, filled from both
+        "L>ring,shared-block": (_BUCKET, _WRITE_RING + BS + 3),
+        "L=3ring+5": (_BUCKET, 3 * _WRITE_RING + 5),
+        "bucket<ring": (BS, BS - 2),
+    },
+}
+_BLOCK_WRITES["latent"] = _BLOCK_WRITES["full"]
+
+
+@pytest.mark.parametrize("heads_first,kind,case", [
+    pytest.param(heads, kind, case, id=f"{layout}-{kind}-{case}")
+    for heads, layout in ((False, "token-major"), (True, "heads-first"))
+    for kind in sorted(_BLOCK_WRITES) for case in _BLOCK_WRITES[kind]
+    if not (heads and kind == "latent")])  # a latent row has no heads
+@pytest.mark.parametrize("stacked", [False, True], ids=["own", "stack"])
+def test_a_fresh_call_writes_by_the_block_what_the_row_scatter_writes(
+        stacked, heads_first, kind, case):
+    """``paged_update`` / ``latent_update`` told a call is ``fresh`` leave, on
+    every row a read can reach (a slot's first ``L`` rows; a ring's first
+    ``min(L, ring)``), the bits the row scatter leaves, and every block
+    outside the call's own seats — the other request's, the garbage block,
+    the other layers of a stack — as it was."""
+    from accelerate_tpu.ops.attention import (
+        block_write_eligible, latent_update, paged_update)
+
+    s, first = _BLOCK_WRITES[kind][case]
+    lengths = [first, max(1, s // 2 - 3)]
+    rng = np.random.default_rng(46)
+    per, ring = _BUCKET // BS, kind == "ring"
+    held = _WRITE_RING // BS if ring else per
+    seats = np.asarray([2, 0], np.int32)
+    nb = _WRITE_SLOTS * per + 1
+    # a full layer's table: the seats' blocks drawn in no order; a ring's
+    # blocks are the seat's own run
+    table = rng.permutation(np.arange(1, nb)).reshape(_WRITE_SLOTS, per)
+    owned = (1 + np.arange(_WRITE_SLOTS * held).reshape(_WRITE_SLOTS, held)
+             if ring else table)
+    blocks = _WRITE_SLOTS * held + 1
+    row = (D,) if kind == "latent" else (HKV, D)
+    block = (HKV, BS, D) if heads_first else (BS, *row)
+    layers, layer = 3, 1
+    shape = ((layers,) if stacked else ()) + (blocks, *block)
+    pools = [jnp.asarray(rng.standard_normal(shape), jnp.float32)
+             for _ in range(1 if kind == "latent" else 2)]
+    rows = [jnp.asarray(rng.standard_normal((2, s, *row)), jnp.float32)
+            for _ in pools]
+
+    def write(fresh):
+        st = PagedKVState(
+            block_table=jnp.asarray(table[seats], jnp.int32),
+            cache_len=jnp.zeros(2, jnp.int32),
+            lengths=jnp.asarray(lengths, jnp.int32), num_blocks=nb,
+            block_size=BS, fresh=fresh, heads_first=heads_first,
+            ring=_WRITE_RING, num_slots=_WRITE_SLOTS, slot=jnp.asarray(seats))
+        assert block_write_eligible(st, s) == fresh
+        at = jnp.asarray(layer, jnp.int32) if stacked else None
+        if kind == "latent":
+            return [latent_update(pools[0], rows[0], st, layer=at)]
+        return list(paged_update(*pools, *rows, st, layer=at, ring=ring))
+
+    for before, by_row, by_block in zip(pools, write(False), write(True)):
+        assert by_block.shape == before.shape
+        if stacked:
+            others = np.asarray([i for i in range(layers) if i != layer])
+            np.testing.assert_array_equal(by_block[others], before[others])
+            before, by_row, by_block = (
+                x[layer] for x in (before, by_row, by_block))
+        if heads_first:
+            before, by_row, by_block = (
+                jnp.swapaxes(x, 1, 2) for x in (before, by_row, by_block))
+        for seat, n in zip(seats, lengths):
+            reach = min(n, _WRITE_RING) if ring else n
+            np.testing.assert_array_equal(
+                np.asarray(by_block)[owned[seat]].reshape(-1, *row)[:reach],
+                np.asarray(by_row)[owned[seat]].reshape(-1, *row)[:reach])
+        untouched = np.setdiff1d(np.arange(blocks), owned[seats].ravel())
+        np.testing.assert_array_equal(
+            np.asarray(by_block)[untouched], np.asarray(before)[untouched])
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["full", "ring"])
+@pytest.mark.parametrize("form", ["gather", "kernel"])
+def test_a_decode_step_reads_nothing_of_a_prompts_padded_last_block(form, ring):
+    """The block form writes the prompt's last, partial block WHOLE: its rows
+    past the length hold the padded positions' K/V (here 1e4: a masked
+    column's weight is an exact zero, so a finite value cannot show where no
+    read takes it) inside the slot's own block. The decode steps that follow
+    overwrite them in order and read none: their attention is the row
+    scatter's, bit for bit."""
+    from accelerate_tpu.ops.attention import paged_update
+
+    rng = np.random.default_rng(47)
+    s, length, window = 4 * BS, 2 * BS + 3, 2 * BS
+    nb = 2 * MAX_BLOCKS + 1
+    table = rng.permutation(np.arange(1, nb)).reshape(2, MAX_BLOCKS)[:1]
+    blocks = 2 * (window // BS) + 1 if ring else nb
+    pools = [jnp.zeros((blocks, HKV, BS, D), jnp.float32) for _ in range(2)]
+    k, v = (jnp.asarray(rng.standard_normal((1, s, HKV, D)), jnp.float32)
+            .at[:, length:].set(1e4) for _ in range(2))
+
+    def state(fresh, cache_len, lengths):
+        return PagedKVState(
+            block_table=jnp.asarray(table, jnp.int32),
+            cache_len=jnp.asarray([cache_len], jnp.int32),
+            lengths=jnp.asarray([lengths], jnp.int32), num_blocks=nb,
+            block_size=BS, fresh=fresh, heads_first=True, ring=window,
+            num_slots=2, slot=jnp.asarray([1], jnp.int32),
+            single_device=form == "kernel")
+
+    def served(fresh):
+        kp, vp = paged_update(*pools, k, v, state(fresh, 0, length), ring=ring)
+        outs = []
+        for step in range(BS):  # across the partial block's end
+            st = state(False, length + step, 1)
+            new = [jnp.asarray(rng.standard_normal((1, 1, HKV, D)), jnp.float32)
+                   for _ in range(3)]
+            kp, vp = paged_update(kp, vp, new[0], new[1], st, ring=ring)
+            assert decode_kernel_eligible(st, 1, kp) == (form == "kernel")
+            outs.append(paged_attention(
+                jnp.repeat(new[2], 2, axis=2), kp, vp, st, ring=ring))
+        return np.asarray(jnp.stack(outs))
+
+    with (kernel_interpret_mode() if form == "kernel"
+          else contextlib.nullcontext()):
+        rng = np.random.default_rng(48)
+        by_block = served(True)
+        rng = np.random.default_rng(48)
+        by_row = served(False)
+    assert np.abs(by_block).max() < 10.0
+    np.testing.assert_array_equal(by_block, by_row)
 
 
 def _while_operands(cfg):

@@ -672,7 +672,8 @@ def test_the_engines_programs_scatter_no_rows_of_an_expert_layer(
     ``jit__decode``, lowered: the expert layers bring their rows back into
     token order by a gather (``ops.moe._to_tokens``) and a sum over the choices,
     so the only ``stablehlo.scatter``s of whole rows are the K/V write's two
-    (``attn/kv_write``: rows of ``head_dim`` into the paged pools);
+    (``attn/kv_write``: rows of ``head_dim`` into the paged pools, which a
+    fresh prefill writes a block of 4 positions an update, PR 46);
     ``bincount``'s and ``moe_experts_touched``'s are scalar."""
     eng = ServingEngine(_model(), params, max_slots=2, block_size=4)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
@@ -690,7 +691,8 @@ def test_the_engines_programs_scatter_no_rows_of_an_expert_layer(
     hidden, rows = CFG["hidden_size"], 32 if program == "prefill" else n
     picked = f"tensor<{CFG['num_experts_per_tok']}x{rows}x{hidden}xf32>"
     assert picked in text  # the gather: a row a choice, (k, T, hidden)
-    kv_row = f"{rows}x{CFG['num_key_value_heads']}x{CFG['head_dim']}xf32"
+    kv_row = f"{CFG['num_key_value_heads']}x{CFG['head_dim']}xf32"
+    kv_row = (f"{rows // 4}x4x" if program == "prefill" else f"{rows}x") + kv_row
     assert [update for _, update in row_scatters(text, CFG["head_dim"])] == [kv_row] * 2
 
 
