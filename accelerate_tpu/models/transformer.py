@@ -965,8 +965,10 @@ class Mamba2(nn.Module):
     training and evaluation path only. ``init`` draws ``A_log`` = log U(1, 16),
     ``D`` = 1 and ``dt_bias`` as the family does (softplus(dt_bias) log-uniform
     in [1e-3, 0.1], floored at 1e-4). Sown under ``intermediates``:
-    ``ssm_delta_mean`` and ``ssm_chunk_decay_min`` (the scan's underflow
-    gauge, ``ops.ssd.chunk_decay_min``)."""
+    ``ssm_delta_mean``, ``ssm_chunk_decay_min`` (the scan's underflow
+    gauge, ``ops.ssd.chunk_decay_min``) and ``ssm_scan_kernel`` (1.0 where
+    the scan lowered to the kernels, as ``ops.ssd.ssd_chunked`` says of the
+    call itself; 0.0 where to the ``jax.numpy`` form)."""
 
     config: TransformerConfig
 
@@ -1000,13 +1002,14 @@ class Mamba2(nn.Module):
         with jax.named_scope("scan"):
             delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
             a = -jnp.exp(a_log)
-            y = ssd_chunked(
+            y, kernels = ssd_chunked(
                 x, delta, a, b_mat.reshape(bsz, s, groups, n),
                 c_mat.reshape(bsz, s, groups, n), cfg.mamba_chunk_size,
-                skip=skip)
+                skip=skip, with_form=True)
         self.sow("intermediates", "ssm_delta_mean", jnp.mean(delta))
         self.sow("intermediates", "ssm_chunk_decay_min",
                  chunk_decay_min(delta, a, cfg.mamba_chunk_size))
+        self.sow("intermediates", "ssm_scan_kernel", jnp.float32(kernels))
         with jax.named_scope("gate_norm"):
             scale = vector("norm", nn.initializers.ones_init(), d)
             g = (y.reshape(bsz, s, d) * nn.silu(z)).astype(jnp.float32)

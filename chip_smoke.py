@@ -500,6 +500,40 @@ def gdn_case(say, dry: bool) -> None:
         assert not bool(jnp.any(o[:, length:] != 0)), "rows past the length"
 
 
+def ssd_case(say, dry: bool) -> None:
+    """The chunked state-space scan as the ``ssd_chunked_fwd`` /
+    ``ssd_chunked_bwd`` kernels against the ``jax.numpy`` form of the same
+    scan, at the heads of ``train-ssm-moe-1chip`` (64 heads of 64 in 8 groups
+    of state 128, chunks of 128, bfloat16 operands), 2 rows of 2,048: ``y``
+    and all six gradients within the microbenchmark's gap
+    (``ssd_scan_on_chip.GAP``, each over its own largest entry)."""
+    import jax.numpy as jnp
+    from ssd_scan_on_chip import GAP, forms, operands, relative
+
+    from accelerate_tpu.ops import ssd
+
+    rows, seq, heads, p, groups, n, chunk = (
+        (2, 24, 8, 4, 2, 8, 8) if dry else (2, 2048, 64, 64, 8, 128, 128))
+    assert ssd.ssd_kernel_eligible(heads, p, groups, n, chunk)
+    ops = operands(rows, seq, heads, p, groups, n, SEED + 7,
+                   jnp.float32 if dry else jnp.bfloat16)
+    both = forms(heads, p, groups, n, chunk)
+    name = (f"ssd {ops[0].dtype} {rows} x {seq}, {heads} heads of {p} in "
+            f"{groups} groups of state {n}")
+    y = run_compiled(say, name + " fwd", both["kernel"][0], ops,
+                     expect_mosaic=1, dry=dry)
+    grads = run_compiled(say, name + " grad", both["kernel"][1], ops,
+                         expect_mosaic=2, dry=dry)
+    want_y, want = both["jnp"][0](*ops), both["jnp"][1](*ops)
+    gaps = {"y": relative(y, want_y), **{
+        "d" + k: relative(g, w)
+        for k, g, w in zip(("x", "delta", "a", "B", "C", "D"), grads, want)}}
+    say(f"kernel {name}: widest difference over the largest entry "
+        + " ".join(f"{k}={e:.2e}" for k, e in gaps.items())
+        + f" (bound {GAP:.0e})")
+    assert all(e <= GAP for e in gaps.values()), gaps
+
+
 def kernel_phase(say, sz: Sizes, dry: bool) -> None:
     import contextlib
 
@@ -522,6 +556,7 @@ def kernel_phase(say, sz: Sizes, dry: bool) -> None:
         adamw_case(say, sz, dry)
         paged_decode_case(say, sz, dry)
         gdn_case(say, dry)
+        ssd_case(say, dry)
     say("kernel phase PASSED")
 
 

@@ -5,6 +5,7 @@ normal path, held against the benchmark's plain float32 reference
 the recurrence itself) on seeded weights (``nemotron_h_weights.py``), at
 widths the CPU can hold."""
 
+import hashlib
 import json
 import os
 import re
@@ -31,6 +32,7 @@ from accelerate_tpu.models import CausalLM, TransformerConfig  # noqa: E402
 from accelerate_tpu.models.transformer import (  # noqa: E402
     Attention, Mamba2, MoE, layer_kinds, plan_layers)
 from accelerate_tpu.ops import ssd  # noqa: E402
+from accelerate_tpu.ops.flash_attention import kernel_interpret_mode  # noqa: E402
 from accelerate_tpu.ops.moe import moe_ragged, share_window_rows  # noqa: E402
 
 SEED = 2**31 + 7
@@ -250,6 +252,193 @@ def test_a_chunk_that_forgets_everything_underflows_to_zero_and_stays_finite():
     assert 0.0 < float(ssd.chunk_decay_min(delta * 0.01, a, 8)) < 1.0
 
 
+# the chunked scan as two kernels (``ssd_chunked_fwd`` / ``ssd_chunked_bwd``),
+# interpreted at tiles of 8: several heads a group two to a tile (heads of 4),
+# several heads a group one to a tile (heads of 8), one head a group
+_KERNEL_GROUPS = {"two_heads_a_tile": (8, 4, 2), "a_head_a_tile": (4, 8, 2),
+                  "one_head_a_group": (2, 8, 2)}
+
+
+def _kernel_operands(seq, group, dtype, seed=0):
+    heads, p, g = _KERNEL_GROUPS[group]
+    x, delta, a, b_mat, c_mat = _scan_operands(seq, seed=seed, heads=heads, p=p, g=g)
+    skip = 1.0 + 0.5 * jax.random.normal(jax.random.PRNGKey(seed + 7), (heads,))
+    return (x.astype(dtype), delta, a, b_mat.astype(dtype), c_mat.astype(dtype),
+            skip)
+
+
+def _recurrence_with_skip(x, delta, a, b_mat, c_mat, skip):
+    """The reference's position-by-position scan plus ``D x``, float32."""
+    x, b_mat, c_mat = (t.astype(jnp.float32) for t in (x, b_mat, c_mat))
+    return (_reference_recurrence(x, delta, a, b_mat, c_mat)
+            + x * skip[:, None])
+
+
+def _value_and_grads(fn, ops):
+    def loss(*o):
+        y = fn(*o).astype(jnp.float32)
+        return jnp.sum(jnp.sin(y)), y
+
+    with HIGHEST:
+        (_, y), grads = jax.value_and_grad(
+            loss, argnums=tuple(range(6)), has_aux=True)(*ops)
+    return y, grads
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("seq", [32, 29], ids=["whole_chunks", "ragged_tail"])
+@pytest.mark.parametrize("group", sorted(_KERNEL_GROUPS))
+def test_the_scan_kernels_equal_the_recurrence_and_the_jax_numpy_form(
+        group, seq, dtype):
+    """``y`` and the gradients of ``x``, ``delta``, ``a``, ``B``, ``C`` and
+    ``D`` out of the interpreted kernels, against the explicit recurrence and
+    against the ``jax.numpy`` form (in bfloat16 both forms round their
+    operands to it and sum in float32: the recurrence, float32 throughout on
+    the same rounded operands, is a rounding's distance from either)."""
+    ops = _kernel_operands(seq, group, dtype)
+    heads, p, g = _KERNEL_GROUPS[group]
+    with kernel_interpret_mode():
+        assert ssd.ssd_kernel_eligible(heads, p, g, 8, 8)
+        got_y, got = _value_and_grads(
+            lambda *o: ssd.ssd_chunked(*o[:5], 8, skip=o[5]), ops)
+    assert got_y.shape == ops[0].shape
+    jnp_y, jnp_grads = _value_and_grads(
+        lambda *o: ssd._chunked_reference(*o[:5], 8, skip=o[5]), ops)
+    plain_y, plain = _value_and_grads(_recurrence_with_skip, ops)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(
+            got_y, _explicit(*ops[:5]) + np.asarray(ops[0] * ops[5][:, None]),
+            atol=2e-5, rtol=2e-5)
+    # bfloat16: 2 ** -8 a rounding; the two forms round the same operands in
+    # other places (3 of them: over seeds 0-2 and these six cases the widest
+    # read 9.9e-3, ``dA``; at the cell's size on the chip 7.8e-3, ``dx``:
+    # PERF.md section 6, PR 47), the float32 recurrence rounds none (20)
+    near, far = (2e-4, 2e-4) if dtype == jnp.float32 else (1.2e-2, 8e-2)
+    for name, a_, b_, c_ in zip(("y", "x", "delta", "a", "B", "C", "D"),
+                                (got_y,) + got, (jnp_y,) + jnp_grads,
+                                (plain_y,) + plain):
+        a_, b_, c_ = (np.asarray(t, np.float32) for t in (a_, b_, c_))
+        scale = np.abs(c_).max() + 1e-6
+        assert np.abs(a_ - c_).max() <= far * scale, (name, "recurrence")
+        assert np.abs(a_ - b_).max() <= near * scale, (name, "jax.numpy form")
+    assert all(g_.dtype == o.dtype for g_, o in zip(got, ops))
+
+
+def test_the_scan_kernels_take_no_skip_and_differentiate_under_jit():
+    ops = _kernel_operands(24, "two_heads_a_tile", jnp.float32, seed=2)[:5]
+
+    def loss(fn):
+        return lambda *o: jnp.sum(jnp.sin(fn(*o, 8)))
+
+    with kernel_interpret_mode(), HIGHEST:
+        got = jax.jit(jax.grad(loss(ssd.ssd_chunked), argnums=(0, 1, 2, 3, 4)))(*ops)
+    with HIGHEST:
+        want = jax.grad(loss(ssd._chunked_reference), argnums=(0, 1, 2, 3, 4))(*ops)
+    for g_, w_ in zip(got, want):
+        np.testing.assert_allclose(g_, w_, atol=1e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("group", ["two_heads_a_tile", "one_head_a_group"])
+def test_the_kernels_chunk_that_forgets_everything_is_an_exact_zero_and_finite(group):
+    """``exp`` of a whole chunk's log-decay underflows to an exact 0.0 in the
+    kernels too: a position reads nothing of the chunks before its own, and
+    both passes stay finite (the differences are masked before their
+    ``exp``, and ``exp(total - a_j)`` never exceeds 1)."""
+    x, delta, a, b_mat, c_mat, skip = _kernel_operands(32, group, jnp.float32)
+    delta, a = delta * 40.0, a * 40.0
+    assert float(ssd.chunk_decay_min(delta, a, 8)) == 0.0
+
+    def run(x):
+        return ssd.ssd_chunked(x, delta, a, b_mat, c_mat, 8, skip=skip)
+
+    with kernel_interpret_mode(), HIGHEST:
+        base = run(x)
+        moved = run(x.at[:, :8].add(1.0))  # the first chunk, whole
+        grads = jax.grad(lambda *o: jnp.sum(jnp.sin(
+            ssd.ssd_chunked(*o[:5], 8, skip=o[5]))), argnums=tuple(range(6)))(
+                x, delta, a, b_mat, c_mat, skip)
+        want = ssd._chunked_reference(x, delta, a, b_mat, c_mat, 8, skip=skip)
+    assert np.all(np.isfinite(np.asarray(base)))
+    np.testing.assert_allclose(base, want, atol=2e-5, rtol=2e-5)
+    # every later chunk forgot the first: not a bit of it is left
+    np.testing.assert_array_equal(base[:, 8:], moved[:, 8:])
+    assert np.abs(np.asarray(moved - base)[:, :8]).max() > 1e-3
+    for g_ in grads:
+        assert np.all(np.isfinite(np.asarray(g_)))
+
+
+# sha256[:16] of what ``ssd_chunked`` lowers to off a TPU at commit 23ee05a,
+# the parent of the PR that brought the kernels: (S, dtype, pass)
+_JNP_LOWERED = {
+    (32, "float32", "fwd"): "3bd95573c8850810",
+    (32, "float32", "grad"): "0fd0f6429024896b",
+    (29, "bfloat16", "fwd"): "2d4383adf65c9bd0",
+    (29, "bfloat16", "grad"): "5920923926d25609",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_JNP_LOWERED), ids=str)
+def test_off_a_tpu_the_scan_lowers_to_the_pinned_jax_numpy_text(case):
+    """No TPU and no interpreter: the predicate is False and the call, and its
+    gradient, lower byte for byte to the einsums they were."""
+    seq, dtype, which = case
+    b, heads, p, g, n = 2, 4, 8, 2, 8
+    assert not ssd.ssd_kernel_eligible(heads, p, g, n, 8)
+    sds = jax.ShapeDtypeStruct
+    ops = (sds((b, seq, heads, p), dtype), sds((b, seq, heads), jnp.float32),
+           sds((heads,), jnp.float32), sds((b, seq, g, n), dtype),
+           sds((b, seq, g, n), dtype), sds((heads,), jnp.float32))
+
+    def fwd(x, delta, a, b_mat, c_mat, skip):
+        return ssd.ssd_chunked(x, delta, a, b_mat, c_mat, 8, skip=skip)
+
+    def total(*o):
+        return jnp.sum(fwd(*o).astype(jnp.float32))
+
+    fn = fwd if which == "fwd" else jax.grad(total, argnums=tuple(range(6)))
+    text = jax.jit(fn).lower(*ops).as_text()
+    assert "custom_call" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == _JNP_LOWERED[case]
+
+
+def test_the_kernel_predicate_reads_the_backend_the_mesh_and_the_shapes():
+    """``ssd_kernel_eligible(heads, p, groups, n, chunk)``: the cell's shapes
+    are taken on a TPU, and only there (or interpreted, at tiles of 8)."""
+    from accelerate_tpu import Accelerator, ParallelismPlugin
+
+    cell = (64, 64, 8, 128, 128)
+    assert not ssd.ssd_kernel_eligible(*cell)  # the CPU, no interpreter
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        assert ssd.ssd_kernel_eligible(*cell)
+        for odd in ((64, 64, 8, 128, 64),  # a chunk of half a tile
+                    (64, 64, 8, 64, 128),  # a state of half the lanes
+                    (64, 64, 64, 128, 128),  # one head of 64 a group: half a tile
+                    (64, 48, 8, 128, 128),  # a head across a tile's edge
+                    (64, 64, 4, 128, 128),  # 16 heads a group: masks past a step's VMEM
+                    (64, 64, 8, 256, 128),  # states past it
+                    (64, 64, 8, 128, 256),  # chunks past it
+                    (64, 64, 0, 128, 128), (64, 64, 7, 128, 128)):
+            assert not ssd.ssd_kernel_eligible(*odd), odd
+        assert ssd.ssd_kernel_eligible(64, 128, 16, 128, 128)  # a head a whole tile
+    with kernel_interpret_mode():
+        assert ssd.ssd_kernel_eligible(4, 8, 2, 8, 8)
+        assert ssd.ssd_kernel_eligible(4, 8, 4, 8, 16)
+        assert not ssd.ssd_kernel_eligible(4, 8, 2, 8, 12)
+        assert not ssd.ssd_kernel_eligible(4, 8, 2, 12, 8)
+        assert not ssd.ssd_kernel_eligible(4, 3, 2, 8, 8)
+        # the call says which form it took: what ``Mamba2`` sows
+        assert ssd.ssd_chunked(*_scan_operands(16), 8, with_form=True)[1] is True
+        Accelerator(parallelism_plugin=ParallelismPlugin(dp_size=2, fsdp_size=4))
+        assert not ssd.ssd_kernel_eligible(4, 8, 2, 8, 8)  # a live mesh
+        # and the call itself then takes the jax.numpy form
+        ops = _scan_operands(16)
+        text = jax.jit(lambda *o: ssd.ssd_chunked(*o, 8)).lower(*ops).as_text(
+            debug_info=True)
+        assert "ssd_chunked_fwd" not in text
+        assert ssd.ssd_chunked(*ops, 8, with_form=True)[1] is False
+
+
 def _mamba_parts(cfg_dict, seed=1, seq=21):
     cfg = common.program_config(cfg_dict)
     lw = W.layer_slice(W.base_key(SEED), cfg_dict, 0, jnp.float32)
@@ -266,7 +455,9 @@ def test_the_operator_equals_the_reference_and_sows_its_gauges():
         out, sown = op.apply({"params": params}, u, mutable=["intermediates"])
     np.testing.assert_allclose(out, ref.mamba2(u, lw, cfg), atol=2e-5, rtol=2e-4)
     gauges = {k: float(v[0]) for k, v in sown["intermediates"].items()}
-    assert set(gauges) == {"ssm_delta_mean", "ssm_chunk_decay_min"}
+    assert set(gauges) == {"ssm_delta_mean", "ssm_chunk_decay_min",
+                           "ssm_scan_kernel"}
+    assert gauges["ssm_scan_kernel"] == 0.0  # the CPU: the jax.numpy form
     assert 1e-4 < gauges["ssm_delta_mean"] < 0.2  # the seeded dt_bias's range
     assert 0.0 < gauges["ssm_chunk_decay_min"] < 1.0
 
@@ -498,7 +689,14 @@ def test_attention_without_rope_at_sixteen_query_heads_a_kv_head(impl):
 # --------------------------------------------------------------------------- #
 # through the Accelerator, with the counters
 # --------------------------------------------------------------------------- #
-def test_unified_step_trains_the_stack_and_returns_its_counters():
+@pytest.mark.parametrize("kernels", [False, True], ids=["jax_numpy", "kernels"])
+def test_unified_step_trains_the_stack_and_returns_its_counters(kernels):
+    """Over the 8-device mesh the scan is the ``jax.numpy`` form
+    (``ssm_scan_kernel`` 0.0); on one device under the interpreter every
+    Mamba-2 layer's scan is the two kernels, forward, recomputed under the
+    remat and backward (1.0), and the stack trains the same."""
+    import contextlib
+
     import optax
 
     from accelerate_tpu import Accelerator
@@ -506,19 +704,24 @@ def test_unified_step_trains_the_stack_and_returns_its_counters():
     cfg = tiny.config()
     model = _model(cfg, remat="dots_with_no_batch_dims")
     acc = Accelerator()
+    if kernels:
+        acc.reform_mesh(jax.devices()[:1])  # a Mosaic kernel is not partitioned
     params, optimizer = acc.prepare(
         W.make_tree(cfg, SEED, jnp.float32), optax.adamw(1e-3))
     step = acc.unified_step(CausalLM.loss_fn(model, with_aux=True), has_aux=True)
     carry = acc.init_carry(params, optimizer)
     ids = _ids(cfg, rows=4, seq=60, seed=1)
     losses = []
-    for _ in range(4):
-        carry, metrics = step(carry, {"input_ids": ids})
-        losses.append(float(metrics["loss"]))
+    with kernel_interpret_mode() if kernels else contextlib.nullcontext():
+        for _ in range(4):
+            carry, metrics = step(carry, {"input_ids": ids})
+            losses.append(float(metrics["loss"]))
     assert all(np.isfinite(losses)) and losses[-1] < losses[0]
     aux = {k: float(v) for k, v in metrics["aux"].items()}
-    assert {"ssm_delta_mean", "ssm_chunk_decay_min", "moe_local_choice_share",
-            "moe_rest_window_share", "moe_rows_computed_over_needed"} <= set(aux)
+    assert {"ssm_delta_mean", "ssm_chunk_decay_min", "ssm_scan_kernel",
+            "moe_local_choice_share", "moe_rest_window_share",
+            "moe_rows_computed_over_needed"} <= set(aux)
+    assert aux["ssm_scan_kernel"] == float(kernels)
     assert 0.0 < aux["ssm_chunk_decay_min"] <= 1.0 and aux["ssm_delta_mean"] > 0
     assert 0.0 < aux["moe_local_choice_share"] < 1.0
     assert step.detector.retraces == 0 if hasattr(step, "detector") else True
@@ -637,6 +840,29 @@ def test_the_per_layer_metrics_find_their_scope_paths():
         fn = cells.named(_metric_args(name)["work"])
         need = fn(tiny.real(), {"tokens_per_step_per_chip": 16384, "seq_len": 8192})
         assert need["flops"] > 0 and need["bytes"] > 0
+    # where the scan is the two kernels (PR 47) both calls lie under the same
+    # scope in all three passes — the forward, the forward again under the
+    # remat, the backward —, so both scan metrics go on reading the work
+    with kernel_interpret_mode():
+        lowered = jax.jit(jax.grad(CausalLM.loss_fn(model))).lower(
+            params, {"input_ids": ids}).as_text(debug_info=True)
+    calls = {raw for raw in re.findall(r'loc\("([^"]+/pallas_call)"', lowered)
+             if "ssd_chunked" in raw}
+    for layer in ("layer_4/", "layer_7/", "/b0/"):
+        mine = sorted(raw for raw in calls if layer in raw)
+        for kernel, passes in (("ssd_chunked_fwd", [
+                lambda r: "rematted_computation" not in r,
+                lambda r: "rematted_computation" in r]),
+                               ("ssd_chunked_bwd", [lambda r: "checkpoint/" in r])):
+            for which in passes:
+                hit = [r for r in mine if kernel in r and which(r)]
+                assert hit, (layer, kernel, mine)
+                for raw in hit:
+                    cleaned = program_trace.scope_of(raw, "CausalLM")
+                    assert cleaned.endswith(f"ssm/scan/{kernel}/pallas_call"), raw
+                    assert scan.search(cleaned) and re.search(
+                        _metric_args("ssm_device_share.train")["scope"], cleaned)
+    assert not [r for r in calls if "intra" in r or "states" in r or "inter/" in r]
 
 
 def test_the_flash_share_reads_the_flash_kernels_and_counts_one_attention_layer():
